@@ -54,6 +54,12 @@ def main(argv: list[str] | None = None) -> int:
     from distributed_deep_q_tpu.parallel.multihost import initialize_multihost
     initialize_multihost(cfg.mesh)
 
+    # persistent compile cache, before first backend use: without it every
+    # cold run recompiles each fused program pair
+    from distributed_deep_q_tpu.utils.compile_cache import (
+        place_compile_cache)
+    place_compile_cache()
+
     # Import past flag parsing so --help never initializes JAX backends.
     from distributed_deep_q_tpu.metrics import Metrics
     from distributed_deep_q_tpu.train import evaluate, train_single_process

@@ -8,20 +8,24 @@ implementation compiled on first use with the baked-in g++ toolchain
 
 ``load()`` returns the ctypes lib or None (missing compiler, failed build);
 callers fall back to the numpy implementation, which remains the semantic
-reference. The build is cached next to the source and rebuilt only when
-``replay_core.cpp`` is newer than the cached ``.so``.
+reference. The artifact is named by a hash of ``replay_core.cpp``, so what
+loads was always built from the source that sits beside it — a stale or
+foreign ``.so`` that rides along in a copied tree (mtimes do not survive a
+copy) simply never matches. ``backend()`` says which implementation ran.
 """
 
 from __future__ import annotations
 
 import ctypes
+import functools
+import glob
+import hashlib
 import os
 import subprocess
 import threading
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "replay_core.cpp")
-_SO = os.path.join(_HERE, "_replay_core.so")
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
@@ -33,11 +37,21 @@ _c_u8p = ctypes.POINTER(ctypes.c_uint8)
 _c_u8pp = ctypes.POINTER(_c_u8p)
 
 
-def _build() -> bool:
+@functools.cache
+def _artifact() -> str:
+    """Path of the shared object for the source this process started
+    with (hashed once)."""
+    with open(_SRC, "rb") as fh:
+        digest = hashlib.sha256(fh.read()).hexdigest()[:16]
+    return os.path.join(_HERE, f"_replay_core.{digest}.so")
+
+
+def _build(so: str) -> bool:
     """Compile to a process-unique temp path, then rename into place —
     atomic on POSIX, so concurrent builders (supervisor-spawned actor
-    processes all importing replay) can never leave a half-written .so."""
-    tmp = f"{_SO}.{os.getpid()}.tmp"
+    processes all importing replay) can never leave a half-written .so.
+    Artifacts of other source revisions are removed afterwards."""
+    tmp = f"{so}.{os.getpid()}.tmp"
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
            "-o", tmp, _SRC]
     try:
@@ -45,36 +59,41 @@ def _build() -> bool:
             # gate: _lock exists to make the first caller compile while
             # the rest wait; nothing hot shares this module lock
             cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, _SO)
-        return True
+        os.replace(tmp, so)
     except (OSError, subprocess.SubprocessError):
         try:
             os.unlink(tmp)
         except OSError:
             pass
         return False
+    for old in glob.glob(os.path.join(_HERE, "_replay_core*.so")):
+        if old != so:
+            try:
+                os.unlink(old)
+            except OSError:
+                pass
+    return True
 
 
 def load() -> ctypes.CDLL | None:
     """Build (if needed) and load the native core; None on any failure."""
     global _lib, _tried
+    so = _artifact()  # reads the source: off-lock, same answer for all
     with _lock:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        stale = (not os.path.exists(_SO)
-                 or os.path.getmtime(_SO) < os.path.getmtime(_SRC))
-        if stale and not _build():
+        if not os.path.exists(so) and not _build(so):
             return None
         try:
-            lib = ctypes.CDLL(_SO)
+            lib = ctypes.CDLL(so)
         except OSError:
             # cached artifact unloadable (foreign arch, corrupt file):
             # rebuild once before giving up
-            if not _build():
+            if not _build(so):
                 return None
             try:
-                lib = ctypes.CDLL(_SO)
+                lib = ctypes.CDLL(so)
             except OSError:
                 return None
         lib.st_set.argtypes = [_c_double_p, ctypes.c_int64, _c_int64_p,
@@ -90,6 +109,12 @@ def load() -> ctypes.CDLL | None:
         lib.staged_append.restype = ctypes.c_int64
         _lib = lib
         return _lib
+
+
+def backend() -> str:
+    """Which replay core this process runs: ``"native"`` (the C++ ``.so``
+    built from ``replay_core.cpp``) or ``"numpy"`` (the fallback)."""
+    return "native" if load() is not None else "numpy"
 
 
 def as_double_p(a) -> _c_double_p:

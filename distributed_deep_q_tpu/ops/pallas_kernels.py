@@ -11,23 +11,20 @@ the whole loss is a single fused region in both directions
 
 Enabled with ``TrainConfig.use_pallas_loss``; the learner falls back to the
 jnp path otherwise (both are tested for equivalence in
-``tests/test_pallas.py``). On non-TPU backends the kernel runs in Pallas
-interpret mode so the same code path is testable on the CPU mesh.
+``tests/test_pallas.py``). ``interpret`` comes from the caller, decided
+once per mesh by ``parallel.mesh.pallas_interpret`` — the same rule the
+ring DMA kernels follow — so the kernel is Mosaic-compiled on a TPU mesh
+and interpreted on the CPU test mesh.
 
 Shapes are the per-device view inside ``shard_map``: ``q`` is [B, A] with B
 the per-device batch. Everything fits in VMEM by construction (B ≤ a few
 hundred, A ≤ 18), so there is no grid — one program, full blocks, which is
 exactly the right schedule for a loss tail this small.
 
-MEASUREMENT: bench.py times this kernel against the XLA-fused jnp path
-every run (``pallas_on_steps_per_s`` vs ``pallas_off_steps_per_s``) so the
-claim is re-made per hardware, not asserted here — early v5e runs landed
-on both sides of parity depending on chip contention, i.e. the two paths
-are close (XLA already fuses this loss tail well; SURVEY §2.1's "Pallas
-only where XLA fusion is insufficient" holds in the sense that neither
-side is decisively faster). The kernel ships default OFF
-(``use_pallas_loss=False``) as the tested hand-written-kernel path;
-consult the current BENCH json before flipping the default.
+MEASUREMENT: not measured on today's code. Whether the kernel beats the
+XLA-fused jnp path on the chip is a question for the benchmark; it ships
+default OFF (``use_pallas_loss=False``) as the tested hand-written-kernel
+path.
 """
 
 from __future__ import annotations
@@ -39,11 +36,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-
-def _interpret() -> bool:
-    """Compile for real TPUs, interpret everywhere else (CPU test mesh)."""
-    return jax.default_backend() != "tpu"
 
 
 def _huber_pieces(td: jax.Array, delta: float):
@@ -80,19 +72,20 @@ def _bwd_kernel(q_ref, a_ref, t_ref, w_ref, g_ref, dq_ref, *, delta: float):
     dq_ref[:] = onehot * coeff
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def fused_dqn_loss(q, actions, targets, weights, delta: float = 1.0):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def fused_dqn_loss(q, actions, targets, weights, delta: float = 1.0,
+                   interpret: bool = False):
     """Fused masked-Huber TD loss (Pallas). Same contract as
     ``ops.losses.dqn_loss``: returns (scalar loss, |TD| [B]).
 
     ``targets``/``weights`` are treated as constants (no gradient), matching
     the stop-gradient semantics of the jnp path.
     """
-    loss, td_abs = _call_fwd(q, actions, targets, weights, delta)
+    loss, td_abs = _call_fwd(q, actions, targets, weights, delta, interpret)
     return loss, td_abs
 
 
-def _call_fwd(q, actions, targets, weights, delta):
+def _call_fwd(q, actions, targets, weights, delta, interpret):
     b, _ = q.shape
     a2 = actions.astype(jnp.int32).reshape(b, 1)
     t2 = targets.astype(q.dtype).reshape(b, 1)
@@ -108,17 +101,17 @@ def _call_fwd(q, actions, targets, weights, delta):
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.VMEM),
         ),
-        interpret=_interpret(),
+        interpret=interpret,
     )(q, a2, t2, w2)
     return loss[0, 0], td[:, 0]
 
 
-def _fwd_rule(q, actions, targets, weights, delta):
-    out = _call_fwd(q, actions, targets, weights, delta)
+def _fwd_rule(q, actions, targets, weights, delta, interpret):
+    out = _call_fwd(q, actions, targets, weights, delta, interpret)
     return out, (q, actions, targets, weights)
 
 
-def _bwd_rule(delta, residuals, cotangents):
+def _bwd_rule(delta, interpret, residuals, cotangents):
     q, actions, targets, weights = residuals
     g_loss, _ = cotangents  # td_abs output carries no gradient (|TD| is
     #                         stop-gradient by contract, like the jnp path)
@@ -133,7 +126,7 @@ def _bwd_rule(delta, residuals, cotangents):
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * 4
         + [pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        interpret=_interpret(),
+        interpret=interpret,
     )(q, a2, t2, w2, g2)
     # int actions take a float0 cotangent; targets/weights are constants
     da = np.zeros(actions.shape, jax.dtypes.float0)

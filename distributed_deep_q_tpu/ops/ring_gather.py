@@ -54,17 +54,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _side_effect_params():
-    """``compiler_params`` marking the kernel side-effecting, in whichever
-    spelling this jax takes: ``pltpu.CompilerParams(has_side_effects=...)``
-    (new), ``TPUCompilerParams`` (mid), or the ``{"mosaic": {...}}`` dict
-    (old, where the dataclass lacks the field)."""
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    try:
-        return cls(has_side_effects=True)
-    except TypeError:
-        return dict(mosaic=dict(has_side_effects=True))
-
 # 1-D int32 arrays tile at 1024 elements (4096 B) on TPU (Mosaic requires
 # dynamic slice starts/sizes provably divisible by the tile) — all row
 # strides here must be multiples of this.
@@ -134,7 +123,7 @@ def gather_windows(idx: jax.Array, ring: jax.Array, *, n: int, w: int,
         kernel,
         out_shape=jax.ShapeDtypeStruct((n * wsz,), jnp.int32),
         grid_spec=grid_spec,
-        compiler_params=_side_effect_params(),
+        compiler_params=pltpu.CompilerParams(has_side_effects=True),
         interpret=interpret,
     )(idx.astype(jnp.int32), ring)
 
@@ -167,6 +156,6 @@ def scatter_rows(src_idx: jax.Array, dst_idx: jax.Array, staged: jax.Array,
         out_shape=jax.ShapeDtypeStruct(ring.shape, jnp.int32),
         grid_spec=grid_spec,
         input_output_aliases={3: 0},  # indexes include the scalar operands
-        compiler_params=_side_effect_params(),
+        compiler_params=pltpu.CompilerParams(has_side_effects=True),
         interpret=interpret,
     )(src_idx.astype(jnp.int32), dst_idx.astype(jnp.int32), staged, ring)

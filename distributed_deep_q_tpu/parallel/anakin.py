@@ -60,11 +60,10 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from distributed_deep_q_tpu import learning, tracing
-from distributed_deep_q_tpu.compat import shard_map
 from distributed_deep_q_tpu.config import Config
 from distributed_deep_q_tpu.models.qnet import stacked_q_apply
 from distributed_deep_q_tpu.ops.jax_envs import make_jax_env
@@ -355,7 +354,7 @@ class AnakinRunner:
                         apply_fn, stacked, batch["obs"], batch["next_obs"],
                         double)
                     loss, td_abs = q_step_loss(cfg_t, q, q_next_o,
-                                               q_next_t, batch)
+                                               q_next_t, batch, interpret)
                     return loss, (td_abs, q)
 
                 (loss, (td_abs, q)), gv = jax.value_and_grad(

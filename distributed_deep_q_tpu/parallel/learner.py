@@ -28,18 +28,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax import lax
+from jax import lax, shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from optax import safe_increment
 
 from distributed_deep_q_tpu import learning, tracing
-from distributed_deep_q_tpu.compat import safe_increment, shard_map
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributed_deep_q_tpu.config import TrainConfig
 from distributed_deep_q_tpu.models.qnet import (
     stacked_q_apply, stacked_q_forwards)
 from distributed_deep_q_tpu.ops.losses import bellman_targets, dqn_loss
 from distributed_deep_q_tpu.parallel.mesh import (
-    AXIS_DP, AXIS_MODEL, tree_shardings)
+    AXIS_DP, AXIS_MODEL, pallas_interpret, tree_shardings)
 from distributed_deep_q_tpu.parallel.multihost import (
     global_batch, put_replicated)
 
@@ -379,10 +379,13 @@ def fused_plane_adam_target_step(
 
 
 def q_step_loss(cfg: TrainConfig, q: jax.Array, q_next_o: jax.Array | None,
-                q_next_t: jax.Array, batch: dict[str, jax.Array]):
+                q_next_t: jax.Array, batch: dict[str, jax.Array],
+                interpret: bool):
     """Bellman targets + (Pallas or XLA) weighted Huber — the loss tail
     shared by the tree-carry and plane-carry step cores, so the two paths
-    can never drift numerically. Returns (loss, |TD|)."""
+    can never drift numerically. ``interpret`` is the mesh's
+    ``pallas_interpret`` verdict (read only when ``use_pallas_loss``).
+    Returns (loss, |TD|)."""
     targets = bellman_targets(batch["reward"], batch["discount"],
                               q_next_t, q_next_o, cfg.double_dqn)
     if cfg.use_pallas_loss:
@@ -390,7 +393,7 @@ def q_step_loss(cfg: TrainConfig, q: jax.Array, q_next_o: jax.Array | None,
             fused_dqn_loss)
         return fused_dqn_loss(q, batch["action"],
                               lax.stop_gradient(targets),
-                              batch["weight"], cfg.huber_delta)
+                              batch["weight"], cfg.huber_delta, interpret)
     return dqn_loss(q, batch["action"], targets, batch["weight"],
                     cfg.huber_delta)
 
@@ -411,6 +414,7 @@ class Learner:
         self.apply_fn = apply_fn
         self.cfg = cfg
         self.mesh = mesh
+        self._interpret = pallas_interpret(mesh)
         self.opt = make_optimizer(cfg)
         self._replicated = NamedSharding(mesh, P())
         self._batch_sharding = NamedSharding(mesh, P(AXIS_DP))
@@ -480,7 +484,8 @@ class Learner:
                     q_next_o = lax.stop_gradient(q_next_o)
                 q_next_t = apply_fn(state.target_params,
                                     batch["next_obs"])
-            loss, td_abs = q_step_loss(cfg, q, q_next_o, q_next_t, batch)
+            loss, td_abs = q_step_loss(cfg, q, q_next_o, q_next_t, batch,
+                                       self._interpret)
             return loss, (td_abs, q)
 
         (loss, (td_abs, q)), grads = jax.value_and_grad(
@@ -713,7 +718,7 @@ class Learner:
                         self.apply_fn, stacked, batch["obs"],
                         batch["next_obs"], cfg.double_dqn)
                     loss, td_abs = q_step_loss(cfg, q, q_next_o,
-                                               q_next_t, batch)
+                                               q_next_t, batch, interpret)
                     return loss, (td_abs, q)
 
                 (loss, (td_abs, q)), gv = jax.value_and_grad(
@@ -783,6 +788,15 @@ class Learner:
             donate_argnums=(0, 4, 5) if donate else ())
         return sample, train
 
+    def device_per_programs(self, spec: tuple, chain: int):
+        """The jitted (sample, train) pair for ``(spec, chain)``, built on
+        first use — by the train loop or by a census taken before it."""
+        key = (spec, chain)
+        if key not in self._device_per_steps:
+            self._device_per_steps[key] = \
+                self._build_device_per_step(spec, chain)
+        return self._device_per_steps[key]
+
     def train_steps_device_per(self, state: TrainState, rows, cursors,
                                sizes, betas: np.ndarray, keys: np.ndarray,
                                spec: tuple):
@@ -792,12 +806,7 @@ class Learner:
         derivation — see ``Solver.train_steps_device_per``). Returns
         (state, new_prio, new_maxp, metrics with a leading [chain] axis).
         """
-        chain = len(betas)
-        cache_key = (spec, chain)
-        if cache_key not in self._device_per_steps:
-            self._device_per_steps[cache_key] = \
-                self._build_device_per_step(spec, chain)
-        sample, train = self._device_per_steps[cache_key]
+        sample, train = self.device_per_programs(spec, len(betas))
 
         def feed(x, dtype=None):
             # host numpy feeds pass through asarray; multi-host global

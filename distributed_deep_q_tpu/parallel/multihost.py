@@ -52,20 +52,20 @@ def initialize_multihost(cfg: MeshConfig) -> None:
     if cfg.num_processes <= 1:
         return
     # NOTE: do not probe jax.process_count() here — it initializes the
-    # backend, which forbids the device-count config updates below. The
-    # distributed client handle is the init-free "already connected?" signal.
-    from jax._src import distributed as _dist
-    if _dist.global_state.client is not None:
+    # backend, which forbids the device-count config updates below.
+    # is_initialized() is the init-free "already connected?" signal.
+    if jax.distributed.is_initialized():
         return  # already connected
     if cfg.backend == "cpu":
         if cfg.num_fake_devices % cfg.num_processes:
             raise ValueError(
                 f"num_fake_devices={cfg.num_fake_devices} must divide evenly "
                 f"across num_processes={cfg.num_processes}")
-        # same pre-init pattern as parallel.mesh._cpu_devices: override the
-        # container's platform latch, then size this process's local slice
+        # same pre-init pattern as parallel.mesh._cpu_devices: backend=cpu
+        # wins over the environment's platform, then size this process's
+        # local slice
         jax.config.update("jax_platforms", "cpu")
-        from distributed_deep_q_tpu.compat import set_cpu_device_count
+        from distributed_deep_q_tpu.parallel.mesh import set_cpu_device_count
         set_cpu_device_count(cfg.num_fake_devices // cfg.num_processes,
                              exact=True)
         # cross-process collectives on the CPU backend go through gloo

@@ -32,9 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax import lax
-
-from distributed_deep_q_tpu.compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from distributed_deep_q_tpu import learning

@@ -262,10 +262,13 @@ PEAK_FLOPS = {
 }
 
 
-def peak_flops_for(device=None) -> float | None:
+def peak_flops_for(device=None, backend: str = "cpu") -> float | None:
     """Spec-sheet bf16 peak for ``device`` (default: the first local
-    device). None when the device publishes no peak we know (CPU
-    containers) — MFU is then absent rather than invented."""
+    device). On behalf of ``backend="tpu"`` a device that is not in the
+    table is an ERROR — a utilization divided by a guessed or missing peak
+    must not be reported from the chip path. On the CPU test path a
+    device with no published peak gives None: MFU is then absent rather
+    than invented."""
     if device is None:
         device = jax.devices()[0]
     kind = getattr(device, "device_kind", "")
@@ -273,52 +276,70 @@ def peak_flops_for(device=None) -> float | None:
                                key=lambda kv: -len(kv[0])):
         if kind.startswith(prefix):
             return peak
+    if backend == "tpu":
+        raise ValueError(
+            f"no published peak FLOP/s for device_kind {kind!r} — add it "
+            "to profiling.PEAK_FLOPS with its source")
     return None
 
 
+def _cost_flops(compiled) -> float | None:
+    cost = compiled.cost_analysis()
+    if isinstance(cost, (list, tuple)):
+        cost = cost[0]
+    flops = float(cost.get("flops", 0.0))
+    return flops if flops > 0 else None
+
+
 def xla_flops(solver, replay, batch) -> float | None:
-    """FLOPs of the compiled ring train step, from XLA's cost model."""
+    """FLOPs of the compiled ring train step, from XLA's cost model.
+    Fails loudly on ``backend="tpu"``; the CPU test path answers None
+    when the census cannot be taken."""
     try:
         fn = solver.learner._ring_steps[tuple(solver.config.net.frame_shape)]
         clean = {k: v for k, v in batch.items()
                  if k not in ("index", "_sampled_at")}
-        cost = fn.lower(solver.state, replay.ring, clean).compile() \
-                 .cost_analysis()
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0]
-        flops = float(cost.get("flops", 0.0))
-        return flops if flops > 0 else None
+        return _cost_flops(
+            fn.lower(solver.state, replay.ring, clean).compile())
     except Exception:
+        if solver.backend == "tpu":
+            raise
         return None
+
+
+def compile_fused_train(solver, replay, chain: int):
+    """The FUSED train program for ``replay``'s geometry, compiled ahead
+    of time from avals alone (``eval_shape`` of the sample program: no
+    device sample execution, no sampling-key-stream side effect) — the
+    one artifact the flops census, the op-count census and the chip
+    smoke's all-reduce check read. Builds the program pair when the train
+    loop has not run yet."""
+    sample, train = solver.learner.device_per_programs(
+        solver.device_per_spec(replay), chain)
+    cursors, sizes = replay.device_inputs()
+    betas = np.full(chain, 0.5, np.float32)
+    keys = np.zeros((replay.num_shards, chain, 2), np.uint32)
+    rows = replay.dstate
+    metas, win, idx = jax.eval_shape(
+        sample, keys, rows.frames, rows.action, rows.reward,
+        rows.done, rows.boundary, rows.prio, np.asarray(cursors),
+        np.asarray(sizes), betas)
+    return train.lower(solver.state, metas, win, idx, rows.prio,
+                       rows.maxp).compile()
 
 
 def fused_train_flops(solver, replay, chain: int) -> float | None:
     """Per-grad-step FLOPs of the FUSED train program — the same program
-    the MFU denominator times (ADVICE r4: the r4 numerator came from the
-    uniform ring step, a cross-program mismatch). XLA's cost model counts
-    a ``lax.scan`` body ONCE (verified against the analytic count: the
-    batch-512 chained program reports ~44.8 GF regardless of chain), so
-    the figure is already per-step."""
+    the MFU denominator times. XLA's cost model counts a ``lax.scan`` body
+    ONCE (verified against the analytic count: the batch-512 chained
+    program reports ~44.8 GF regardless of chain), so the figure is
+    already per-step. Fails loudly on ``backend="tpu"``; the CPU test
+    path answers None when the census cannot be taken."""
     try:
-        sample, train = solver.learner._device_per_steps[
-            (solver._dp_spec, chain)]
-        cursors, sizes = replay.device_inputs()
-        betas = np.full(chain, 0.5, np.float32)
-        keys = np.zeros((replay.num_shards, chain, 2), np.uint32)
-        rows = replay.dstate
-        # eval_shape: the lowering only needs avals — no device sample
-        # execution, no sampling-key-stream side effect
-        metas, win, idx = jax.eval_shape(
-            sample, keys, rows.frames, rows.action, rows.reward,
-            rows.done, rows.boundary, rows.prio, np.asarray(cursors),
-            np.asarray(sizes), betas)
-        cost = train.lower(solver.state, metas, win, idx, rows.prio,
-                           rows.maxp).compile().cost_analysis()
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0]
-        flops = float(cost.get("flops", 0.0))
-        return flops if flops > 0 else None
+        return _cost_flops(compile_fused_train(solver, replay, chain))
     except Exception:
+        if solver.backend == "tpu":
+            raise
         return None
 
 

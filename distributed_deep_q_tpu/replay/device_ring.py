@@ -42,7 +42,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from distributed_deep_q_tpu.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributed_deep_q_tpu import tracing

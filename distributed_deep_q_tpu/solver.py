@@ -185,16 +185,7 @@ class Solver:
             replay.flush()
         cursors, sizes = replay.device_inputs()
         betas = replay.next_betas(chain)
-        spec = self._dp_spec
-        if spec is None or self._dp_spec_replay is not replay:
-            spec = (replay.slot_cap, replay.slot_pad, replay.rowb,
-                    replay._row_len, replay.stack, replay.n_step,
-                    replay.gamma, tuple(replay.frame_shape),
-                    self.config.replay.batch_size // replay.num_shards,
-                    float(self.config.replay.priority_alpha),
-                    float(self.config.replay.priority_eps),
-                    replay.num_shards, replay._interpret)
-            self._dp_spec, self._dp_spec_replay = spec, replay
+        spec = self.device_per_spec(replay)
         keys = self._next_sample_keys(replay.num_shards, chain)
         if replay._pc > 1:
             # multi-controller: ship each plane as this process's local
@@ -211,6 +202,21 @@ class Solver:
                 spec)
         replay.dstate = replay.dstate.replace(prio=prio, maxp=maxp)
         return dict(metrics)
+
+    def device_per_spec(self, replay) -> tuple:
+        """Static geometry of the fused step for ``replay`` (the key of
+        ``Learner.device_per_programs``), cached per replay object."""
+        if self._dp_spec is None or self._dp_spec_replay is not replay:
+            self._dp_spec = (
+                replay.slot_cap, replay.slot_pad, replay.rowb,
+                replay._row_len, replay.stack, replay.n_step,
+                replay.gamma, tuple(replay.frame_shape),
+                self.config.replay.batch_size // replay.num_shards,
+                float(self.config.replay.priority_alpha),
+                float(self.config.replay.priority_eps),
+                replay.num_shards, replay._interpret)
+            self._dp_spec_replay = replay
+        return self._dp_spec
 
     def _next_sample_keys(self, num_shards: int, chain: int) -> np.ndarray:
         return next_fused_keys(self, num_shards, chain)

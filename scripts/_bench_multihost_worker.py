@@ -103,7 +103,7 @@ def main() -> None:
         # pin the platform + device count the conftest way
         import jax
         jax.config.update("jax_platforms", "cpu")
-        from distributed_deep_q_tpu.compat import set_cpu_device_count
+        from distributed_deep_q_tpu.parallel.mesh import set_cpu_device_count
         set_cpu_device_count(DEVICES, exact=True)
     initialize_multihost(mesh_cfg)
 

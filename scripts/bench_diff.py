@@ -1,9 +1,9 @@
 #!/usr/bin/env python
-"""Diff two bench result files (BENCH_r*.json) and flag regressions.
+"""Diff two bench result files (one JSON line each) and flag regressions.
 
 Usage::
 
-    python scripts/bench_diff.py BENCH_r05.json BENCH_r06.json
+    python scripts/bench_diff.py old.json new.json
     python scripts/bench_diff.py --tolerance 0.05 old.json new.json
 
 Compares every numeric metric present in both files. A metric has
@@ -12,15 +12,8 @@ op-count up) by more than its tolerance — the larger recorded ``spread``
 of the two runs when one exists (benches record run-to-run relative
 spread next to gated metrics), else ``--tolerance`` (default 2%).
 
-Keys listed under ``tunnel_bound_keys`` are measurements of the
-benchmarking transport, not of the system (EVAL_PROTOCOL.md) — their
-regressions are ANNOTATED but never fail the diff. The CANDIDATE run's
-list wins (falling back to the baseline's when absent): when a bench
-graduates a key out of the tunnel set — e.g. ``ingest_curve`` once the
-columnar drain made it learner-bound — diffs against old baselines gate
-it immediately. Exit status is 1 iff a non-tunnel-bound metric
-regressed; stdlib only, no repo imports, so it runs anywhere the jsons
-land.
+Exit status is 1 iff a metric regressed; stdlib only, no repo imports, so
+it runs anywhere the jsons land.
 """
 
 from __future__ import annotations
@@ -136,12 +129,7 @@ def _flatten(d: dict, prefix: str = "") -> dict:
 
 def diff(a: dict, b: dict, tolerance: float):
     """-> (rows, failed). Each row: (key, old, new, rel_delta, tol,
-    status) with status in {ok, improved, regressed, tunnel-bound}."""
-    # candidate's tunnel list wins: a bench that PROMOTES a key out of
-    # the tunnel set (ingest_curve, ISSUE 8) starts gating it even
-    # against baselines that still listed it
-    tunnel = set(b.get("tunnel_bound_keys")
-                 or a.get("tunnel_bound_keys") or [])
+    status) with status in {ok, improved, regressed}."""
     fa, fb = _flatten(a), _flatten(b)
     rows, failed = [], False
     for key in sorted(fa.keys() & fb.keys()):
@@ -160,11 +148,7 @@ def diff(a: dict, b: dict, tolerance: float):
                                                     else float("inf"))
         bad = -delta if _lower_is_better(key) else delta
         if bad < -tol:
-            root = key.split(".", 1)[0]
-            if root in tunnel or key in tunnel:
-                status = "tunnel-bound"
-            else:
-                status, failed = "regressed", True
+            status, failed = "regressed", True
         elif bad > tol:
             status = "improved"
         else:
@@ -175,8 +159,8 @@ def diff(a: dict, b: dict, tolerance: float):
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("old", help="baseline BENCH_r*.json")
-    ap.add_argument("new", help="candidate BENCH_r*.json")
+    ap.add_argument("old", help="baseline bench json")
+    ap.add_argument("new", help="candidate bench json")
     ap.add_argument("--tolerance", type=float, default=0.02,
                     help="relative tolerance for metrics with no "
                          "recorded spread (default 0.02)")
@@ -191,23 +175,19 @@ def main(argv=None) -> int:
         return 2
 
     width = max(len(r[0]) for r in rows)
-    marks = {"regressed": "!!", "tunnel-bound": "~~", "improved": "++",
-             "ok": "  "}
+    marks = {"regressed": "!!", "improved": "++", "ok": "  "}
     shown = 0
     for key, old, new, delta, tol, status in rows:
         if status == "ok" and not args.all:
             continue
         shown += 1
-        note = " (tunnel-bound: informational, never gates)" \
-            if status == "tunnel-bound" else ""
         print(f"{marks[status]} {key:<{width}}  {old:>12.4g} -> "
               f"{new:>12.4g}  {delta:+8.2%} (tol {tol:.2%}) "
-              f"{status}{note}")
+              f"{status}")
     if shown == 0:
         print(f"all {len(rows)} shared metrics within tolerance")
     print(f"\n{len(rows)} metrics compared; "
           f"{sum(r[5] == 'regressed' for r in rows)} regressed, "
-          f"{sum(r[5] == 'tunnel-bound' for r in rows)} tunnel-bound, "
           f"{sum(r[5] == 'improved' for r in rows)} improved")
     return 1 if failed else 0
 

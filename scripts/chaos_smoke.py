@@ -1341,7 +1341,7 @@ def run_train_chaos(argv: list[str]) -> dict:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    from distributed_deep_q_tpu.compat import set_cpu_device_count
+    from distributed_deep_q_tpu.parallel.mesh import set_cpu_device_count
     set_cpu_device_count(2)
 
     from distributed_deep_q_tpu.config import apply_overrides, cartpole_config

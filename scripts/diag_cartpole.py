@@ -9,7 +9,7 @@ import time
 import jax
 
 jax.config.update("jax_platforms", "cpu")
-from distributed_deep_q_tpu.compat import set_cpu_device_count
+from distributed_deep_q_tpu.parallel.mesh import set_cpu_device_count
 set_cpu_device_count(8)
 
 import numpy as np

@@ -11,7 +11,7 @@ Q*(s0,a1)=1+0.9*V(s1)=1.9 ; Q*(s0,a0)=0+0.9*V(s0)=0.9*1.9=1.71
 import jax
 
 jax.config.update("jax_platforms", "cpu")
-from distributed_deep_q_tpu.compat import set_cpu_device_count
+from distributed_deep_q_tpu.parallel.mesh import set_cpu_device_count
 set_cpu_device_count(8)
 
 import numpy as np

@@ -4,11 +4,11 @@ Round-5 diagnostic that drove the flat-ring redesign: before it, the
 sample program went 28 → 105 ms/chunk between capacities (tile-amplified
 meta element-gathers ~42 ms + frame row-gathers ~44 ms, searchsorted
 ~2 ms — recorded in PERF.md); after the Pallas row-DMA ring + meta pack
-both capacities sit near the small-ring cost. Re-run on the TPU box to
-re-attribute if the shape of the programs changes.
+both capacities sit near the small-ring cost (not measured on today's
+code). Re-run on the chip to re-attribute if the shape of the programs
+changes.
 
-All timings honestly fenced (D2H read of a data-dependent scalar, minus
-measured RTT; block_until_ready acks enqueue on this runtime).
+Every timed window ends with a D2H read of a data-dependent scalar.
 """
 
 from __future__ import annotations
@@ -23,7 +23,7 @@ import numpy as np
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-from bench import _fence_rtt, build  # noqa: E402
+from bench import build  # noqa: E402
 from distributed_deep_q_tpu import config as cfg_mod  # noqa: E402
 
 CHAIN = 32
@@ -56,13 +56,12 @@ def probe_capacity(cap: int, prefill: int) -> None:
 
     note("time sample program")
     metas, win, idx = one_sample()
-    rtt = _fence_rtt(solver)
     ts = []
     for _ in range(7):
         del metas, win, idx
         t0 = time.perf_counter()
         metas, win, idx = one_sample()
-        ts.append(time.perf_counter() - t0 - rtt)
+        ts.append(time.perf_counter() - t0)
     t_sample = float(np.median(ts))
 
     note("time train program")
@@ -81,7 +80,7 @@ def probe_capacity(cap: int, prefill: int) -> None:
     for _ in range(3):
         t0 = time.perf_counter()
         state, prio, maxp = run_train(state, prio, maxp)
-        ts.append((time.perf_counter() - t0 - rtt) / reps)
+        ts.append((time.perf_counter() - t0) / reps)
     t_train = float(np.median(ts))
     total = t_sample + t_train
     print(f"cap {cap:>9}: sample {t_sample*1e3:8.2f} ms/chunk | "

@@ -50,7 +50,7 @@ def main() -> None:
         # pin the CPU platform + 8 virtual devices the conftest way
         import jax
         jax.config.update("jax_platforms", "cpu")
-        from distributed_deep_q_tpu.compat import set_cpu_device_count
+        from distributed_deep_q_tpu.parallel.mesh import set_cpu_device_count
         set_cpu_device_count(8, exact=True)
     # must precede any backend init — this is the whole API contract
     initialize_multihost(mesh_cfg)

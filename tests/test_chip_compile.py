@@ -1,0 +1,115 @@
+"""The main path's Pallas kernels, compiled by the TPU's own compiler for a
+DESCRIBED v5e chip (no chip attached) at the real geometries.
+
+Interpret-mode tests cannot see what Mosaic refuses (unaligned slices,
+VMEM limits, 32-bit index overflow); these can, at no chip time. All of
+them live in this ONE file: the worker that runs it loads libtpu once and
+keeps it. The topology is described inside a fixture — never at import —
+so every xdist worker collects the same tests. A compile that passes is
+not a chip run.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from distributed_deep_q_tpu.ops.pallas_kernels import fused_dqn_loss
+from distributed_deep_q_tpu.ops.ring_gather import (
+    gather_windows, padded_row_bytes, scatter_rows)
+
+ROWB = padded_row_bytes(84 * 84)        # 8192 B per 84x84 frame row
+# breakout/apex preset: 1M frames, 4 sub-rings, window = stack 4 + n_step 3
+BREAKOUT_RING_ROWS = 4 * (250_000 + 6) + 1
+BATCH = 512
+# r2d2 preset: one "row" is a whole sequence — (stack-1) + (seq_len+1)
+# frames — and a shard holds its share of capacity // seq_len sequences
+# + 1 scratch. The per-shard plane of the preset's 12 500 sequences on ONE
+# chip (12 501 x 172 032 int32) passes Mosaic's 2^31 element range, and
+# DeviceSequenceReplay refuses to build it; the dp=4 share is what fits.
+R2D2_W = 3 + 81
+R2D2_SEQ_BYTES = R2D2_W * ROWB
+R2D2_RING_SEQS = (1_000_000 // 80) // 4 + 1
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """Sharding on one described chip, with the persistent compile cache
+    off around the module's compiles: an entry written for a described
+    device cannot be read back without the chip, and the retry warns."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compiled_text(fn, *avals) -> str:
+    return jax.jit(fn).lower(*avals).compile().as_text()
+
+
+@pytest.mark.parametrize("n,w,rowb,ring_rows", [
+    (8 * BATCH, 7, ROWB, BREAKOUT_RING_ROWS),     # fused_chain=8 chunk
+    (32 * BATCH, 7, ROWB, BREAKOUT_RING_ROWS),    # chain=32 chunk
+    (64, R2D2_W, ROWB, R2D2_RING_SEQS * R2D2_W),        # r2d2 per-step
+    (8 * 64, R2D2_W, ROWB, R2D2_RING_SEQS * R2D2_W),    # r2d2 chained
+], ids=["breakout-chain8", "breakout-chain32", "r2d2-step", "r2d2-chain8"])
+def test_gather_windows_compiles_for_v5e(one_chip, n, w, rowb, ring_rows):
+    assert ring_rows * (rowb // 4) < 2**31    # Mosaic's 32-bit indexing
+    idx = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+    ring = jax.ShapeDtypeStruct((ring_rows * (rowb // 4),), jnp.int32,
+                                sharding=one_chip)
+    text = _compiled_text(
+        functools.partial(gather_windows, n=n, w=w, rowb=rowb), idx, ring)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("n,staged_rows,rowb,ring_rows", [
+    (2 * 64, 64, ROWB, BREAKOUT_RING_ROWS),       # write_chunk=64 + ghosts
+    (2 * 1024, 1024, ROWB, BREAKOUT_RING_ROWS),   # largest chunk in use
+    (4, 4, R2D2_SEQ_BYTES, R2D2_RING_SEQS),       # r2d2: 4 sequences/flush
+], ids=["breakout-chunk64", "breakout-chunk1024", "r2d2-chunk4"])
+def test_scatter_rows_compiles_for_v5e(one_chip, n, staged_rows, rowb,
+                                       ring_rows):
+    rowp = rowb // 4
+    assert ring_rows * rowp < 2**31
+    i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
+                            sharding=one_chip)
+    text = _compiled_text(
+        functools.partial(scatter_rows, n=n, rowb=rowb),
+        i32((n,)), i32((n,)), i32((staged_rows * rowp,)),
+        i32((ring_rows * rowp,)))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("batch,actions", [(512, 4), (512, 18), (32, 6)],
+                         ids=["breakout-b512", "apex-b512-a18", "b32"])
+def test_fused_huber_fwd_and_grad_compile_for_v5e(one_chip, batch, actions):
+    def loss_and_grad(q, a, t, w):
+        return jax.value_and_grad(
+            lambda qq: fused_dqn_loss(qq, a, t, w, 1.0, False)[0])(q)
+
+    f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32,
+                            sharding=one_chip)
+    text = _compiled_text(
+        loss_and_grad, f32((batch, actions)),
+        jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=one_chip),
+        f32((batch,)), f32((batch,)))
+    # forward kernel + hand-written backward kernel
+    assert text.count("tpu_custom_call") >= 2

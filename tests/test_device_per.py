@@ -114,7 +114,7 @@ def test_packed_draw_matches_reference_draw():
     keys, and βs — meta, IS weights, validity planes, and scatter
     indices. This is the invariant that lets the two implementations
     coexist without drifting."""
-    from distributed_deep_q_tpu.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from distributed_deep_q_tpu.replay.device_per import (
@@ -449,7 +449,7 @@ def test_fused_sample_zero_mass_shard_yields_zero_weights():
     rows and drop its priority scatter (OOB index) instead of composing
     garbage with extreme IS weights."""
     from distributed_deep_q_tpu.replay.device_per import fused_sample
-    from distributed_deep_q_tpu.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = make_mesh(MeshConfig(backend="cpu", num_fake_devices=8, dp=2))

@@ -76,7 +76,7 @@ def test_device_batch_shard_locality_dp8():
     import functools
 
     import jax
-    from distributed_deep_q_tpu.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     dp, per = 8, 4

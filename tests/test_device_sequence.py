@@ -78,7 +78,7 @@ def test_device_sequence_sample_matches_host_store():
     device-composed pixel batches equal the host store's rows byte-exactly
     (metadata equality included)."""
     import jax.numpy as jnp
-    from distributed_deep_q_tpu.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     seq_len, burn_in, stack = 8, 4, 3

@@ -23,7 +23,8 @@ def test_fused_loss_matches_reference_value_and_td():
     rng = np.random.default_rng(0)
     q, actions, targets, weights = _random_batch(rng)
     for delta in (0.5, 1.0, 2.0):
-        loss_p, td_p = fused_dqn_loss(q, actions, targets, weights, delta)
+        loss_p, td_p = fused_dqn_loss(q, actions, targets, weights, delta,
+                                      interpret=True)
         loss_j, td_j = dqn_loss(q, actions, targets, weights, delta)
         np.testing.assert_allclose(loss_p, loss_j, rtol=1e-6)
         np.testing.assert_allclose(td_p, td_j, rtol=1e-6)
@@ -34,7 +35,8 @@ def test_fused_loss_gradient_matches_reference():
     q, actions, targets, weights = _random_batch(rng, b=16, a=4)
 
     def f_pallas(qq):
-        return fused_dqn_loss(qq, actions, targets, weights, 1.0)[0]
+        return fused_dqn_loss(qq, actions, targets, weights, 1.0,
+                              interpret=True)[0]
 
     def f_jnp(qq):
         return dqn_loss(qq, actions, targets, weights, 1.0)[0]
