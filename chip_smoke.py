@@ -42,6 +42,9 @@ import sys
 import tempfile
 import time
 
+from distributed_deep_q_tpu.utils.compile_cache import (
+    CompileClock, place_compile_cache)
+
 DEADLINE_S = 1150           # the contract allows 1200 s, compilation included
 F32_RTOL = 2e-4             # tests/test_solver.py's dp=N vs dp=1 bound
 # bf16 keeps 8 significand bits (eps = 2^-8 ≈ 3.9e-3). dp=4 and dp=1 take
@@ -57,30 +60,9 @@ def emit(**kv) -> None:
     print(json.dumps(kv), flush=True)
 
 
-class CompileClock:
+class PhaseClock(CompileClock):
     """Splits a phase's wall time into compile vs steady, and counts
     persistent-cache hits, from JAX's own monitoring events."""
-
-    COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
-                      "/jax/core/compile/jaxpr_to_mlir_module_duration",
-                      "/jax/core/compile/backend_compile_duration")
-
-    def __init__(self):
-        import jax.monitoring as mon
-        self.compile_s = 0.0
-        self.hits = self.misses = 0
-        mon.register_event_duration_secs_listener(self._on_duration)
-        mon.register_event_listener(self._on_event)
-
-    def _on_duration(self, event, secs, **kw):
-        if event in self.COMPILE_EVENTS:
-            self.compile_s += secs
-
-    def _on_event(self, event, **kw):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
 
     @contextlib.contextmanager
     def phase(self, name: str, **extra):
@@ -318,7 +300,7 @@ def preset_cfg(rehearse: bool, seed: int, sets: list[str]):
     return cfg
 
 
-def one_chip(args, clock: CompileClock, dev) -> None:
+def one_chip(args, clock: PhaseClock, dev) -> None:
     import jax
 
     from distributed_deep_q_tpu import native
@@ -515,7 +497,7 @@ def sharded_ring(cfg, chunks: int = 3) -> dict:
             "loss_last": losses[-1]}
 
 
-def four_chips(args, clock: CompileClock) -> None:
+def four_chips(args, clock: PhaseClock) -> None:
     rehearse = args.rehearse_cpu
     sets = []
     if rehearse:
@@ -552,8 +534,6 @@ def main(argv: list[str] | None = None) -> int:
     signal.alarm(DEADLINE_S)
 
     # before first backend use; JAX_COMPILATION_CACHE_DIR wins when set
-    from distributed_deep_q_tpu.utils.compile_cache import (
-        place_compile_cache)
     cache_dir = place_compile_cache()
 
     import jax
@@ -586,7 +566,7 @@ def main(argv: list[str] | None = None) -> int:
     emit(compile_cache_dir=cache_dir, entries_at_start=entries,
          from_env=bool(os.environ.get("JAX_COMPILATION_CACHE_DIR")))
 
-    clock = CompileClock()
+    clock = PhaseClock()
     t0 = time.perf_counter()
     if args.chips == 4:
         four_chips(args, clock)

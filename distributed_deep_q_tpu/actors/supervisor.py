@@ -1425,6 +1425,8 @@ def train_distributed(cfg: Config, metrics: Metrics | None = None,
                         cfg.train.profile_num_steps)
     if cfg.train.profile_port:
         start_profiler_server(cfg.train.profile_port)
+    from distributed_deep_q_tpu.utils.compile_cache import process_clock
+    compile_clock = process_clock()
     from distributed_deep_q_tpu.utils.checkpoint import maybe_checkpointer
     ckpt = maybe_checkpointer(cfg.train)
     if ckpt and cfg.train.resume and ckpt.latest_step() is not None:
@@ -1532,61 +1534,70 @@ def train_distributed(cfg: Config, metrics: Metrics | None = None,
                 writeback.push(m["index"], m["td_abs"], sampled_at)
 
             if gstep % cfg.actors.param_sync_period == 0:
-                t0 = time.perf_counter()
-                _publish_weights(server, infer_server, solver.get_weights())
-                metrics.observe("learner/publish_params_ms",
-                                1e3 * (time.perf_counter() - t0))
+                with tracing.span("learner_publish"):
+                    t0 = time.perf_counter()
+                    _publish_weights(server, infer_server,
+                                     solver.get_weights())
+                    metrics.observe("learner/publish_params_ms",
+                                    1e3 * (time.perf_counter() - t0))
 
             if ckpt and gstep % cfg.train.checkpoint_every == 0:
-                ckpt.save(solver.state,
-                          extra={"env_steps": server.counters()["env_steps"]})
-                if cfg.train.server_snapshot_path:
-                    # capture-only under the lock; serialize + fsync in a
-                    # background thread (a still-running previous dump
-                    # just skips this tick — counted, never stacked)
-                    server.snapshot_async(cfg.train.server_snapshot_path)
+                with tracing.span("learner_checkpoint"):
+                    ckpt.save(solver.state, extra={
+                        "env_steps": server.counters()["env_steps"]})
+                    if cfg.train.server_snapshot_path:
+                        # capture-only under the lock; serialize + fsync
+                        # in a background thread (a still-running
+                        # previous dump just skips this tick — counted,
+                        # never stacked)
+                        server.snapshot_async(
+                            cfg.train.server_snapshot_path)
 
             if gstep % log_every == 0:
-                timer.measure_device(m["loss"])
-                counts = server.counters()
-                summary = {
-                    "loss": float(m["loss"]),
-                    "q_mean": float(m["q_mean"]),
-                    "return_avg100": server.mean_recent_return(),
-                    "env_steps": counts["env_steps"],
-                    "replay_size": counts["replay_size"],
-                    "grad_steps_per_s": metrics.rate("grad_steps"),
-                    "actor_restarts": sup.restarts,
-                    "actor_kill_escalations": sup.kill_escalations,
-                    "actor_scale_terminations": sup.executor_terminations,
-                }
-                # one record carries the whole telemetry spine: per-phase
-                # times, per-RPC-method latency/size percentiles, queue
-                # gauges, and the fleet counters actors flushed back
-                infer_tm = (infer_server.telemetry_summary()
-                            if infer_server is not None else {})
-                if learn_acc is not None:
-                    # fold this window's planes (D2H happens HERE, at
-                    # log cadence) and surface learn/* + the TD-error
-                    # histogram summary through the metrics spine
-                    for plane in fused_stream.drain_planes():
-                        learn_acc.ingest(plane)
-                    for lk, lv in learn_acc.gauges().items():
-                        metrics.gauge(lk, lv)
-                    for lk, lv in learn_acc.hist_snapshot().summary(
-                            prefix="learn/td_error").items():
-                        metrics.gauge(lk, lv)
-                # health plane: live MFU/ingest-utilization gauges + the
-                # aggregated fleet verdict (scraped every
-                # health.scrape_every log ticks; {} while disabled)
-                hk = _health_tick(
-                    fleet_health, mfu_meter, server, gstep,
-                    scrape=(gstep // log_every)
-                    % max(cfg.health.scrape_every, 1) == 0,
-                    autoscaler=autoscaler, executor=scale_executor)
-                metrics.log(gstep, **summary, **timer.summary(),
-                            **server.telemetry_summary(), **infer_tm,
-                            **metrics.telemetry(), **hk)
+                # the whole row is one span: its float(loss) fence is
+                # where the learner waits for the device
+                with tracing.span("learner_log"):
+                    timer.measure_device(m["loss"])
+                    counts = server.counters()
+                    summary = {
+                        "loss": float(m["loss"]),
+                        "q_mean": float(m["q_mean"]),
+                        "return_avg100": server.mean_recent_return(),
+                        "env_steps": counts["env_steps"],
+                        "replay_size": counts["replay_size"],
+                        "grad_steps_per_s": metrics.rate("grad_steps"),
+                        "actor_restarts": sup.restarts,
+                        "actor_kill_escalations": sup.kill_escalations,
+                        "actor_scale_terminations": sup.executor_terminations,
+                    }
+                    # one record carries the whole telemetry spine: per-phase
+                    # times, per-RPC-method latency/size percentiles, queue
+                    # gauges, and the fleet counters actors flushed back
+                    infer_tm = (infer_server.telemetry_summary()
+                                if infer_server is not None else {})
+                    if learn_acc is not None:
+                        # fold this window's planes (D2H happens HERE, at
+                        # log cadence) and surface learn/* + the TD-error
+                        # histogram summary through the metrics spine
+                        for plane in fused_stream.drain_planes():
+                            learn_acc.ingest(plane)
+                        for lk, lv in learn_acc.gauges().items():
+                            metrics.gauge(lk, lv)
+                        for lk, lv in learn_acc.hist_snapshot().summary(
+                                prefix="learn/td_error").items():
+                            metrics.gauge(lk, lv)
+                    # health plane: live MFU/ingest-utilization gauges + the
+                    # aggregated fleet verdict (scraped every
+                    # health.scrape_every log ticks; {} while disabled)
+                    hk = _health_tick(
+                        fleet_health, mfu_meter, server, gstep,
+                        scrape=(gstep // log_every)
+                        % max(cfg.health.scrape_every, 1) == 0,
+                        autoscaler=autoscaler, executor=scale_executor)
+                    metrics.log(gstep, **summary, **timer.summary(),
+                                **server.telemetry_summary(), **infer_tm,
+                                **metrics.telemetry(), **hk,
+                                **compile_clock.row())
     finally:
         trace.close()
         if stager is not None:

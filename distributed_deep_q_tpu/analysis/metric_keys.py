@@ -42,7 +42,8 @@ RULE_SPAN = "metric_keys.unknown-span"
 
 NAMESPACES = ("rpc", "fleet", "queue", "durability", "flow", "trace",
               "learner", "ingest", "inference", "shard", "actor",
-              "health", "train", "learn", "autoscale", "tenant")
+              "health", "train", "learn", "autoscale", "tenant",
+              "compile")
 _NS_RE = re.compile(r"^(?:%s)/.+" % "|".join(NAMESPACES))
 
 EMITTERS = frozenset(
@@ -213,6 +214,11 @@ REGISTRY = frozenset({
     "tenant/shed_primary",
     "tenant/*/latency_ms_p99",
     "tenant/*/sheds",
+    # the compile clock (utils/compile_cache.CompileClock.row): cumulative
+    # programs and seconds in every train-loop log row — growth in steady
+    # state is a recompile
+    "compile/count",
+    "compile/seconds",
 })
 
 _TRACING_REL = os.path.join("distributed_deep_q_tpu", "tracing.py")

@@ -61,8 +61,8 @@ class BatchedPolicy:
         self._treedef = jax.tree_util.tree_structure(self.params)
         # the exact apply QNet jits on the actor side — same program
         # family, so remote vs local Q rows match bitwise on one platform
-        self._fwd = jax.jit(
-            lambda p, o: self.module.apply({"params": p}, o))
+        self._fwd = jax.jit(jax.named_scope("ddq.infer")(
+            lambda p, o: self.module.apply({"params": p}, o)))
         self._compiled: set[int] = set()
         self.forwards = 0
         self.rows = 0

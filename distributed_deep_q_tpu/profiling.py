@@ -18,7 +18,10 @@ rebuild gets two first-class tools:
 - ``TraceWindow`` — a ``jax.profiler`` trace capture over a step window
   (e.g. steps 100–120), plus ``start_profiler_server`` for live
   TensorBoard-connected profiling. Enabled with
-  ``TrainConfig.profile_dir`` / ``profile_port``.
+  ``TrainConfig.profile_dir`` / ``profile_port``. While the window is
+  open every ``tracing`` span of this process is also written into the
+  capture as ``ddq/<name>`` on its thread's line, on the device
+  operations' clock (``tracing.profile_start``).
 """
 
 from __future__ import annotations
@@ -32,6 +35,7 @@ from typing import Iterator
 import jax
 import numpy as np
 
+from distributed_deep_q_tpu import tracing
 from distributed_deep_q_tpu.metrics import Histogram
 
 
@@ -398,6 +402,11 @@ class TraceWindow:
     ``on_step(step)`` is called once per train-loop step; the trace starts
     when ``step == start_step`` and stops after ``num_steps`` steps (or at
     ``close()``). Output is a TensorBoard-loadable trace directory.
+
+    The capture runs WITHOUT the Python tracer: the program's own spans
+    (``ddq/<name>``) name what each thread is doing, and a hook on every
+    Python call slows exactly the Python-heavy threads being measured
+    (PERF.md §6, PR 24). Host-side runtime events (TraceMe) stay on.
     """
 
     def __init__(self, logdir: str, start_step: int = 100,
@@ -412,7 +421,10 @@ class TraceWindow:
         if self._done or not self.logdir:
             return
         if not self._active and step >= self.start_step:
-            jax.profiler.start_trace(self.logdir)
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(self.logdir, profiler_options=options)
+            tracing.profile_start(jax.profiler.TraceAnnotation)
             self._active = True
             self._stop_at = step + self.num_steps
         elif self._active and step >= self._stop_at:
@@ -420,6 +432,7 @@ class TraceWindow:
 
     def stop(self) -> None:
         if self._active:
+            tracing.profile_stop()
             jax.profiler.stop_trace()
             self._active = False
             self._done = True

@@ -514,8 +514,9 @@ def make_write_fn(*, k: int, rowb: int, row_len: int, alpha: float,
                 alpha=alpha)
         else:
             new_p = rows.maxp ** alpha
-        frames = scatter_rows(sidx, didx, staged, rows.frames,
-                              n=2 * k, rowb=rowb, interpret=interpret)
+        with jax.named_scope("ddq.scatter_rows"):
+            frames = scatter_rows(sidx, didx, staged, rows.frames,
+                                  n=2 * k, rowb=rowb, interpret=interpret)
         return DeviceReplayState(
             frames=frames,
             action=rows.action.at[midx].set(act, mode="drop"),
@@ -526,7 +527,8 @@ def make_write_fn(*, k: int, rowb: int, row_len: int, alpha: float,
             maxp=rows.maxp,
         )
 
-    return write
+    # keeps the name: the program stays ``jit_write`` in a trace
+    return jax.named_scope("ddq.write")(write)
 
 
 # ---------------------------------------------------------------------------

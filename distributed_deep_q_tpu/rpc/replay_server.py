@@ -735,28 +735,33 @@ class ReplayFeedServer:
                     self.telemetry.record_dispatch_error()
                     self._log_error("bad frame", e)
                     return
+                # the clock starts AFTER the receive: payload read, CRC
+                # and decode (wire_recv / crc_verify / wire_decode spans)
+                # are in neither this histogram nor rpc_handle
                 t0 = time.perf_counter()
-                with self._inflight_cv:
-                    self._inflight += 1
-                try:
-                    try:
-                        resp = self._dispatch(req)
-                    except Exception as e:  # noqa: BLE001 — malformed
-                        # payloads (KeyError on a missing field, shape
-                        # mismatch, ...) must never kill the serve thread
-                        # silently: answer with an error dict so the
-                        # caller fails loudly
-                        self.telemetry.record_dispatch_error()
-                        self._log_error(f"dispatch {req.get('method')!r}", e)
-                        resp = {"error": f"{type(e).__name__}: {e}"}
-                finally:
+                with tracing.span("rpc_handle"):
                     with self._inflight_cv:
-                        self._inflight -= 1
-                        self._inflight_cv.notify_all()
-                if isinstance(resp, (bytes, bytearray)):
-                    conn.sendall(resp)  # pre-encoded frame (θ snapshot)
-                else:
-                    send_msg(conn, resp)
+                        self._inflight += 1
+                    try:
+                        try:
+                            resp = self._dispatch(req)
+                        except Exception as e:  # noqa: BLE001 — malformed
+                            # payloads (KeyError on a missing field,
+                            # shape mismatch, ...) must never kill the
+                            # serve thread silently: answer with an error
+                            # dict so the caller fails loudly
+                            self.telemetry.record_dispatch_error()
+                            self._log_error(
+                                f"dispatch {req.get('method')!r}", e)
+                            resp = {"error": f"{type(e).__name__}: {e}"}
+                    finally:
+                        with self._inflight_cv:
+                            self._inflight -= 1
+                            self._inflight_cv.notify_all()
+                    if isinstance(resp, (bytes, bytearray)):
+                        conn.sendall(resp)  # pre-encoded frame (θ snapshot)
+                    else:
+                        send_msg(conn, resp)
                 # latency covers dispatch + response serialization + send —
                 # what the actor actually waits on past its own upload
                 self.telemetry.record_call(

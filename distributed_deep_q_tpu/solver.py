@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from distributed_deep_q_tpu import tracing
 from distributed_deep_q_tpu.config import Config
 from distributed_deep_q_tpu.models.qnet import build_qnet, init_params
 from distributed_deep_q_tpu.parallel.learner import Learner, TrainState
@@ -177,31 +178,43 @@ class Solver:
         of per-step host overhead. Returns metrics stacked ``[chain]``
         (device arrays — convert only when logging)."""
         chain = chain or max(int(self.config.replay.fused_chain), 1)
-        if replay.pending_rows() or replay.defer_flush:
-            # device rows must cover everything the host bookkeeping
-            # (cursors/sizes below) claims is written. Multi-host the
-            # flush is a lockstep collective with an agreed round count,
-            # so EVERY process calls it here even with an empty backlog.
-            replay.flush()
-        cursors, sizes = replay.device_inputs()
-        betas = replay.next_betas(chain)
-        spec = self.device_per_spec(replay)
-        keys = self._next_sample_keys(replay.num_shards, chain)
-        if replay._pc > 1:
-            # multi-controller: ship each plane as this process's local
-            # block of the global P('dp') array (keys are computed
-            # identically everywhere — slice the local shard rows)
-            keys = replay.to_global(
-                np.ascontiguousarray(keys[replay.local_shards]))
-            cursors = replay.to_global(np.asarray(cursors))
-            sizes = replay.to_global(np.asarray(sizes))
-            betas = replay.to_replicated(np.asarray(betas, np.float32))
-        self.state, prio, maxp, metrics = \
-            self.learner.train_steps_device_per(
-                self.state, replay.dstate, cursors, sizes, betas, keys,
-                spec)
-        replay.dstate = replay.dstate.replace(prio=prio, maxp=maxp)
-        return dict(metrics)
+        # the span takes the gate with the flush, so every chunk has one:
+        # a chunk that found nothing staged (the drain thread got there
+        # first) reads as microseconds, not as a span that is missing
+        with tracing.span("learner_flush"):
+            if replay.pending_rows() or replay.defer_flush:
+                # device rows must cover everything the host bookkeeping
+                # (cursors/sizes below) claims is written. Multi-host the
+                # flush is a lockstep collective with an agreed round
+                # count, so EVERY process calls it here even with an
+                # empty backlog.
+                replay.flush()
+        with tracing.span("learner_feed"):
+            cursors, sizes = replay.device_inputs()
+            betas = replay.next_betas(chain)
+            spec = self.device_per_spec(replay)
+            keys = self._next_sample_keys(replay.num_shards, chain)
+            if replay._pc > 1:
+                # multi-controller: ship each plane as this process's
+                # local block of the global P('dp') array (keys are
+                # computed identically everywhere — slice the local
+                # shard rows)
+                keys = replay.to_global(
+                    np.ascontiguousarray(keys[replay.local_shards]))
+                cursors = replay.to_global(np.asarray(cursors))
+                sizes = replay.to_global(np.asarray(sizes))
+                betas = replay.to_replicated(
+                    np.asarray(betas, np.float32))
+        out = self.learner.train_steps_device_per(
+            self.state, replay.dstate, cursors, sizes, betas, keys, spec)
+        # taking the new handles drops the donated ones. Beside busy RPC
+        # threads the learner loses the interpreter here for one switch
+        # interval (5 ms) a chunk, still under the dispatch lock (PERF.md
+        # §5) — so the stretch has a span of its own
+        with tracing.span("learner_adopt"):
+            self.state, prio, maxp, metrics = out
+            replay.dstate = replay.dstate.replace(prio=prio, maxp=maxp)
+            return dict(metrics)
 
     def device_per_spec(self, replay) -> tuple:
         """Static geometry of the fused step for ``replay`` (the key of
@@ -263,7 +276,7 @@ class FusedStepStream:
     easy to get subtly wrong in hand-maintained copies — an off-by-one
     would attribute metrics to the neighboring grad step.
 
-    ``dispatch_lock`` (optional context manager, e.g. the ReplayFeed
+    ``dispatch_lock`` (optional lock, e.g. the ReplayFeed
     server's ``replay_lock``) is held across the dispatch only — the
     donated device state must not be swapped mid-dispatch, but writers get
     the window while the chunk executes on device. ``timer`` is the train
@@ -275,7 +288,7 @@ class FusedStepStream:
         self._solver = solver
         self._replay = replay
         self.chain = max(int(chain), 1)
-        self._lock = dispatch_lock or contextlib.nullcontext()
+        self._lock = dispatch_lock
         self._timer = timer
         self._chunk: dict[str, Any] | None = None
         self._len = 0
@@ -305,16 +318,22 @@ class FusedStepStream:
                 f"steps_left={steps_left}: dispatching with a non-positive "
                 "budget would silently run an extra optimizer step")
             self._len = min(self.chain, int(steps_left))
+            # the learner's wait for the lock is its own span
+            # (lock_wait), outside the dispatch phase as before
+            lock = (tracing.locked(self._lock) if self._lock is not None
+                    else contextlib.nullcontext())
             phase = (self._timer.phase("dispatch") if self._timer
                      else contextlib.nullcontext())
-            with self._lock, phase:
-                self._chunk = self._solver.train_steps_device_per(
-                    self._replay, chain=self._len)
-            plane = self._chunk.pop("learn_plane", None)
-            if plane is not None:
-                self._planes.append(plane)
+            with tracing.span("learner_chunk"):
+                with lock, phase:
+                    self._chunk = self._solver.train_steps_device_per(
+                        self._replay, chain=self._len)
+                plane = self._chunk.pop("learn_plane", None)
+                if plane is not None:
+                    self._planes.append(plane)
             self._pending = self._len
-        m = {k: v[self._len - self._pending]
-             for k, v in self._chunk.items()}
+        with tracing.span("learner_slice"):
+            m = {k: v[self._len - self._pending]
+                 for k, v in self._chunk.items()}
         self._pending -= 1
         return m

@@ -13,10 +13,11 @@ use, so NO wire version bump and v4 peers without context stay valid
 
 Design constraints, in order:
 
-1. **Near-zero cost when disabled.** ``ENABLED`` is a module-level bool;
-   every entry point branches on it ONCE and returns a preallocated
-   singleton (``span()`` → ``_NULL``, a no-op context manager) or an
-   empty constant. No dict/list/closure allocation on the disabled path.
+1. **Near-zero cost when disabled.** ``ENABLED`` and ``PROFILING`` are
+   module-level bools; every entry point branches on them ONCE and
+   returns a preallocated singleton (``span()`` → ``_NULL``, a no-op
+   context manager) or an empty constant. No dict/list/closure
+   allocation on the disabled path.
 2. **Never block the data path.** Spans buffer in per-thread ring
    buffers: bounded, drop-OLDEST on overflow, drop counter exposed
    (``drop_count``/``counters``). A burst costs old spans, never memory
@@ -29,6 +30,17 @@ Design constraints, in order:
    cross-process offset, which corrects lineage birth stamps before
    they are sent and shifts exported shards at merge time
    (``scripts/trace_report.py``).
+
+**Two sinks, one set of call sites.** ``ENABLED`` (``cfg.trace``) records
+spans to the per-thread rings and exports the JSON shards above, on the
+anchored wall clock — the only clock several processes share. While a
+``profiling.TraceWindow`` captures a ``jax.profiler`` trace it sets
+``PROFILING`` and hands in the profiler's annotation class; every span
+then ALSO (or only) opens an annotation named ``ddq/<name>``, which lands
+on its thread's line of the host plane in the same ``.xplane.pb`` as the
+device's operations, on their clock, nested by time. Only the process
+that holds the chip can be on that clock; actor processes keep the
+shards and the skew estimate.
 
 **Sampling** is deterministic and counter-based (every k-th cycle, k
 from ``sample_rate``) rather than RNG-based: no random() call on the
@@ -65,10 +77,19 @@ STAGES = (
     "ring_insert",     # replay add_batch under replay_lock
     "staged_append",   # columnar stage memcpy (replay/columnar.py)
     "ingest_drain",    # batched staging→device flush (drain thread)
+    "rpc_handle",      # one request on a serve thread: dispatch + reply
     "sample",          # replay sample (host compose / device draw)
     "stage_batch",     # DeviceStager cycle (sample + device_put)
     "device_put",      # host→device transfer of a sampled batch
     "train_step",      # train-step dispatch (fused chain or per-step)
+    "learner_chunk",   # FusedStepStream.next when it dispatches a chunk
+    "learner_flush",   # staged rows → device before a fused dispatch
+    "learner_feed",    # cursors/sizes/betas/keys for the fused programs
+    "learner_adopt",   # new device state taken, the donated one dropped
+    "learner_slice",   # one grad step's row out of the chunk's metrics
+    "learner_publish",  # θ → the RPC plane (param_sync_period)
+    "learner_log",     # log row: loss fence, counters, telemetry, sink
+    "learner_checkpoint",  # checkpoint (+ replay/server snapshot) save
     "param_pull",      # actor get_params round trip
     "infer_wait",      # inference serve thread waiting on its microbatch
     "infer_batch",     # microbatch cut: stack + pad to a compiled bucket
@@ -90,7 +111,9 @@ EVENTS = (
     "degraded",        # flow controller tripped degraded mode
 )
 
-_VALID_NAMES = frozenset(STAGES) | frozenset(EVENTS)
+# the names as the profiler's trace carries them (built once: no string
+# is formatted on a hot path)
+_ANNOTATION = {name: f"ddq/{name}" for name in STAGES + EVENTS}
 
 # wire piggyback keys (plain dict entries — no wire version bump; see
 # rpc/protocol.py "evolution without a version bump")
@@ -102,6 +125,8 @@ KEY_DONE_AT = "tr_done_at"  # float: server clock on reply build (t3)
 KEY_BIRTH = "tr_birth"      # float64[n]: per-row birth stamps (lineage)
 
 ENABLED = False  # module flag: the single branch on every hot path
+PROFILING = False  # a TraceWindow is capturing: spans annotate its trace
+_annotate = None   # name → context manager (jax.profiler.TraceAnnotation)
 
 _SAMPLE_EVERY = 100   # 1 / sample_rate, rounded (counter-based sampling)
 _LINEAGE_EVERY = 20   # 1 / lineage_rate
@@ -241,12 +266,23 @@ _NULL = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("name", "trace", "span", "t0")
+    """Each sink is decided once, on entry: a flag flipped while the span
+    is open (``TraceWindow.stop`` on the learner thread, a serve thread
+    mid-request) leaves the exit balanced."""
+
+    __slots__ = ("name", "trace", "span", "t0", "ann", "rec")
 
     def __init__(self, name: str):
         self.name = name
 
     def __enter__(self):
+        self.ann = None
+        if PROFILING:
+            self.ann = _annotate(_ANNOTATION[self.name])
+            self.ann.__enter__()
+        self.rec = ENABLED
+        if not self.rec:
+            return self
         st = _tls
         if st.stack:
             self.trace = st.stack[-1][0]
@@ -258,7 +294,13 @@ class _Span:
         return self
 
     def __exit__(self, *exc):
-        t1 = time.perf_counter()
+        if self.rec:
+            self._record(time.perf_counter())
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
+        return False
+
+    def _record(self, t1: float) -> None:
         st = _tls
         st.stack.pop()
         parent = st.stack[-1][1] if st.stack else 0
@@ -270,13 +312,12 @@ class _Span:
             "args": {"trace": self.trace, "span": self.span,
                      "parent": parent},
         })
-        return False
 
 
 def span(name: str):
     """Duration span context manager. ``name`` must be in ``STAGES``
     (statically enforced). Disabled → the ``_NULL`` singleton."""
-    if not ENABLED:
+    if not (ENABLED or PROFILING):
         return _NULL
     return _Span(name)
 
@@ -285,7 +326,7 @@ def span_sampled(name: str):
     """Like ``span`` but records only every k-th call per thread
     (k = 1/sample_rate) — for per-env-step hot paths where tracing
     every iteration would itself become the bottleneck."""
-    if not ENABLED:
+    if not (ENABLED or PROFILING):
         return _NULL
     st = _tls
     st.tick += 1
@@ -296,6 +337,11 @@ def span_sampled(name: str):
 
 def instant(name: str, **args) -> None:
     """Point event (``EVENTS`` table): shed/retry/reconnect/degraded."""
+    if not (ENABLED or PROFILING):
+        return
+    if PROFILING:
+        with _annotate(_ANNOTATION[name]):
+            pass
     if not ENABLED:
         return
     st = _tls
@@ -376,7 +422,7 @@ class _LockedTracer:
 def locked(lock):
     """Trace-aware lock context: disabled → the lock itself (its native
     ``with`` protocol, zero overhead); enabled → wait/hold split."""
-    if not ENABLED:
+    if not (ENABLED or PROFILING):
         return lock
     return _LockedTracer(lock)
 
@@ -419,6 +465,20 @@ def configure_from(trace_cfg) -> None:
 def disable() -> None:
     global ENABLED
     ENABLED = False
+
+
+def profile_start(annotate) -> None:
+    """A profiler trace has started in this process: spans annotate it
+    through ``annotate(name)`` (``jax.profiler.TraceAnnotation``, handed
+    in by ``profiling.TraceWindow`` so this module never imports jax)."""
+    global PROFILING, _annotate
+    _annotate = annotate    # before the flag: a span that sees it set
+    PROFILING = True        # must find the callable
+
+
+def profile_stop() -> None:
+    global PROFILING
+    PROFILING = False
 
 
 # -- drain / export / counters ---------------------------------------------

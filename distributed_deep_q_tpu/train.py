@@ -13,6 +13,7 @@ import os
 import jax
 import numpy as np
 
+from distributed_deep_q_tpu import tracing
 from distributed_deep_q_tpu.actors.game import (
     FrameStacker, NStepAccumulator, make_env)
 from distributed_deep_q_tpu.config import Config
@@ -24,6 +25,7 @@ from distributed_deep_q_tpu.replay.prioritized import maybe_prioritize
 from distributed_deep_q_tpu.replay.replay_memory import FrameStackReplay, ReplayMemory
 from distributed_deep_q_tpu.solver import Solver
 from distributed_deep_q_tpu.utils.checkpoint import maybe_checkpointer
+from distributed_deep_q_tpu.utils.compile_cache import process_clock
 
 
 def epsilon_at(step: int, cfg) -> float:
@@ -188,6 +190,7 @@ def train_single_process(cfg: Config, metrics: Metrics | None = None,
                         cfg.train.profile_num_steps)
     if cfg.train.profile_port:
         start_profiler_server(cfg.train.profile_port)
+    compile_clock = process_clock()
     ckpt = maybe_checkpointer(cfg.train)
     if ckpt and cfg.train.resume and ckpt.latest_step() is not None:
         solver.state, _ = ckpt.restore(solver.state)
@@ -279,39 +282,44 @@ def train_single_process(cfg: Config, metrics: Metrics | None = None,
                         writeback.push(m["index"], m["td_abs"], sampled_at)
                     metrics.count("grad_steps")
                     if ckpt and gsteps % cfg.train.checkpoint_every == 0:
-                        ckpt.save(solver.state, extra={"env_steps": t})
-                        if persist:
-                            from distributed_deep_q_tpu.replay.persistence \
-                                import save_replay
-                            save_replay(replay, persist)
+                        with tracing.span("learner_checkpoint"):
+                            ckpt.save(solver.state, extra={"env_steps": t})
+                            if persist:
+                                from distributed_deep_q_tpu.replay \
+                                    .persistence import save_replay
+                                save_replay(replay, persist)
                     # host-side counter: reading solver.step would sync on the
                     # just-dispatched device step every iteration
                     if gsteps % log_every == 0:
-                        timer.measure_device(m["loss"])
-                        summary = {
-                            "loss": float(m["loss"]),
-                            "q_mean": float(m["q_mean"]),
-                            "return_avg100": ep_returns.value, "epsilon": eps,
-                            "grad_steps_per_s": metrics.rate("grad_steps"),
-                            "env_steps_per_s": metrics.rate("env_steps"),
-                        }
-                        metrics.gauge("queue/replay_size", len(replay))
-                        pending = getattr(replay, "pending_rows", None)
-                        if pending is not None:
-                            metrics.gauge("queue/staged_rows", pending())
-                        if learn_acc is not None:
-                            # D2H of the window's planes happens here, at
-                            # log cadence — never on the step path
-                            for plane in fused_stream.drain_planes():
-                                learn_acc.ingest(plane)
-                            for lk, lv in learn_acc.gauges().items():
-                                metrics.gauge(lk, lv)
-                            for lk, lv in learn_acc.hist_snapshot(
-                                    ).summary(
-                                    prefix="learn/td_error").items():
-                                metrics.gauge(lk, lv)
-                        metrics.log(solver.step, **summary, **timer.summary(),
-                                    **metrics.telemetry())
+                        with tracing.span("learner_log"):
+                            timer.measure_device(m["loss"])
+                            summary = {
+                                "loss": float(m["loss"]),
+                                "q_mean": float(m["q_mean"]),
+                                "return_avg100": ep_returns.value,
+                                "epsilon": eps,
+                                "grad_steps_per_s": metrics.rate("grad_steps"),
+                                "env_steps_per_s": metrics.rate("env_steps"),
+                            }
+                            metrics.gauge("queue/replay_size", len(replay))
+                            pending = getattr(replay, "pending_rows", None)
+                            if pending is not None:
+                                metrics.gauge("queue/staged_rows", pending())
+                            if learn_acc is not None:
+                                # D2H of the window's planes happens here, at
+                                # log cadence — never on the step path
+                                for plane in fused_stream.drain_planes():
+                                    learn_acc.ingest(plane)
+                                for lk, lv in learn_acc.gauges().items():
+                                    metrics.gauge(lk, lv)
+                                for lk, lv in learn_acc.hist_snapshot(
+                                        ).summary(
+                                        prefix="learn/td_error").items():
+                                    metrics.gauge(lk, lv)
+                            metrics.log(solver.step, **summary,
+                                        **timer.summary(),
+                                        **metrics.telemetry(),
+                                        **compile_clock.row())
 
             if (cfg.train.eval_every and t % cfg.train.eval_every == 0):
                 ret = evaluate(solver, cfg)

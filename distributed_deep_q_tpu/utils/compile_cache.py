@@ -16,6 +16,13 @@ only an exported ``JAX_COMPILATION_CACHE_DIR`` reaches them. bench's
 multi-process CPU workers must run WITHOUT the cache (deserialized
 executables segfault inside the gloo collectives), so
 ``bench._multihost_curve`` strips the variable from their environment.
+
+``CompileClock`` is the one clock for what compiling costs: seconds of
+tracing + lowering + backend compile (or cache load), the number of
+programs, and persistent-cache hits and misses, from JAX's own monitoring
+events. ``chip_smoke.py`` splits its phases with it, and both train loops
+put ``process_clock().row()`` in their log rows, where a count that
+still grows in steady state is a recompile.
 """
 
 from __future__ import annotations
@@ -37,3 +44,51 @@ def place_compile_cache() -> str:
     path = os.path.join(_CHECKOUT, ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     return path
+
+
+class CompileClock:
+    """Compile seconds, compiled programs and persistent-cache hits and
+    misses since construction. JAX keeps its listeners for the life of
+    the process, so a loop that may run many times in one process shares
+    ``process_clock()`` instead of making its own."""
+
+    COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                      "/jax/core/compile/jaxpr_to_mlir_module_duration",
+                      "/jax/core/compile/backend_compile_duration")
+
+    def __init__(self):
+        import jax.monitoring as mon
+
+        self.compile_s = 0.0
+        self.programs = 0       # backend compiles and cache loads
+        self.hits = self.misses = 0
+        mon.register_event_duration_secs_listener(self._on_duration)
+        mon.register_event_listener(self._on_event)
+
+    def _on_duration(self, event, secs, **kw):
+        if event in self.COMPILE_EVENTS:
+            self.compile_s += secs
+            if event == self.COMPILE_EVENTS[2]:
+                self.programs += 1
+
+    def _on_event(self, event, **kw):
+        if event == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self.misses += 1
+
+    def row(self) -> dict[str, float]:
+        """The two cumulative keys a train loop's log row carries."""
+        return {"compile/count": self.programs,
+                "compile/seconds": round(self.compile_s, 3)}
+
+
+_process_clock: CompileClock | None = None
+
+
+def process_clock() -> CompileClock:
+    """The process's shared clock, started on first call."""
+    global _process_clock
+    if _process_clock is None:
+        _process_clock = CompileClock()
+    return _process_clock

@@ -111,7 +111,7 @@ def test_ring_overflow_drops_oldest_and_counts():
 
 # -- disabled fast path -----------------------------------------------------
 def test_disabled_path_allocates_nothing():
-    assert not tracing.ENABLED
+    assert not tracing.ENABLED and not tracing.PROFILING
     lock = threading.Lock()
     # singletons / passthroughs: no per-call object on the disabled path
     assert tracing.span("env_step") is tracing._NULL
