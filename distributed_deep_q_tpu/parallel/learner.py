@@ -231,32 +231,33 @@ def refresh_target(cfg: TrainConfig, params: Any, target_params: Any,
 # decomposes into ~5 scheduled fusions PER LEAF, plus a per-leaf stack
 # concat feeding the stacked forward and a per-leaf gnorm partial — ~85 of
 # the body's ~125 scheduled ops for a 12-leaf Nature net. The fix: carry
-# θ/θ⁻ as ONE flat f32 plane and the Adam moments as two more, so the
-# whole optimizer is a fixed handful of plane-wide kernels independent of
-# leaf count. Layout of the PT plane ([2N], N = total param count): per
-# leaf the online and target blocks sit ADJACENT ([θ_i; θ⁻_i] at offset
+# θ/θ⁻ as ONE flat f32 plane and the Adam moments as two more, so clip,
+# moments and the Adam update are plane-wide kernels independent of leaf
+# count. Layout of the PT plane ([2N], N = total param count): per leaf
+# the online and target blocks sit ADJACENT ([θ_i; θ⁻_i] at offset
 # 2·off_i), so the stacked ``[2, shape]`` leaf view the vmapped forward
 # wants is a contiguous slice — free, where a [P; T] split layout would
-# pay a concat per leaf per step. Tree↔plane conversion happens once per
-# chunk at the scan boundary, amortized over ``chain`` grad steps.
+# pay a concat per leaf per step. The layout is STATIC STRUCTURE only:
+# every block is addressed by a Python-int offset and size, so the step
+# reads and writes it with static slices and one concatenate, which any
+# backend lowers to contiguous copies. Never address it through index
+# arrays: a ``jnp.take`` over the plane is one op in the CPU census and
+# an element-by-element gather of 3.37M scalars on the TPU (two of them
+# were 86 of the batch-32 step's 86.8 ms, PERF.md §6 PR 25).
+# Tree↔plane conversion happens once per chunk at the scan boundary,
+# amortized over ``chain`` grad steps.
 
 class PlaneMeta(NamedTuple):
-    """Static layout of the flat planes, derived from the param treedef.
-
-    ``upd_map``/``src_map``/``onl`` are host-side constants baked into the
-    program: ``upd_map`` sends every PT position to its leaf's online
-    position in the [N] update plane (both halves — the target half reuses
-    the online update on refresh); ``src_map`` mirrors each target
-    position onto its online twin (identity on the online half); ``onl``
-    marks the online half."""
+    """Static layout of the flat planes, derived from the param treedef:
+    leaf ``i`` is ``[off_i, off_i + size_i)`` of an [N] plane and
+    ``[2·off_i, 2·off_i + 2·size_i)`` (online block, then target block)
+    of the [2N] PT plane. Python ints and shapes only — nothing here is
+    an array, so nothing of it is baked into a program as a constant."""
     treedef: Any
     shapes: tuple
     sizes: tuple
     offsets: tuple
     n: int
-    upd_map: np.ndarray
-    src_map: np.ndarray
-    onl: np.ndarray
 
 
 def plane_meta(params: Any) -> PlaneMeta:
@@ -264,21 +265,7 @@ def plane_meta(params: Any) -> PlaneMeta:
     shapes = tuple(leaf.shape for leaf in leaves)
     sizes = tuple(int(np.prod(s, dtype=np.int64)) for s in shapes)
     offsets = tuple(int(o) for o in np.cumsum((0,) + sizes[:-1]))
-    n = int(sum(sizes))
-    upd_map = np.empty(2 * n, np.int32)
-    src_map = np.empty(2 * n, np.int32)
-    onl = np.zeros(2 * n, bool)
-    for off, size in zip(offsets, sizes):
-        o2 = 2 * off
-        upd = np.arange(off, off + size, dtype=np.int32)
-        upd_map[o2:o2 + size] = upd
-        upd_map[o2 + size:o2 + 2 * size] = upd
-        src = np.arange(o2, o2 + size, dtype=np.int32)
-        src_map[o2:o2 + size] = src
-        src_map[o2 + size:o2 + 2 * size] = src
-        onl[o2:o2 + size] = True
-    return PlaneMeta(treedef, shapes, sizes, offsets, n,
-                     upd_map, src_map, onl)
+    return PlaneMeta(treedef, shapes, sizes, offsets, int(sum(sizes)))
 
 
 def params_to_plane(meta: PlaneMeta, params: Any,
@@ -338,14 +325,16 @@ def fused_plane_adam_target_step(
     v: jax.Array, count: jax.Array, pt: jax.Array, step: jax.Array,
     gnorm: jax.Array,
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """``fused_adam_target_step`` on the flat planes: clip + Adam + the
-    parameter/target update as a FIXED number of plane-wide kernels
-    (two multiply-adds, two gathers, one select/lerp) regardless of how
-    many leaves the net has. Per-element arithmetic is identical to the
-    per-leaf version (the maps only permute positions), so the hard
-    refresh stays a bitwise select of the freshly-updated online value.
-    ``g`` is the [N] online-layout gradient plane (already allreduced);
-    ``step`` the already-incremented step. Returns (m2, v2, pt2, count2).
+    """``fused_adam_target_step`` on the flat planes: clip + Adam as
+    plane-wide kernels on the [N] online layout, then the [2N] PT plane
+    assembled from static per-leaf slices: leaf ``i``'s online block is
+    ``θ_i − upd_i``, its target block the refresh rule applied to that
+    fresh value — no index gather, no plane-sized constant. Per-element
+    arithmetic is identical to the per-leaf version (only positions
+    differ), so the hard refresh stays a bitwise select of the
+    freshly-updated online value. ``g`` is the [N] online-layout gradient
+    plane (already allreduced); ``step`` the already-incremented step.
+    Returns (m2, v2, pt2, count2).
     """
     b1, b2 = ADAM_B1, ADAM_B2
     count2 = safe_increment(count)
@@ -365,16 +354,28 @@ def fused_plane_adam_target_step(
     # bitwise guarantee (measured: ~300 one-ulp params diffs per step)
     upd = (m2 / bc1) / ((jnp.sqrt(v2 / bc2) + cfg.adam_eps)
                         * np.float32(1.0 / cfg.lr))
-    # candidate value for EVERY PT position: its (fresh) online twin
-    p2t = jnp.take(pt, meta.src_map) - jnp.take(upd, meta.upd_map)
     if cfg.target_tau > 0:
-        w = jnp.asarray(np.where(meta.onl, 1.0, cfg.target_tau),
-                        jnp.float32)
-        pt2 = w * p2t + (1.0 - w) * pt
+        # Polyak weights rounded to f32 first, 1 − τ formed in f32. The
+        # lerp is FMA-contractible either way round; this addend order is
+        # the one LLVM contracts like the [2N] weight-plane lerp it
+        # replaced (pinned bitwise in tests/test_op_surgery.py)
+        tau = np.float32(cfg.target_tau)
+        keep = np.float32(1.0) - tau
+
+        def target_block(p2, t):
+            return keep * t + tau * p2
     else:
-        take = jnp.asarray(meta.onl) | (
-            step % cfg.target_update_period == 0)
-        pt2 = jnp.where(take, p2t, pt)
+        refresh = step % cfg.target_update_period == 0
+
+        def target_block(p2, t):
+            return jnp.where(refresh, p2, t)
+
+    blocks = []
+    for off, size in zip(meta.offsets, meta.sizes):
+        o2 = 2 * off
+        p2 = pt[o2:o2 + size] - upd[off:off + size]
+        blocks += [p2, target_block(p2, pt[o2 + size:o2 + 2 * size])]
+    pt2 = jnp.concatenate(blocks)
     return m2.astype(jnp.dtype(cfg.adam_mu_dtype)), v2, pt2, count2
 
 

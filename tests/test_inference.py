@@ -403,7 +403,15 @@ def test_shadow_is_mirror_only_and_counts_divergence():
                 (4, 6)).astype(np.float32))
             assert r["tenant"] == TENANT_PRIMARY  # never a shadow reply
             assert all(int(a) == 1 for a in np.asarray(r["actions"]))
-        tm = server.telemetry_summary()
+        # the mirror runs after the primary waiters are released, so the
+        # last reply can arrive before its rows are counted
+        deadline = time.monotonic() + 5.0
+        while True:
+            tm = server.telemetry_summary()
+            if (tm["tenant/shadow:next/shadow_diverged"] >= 16.0
+                    or time.monotonic() > deadline):
+                break
+            time.sleep(0.01)
         assert tm["tenant/shadow:next/shadow_requests"] >= 16.0
         assert tm["tenant/shadow:next/shadow_diverged"] >= 16.0
         assert tm["tenant/shadow:next/requests"] == 0.0  # served nobody

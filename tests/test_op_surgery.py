@@ -5,7 +5,8 @@ Every rewritten program is pinned against the program it replaced:
 - the stacked-weight triple Q-forward vs three separate module applies
 - the donated fused step vs the same step compiled without donation
   (donation is an aliasing contract — it must never change values)
-- the plane-carry fused chain body vs the tree-carry body
+- the plane-carry fused chain body vs the tree-carry body, and its
+  static-slice optimizer step vs the index-gather step it replaced
 - the time-batched R2D2 torso (burn-in included) vs the module-apply
   in-scan reference, at the CPU bench shapes
 
@@ -13,6 +14,8 @@ Bitwise where the two programs are the same math in the same order
 (donation); tight-atol where a rewrite legitimately reorders conv/reduce
 lanes (stacked batching changes the batch shape XLA reduces over).
 """
+
+import re
 
 import numpy as np
 import pytest
@@ -151,6 +154,150 @@ def test_plane_body_matches_tree_body():
     np.testing.assert_allclose(np.asarray(da.dstate.prio),
                                np.asarray(db.dstate.prio),
                                rtol=1e-4, atol=1e-5)
+
+
+def _gather_plane_step(cfg, meta, g, m, v, count, pt, step, gnorm):
+    """The formulation ``fused_plane_adam_target_step`` replaced (PR 4 to
+    PR 24), kept here as its reference: [2N] position maps baked in as
+    constants and two ``jnp.take`` gathers over the plane. Same Adam
+    arithmetic, operand for operand."""
+    from distributed_deep_q_tpu.parallel.learner import (
+        ADAM_B1, ADAM_B2, safe_increment)
+
+    upd_map = np.empty(2 * meta.n, np.int32)
+    src_map = np.empty(2 * meta.n, np.int32)
+    onl = np.zeros(2 * meta.n, bool)
+    for off, size in zip(meta.offsets, meta.sizes):
+        o2 = 2 * off
+        upd_map[o2:o2 + size] = upd_map[o2 + size:o2 + 2 * size] = \
+            np.arange(off, off + size, dtype=np.int32)
+        src_map[o2:o2 + size] = src_map[o2 + size:o2 + 2 * size] = \
+            np.arange(o2, o2 + size, dtype=np.int32)
+        onl[o2:o2 + size] = True
+    count2 = safe_increment(count)
+    c = count2.astype(jnp.float32)
+    bc1, bc2 = 1.0 - ADAM_B1 ** c, 1.0 - ADAM_B2 ** c
+    g = g * jnp.minimum(1.0, cfg.grad_clip_norm / jnp.maximum(gnorm, 1e-12))
+    m2 = ADAM_B1 * m.astype(jnp.float32) + (1.0 - ADAM_B1) * g
+    v2 = ADAM_B2 * v + (1.0 - ADAM_B2) * jnp.square(g)
+    upd = (m2 / bc1) / ((jnp.sqrt(v2 / bc2) + cfg.adam_eps)
+                        * np.float32(1.0 / cfg.lr))
+    p2t = jnp.take(pt, src_map) - jnp.take(upd, upd_map)
+    if cfg.target_tau > 0:
+        w = jnp.asarray(np.where(onl, 1.0, cfg.target_tau), jnp.float32)
+        pt2 = w * p2t + (1.0 - w) * pt
+    else:
+        pt2 = jnp.where(
+            jnp.asarray(onl) | (step % cfg.target_update_period == 0),
+            p2t, pt)
+    return m2.astype(jnp.dtype(cfg.adam_mu_dtype)), v2, pt2, count2
+
+
+@pytest.mark.parametrize("target_tau,period,step", [
+    (0.0, 4, 8),      # hard refresh, on a refresh step
+    (0.0, 4, 7),      # hard refresh, off a refresh step
+    (0.005, 4, 7),    # Polyak lerp every step
+], ids=["hard-refresh-step", "hard-off-step", "polyak"])
+def test_plane_step_static_slices_match_gather_reference(
+        target_tau, period, step):
+    """The static-slice plane step == the index-gather formulation it
+    replaced, BITWISE on every output: the rewrite moves the same values
+    by contiguous copies instead of scalar fetches and touches no
+    arithmetic. Leaves of unequal, tile-unaligned sizes."""
+    from distributed_deep_q_tpu.parallel.learner import (
+        fused_plane_adam_target_step, params_to_plane, plane_meta,
+        tree_to_plane)
+
+    rng = np.random.default_rng(7)
+
+    def tree(scale):
+        return {"a": {"kernel": rng.standard_normal((5, 7, 3)) * scale,
+                      "bias": rng.standard_normal((3,)) * scale},
+                "b": {"kernel": rng.standard_normal((129, 11)) * scale,
+                      "bias": rng.standard_normal((11,)) * scale},
+                "c": rng.standard_normal((1,)) * scale}
+
+    as_f32 = lambda t: jax.tree.map(
+        lambda x: jnp.asarray(x, jnp.float32), t)
+    params, target = as_f32(tree(1.0)), as_f32(tree(1.0))
+    meta = plane_meta(params)
+    assert len(set(meta.sizes)) >= 3 and len(meta.sizes) == 5
+    cfg = TrainConfig(target_tau=target_tau, target_update_period=period,
+                      grad_clip_norm=1.0, lr=6.25e-5, adam_eps=1.5e-4)
+    g = tree_to_plane(as_f32(tree(0.5)))
+    args = (g, tree_to_plane(as_f32(tree(0.1))),
+            jnp.square(tree_to_plane(as_f32(tree(0.1)))),
+            jnp.asarray(41, jnp.int32),
+            params_to_plane(meta, params, target),
+            jnp.asarray(step, jnp.int32), jnp.sqrt(jnp.sum(jnp.square(g))))
+    got = jax.jit(
+        lambda *a: fused_plane_adam_target_step(cfg, meta, *a))(*args)
+    want = jax.jit(lambda *a: _gather_plane_step(cfg, meta, *a))(*args)
+    for name, x, y in zip(("m2", "v2", "pt2", "count2"), got, want):
+        assert x.dtype == y.dtype and x.shape == y.shape, name
+        # raw bits: -0.0 and NaN payloads count too
+        np.testing.assert_array_equal(
+            np.asarray(x).view(np.uint32), np.asarray(y).view(np.uint32),
+            err_msg=name)
+    # the refresh rule did what the case says, not merely the same thing
+    pt, pt2 = np.asarray(args[4]), np.asarray(got[2])
+    for off, size in zip(meta.offsets, meta.sizes):
+        onl, tgt = pt2[2 * off:2 * off + size], \
+            pt2[2 * off + size:2 * off + 2 * size]
+        assert not np.array_equal(onl, pt[2 * off:2 * off + size])
+        old_tgt = pt[2 * off + size:2 * off + 2 * size]
+        if target_tau > 0:
+            assert not np.array_equal(tgt, old_tgt)
+            assert not np.array_equal(tgt, onl)
+        elif step % period == 0:
+            np.testing.assert_array_equal(tgt, onl)
+        else:
+            np.testing.assert_array_equal(tgt, old_tgt)
+
+
+def _plane_index_ops(text, n):
+    """(gathers with an operand or result of >= n elements, s32 constants
+    of >= 2n elements) in a compiled program's HLO text."""
+    def elems(shape):
+        return int(np.prod([int(x) for x in shape.split(",") if x] or [1]))
+
+    gathers = [
+        line.strip()[:160] for line in text.splitlines()
+        if re.search(r"= \S+ gather\(", line)
+        and any(elems(s) >= n
+                for s in re.findall(r"[a-z]\w*\[([\d,]*)\]", line))]
+    constants = [
+        line.strip()[:160] for line in text.splitlines()
+        if (m := re.search(r"= s32\[([\d,]*)\]\S* constant\(", line))
+        and elems(m.group(1)) >= 2 * n]
+    return gathers, constants
+
+
+def test_plane_train_program_has_no_plane_sized_gather():
+    """The compiled plane train program holds no gather over the
+    parameter plane and no [2N] int32 constant: the position maps cannot
+    come back through a refactor that is only ever timed on CPU, where
+    two gathers are just two ops (on the TPU they were 86 of the batch-32
+    step's 86.8 ms, PERF.md §6 PR 25)."""
+    from distributed_deep_q_tpu.parallel.learner import plane_meta
+    from distributed_deep_q_tpu.profiling import compile_fused_train
+    from distributed_deep_q_tpu.solver import Solver
+
+    cfg = _transition_cfg(stack_forwards="on")
+    solver = Solver(cfg)
+    meta = plane_meta(solver.state.params)
+    text = compile_fused_train(
+        solver, _filled_dev_replay(solver, cfg), 2).as_text()
+    assert "plane_train_fn" in text     # the plane body, not the tree's
+    assert _plane_index_ops(text, meta.n) == ([], [])
+    # ... and the census does see the formulation it guards against
+    plane = jnp.zeros((meta.n,), jnp.float32)
+    ref = jax.jit(lambda g, m, v, pt: _gather_plane_step(
+        cfg.train, meta, g, m, v, jnp.int32(0), pt, jnp.int32(1),
+        jnp.float32(1.0))).lower(
+            plane, plane, plane, jnp.zeros((2 * meta.n,), jnp.float32))
+    gathers, constants = _plane_index_ops(ref.compile().as_text(), meta.n)
+    assert gathers and constants
 
 
 def _r2d2_solver(stack_forwards):
