@@ -8,21 +8,27 @@ go into the window. ``Recorder`` stands in the
 learner's program table for those two chunks only and keeps what the
 sample program really fed the train program (drawn rows, windows,
 composed metadata, weights). After the window has closed — so neither
-``setup_s`` nor ``memory_peak_bytes`` carries it — ``verdict`` lets
-``reference/dqn.py`` follow the same steps from the seed and prints every
-number compared beside its limit.
+``setup_s`` nor ``memory_peak_bytes`` carries it — ``compare`` lets
+``reference/dqn.py`` follow the same steps from the seed and hands every
+number compared to ``family.verdict``, which holds each to its limit.
+
+This is the comparison of ONE model family: the Nature CNN on the frame
+ring (``DevicePERFrameReplay`` + ``FusedStepStream``). A configuration
+takes it by ``"check": "check"``; another family brings its own module
+with the same two functions, ``build_checked`` and ``compare``
+(``family.py``; ``FOLLOWED_CHUNKS`` and ``toy`` are the two optional names
+listed there).
 """
 
 from __future__ import annotations
 
 import contextlib
-import importlib
-import time
 
 import numpy as np
 
 from benchmark import program
-from benchmark.common import emit
+from benchmark.common import emit, load_json
+from benchmark.family import load_reference
 
 # reference weight names in the order the program's weight IO lists its
 # leaves (``Solver.get_weights``: flax tree, keys sorted)
@@ -36,10 +42,6 @@ PROGRAM_LEAF_ORDER = ("q_b", "q_w", "conv1_b", "conv1_w", "conv2_b",
 # from the control WORSE (the two sides drift apart step by step, PERF.md
 # §2); ``control.py --follow-chunks`` sets this to read that again.
 FOLLOWED_CHUNKS = 1
-
-
-def load_reference(conf: dict):
-    return importlib.import_module(f"benchmark.reference.{conf['reference']}")
 
 
 _STEPS: dict = {}
@@ -244,7 +246,8 @@ def build_checked(conf: dict, cfg, seed: int, rows, episode: int,
     """The object the window will drive, built and checked once: a solver
     with the seed's weights, a ring as ``train_distributed`` builds it with
     ``rows`` seeded rows, the stream, and the first chunks driven through
-    it. Returns ``(solver, replay, stream, mirror, rec)``."""
+    it. Returns ``(solver, replay, stream, mirror, rec)``;
+    ``rec["driven_steps"]`` is what a driver takes off its warm-up."""
     from distributed_deep_q_tpu.solver import FusedStepStream
 
     assert_hparams(conf, cfg)
@@ -261,6 +264,7 @@ def build_checked(conf: dict, cfg, seed: int, rows, episode: int,
     stream = FusedStepStream(solver, replay, chain)
     rec = drive_first_chunks(solver, stream, replay, chain,
                              FOLLOWED_CHUNKS)
+    rec["driven_steps"] = (FOLLOWED_CHUNKS + 1) * chain
     mark("first_chunks")
     return solver, replay, stream, mirror, rec
 
@@ -343,15 +347,13 @@ def _follow(ref, hp: dict, seed: int, mirror, idxs, betas, quant):
                 theta=host(state["theta"]), m=host(state["m"]))
 
 
-def verdict(conf: dict, seed: int, mirror, rec: dict, *, quant=None,
-            label: str = "check") -> dict:
+def compare(conf: dict, seed: int, mirror, rec: dict, *, quant=None) -> dict:
     """Let the reference follow the recorded steps and compare. Returns
-    ``{"correct": bool, "numbers": {name: [value, limit]}, "seconds": s,
-    "steps": per-step metrics of both}`` and prints the numbers. With
-    ``quant`` the CONTROL — the reference in the next precision down —
-    stands where the program stood, for the optimizer numbers (the feed
-    numbers are the program's either way)."""
-    t0 = time.perf_counter()
+    ``{"numbers": {name: value}, "steps": per-step metrics of both,
+    "print": what to print beside them}``; ``family.verdict`` holds the
+    numbers to their limits. With ``quant`` the CONTROL — the reference in
+    the next precision down — stands where the program stood, for the
+    optimizer numbers (the feed numbers are the program's either way)."""
     ref = load_reference(conf)
     hp = conf["hparams"]
     chain = hp["fused_chain"]
@@ -449,23 +451,37 @@ def verdict(conf: dict, seed: int, mirror, rec: dict, *, quant=None,
         {k: prog["theta"][k] - prog["theta0"][k] for k in gold["theta"]},
         {k: gold["theta"][k] - gold["theta0"][k] for k in gold["theta"]})
 
-    # exact limits are the reference's; every other limit is the
-    # configuration's own, read on the chip — a configuration that lacks
-    # one has not been read, and is not correct
-    limits = {**conf.get("limits", {}), **ref.EXACT_LIMITS}
-    missing = sorted(set(nums) - set(limits))
-    if missing:
-        raise SystemExit(f"configuration {conf['name']} states no limit for "
-                         f"{missing}: read them with benchmark/control.py")
-    numbers = {k: [v, limits[k]] for k, v in nums.items()}
-    correct = all(np.isfinite(v) and v <= lim for v, lim in numbers.values())
-    secs = time.perf_counter() - t0
-    emit(**{label: numbers}, correct=bool(correct),
-         reference_seconds=round(secs, 3), quant=quant,
-         followed_steps=follow * chain,
-         reference_loss=gold["metrics"]["loss"][:3],
-         compared_loss=[float(x) for x in prog["metrics"]["loss"][:3]])
     steps = {k: [[float(x) for x in prog["metrics"][k]], gold["metrics"][k]]
              for k in gold["metrics"]}
-    return dict(correct=bool(correct), numbers=numbers, seconds=secs,
-                steps=steps)
+    return dict(numbers=nums, steps=steps, print=dict(
+        followed_steps=follow * chain,
+        reference_loss=gold["metrics"]["loss"][:3],
+        compared_loss=[float(x) for x in prog["metrics"]["loss"][:3]]))
+
+
+# ---- toy sizes: the CPU walk of this family's cells ----
+
+TOY_OVERRIDES = ["replay.capacity=8192", "replay.batch_size=32",
+                 "env.frame_shape=36,36", "net.frame_shape=36,36",
+                 "mesh.num_fake_devices=1"]
+TOY_HPARAMS = {"capacity": 8192, "batch_size": 32, "frame_shape": [36, 36]}
+TOY_TRAFFIC = {"episode": 256, "warmup_steps": 16, "row_every": 40,
+               "num_actors": 2, "learn_start": 300, "trace_start_step": 16,
+               "trace_num_steps": 16}
+TOY_SLACK = 3.0     # 36x36 frames at batch 32 are noisier than any cell
+
+
+def toy(conf: dict, traffic: dict) -> None:
+    """This family's toy sizes for a CPU walk (``rehearse.py``,
+    ``test_control.py``), as a ``conf_patch``. At batch 32 every
+    configuration takes the plane path, so the limits start from those
+    read for the batch-32 configuration; the numbers that gather the
+    steps' drifting apart get ``TOY_SLACK`` times the room, the first
+    step's forward gap — the one that separates the fp8 control — keeps
+    its limit."""
+    limits = load_json("configs", "dqn_b32.json")["limits"]
+    conf["limits"] = {k: v if k == "q_mean_first_rel" else TOY_SLACK * v
+                      for k, v in limits.items()}
+    conf["overrides"] = [*conf["overrides"], *TOY_OVERRIDES]
+    conf["hparams"].update(TOY_HPARAMS)
+    traffic.update({k: v for k, v in TOY_TRAFFIC.items() if k in traffic})

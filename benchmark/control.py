@@ -1,13 +1,14 @@
 #!/usr/bin/env python3
-"""Readings the limits in ``reference/dqn.py`` are set from, on the chip at
-a cell's own size, several seeds in one process:
+"""Readings a configuration's ``limits`` are set from, on the chip at a
+cell's own size, several seeds in one process:
 
     python3 benchmark/control.py --workload <cell> --seeds 1,2,3[,...]
 
 For each seed: the program's first chunks against the reference (the SOUND
 reading) and the CONTROL — the reference computed in float8_e4m3, the
 nearest precision below the configuration's bfloat16 — held against the
-same reference. No measured window is needed: the comparison reads the
+same reference, both through the comparison the configuration names
+(``family.py``). No measured window is needed: the comparison reads the
 program's first chunks only. Prints, per compared number, the largest
 sound reading, the smallest control reading and the limit; the benchmark's
 own runs never run this.
@@ -28,7 +29,7 @@ if ROOT not in sys.path:
 
 def readings(workload: str, seeds, backend: str = "tpu", conf_patch=None,
              prefill=None):
-    from benchmark import check, program, run
+    from benchmark import family, program, run
 
     out = []
     for seed in seeds:
@@ -38,14 +39,15 @@ def readings(workload: str, seeds, backend: str = "tpu", conf_patch=None,
             conf_patch(conf, traffic)
         cfg = program.make_cfg(conf, seed, backend,
                                traffic.get("overrides", []))
-        solver, replay, stream, mirror, rec = check.build_checked(
-            conf, cfg, seed, prefill or traffic["prefill"],
-            traffic["episode"])
+        solver, replay, stream, mirror, rec = family.load_check(
+            conf).build_checked(conf, cfg, seed,
+                                prefill or traffic["prefill"],
+                                traffic["episode"])
         del stream, replay, solver
         gc.collect()
-        sound = check.verdict(conf, seed, mirror, rec, label="sound")
-        ctrl = check.verdict(conf, seed, mirror, rec, quant="fp8",
-                             label="control")
+        sound = family.verdict(conf, seed, mirror, rec, label="sound")
+        ctrl = family.verdict(conf, seed, mirror, rec, quant="fp8",
+                              label="control")
         out.append({"seed": seed, "sound": sound, "control": ctrl})
     return out
 
@@ -91,7 +93,11 @@ def main(argv=None) -> int:
         print("control: no TPU — nothing was run", file=sys.stderr)
         return 1
     if args.follow_chunks:      # the limits were read at the default
-        from benchmark import check
+        from benchmark import family, run
+        check = family.load_check(run.load_cell(args.workload)[2]["conf"])
+        if not hasattr(check, "FOLLOWED_CHUNKS"):
+            raise SystemExit(f"{check.__name__} states no FOLLOWED_CHUNKS "
+                             "(family.py): --follow-chunks is not for it")
         check.FOLLOWED_CHUNKS = args.follow_chunks
     rs = readings(args.workload, [int(s) for s in args.seeds.split(",")],
                   prefill=args.prefill)

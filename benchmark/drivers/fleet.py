@@ -36,7 +36,7 @@ import multiprocessing
 import os
 import time
 
-from benchmark import check, program
+from benchmark import family, program
 from benchmark.common import emit, fence, memory_peak_bytes
 
 TOTAL_STEPS = 50_000_000        # far beyond any window
@@ -113,9 +113,10 @@ def run(ctx) -> dict:
 
     mark("imports_config")
     # the check's chunks, on the solver and the ring that will train
-    solver, ring, stream, mirror, rec = check.build_checked(
-        conf, cfg, ctx.seed, traffic["prefill"], traffic["episode"],
-        beta_steps=cfg.train.total_steps, mark=mark)
+    solver, ring, stream, mirror, rec = family.load_check(
+        conf).build_checked(conf, cfg, ctx.seed, traffic["prefill"],
+                            traffic["episode"],
+                            beta_steps=cfg.train.total_steps, mark=mark)
     rows0 = ring.steps_added
     emit(ring_capacity=ring.capacity, ring_rows_written=len(ring),
          streams=ring.num_streams, slot_cap=ring.slot_cap, chain=chain,

@@ -19,7 +19,7 @@ import math
 import os
 import time
 
-from benchmark import check, program
+from benchmark import family, program
 from benchmark.common import CompileClock, emit, fence, memory_peak_bytes
 
 BIG = 10 ** 9       # steps_left: never clamp a chunk
@@ -40,14 +40,13 @@ def run(ctx) -> dict:
         marks[name] = time.perf_counter() - ctx.t_start
 
     mark("imports_config")
-    solver, replay, stream, mirror, rec = check.build_checked(
-        conf, cfg, ctx.seed, traffic["prefill"], traffic["episode"],
-        mark=mark)
+    solver, replay, stream, mirror, rec = family.load_check(
+        conf).build_checked(conf, cfg, ctx.seed, traffic["prefill"],
+                            traffic["episode"], mark=mark)
     emit(ring_capacity=replay.capacity, ring_rows_written=len(replay),
          streams=replay.num_streams, slot_cap=replay.slot_cap,
          chain=chain, batch=cfg.replay.batch_size)
-    driven = (check.FOLLOWED_CHUNKS + 1) * chain
-    for _ in range(max(traffic["warmup_steps"] - driven, 0)
+    for _ in range(max(traffic["warmup_steps"] - rec["driven_steps"], 0)
                    // chain * chain):
         stream.next(BIG)
 

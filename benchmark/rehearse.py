@@ -1,14 +1,16 @@
 #!/usr/bin/env python3
-"""CPU rehearsal of both drivers at a toy size, before chip time is spent.
+"""CPU rehearsal of the drivers at a toy size, before chip time is spent.
 
     JAX_PLATFORMS=cpu python3 benchmark/rehearse.py [--workload <cell>] [--trace 1]
 
 Walks the real control flow — data-file loading, prefill and mirror, the
 recorder and the reference check, the sink and its raise-to-stop teardown
-— with ``backend=cpu``, capacity 8 192, batch 32, 36x36 frames, 2 actors,
-Pallas interpreted, 3 s. It prints under ``rehearsal_*`` names, never the
-contract's line, and always exits non-zero: nothing it prints is a device
-number.
+— with ``backend=cpu`` at the toy sizes the cell's family states (its
+comparison module's ``toy``, ``family.py``; for the frame ring: capacity
+8 192, batch 32, 36x36 frames, 2 actors, Pallas interpreted), 3 s. A cell
+whose family states none is passed over. It prints under ``rehearsal_*``
+names, never the contract's line, and always exits non-zero: nothing it
+prints is a device number.
 """
 
 from __future__ import annotations
@@ -22,32 +24,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-TOY_OVERRIDES = ["replay.capacity=8192", "replay.batch_size=32",
-                 "env.frame_shape=36,36", "net.frame_shape=36,36",
-                 "mesh.num_fake_devices=1"]
-TOY_HPARAMS = {"capacity": 8192, "batch_size": 32, "frame_shape": [36, 36]}
-TOY_TRAFFIC = {"episode": 256, "warmup_steps": 16, "row_every": 40,
-               "num_actors": 2, "learn_start": 300, "trace_start_step": 16,
-               "trace_num_steps": 16}
-
-
-TOY_SLACK = 3.0     # 36x36 frames at batch 32 are noisier than any cell
-
 
 def toy(conf: dict, traffic: dict) -> None:
-    """Toy sizes. At batch 32 every configuration takes the plane path, so
-    the limits start from those read for the batch-32 configuration; the
-    numbers that gather the steps' drifting apart get ``TOY_SLACK`` times
-    the room, the first step's forward gap — the one that separates the
-    fp8 control — keeps its limit."""
-    from benchmark.common import load_json
+    """The toy sizes of the cell's own family (a ``conf_patch``)."""
+    from benchmark import family
 
-    limits = load_json("configs", "dqn_b32.json")["limits"]
-    conf["limits"] = {k: v if k == "q_mean_first_rel" else TOY_SLACK * v
-                      for k, v in limits.items()}
-    conf["overrides"] = [*conf["overrides"], *TOY_OVERRIDES]
-    conf["hparams"].update(TOY_HPARAMS)
-    traffic.update({k: v for k, v in TOY_TRAFFIC.items() if k in traffic})
+    family.load_check(conf).toy(conf, traffic)
 
 
 def main(argv=None) -> int:
@@ -61,10 +43,12 @@ def main(argv=None) -> int:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    from benchmark import run
+    from benchmark import family, run
 
     with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
         cells = [w["name"] for w in json.load(fh)["workloads"]]
+    cells = [c for c in cells if hasattr(
+        family.load_check(run.load_cell(c)[2]["conf"]), "toy")]
     for cell in args.workload or cells:
         ns = argparse.Namespace(workload=cell, seed=args.seed,
                                 seconds=args.seconds, trace=args.trace)

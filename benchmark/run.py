@@ -5,9 +5,12 @@
 
 One process holds the chip (actor children are CPU). Everything that
 belongs to one configuration, one traffic mix or one per-layer metric is a
-file found by the name in ``BENCHMARK.json`` (see ``benchmark/README.md``).
-Every line but the last is one JSON object of things worth reading; the
-last line is the contract's object. No TPU, fewer chips than the cell
+file found by the name in ``BENCHMARK.json``, and what belongs to one model
+family (its comparison, its reference, its counts) by a name in the
+configuration (``family.py``; see ``benchmark/README.md``). Every line but
+the last is one JSON object of things worth reading; the last line is the
+contract's object, and the numbers compared are standard error's last
+lines. No TPU, fewer chips than the cell
 asks for, a compilation inside the window, a missing data file or a trace
 pattern that matches nothing: exit code != 0 and no result line.
 """
@@ -93,7 +96,7 @@ def run_cell(args, *, backend: str = "tpu", conf_patch=None) -> dict:
     """Everything after the look for a chip: the driver, the metrics, the
     verdict. Returns the contract's object (``test_control.py`` drives this
     with the timed path broken underneath)."""
-    from benchmark import check, trace_reduce
+    from benchmark import family, trace_reduce
 
     bench, cell, files = load_cell(args.workload)
     conf, traffic = files["conf"], files["traffic"]
@@ -127,16 +130,12 @@ def run_cell(args, *, backend: str = "tpu", conf_patch=None) -> dict:
 
     import jax
 
-    from benchmark import counts
-
     dev = jax.devices()[0]
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices()),
               "memory_peak_bytes": result["memory_peak_bytes"]}
     peaks_all = load_json("peaks.json")
-    emit(analytic_flops_per_step=counts.train_flops_per_step(conf["hparams"]),
-         gather_bytes_per_chunk=counts.gather_windows_bytes_per_chunk(
-             conf["hparams"]))
+    emit(**family.printed_counts(conf))
     line: dict = {}
     if args.trace:
         if dev.device_kind not in peaks_all and backend == "tpu":
@@ -146,7 +145,7 @@ def run_cell(args, *, backend: str = "tpu", conf_patch=None) -> dict:
             trace = trace_reduce.load(
                 trace_reduce.find_xplane(result["trace_dir"]))
         rctx = types.SimpleNamespace(
-            trace=trace, result=result, hp=conf["hparams"],
+            trace=trace, result=result, conf=conf, hp=conf["hparams"],
             peaks=peaks_all.get(dev.device_kind, {}))
         try:
             metrics = per_layer(bench, args.workload, rctx,
@@ -174,11 +173,12 @@ def run_cell(args, *, backend: str = "tpu", conf_patch=None) -> dict:
 
     # the reference follows the program's first steps only now: outside
     # set-up, outside the window, after the ring was freed
-    v = check.verdict(conf, args.seed, result["mirror"], result["rec"])
+    v = family.verdict(conf, args.seed, result["mirror"], result["rec"])
     failed = int(result["failed"])
     return {"correct": bool(v["correct"] and failed == 0),
             "attempted": int(result["attempted"]), "failed": failed,
-            "metrics": metrics, "device": device, **line}
+            "metrics": metrics, "device": device, **line,
+            "compared": v["numbers"]}
 
 
 def main(argv=None) -> int:
@@ -217,6 +217,9 @@ def main(argv=None) -> int:
          cache_from_env=bool(os.environ.get("JAX_COMPILATION_CACHE_DIR")))
     line = run_cell(args)
     sys.stdout.flush()
+    for name, (value, limit) in line["compared"].items():
+        print(f"compared {name} {value!r} limit {limit!r}", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return 0
 
