@@ -156,6 +156,42 @@ class SignalAtari:
         return self._frame(), reward, done, done
 
 
+class TokenEnv:
+    """Seeded token env for the token-window Q-network (``env.kind =
+    "token"``): the observation is the current token, the ACTION is the
+    next token, and emitting token ``a`` after token ``s`` pays +1 when
+    ``a == (3 s + 1) mod V`` and 0 otherwise — a contextual rule a
+    Q-network over token prefixes can learn, as ``SignalAtari`` is for the
+    pixel path. An episode is ``episode_len`` tokens from a seeded first
+    token. It stands in for a text environment with a learned or
+    programmatic reward, which this sandbox has none of."""
+
+    def __init__(self, vocab: int = 64, episode_len: int = 96,
+                 seed: int = 0):
+        self.num_actions = int(vocab)
+        self.episode_len = int(episode_len)
+        self.obs_shape = (1,)
+        self.obs_dtype = np.int32
+        self._rng = np.random.default_rng(seed)
+        self._t = 0
+        self._tok = 0
+
+    def best_action(self, token: int) -> int:
+        return (3 * int(token) + 1) % self.num_actions
+
+    def reset(self) -> np.ndarray:
+        self._t = 0
+        self._tok = int(self._rng.integers(self.num_actions))
+        return np.asarray([self._tok], np.int32)
+
+    def step(self, action: int):
+        self._t += 1
+        reward = 1.0 if int(action) == self.best_action(self._tok) else 0.0
+        self._tok = int(action)
+        done = self._t >= self.episode_len
+        return np.asarray([self._tok], np.int32), reward, done, done
+
+
 class VelocitySignalAtari:
     """Pixel env whose reward is a function of MOTION, not appearance — the
     temporal-integration probe (VERDICT r3 next #9).
@@ -422,6 +458,8 @@ def make_env(cfg: EnvConfig, seed: int = 0) -> Env:
                                        segment=0 if "-ep" in cfg.id else 8)
         return SignalAtari(frame_shape=cfg.frame_shape, seed=seed,
                            orientation=orientation)
+    if cfg.kind == "token":
+        return TokenEnv(cfg.token_vocab, cfg.max_episode_steps, seed)
     raise ValueError(f"unknown env kind {cfg.kind!r}")
 
 

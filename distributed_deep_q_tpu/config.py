@@ -18,10 +18,52 @@ from typing import Any
 
 
 @dataclass
+class TokenQConfig:
+    """Token-window Q-network backbone (``net.kind = "tokenq"``,
+    ``models/tokenq.py``): a decoder-only transformer whose head row ``a``
+    is Q(token prefix, next token ``a``). The keys are the published
+    ``config.json`` keys of the architecture it runs; the defaults are a
+    toy. ``experts_held`` / ``expert_offset`` and ``net.num_actions`` (the
+    vocabulary rows held) say which SHARE of an expert-parallel deployment
+    this process computes: the router stays ``moe_num_primary_experts``
+    wide and what the absent experts would add is left out."""
+
+    hidden_size: int = 64
+    num_hidden_layers: int = 4
+    num_attention_heads: int = 4
+    num_key_value_heads: int = 2
+    head_dim: int = 16
+    rms_norm_eps: float = 1e-6
+    # per layer: 1 = sliding window of ``sliding_window_size`` tokens
+    # (query t sees keys s, t - window < s <= t), 0 = full causal; and
+    # 1 = rotary embedding on q/k, 0 = no positional encoding. Layer l
+    # takes entry l (a longer published layout is cut to the depth)
+    sliding_window_layout: tuple[int, ...] = (0, 1, 1, 1)
+    rope_layout: tuple[int, ...] = (0, 1, 1, 1)
+    sliding_window_size: int = 8
+    rope_theta: float = 10_000.0
+    # experts: ReGLU of width moe_ffn_hidden_size, router softmax over
+    # ALL moe_num_primary_experts, top moe_num_active_primary_experts
+    # kept and renormalised
+    moe_ffn_hidden_size: int = 32
+    moe_num_primary_experts: int = 8
+    moe_num_active_primary_experts: int = 2
+    experts_held: int = 8
+    expert_offset: int = 0
+    # kernel blocks: attention q/kv block (the window is padded to a
+    # multiple) and the kv columns of one inner step (a divisor of it),
+    # tokens per block of the Q head + TD loss, gmm m-tile
+    attn_block: int = 128
+    attn_compute_block: int = 128
+    head_block: int = 128
+    moe_tile: int = 128
+
+
+@dataclass
 class NetConfig:
     """Q-network topology. Replaces the reference's ``models/*.prototxt``."""
 
-    kind: str = "mlp"  # mlp | nature_cnn | r2d2
+    kind: str = "mlp"  # mlp | nature_cnn | r2d2 | tokenq
     num_actions: int = 2
     # mlp
     hidden: tuple[int, ...] = (64, 64)
@@ -35,6 +77,8 @@ class NetConfig:
     # compute dtype for the torso ("bfloat16" on TPU keeps the MXU fed;
     # params stay float32)
     compute_dtype: str = "float32"
+    # tokenq backbone (kind = "tokenq")
+    tokenq: TokenQConfig = field(default_factory=TokenQConfig)
 
 
 @dataclass
@@ -190,7 +234,7 @@ class TrainConfig:
 @dataclass
 class EnvConfig:
     id: str = "CartPole-v1"
-    kind: str = "gym"  # gym | atari | fake_atari | signal_atari
+    kind: str = "gym"  # gym | atari | fake_atari | signal_atari | token
     # multi-game fleets (config 4 "Atari-57 8-game subset"): when non-empty,
     # actor i plays games[i % len(games)] (env_for_actor) and eval reports
     # per-game returns. All games must expose the same action count — for
@@ -203,6 +247,9 @@ class EnvConfig:
     reward_clip: float = 1.0  # 0 disables; Atari clips to ±1 [P]
     terminal_on_life_loss: bool = True
     max_episode_steps: int = 27_000  # 108k frames / skip 4, standard Atari cap
+    # kind = "token": the seeded token env's vocabulary (= its action
+    # count: the action IS the next token)
+    token_vocab: int = 64
     noop_max: int = 30
 
 
@@ -588,6 +635,58 @@ def r2d2_config() -> Config:
     return c
 
 
+def tokenq_config() -> Config:
+    """Token-window Q-network at a toy size: a 4-layer transformer (full
+    + window attention, a held-experts MoE layer) doing token-level
+    Double-DQN over windows of ``replay.sequence_length`` + 1 tokens on
+    the seeded token env, through ``SequenceSolver`` and the fused
+    sequence step. The recipe is the Ape-X learner's."""
+    c = Config()
+    c.net = NetConfig(kind="tokenq", num_actions=64)
+    c.replay = ReplayConfig(
+        capacity=256 * 24, batch_size=8, sequence_length=24, burn_in=0,
+        prioritized=True, device_per=True, fused_chain=4,
+        learn_start=32 * 24, priority_beta_steps=10_000)
+    c.train = TrainConfig(lr=6.25e-5, adam_eps=1.5e-4, double_dqn=True,
+                          target_update_period=2_500, total_steps=2_000,
+                          train_every=24)
+    c.env = EnvConfig(id="token", kind="token", stack=1, reward_clip=0.0,
+                      max_episode_steps=96)
+    c.actors = ActorConfig(num_actors=1, eps_decay_steps=1_000)
+    return c
+
+
+def smallthinker_tokenq_config() -> Config:
+    """SmallThinker-21BA3B-Instruct (PowerInfer, config.json) as a
+    token-window Q-network, one chip's share of an 8-chip expert-parallel
+    deployment: every width as published (hidden 2560, 28/4 heads of 128,
+    experts of width 768, router 64 wide, top 6, window 4096, rope theta
+    1.5e6); one period of 4 layers (1 full/NoPE + 3 window/RoPE), 8 of
+    the 64 experts and 18 992 of the 151 936 vocabulary rows held here.
+    Windows of 8 192 steps (+1 token), batch 4, chain 4."""
+    c = tokenq_config()
+    c.net = NetConfig(
+        kind="tokenq", num_actions=18_992, compute_dtype="bfloat16",
+        tokenq=TokenQConfig(
+            hidden_size=2560, num_hidden_layers=4, num_attention_heads=28,
+            num_key_value_heads=4, head_dim=128, rms_norm_eps=1e-6,
+            sliding_window_layout=(0, 1, 1, 1), rope_layout=(0, 1, 1, 1),
+            sliding_window_size=4096, rope_theta=1_500_000.0,
+            moe_ffn_hidden_size=768, moe_num_primary_experts=64,
+            moe_num_active_primary_experts=6, experts_held=8,
+            expert_offset=0, attn_block=1024, attn_compute_block=512,
+            head_block=1024, moe_tile=256))
+    c.replay = dataclasses.replace(
+        c.replay, capacity=16_384 * 8_192, batch_size=4,
+        sequence_length=8_192, learn_start=64 * 8_192,
+        priority_beta_steps=1_000_000)
+    c.train = dataclasses.replace(c.train, total_steps=1_000_000,
+                                  train_every=8_192)
+    c.env = dataclasses.replace(c.env, max_episode_steps=4_096,
+                                token_vocab=18_992)
+    return c
+
+
 def env_for_actor(env: EnvConfig, actor_id: int) -> EnvConfig:
     """Per-actor game assignment (config 4 multi-game fleets): actor i
     plays ``games[i % len(games)]``; single-game configs pass through."""
@@ -603,6 +702,8 @@ PRESETS = {
     "breakout": breakout_config,
     "apex": apex_config,
     "r2d2": r2d2_config,
+    "tokenq": tokenq_config,
+    "smallthinker_tokenq": smallthinker_tokenq_config,
 }
 
 

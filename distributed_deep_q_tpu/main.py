@@ -94,6 +94,9 @@ def main(argv: list[str] | None = None) -> int:
         if cfg.net.kind == "r2d2":
             from distributed_deep_q_tpu.train import evaluate_recurrent
             ret = evaluate_recurrent(solver, cfg)
+        elif cfg.net.kind == "tokenq":
+            from distributed_deep_q_tpu.train import evaluate_tokenq
+            ret = evaluate_tokenq(solver, cfg)
         else:
             ret = evaluate(solver, cfg)
         print(json.dumps({"mode": "eval", "eval_return": ret,
@@ -110,20 +113,28 @@ def main(argv: list[str] | None = None) -> int:
         _maybe_restore(solver, cfg)
         rng = np.random.default_rng(cfg.train.seed)
         recurrent = cfg.net.kind == "r2d2"
+        tokens = cfg.net.kind == "tokenq"   # the state is the token prefix
         carry = solver.initial_state(1) if recurrent else None
         stacker = (FrameStacker(env.obs_shape, cfg.env.stack)
                    if env.obs_dtype == np.uint8 else None)
         obs, over, t, ep_ret = env.reset(), False, 0, 0.0
         if stacker:
             obs = stacker.reset(obs)
+        prefix = [int(obs[0])] if tokens else []
         while not over:
-            if recurrent:
+            if tokens:
+                a = solver.token_act(
+                    np.asarray(prefix[-(cfg.replay.sequence_length + 1):]),
+                    cfg.actors.eval_eps, rng)
+            elif recurrent:
                 a, carry = solver.act(np.asarray(obs), carry,
                                       cfg.actors.eval_eps, rng)
             else:
                 a = solver.act(obs, cfg.actors.eval_eps, rng)
             frame, r, _, over = env.step(a)
             obs = stacker.push(frame) if stacker else frame
+            if tokens:
+                prefix.append(int(frame[0]))
             ep_ret += r
             t += 1
             print(f"t={t} a={a} r={r:+.1f} R={ep_ret:.1f}")
@@ -134,12 +145,13 @@ def main(argv: list[str] | None = None) -> int:
 
 
 def _build_solver(cfg, env):
-    """Solver for eval/play: SequenceSolver for recurrent (r2d2) nets, the
-    feed-forward Solver otherwise — a train-mode r2d2 checkpoint must be
-    evaluable/playable from the CLI."""
+    """Solver for eval/play: SequenceSolver for recurrent (r2d2) and
+    token-window (tokenq) nets, the feed-forward Solver otherwise — a
+    train-mode checkpoint of either must be evaluable/playable from the
+    CLI."""
     import numpy as np
     obs_dim = int(np.prod(env.obs_shape))
-    if cfg.net.kind == "r2d2":
+    if cfg.net.kind in ("r2d2", "tokenq"):
         from distributed_deep_q_tpu.parallel.sequence_learner import (
             SequenceSolver)
         return SequenceSolver(cfg, obs_dim=obs_dim)

@@ -18,10 +18,17 @@ program:
    PER write-back.
 
 Batch sequences are sharded over ``dp`` on the batch axis — the scope
-decision recorded in SURVEY §5.7: sequence *length* stays ≤ O(100) steps so
-sequence-axis parallelism (ring attention / Ulysses-style CP) is
-deliberately not applicable; scale comes from sharding the batch of
+decision recorded in SURVEY §5.7: the RECURRENT path's sequence length
+stays ≤ O(100) steps, so sequence-axis parallelism (ring attention /
+Ulysses-style CP) is not applied; scale comes from sharding the batch of
 sequences.
+
+The TOKEN path (``net.kind = "tokenq"``, ``models/tokenq.py``) shares the
+learner, the loss and the optimizer step but has no carry and no burn-in:
+θ and θ⁻ each run ONE causal forward over a window of T+1 tokens — T in
+the thousands — and the Q head and the TD loss run blockwise over tokens
+(``_token_step_core``), because ``[tokens, vocabulary]`` Q-values cannot
+be materialised at that length. Its ring is ``replay/device_tokens.py``.
 """
 
 from __future__ import annotations
@@ -35,7 +42,7 @@ import optax
 from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from distributed_deep_q_tpu import learning
+from distributed_deep_q_tpu import learning, tracing
 from distributed_deep_q_tpu.config import ReplayConfig, TrainConfig
 from distributed_deep_q_tpu.models.qnet import (
     r2d2_burn_carry, r2d2_param_split, r2d2_recur, stacked_r2d2_features)
@@ -49,12 +56,57 @@ from distributed_deep_q_tpu.parallel.multihost import (
     global_batch, put_replicated)
 
 
+def token_q_select(hid_on: jax.Array, hid_tg: jax.Array, head_on: jax.Array,
+                   head_tg: jax.Array, actions: jax.Array, *, block: int,
+                   dtype, double: bool):
+    """The Q head over token positions, BLOCKWISE: ``[positions,
+    vocabulary]`` Q-values exist only one block of ``block`` positions at
+    a time (at 32 768 positions x 18 992 rows they are 2.5 GB, three times
+    over for θ, θ⁻ and the cotangent), and each block is rematerialised in
+    the backward pass.
+
+    ``hid_*`` [P, h] final-normed hidden states of θ and θ⁻, ``head_*``
+    [h, V], ``actions`` [P] the token taken at each position. Per position
+    p: ``q_sa`` = Q_θ(p, actions[p]), ``q_boot`` = Q_θ⁻(p, a*) with a* the
+    argmax of Q_θ(p, ·) (Double-DQN) or of Q_θ⁻(p, ·), and ``q_row`` =
+    Σ_a Q_θ(p, a). Only ``q_sa`` carries a gradient."""
+    n, h = hid_on.shape
+    nb = -(-n // block)
+    pad = nb * block - n
+
+    def blocks(x):
+        x = jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1))
+        return x.reshape((nb, block) + x.shape[1:])
+
+    w_on, w_tg = head_on.astype(dtype), head_tg.astype(dtype)
+
+    @jax.checkpoint
+    def one(ho, ht, a):
+        q_on = jnp.dot(ho.astype(dtype), w_on,
+                       preferred_element_type=jnp.float32)
+        q_tg = jnp.dot(ht.astype(dtype), w_tg,
+                       preferred_element_type=jnp.float32)
+        a_star = jnp.argmax(lax.stop_gradient(q_on) if double else q_tg,
+                            axis=-1)
+        q_sa = jnp.take_along_axis(q_on, a[:, None], axis=-1)[:, 0]
+        q_boot = jnp.take_along_axis(q_tg, a_star[:, None], axis=-1)[:, 0]
+        return q_sa, q_boot, jnp.sum(lax.stop_gradient(q_on), axis=-1)
+
+    _, (q_sa, q_boot, q_row) = lax.scan(
+        lambda c, xs: (c, one(*xs)), None,
+        (blocks(hid_on), blocks(hid_tg), blocks(actions)))
+    return (q_sa.reshape(-1)[:n], lax.stop_gradient(q_boot.reshape(-1)[:n]),
+            q_row.reshape(-1)[:n])
+
+
 class SequenceLearner:
-    """Owns the sharded R2D2 train step for recurrent Q-nets."""
+    """Owns the sharded sequence train step: R2D2 for recurrent Q-nets,
+    and the token path for the token-window Q-network."""
 
     def __init__(self, module, cfg: TrainConfig, replay_cfg: ReplayConfig,
-                 mesh):
+                 mesh, net_cfg=None):
         self.module = module
+        self.net_cfg = net_cfg      # the token path's NetConfig
         self.cfg = cfg
         self.burn_in = int(replay_cfg.burn_in)
         self.mesh = mesh
@@ -381,11 +433,189 @@ class SequenceLearner:
             check_vma=False), donate_argnums=(0, 4, 5))
         return sample, train
 
+    def _token_step_core(self, state: TrainState,
+                         batch: dict[str, jax.Array]):
+        """The token-window step body (per shard): θ and θ⁻ forward over
+        the SAME window of T+1 tokens, the Q head and the Double-DQN
+        selection blockwise over tokens, the repo's sequence loss over the
+        selected values, then the shared clip + Adam + target step.
+        ``batch``: ``tokens`` [b, T+1] int32, ``reward`` / ``discount`` /
+        ``mask`` [b, T], ``weight`` [b]."""
+        from distributed_deep_q_tpu.models import tokenq
+        from distributed_deep_q_tpu.parallel.mesh import pallas_interpret
+
+        cfg, net = self.cfg, self.net_cfg
+        assert not cfg.learn_metrics, "learn_metrics: not on the token path"
+        interpret = pallas_interpret(self.mesh)
+        dtype = jnp.dtype(net.compute_dtype)
+        tokens = batch["tokens"]
+        b, t1 = tokens.shape
+        # the action at position p is the next token; the last position
+        # only bootstraps
+        actions = jnp.concatenate(
+            [tokens[:, 1:], jnp.zeros((b, 1), tokens.dtype)], axis=1)
+        hid_tg, _ = tokenq.backbone(state.target_params, tokens, net,
+                                    interpret)
+
+        def loss_fn(params):
+            hid_on, counters = tokenq.backbone(params, tokens, net,
+                                               interpret)
+            with jax.named_scope("ddq.q_head_loss"):
+                q_sa, q_boot, q_row = token_q_select(
+                    hid_on.reshape(b * t1, -1), hid_tg.reshape(b * t1, -1),
+                    params["head"], state.target_params["head"],
+                    actions.reshape(-1), block=net.tokenq.head_block,
+                    dtype=dtype, double=cfg.double_dqn)
+                q_sa = q_sa.reshape(b, t1)[:, :-1, None]
+                q_boot = q_boot.reshape(b, t1)[:, 1:, None]
+                # the selection is done: the repo's loss sees one action
+                targets = sequence_bellman_targets(
+                    batch["reward"], batch["discount"], q_boot, q_boot,
+                    double=cfg.double_dqn, rescale=cfg.value_rescale)
+                loss, priority = sequence_dqn_loss(
+                    q_sa, jnp.zeros((b, t1 - 1), jnp.int32), targets,
+                    batch["mask"], batch["weight"], cfg.huber_delta,
+                    eta=cfg.priority_eta)
+                q_mean = jnp.mean(q_row.reshape(b, t1)[:, :-1]) / \
+                    params["head"].shape[1]
+            return loss, (priority, q_mean, counters)
+
+        (loss, (priority, q_mean, counters)), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(state.params)
+        grads = lax.pmean(grads, AXIS_DP)
+        with jax.named_scope("ddq.optimizer"):
+            # per-leaf norms (in ``tokenq.named_leaves`` order): which
+            # layer's gradient moved, and the global norm from them
+            leaf_sq = jnp.stack([jnp.sum(jnp.square(g))
+                                 for g in jax.tree.leaves(grads)])
+            gnorm = jnp.sqrt(jnp.sum(leaf_sq))
+            step = state.step + 1
+            if cfg.optimizer == "adam":
+                opt_state, params, target_params = fused_adam_target_step(
+                    cfg, grads, state.opt_state, state.params,
+                    state.target_params, gnorm, step)
+            else:
+                grads, gnorm = clip_grads(cfg, grads, gnorm)
+                updates, opt_state = self.opt.update(
+                    grads, state.opt_state, state.params)
+                params = optax.apply_updates(state.params, updates)
+                target_params = refresh_target(cfg, params,
+                                               state.target_params, step)
+        load = counters["load"].astype(jnp.float32)        # [layers, held]
+        metrics = {
+            "loss": lax.pmean(loss, AXIS_DP),
+            "q_mean": lax.pmean(q_mean, AXIS_DP),
+            "grad_norm": gnorm,
+            "grad_leaf_norm": jnp.sqrt(leaf_sq),
+            # the expert layer's counters, over layers and shards
+            "moe_slots_held": lax.psum(jnp.sum(counters["slots_held"]),
+                                       AXIS_DP),
+            "moe_slots": lax.psum(jnp.sum(counters["slots"]), AXIS_DP),
+            "moe_overflow": lax.psum(jnp.sum(counters["overflow"]),
+                                     AXIS_DP),
+            "moe_load_max_over_mean": lax.pmean(jnp.mean(
+                jnp.max(load, -1) / jnp.maximum(jnp.mean(load, -1), 1.0)),
+                AXIS_DP),
+        }
+        return (TrainState(params, target_params, opt_state, step), metrics,
+                priority)
+
+    def _build_token_fused_steps(self, spec: tuple, chain: int):
+        """The fused two-program step on the token ring
+        (``replay/device_tokens.py``): the SAMPLE program draws ``chain``
+        batches of windows against chunk-start priorities and gathers
+        whole windows (tokens, rewards, flags) and their IS weights; the
+        TRAIN program scans ``chain`` token steps with same-step priority
+        scatters. Nothing reads back."""
+        (caps_local, seq_len, per_shard, alpha, eps, num_shards,
+         gamma) = spec
+        from distributed_deep_q_tpu.replay.device_per import (
+            build_cdf, draw_from_cdf, scatter_priorities,
+            stratified_is_weights)
+        from distributed_deep_q_tpu.replay.device_tokens import unpack_flags
+
+        S = P(AXIS_DP)
+        SK = P(None, AXIS_DP)
+        SK3 = P(None, AXIS_DP, None)
+        ring_spec = {"tokens": S, "reward": S, "flags": S}
+        batch_spec = {"tokens": SK3, "reward": SK3, "discount": SK3,
+                      "mask": SK3, "weight": SK}
+
+        def token_sample_fn(keys, ring, prio, sizes, betas):
+            filled = (jnp.arange(caps_local) < sizes[0]).astype(
+                jnp.float32)
+            pm = prio * filled
+            cdf, mass = build_cdf(pm)
+            n_glob = lax.psum(jnp.sum(filled), AXIS_DP)
+            idx, p = jax.vmap(
+                lambda k: draw_from_cdf(k, cdf, pm, mass, per_shard))(
+                keys[0])                               # [chain, b]
+            flat = idx.reshape(-1)
+
+            def rows(x):
+                return x[flat].reshape((chain, per_shard) + x.shape[1:])
+
+            discount, mask = unpack_flags(rows(ring["flags"]), gamma)
+            batch = {"tokens": rows(ring["tokens"]),
+                     "reward": rows(ring["reward"]),
+                     "discount": discount, "mask": mask,
+                     "weight": stratified_is_weights(p, mass, n_glob,
+                                                     betas, num_shards)}
+            idx = jnp.where(mass > 0, idx, caps_local)
+            return batch, idx.astype(jnp.int32)
+
+        sample = jax.jit(shard_map(
+            jax.named_scope("ddq.sample")(token_sample_fn), mesh=self.mesh,
+            in_specs=(S, ring_spec, S, S, P()),
+            out_specs=(batch_spec, SK), check_vma=False))
+
+        def token_train_fn(state: TrainState, batch, idxs, prio, maxp):
+            def body(carry, xs):
+                state, prio, maxp = carry
+                one, idx = xs
+                state, metrics, priority = self._token_step_core(state, one)
+                prio, maxp = scatter_priorities(prio, maxp, idx, priority,
+                                                alpha, eps)
+                return (state, prio, maxp), metrics
+
+            (state, prio, maxp), metrics = lax.scan(
+                body, (state, prio, maxp), (batch, idxs))
+            return state, prio, maxp, metrics
+
+        train = jax.jit(shard_map(
+            jax.named_scope("ddq.train")(token_train_fn), mesh=self.mesh,
+            in_specs=(P(), batch_spec, SK, S, P()),
+            out_specs=(P(), S, P(), P()),
+            check_vma=False), donate_argnums=(0, 3, 4))
+        return sample, train
+
+    def token_fused_programs(self, replay, batch_size: int, chain: int):
+        """(sample, train) of the token ring's fused step, built once per
+        ring geometry and chain."""
+        spec = (replay.caps_local, replay.seq_len,
+                batch_size // replay.num_shards, replay.alpha, replay.eps,
+                replay.num_shards, replay.gamma)
+        cache_key = ("tokens", spec, chain)
+        if cache_key not in self._fused_steps:
+            self._fused_steps[cache_key] = self._build_token_fused_steps(
+                spec, chain)
+        return self._fused_steps[cache_key]
+
     def train_steps_fused(self, state: TrainState, replay, batch_size: int,
                           sizes, betas: np.ndarray, keys: np.ndarray):
         """``len(betas)`` fused sequence steps in one two-program dispatch.
         Returns (state, new prio, new maxp, metrics stacked [chain])."""
         chain = len(betas)
+        if getattr(replay, "window_kind", "frames") == "tokens":
+            sample, train = self.token_fused_programs(replay, batch_size,
+                                                      chain)
+            with tracing.span("sample"):
+                batch, idx = sample(keys, replay.ring, replay.dmeta["prio"],
+                                    np.asarray(sizes),
+                                    np.asarray(betas, np.float32))
+            with tracing.span("train_step"):
+                return train(state, batch, idx, replay.dmeta["prio"],
+                             replay.dmaxp)
         spec = (replay.caps_local, replay.seq_len, replay.stack, replay.W,
                 replay.rowb, replay._row_len, tuple(replay.frame_shape),
                 batch_size // replay.num_shards,
@@ -416,10 +646,13 @@ class SequenceLearner:
 
 
 class SequenceSolver:
-    """Reference ``Solver`` surface for the recurrent pipeline.
+    """Reference ``Solver`` surface for the sequence pipelines.
 
     Mirrors ``solver.Solver`` (train_step / q_values / act / weight IO [M])
-    with recurrent state threading for the actor path.
+    with recurrent state threading for the actor path (``r2d2``), or — for
+    the token-window Q-network (``tokenq``) — the token prefix as the
+    state: ``token_q_values`` / ``token_act`` run the whole prefix, and
+    weights also go by per-path leaf names (``get_named_weights``).
     """
 
     def __init__(self, config, obs_dim: int = 4, backend: str | None = None):
@@ -430,23 +663,37 @@ class SequenceSolver:
         from distributed_deep_q_tpu.parallel.mesh import make_mesh
         from distributed_deep_q_tpu.solver import _strip_host_keys
 
-        assert config.net.kind == "r2d2", "SequenceSolver is for r2d2 nets"
+        assert config.net.kind in ("r2d2", "tokenq"), (
+            "SequenceSolver is for r2d2 and tokenq nets")
+        self.tokenq = config.net.kind == "tokenq"
         if backend is not None:
             config = dataclasses.replace(
                 config, mesh=dataclasses.replace(config.mesh, backend=backend))
         self.config = config
         self.backend = config.mesh.backend
         self.mesh = make_mesh(config.mesh)
-        self.module = build_qnet(config.net)
+        if self.tokenq:
+            from distributed_deep_q_tpu.models import tokenq
+            from distributed_deep_q_tpu.parallel.mesh import (
+                pallas_interpret)
+
+            self.module = None
+            params = tokenq.init_params(config.net, config.train.seed)
+            interpret = pallas_interpret(self.mesh)
+            self._fwd = jax.jit(lambda p, tok, pos: tokenq.q_at(
+                p, tok, pos, config.net, interpret))
+        else:
+            self.module = build_qnet(config.net)
+            params = init_params(self.module, config.net,
+                                 config.train.seed, obs_dim)
+            self._fwd = jax.jit(
+                lambda p, o, c: self.module.apply({"params": p}, o, c))
         self.learner = SequenceLearner(self.module, config.train,
-                                       config.replay, self.mesh)
-        params = init_params(self.module, config.net, config.train.seed,
-                             obs_dim)
+                                       config.replay, self.mesh,
+                                       net_cfg=config.net)
         self.state: TrainState = self.learner.init_state(params)
         self._treedef = jax.tree_util.tree_structure(params)
         self._strip = _strip_host_keys
-        self._fwd = jax.jit(
-            lambda p, o, c: self.module.apply({"params": p}, o, c))
         # fused chained-path key bookkeeping (Solver's scheme)
         self._fused_key_base: int | None = None
         self._fused_steps_issued = 0
@@ -487,24 +734,30 @@ class SequenceSolver:
         from distributed_deep_q_tpu.solver import next_fused_keys
 
         chain = chain or max(int(self.config.replay.fused_chain), 1)
-        if replay.pending_rows() or replay.defer_flush:
-            # multi-host the flush is a lockstep collective with an
-            # agreed round count — every process calls it here
-            replay.flush()
-        sizes = replay.device_inputs()
-        betas = replay.next_betas(chain)
-        keys = next_fused_keys(self, replay.num_shards, chain)
-        if replay._pc > 1:
-            keys = replay.to_global(
-                np.ascontiguousarray(keys[replay.local_shards]))
-            sizes = replay.to_global(np.asarray(sizes))
-            betas = replay.to_replicated(np.asarray(betas, np.float32))
-        self.state, prio, maxp, metrics = self.learner.train_steps_fused(
+        # the same spans as Solver.train_steps_device_per, so the device's
+        # idle time has an owner in this loop too (tracing.STAGES)
+        with tracing.span("learner_flush"):
+            if replay.pending_rows() or replay.defer_flush:
+                # multi-host the flush is a lockstep collective with an
+                # agreed round count — every process calls it here
+                replay.flush()
+        with tracing.span("learner_feed"):
+            sizes = replay.device_inputs()
+            betas = replay.next_betas(chain)
+            keys = next_fused_keys(self, replay.num_shards, chain)
+            if replay._pc > 1:
+                keys = replay.to_global(
+                    np.ascontiguousarray(keys[replay.local_shards]))
+                sizes = replay.to_global(np.asarray(sizes))
+                betas = replay.to_replicated(np.asarray(betas, np.float32))
+        state, prio, maxp, metrics = self.learner.train_steps_fused(
             self.state, replay, self.config.replay.batch_size, sizes,
             betas, keys)
-        replay.dmeta = dict(replay.dmeta)
-        replay.dmeta["prio"] = prio
-        replay.dmaxp = maxp
+        with tracing.span("learner_adopt"):
+            self.state = state
+            replay.dmeta = dict(replay.dmeta)
+            replay.dmeta["prio"] = prio
+            replay.dmaxp = maxp
         return dict(metrics)
 
     # -- recurrent actor path ----------------------------------------------
@@ -532,7 +785,47 @@ class SequenceSolver:
             return int(rng.integers(self.config.net.num_actions)), carry
         return int(np.argmax(q[0])), carry
 
+    # -- token actor path ---------------------------------------------------
+
+    def token_q_values(self, prefix: np.ndarray) -> np.ndarray:
+        """Q(prefix, ·) [V] for one token prefix of at most a window's
+        T+1 tokens. There is no cache: the prefix is padded to the window
+        (ONE compiled shape; causal attention keeps the padding out of
+        every real position) and the whole window is run."""
+        n = len(prefix)
+        window = np.zeros((1, self.config.replay.sequence_length + 1),
+                          np.int32)
+        window[0, :n] = prefix
+        return np.asarray(self._fwd(self.state.params, window,
+                                    np.int32(n - 1))[0])
+
+    def token_act(self, prefix: np.ndarray, epsilon: float,
+                  rng: np.random.Generator) -> int:
+        """ε-greedy next token."""
+        if rng.random() < epsilon:
+            return int(rng.integers(self.config.net.num_actions))
+        return int(np.argmax(self.token_q_values(prefix)))
+
     # -- weight IO ----------------------------------------------------------
+
+    def get_named_weights(self) -> dict[str, np.ndarray]:
+        """θ by per-path leaf names (``layer_00/w_q``): the token path's
+        weight IO, independent of the tree's flattening order."""
+        from distributed_deep_q_tpu.models import tokenq
+        return {k: np.asarray(v) for k, v in tokenq.named_leaves(
+            self.state.params).items()}
+
+    def set_named_weights(self, named: dict[str, np.ndarray],
+                          target: bool = True) -> None:
+        """Install θ (and θ⁻ with ``target``) from per-path leaf names."""
+        from distributed_deep_q_tpu.models import tokenq
+        params = jax.device_put(
+            tokenq.from_named(self.state.params, named),
+            self.learner._replicated)
+        self.state = self.state.replace(params=params)
+        if target:
+            self.state = self.state.replace(
+                target_params=jax.tree.map(jnp.copy, params))
 
     def get_weights(self) -> list[np.ndarray]:
         return [np.asarray(x)

@@ -100,6 +100,8 @@ def train_single_process(cfg: Config, metrics: Metrics | None = None,
     """
     if cfg.net.kind == "r2d2":
         return train_recurrent(cfg, metrics, log_every)
+    if cfg.net.kind == "tokenq":
+        return train_tokenq(cfg, metrics, log_every)
     metrics = metrics or Metrics()
     # NOTE: solver/env construction initializes the JAX backend; only then
     # is process topology safe to query (probing earlier would pre-empt the
@@ -556,6 +558,167 @@ def train_recurrent(cfg: Config, metrics: Metrics | None = None,
         save_replay(replay, persist)
     summary["final_return_avg100"] = ep_returns.value
     summary["eval_return"] = evaluate_recurrent(solver, cfg)
+    summary["solver"] = solver
+    summary["replay"] = replay
+    return summary
+
+
+# -- token-window Q-network (net.kind = "tokenq") ---------------------------
+
+
+def make_token_replay(cfg: Config, mesh):
+    """The token ring for this Config: ``replay.capacity`` counts steps
+    (as for the other sequence rings), a window holds
+    ``replay.sequence_length`` of them."""
+    from distributed_deep_q_tpu.replay.device_tokens import DeviceTokenReplay
+
+    seq_len = cfg.replay.sequence_length
+    return DeviceTokenReplay(
+        max(cfg.replay.capacity // seq_len, 2), seq_len, mesh,
+        cfg.train.gamma, alpha=cfg.replay.priority_alpha,
+        beta0=cfg.replay.priority_beta0,
+        beta_steps=cfg.replay.priority_beta_steps,
+        eps=cfg.replay.priority_eps, write_chunk=cfg.replay.write_chunk)
+
+
+class TokenWindowBuilder:
+    """Cuts an actor's token stream into the ring's windows: ``seq_len``
+    steps (+1 token), back to back inside an episode; an episode's last
+    window is padded with invalid steps."""
+
+    def __init__(self, seq_len: int):
+        self.seq_len = int(seq_len)
+        self.reset(0)
+
+    def reset(self, first_token: int) -> None:
+        self._tok = [int(first_token)]
+        self._rew: list[float] = []
+        self._done: list[bool] = []
+
+    def on_step(self, token: int, reward: float, done: bool, over: bool):
+        """One env step: ``token`` was emitted. Returns a finished window
+        ``(tokens, reward, done, valid)`` or None."""
+        self._tok.append(int(token))
+        self._rew.append(float(reward))
+        self._done.append(bool(done))
+        n, t = len(self._rew), self.seq_len
+        if n < t and not over:
+            return None
+        tokens = np.zeros(t + 1, np.int32)
+        tokens[:n + 1] = self._tok
+        reward_, done_ = np.zeros(t, np.float32), np.zeros(t, bool)
+        reward_[:n], done_[:n] = self._rew, self._done
+        self.reset(token)       # the next window starts at this token
+        return tokens, reward_, done_, np.arange(t) < n
+
+
+def evaluate_tokenq(solver, cfg: Config, episodes: int | None = None,
+                    seed: int = 10_000) -> float:
+    """ε=eval_eps rollouts of the token env, the episode so far as the
+    prefix (capped at the training window)."""
+    env = make_env(cfg.env, seed=seed)
+    rng = np.random.default_rng(seed)
+    cap = cfg.replay.sequence_length + 1
+    returns = []
+    for _ in range(episodes or cfg.train.eval_episodes):
+        prefix, ep_ret, over = [int(env.reset()[0])], 0.0, False
+        while not over:
+            a = solver.token_act(np.asarray(prefix[-cap:]),
+                                 cfg.actors.eval_eps, rng)
+            obs, r, _, over = env.step(a)
+            prefix.append(int(obs[0]))
+            ep_ret += r
+        returns.append(ep_ret)
+    return float(np.mean(returns))
+
+
+def train_tokenq(cfg: Config, metrics: Metrics | None = None,
+                 log_every: int = 1_000) -> dict:
+    """Token-level Q-learning: ε-greedy token actor → ``TokenWindowBuilder``
+    → ``DeviceTokenReplay`` → the fused sequence step
+    (``SequenceSolver.train_steps_device_per`` through ``FusedStepStream``:
+    sample program + train program, priorities written back on device).
+    Every ``train.train_every`` env steps the learner takes
+    ``train.grad_steps_per_train`` grad steps. The actor runs the whole
+    prefix per token (no cache): the recipe is for high replay ratios."""
+    from distributed_deep_q_tpu.parallel.sequence_learner import SequenceSolver
+    from distributed_deep_q_tpu.solver import FusedStepStream
+
+    metrics = metrics or Metrics()
+    env = make_env(cfg.env, seed=cfg.train.seed)
+    cfg.net.num_actions = env.num_actions
+    solver = SequenceSolver(cfg)
+    replay = make_token_replay(cfg, solver.mesh)
+    seq_len = cfg.replay.sequence_length
+    stream = FusedStepStream(solver, replay,
+                             max(int(cfg.replay.fused_chain), 1))
+    builder = TokenWindowBuilder(seq_len)
+    rng = np.random.default_rng(cfg.train.seed)
+    learn_start = max(cfg.replay.learn_start // seq_len,
+                      cfg.replay.batch_size)
+    trace = TraceWindow(cfg.train.profile_dir, cfg.train.profile_start_step,
+                        cfg.train.profile_num_steps)
+    ckpt = maybe_checkpointer(cfg.train)
+    if ckpt and cfg.train.resume and ckpt.latest_step() is not None:
+        solver.state, _ = ckpt.restore(solver.state)
+
+    prefix = [int(env.reset()[0])]
+    builder.reset(prefix[0])
+    ep_ret, ep_returns = 0.0, MovingAverage(100)
+    gsteps, summary = 0, {}
+    for t in range(1, cfg.train.total_steps + 1):
+        eps = epsilon_at(t, cfg.actors)
+        a = solver.token_act(np.asarray(prefix[-(seq_len + 1):]), eps, rng)
+        obs, r, done, over = env.step(a)
+        prefix.append(int(obs[0]))
+        ep_ret += r
+        window = builder.on_step(int(obs[0]), r, done, over)
+        if window is not None:
+            replay.add_window(*window)
+        metrics.count("env_steps")
+        if over:
+            ep_returns.add(ep_ret)
+            ep_ret = 0.0
+            prefix = [int(env.reset()[0])]
+            builder.reset(prefix[0])
+        if not (replay.ready(learn_start)
+                and t % cfg.train.train_every == 0):
+            continue
+        for _ in range(max(cfg.train.grad_steps_per_train, 1)):
+            trace.on_step(gsteps)
+            remaining = ((cfg.train.total_steps - t)
+                         // cfg.train.train_every + 1) * max(
+                cfg.train.grad_steps_per_train, 1)
+            m = stream.next(remaining)
+            gsteps += 1
+            metrics.count("grad_steps")
+            if ckpt and gsteps % cfg.train.checkpoint_every == 0:
+                with tracing.span("learner_checkpoint"):
+                    ckpt.save(solver.state, extra={"env_steps": t})
+            if gsteps % log_every == 0:
+                with tracing.span("learner_log"):
+                    summary = {
+                        "loss": float(m["loss"]),
+                        "q_mean": float(m["q_mean"]),
+                        "return_avg100": ep_returns.value, "epsilon": eps,
+                        "grad_steps_per_s": metrics.rate("grad_steps"),
+                        "env_steps_per_s": metrics.rate("env_steps"),
+                        # the expert layer's counters (ops/moe.py)
+                        "moe_slots_held_share": float(m["moe_slots_held"])
+                        / max(float(m["moe_slots"]), 1.0),
+                        "moe_load_max_over_mean": float(
+                            m["moe_load_max_over_mean"]),
+                        "moe_overflow": float(m["moe_overflow"]),
+                    }
+                    metrics.gauge("queue/replay_size", len(replay))
+                    metrics.log(gsteps, **summary, **metrics.telemetry())
+    trace.close()
+    if ckpt:
+        ckpt.save(solver.state, extra={"env_steps": cfg.train.total_steps},
+                  wait=True)
+    summary["final_return_avg100"] = ep_returns.value
+    summary["grad_steps"] = gsteps
+    summary["eval_return"] = evaluate_tokenq(solver, cfg)
     summary["solver"] = solver
     summary["replay"] = replay
     return summary

@@ -1,0 +1,105 @@
+"""The benchmark's seam under tier-1: the cases of ``benchmark/test_seam.py``
+(a model family arrives as new files; ``family.judge`` refuses a missing
+limit or a number that is not finite), imported here so the driver's run
+guards them, plus one case over every configuration ``BENCHMARK.json`` has.
+
+``benchmark/test_seam.py`` lays its throwaway family down as new files
+only, the package marker ``benchmark/families/__init__.py`` among them:
+``benchmark/families/`` therefore carries no marker of its own (the
+``tokenq`` family resolves as a namespace package), and the cases run here
+as they are written there.
+"""
+
+from __future__ import annotations
+
+import importlib
+import os
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark import test_seam  # noqa: E402
+
+from benchmark.test_seam import *  # noqa: E402,F401,F403
+from benchmark.test_seam import copy  # noqa: E402,F401  (the fixture)
+
+
+@pytest.mark.parametrize(
+    "config", [c["name"] for c in test_seam.bench_json()["configs"]])
+def test_every_configuration_resolves_its_family_modules(config):
+    """``check`` (with ``build_checked`` and ``compare``), ``reference``
+    (with ``EXACT_LIMITS``) and ``counts`` (every printed count and every
+    roofline metric's ``count`` a function of ``hparams``)."""
+    from benchmark import family, run
+    from benchmark.common import load_json
+
+    bench = test_seam.bench_json()
+    entry = next(c for c in bench["configs"] if c["name"] == config)
+    conf = load_json(os.path.relpath(os.path.join(ROOT, entry["file"]),
+                                     os.path.join(ROOT, "benchmark")))
+    check = family.load_check(conf)
+    assert callable(check.build_checked) and callable(check.compare)
+    # what a family-blind driver asks of it beside those two (optional)
+    if getattr(check, "ROW_COUNTERS", ()):
+        row = check.log_row(dict.fromkeys(check.ROW_COUNTERS, 1.0))
+        assert row and all(isinstance(v, float) for v in row.values())
+    assert isinstance(family.load_reference(conf).EXACT_LIMITS, dict)
+    counts = family.load_counts(conf)
+    printed = family.printed_counts(conf)
+    assert printed and all(v is not None for v in printed.values())
+    cells = [w["name"] for w in bench["workloads"] if w["config"] == config]
+    assert cells
+    for cell in cells:
+        for m in run.metrics_for(bench, "per_layer", cell):
+            spec = load_json("layer_metrics", f"{m['name']}.json")
+            importlib.import_module(f"benchmark.readers.{spec['reader']}")
+            if "count" in spec["args"]:
+                assert getattr(counts, spec["args"]["count"])(
+                    conf["hparams"]) > 0
+    # every inexact limit is a number, and no exact one is loosened there
+    assert all(isinstance(v, (int, float)) for v in conf["limits"].values())
+
+
+def test_the_token_family_walks_its_cell_on_the_cpu():
+    """``rehearse.py``'s walk of the token-window cell at the family's toy
+    sizes (``families/tokenq/check.toy``): driver, recorder, reference and
+    verdict, float32 on both sides."""
+    import argparse
+
+    from benchmark import rehearse, run
+
+    ns = argparse.Namespace(
+        workload="smallthinker_21b_tokenq_ep8.seq_learner_only",
+        seed=2 ** 31 + 23, seconds=1.0, trace=0)
+    line = run.run_cell(ns, backend="cpu", conf_patch=rehearse.toy)
+    assert line["correct"] and line["failed"] == 0
+    worst = max(v for k, (v, _) in line["compared"].items())
+    assert worst < 1e-4, line["compared"]
+    assert line["metrics"]["grad_steps_per_s"]["value"] > 0
+
+
+def test_the_token_familys_control_is_not_correct():
+    """``control.py``'s readings at the toy sizes: the program (float32
+    there) agrees with the reference to rounding, and the reference one
+    precision down — fp8 operands where the configuration states bfloat16
+    — is not correct, on the forward path (first step's loss, the
+    priorities it wrote) and on the backward path (first step's gradient
+    norm, Adam's first moment by the worst leaf)."""
+    from benchmark import control, rehearse
+
+    rs = control.readings("smallthinker_21b_tokenq_ep8.seq_learner_only",
+                          [2 ** 31 + 5], backend="cpu",
+                          conf_patch=rehearse.toy, prefill=256)
+    table = control.summarize(rs)
+    assert table["sound_all_correct"] and table["control_all_not_correct"]
+    n = table["numbers"]
+    for k in ("loss_first_rel", "priority_first_max_rel",
+              "grad_norm_first_rel", "moment_first_worst_leaf"):
+        assert n[k]["sound_max"] < 1e-5 < 1e-3 < n[k]["control_min"], k
+    for k in ("windows_illegal", "token_window_mismatch",
+              "validity_mismatch", "expert_buffer_overflow"):
+        assert n[k]["sound_max"] == 0

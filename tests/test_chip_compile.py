@@ -113,3 +113,66 @@ def test_fused_huber_fwd_and_grad_compile_for_v5e(one_chip, batch, actions):
         f32((batch,)), f32((batch,)))
     # forward kernel + hand-written backward kernel
     assert text.count("tpu_custom_call") >= 2
+
+
+# -- the token-window Q-network's kernels at the published widths -----------
+# SmallThinker-21BA3B: 28 query / 4 key-value heads of 128, window 4 096 on
+# windows of 8 193 tokens (padded to the 1 024 block); experts of width 768
+# over hidden 2 560, 8 held: the sizes, the buffer and the tiles are read
+# from the preset (config.smallthinker_tokenq_config), not restated here.
+
+@pytest.mark.parametrize("window", [0, 4096], ids=["full", "window4096"])
+def test_window_attention_compiles_for_v5e(one_chip, window):
+    from distributed_deep_q_tpu.config import PRESETS
+    from distributed_deep_q_tpu.ops.attention import causal_attention
+
+    cfg = PRESETS["smallthinker_tokenq"]()
+    tq, t = cfg.net.tokenq, cfg.replay.sequence_length + 1
+    assert (t, tq.sliding_window_size) == (8193, 4096)
+    q = jax.ShapeDtypeStruct(
+        (1, tq.num_attention_heads, t, tq.head_dim), jnp.bfloat16,
+        sharding=one_chip)
+    kv = jax.ShapeDtypeStruct(
+        (1, tq.num_key_value_heads, t, tq.head_dim), jnp.bfloat16,
+        sharding=one_chip)
+
+    def fwd_bwd(q, k, v):
+        f = lambda *a: jnp.sum(causal_attention(  # noqa: E731
+            *a, window=window, block=tq.attn_block,
+            compute_block=tq.attn_compute_block).astype(jnp.float32))
+        return jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+
+    text = _compiled_text(fwd_bwd, q, kv, kv)
+    for kernel in ("splash_mqa_fwd", "splash_mqa_dkv"):    # dq is fused in
+        assert kernel in text
+    assert "tpu_custom_call" in text
+
+
+def test_held_experts_grouped_matmul_compiles_for_v5e(one_chip):
+    """One sequence's expert layer as ``models/tokenq.layer`` calls it:
+    the preset's worst-case buffer at the preset's m-tile, bfloat16."""
+    from distributed_deep_q_tpu.config import PRESETS
+    from distributed_deep_q_tpu.ops import moe
+
+    cfg = PRESETS["smallthinker_tokenq"]()
+    tq, n = cfg.net.tokenq, cfg.replay.sequence_length + 1
+    k, held = tq.moe_num_active_primary_experts, tq.experts_held
+    h, f = tq.hidden_size, tq.moe_ffn_hidden_size
+    rows = moe.buffer_rows(n, k, held, tq.moe_tile)
+    assert (rows, tq.moe_tile) == (49408, 256)  # 8 193 x 6, tile-rounded
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+
+    def fwd_bwd(x, idx, p, wg, wu, wd):
+        f_ = lambda x, wg, wu, wd: jnp.sum(moe.held_experts_ffn(  # noqa: E731
+            x, idx, p, wg, wu, wd, offset=tq.expert_offset, rows=rows,
+            tile=tq.moe_tile, compute_dtype=jnp.dtype(
+                cfg.net.compute_dtype))[0])
+        return jax.grad(f_, argnums=(0, 1, 2, 3))(x, wg, wu, wd)
+
+    text = _compiled_text(
+        fwd_bwd, S((n, h), jnp.float32), S((n, k), jnp.int32),
+        S((n, k), jnp.float32), S((held, h, f), jnp.float32),
+        S((held, h, f), jnp.float32), S((held, f, h), jnp.float32))
+    # forward gate+up (the down product's VALUE is not needed under a
+    # sum); backward: two input-side products and two weight-side ones
+    assert text.count('custom_call_target="tpu_custom_call"') >= 5
