@@ -1309,6 +1309,16 @@ def _tear_down_rpc_plane(cfg: Config, server, sup, infer_server=None) -> None:
         server.close()
 
 
+# The least wall time of a ``train.profile_dir`` window over the
+# distributed loop. The window is counted in grad steps, but what it is
+# opened to show beside the loop — RPC handlers, the drain thread, ring
+# writes — arrives in bursts: each actor flushes about twice a second, and
+# a CRC convoy holds all of them for ~0.4 s. At the chip's ~600 steps/s
+# 160 steps are 0.27 s, which can hold no ring write at all (PERF.md §6,
+# PR 28).
+PROFILE_MIN_SECONDS = 1.0
+
+
 def train_distributed(cfg: Config, metrics: Metrics | None = None,
                       log_every: int = 500) -> dict:
     """Actor fleet over RPC → replay → mesh learner; returns summary.
@@ -1422,7 +1432,8 @@ def train_distributed(cfg: Config, metrics: Metrics | None = None,
         StepTimer, TraceWindow, start_profiler_server)
     timer = StepTimer()
     trace = TraceWindow(cfg.train.profile_dir, cfg.train.profile_start_step,
-                        cfg.train.profile_num_steps)
+                        cfg.train.profile_num_steps,
+                        min_seconds=PROFILE_MIN_SECONDS)
     if cfg.train.profile_port:
         start_profiler_server(cfg.train.profile_port)
     from distributed_deep_q_tpu.utils.compile_cache import process_clock

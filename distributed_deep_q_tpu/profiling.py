@@ -401,7 +401,10 @@ class TraceWindow:
 
     ``on_step(step)`` is called once per train-loop step; the trace starts
     when ``step == start_step`` and stops after ``num_steps`` steps (or at
-    ``close()``). Output is a TensorBoard-loadable trace directory.
+    ``close()``) — and, where the caller gives ``min_seconds``, not before
+    that much wall time: a loop with threads around it (the RPC plane of
+    ``train_distributed``) works in periods a fast learner's step count
+    no longer spans. Output is a TensorBoard-loadable trace directory.
 
     The capture runs WITHOUT the Python tracer: the program's own spans
     (``ddq/<name>``) name what each thread is doing, and a hook on every
@@ -410,10 +413,11 @@ class TraceWindow:
     """
 
     def __init__(self, logdir: str, start_step: int = 100,
-                 num_steps: int = 20):
+                 num_steps: int = 20, min_seconds: float = 0.0):
         self.logdir = logdir
         self.start_step = int(start_step)
         self.num_steps = int(num_steps)
+        self.min_seconds = float(min_seconds)
         self._active = False
         self._done = False
 
@@ -427,17 +431,36 @@ class TraceWindow:
             tracing.profile_start(jax.profiler.TraceAnnotation)
             self._active = True
             self._stop_at = step + self.num_steps
-        elif self._active and step >= self._stop_at:
+            self._not_before = time.perf_counter() + self.min_seconds
+        elif (self._active and step >= self._stop_at
+              and time.perf_counter() >= self._not_before):
             self.stop()
 
     def stop(self) -> None:
         if self._active:
+            # the loop runs ahead of the device (``FusedStepStream``: up
+            # to two chunks): let the device finish what the window's
+            # steps dispatched, or the trace cuts its last program short
+            # and every per-execution device time read from it is low
+            _drain_device()
             tracing.profile_stop()
             jax.profiler.stop_trace()
             self._active = False
             self._done = True
 
     close = stop
+
+
+def _drain_device() -> None:
+    """Wait for every device array this process holds. Needs no handle
+    into the train loop and compiles nothing; an array an ingest thread
+    donates between the listing and the wait is skipped."""
+    for a in jax.live_arrays():
+        try:
+            if not a.is_deleted():
+                a.block_until_ready()
+        except RuntimeError:        # donated between the two lines
+            pass
 
 
 def start_profiler_server(port: int) -> None:

@@ -12,7 +12,9 @@ parameter-server round trip.
 
 from __future__ import annotations
 
+import collections
 import contextlib
+from collections.abc import Iterator, Mapping
 from typing import Any
 
 import jax
@@ -176,7 +178,9 @@ class Solver:
         Philox key draw, two dispatches — amortized over ``chain`` grad
         steps; this is what closes the matched-batch north star's ~400 µs
         of per-step host overhead. Returns metrics stacked ``[chain]``
-        (device arrays — convert only when logging)."""
+        (device arrays; ``FusedStepStream`` hands them out as per-step
+        views — index or convert one only where its value is read: each
+        ``v[i]`` launches two tiny device programs)."""
         chain = chain or max(int(self.config.replay.fused_chain), 1)
         # the span takes the gate with the flush, so every chunk has one:
         # a chunk that found nothing staged (the drain thread got there
@@ -263,6 +267,34 @@ class Solver:
     set_weights = update
 
 
+class _StepRow(Mapping):
+    """One grad step's metrics: a read-only view of row ``i`` of a chunk's
+    stacked ``[chain]`` device arrays. Making one launches nothing;
+    ``row[k]`` is ``chunk[k][i]`` — ONE slice of ONE array (two tiny
+    device programs, ~0.35 ms of dispatch on the chip), paid by the
+    caller that reads the value, when it reads it. Everything else a
+    dict of the chunk's keys answers (``keys``, iteration, ``len``,
+    ``in``, ``items``, ``dict(row)``) comes from ``Mapping``."""
+
+    __slots__ = ("_chunk", "_i")
+
+    def __init__(self, chunk: dict[str, Any], i: int):
+        self._chunk = chunk
+        self._i = i
+
+    def __getitem__(self, key: str) -> Any:
+        return self._chunk[key][self._i]
+
+    def __iter__(self) -> Iterator[str]:
+        return iter(self._chunk)
+
+    def __len__(self) -> int:
+        return len(self._chunk)
+
+    def __contains__(self, key: object) -> bool:
+        return key in self._chunk       # Mapping's default would slice
+
+
 class FusedStepStream:
     """Per-grad-step metrics from chained fused-PER dispatches.
 
@@ -272,9 +304,14 @@ class FusedStepStream:
     This owns the bridge in ONE place: dispatch a chunk of
     ``min(chain, steps_left)`` steps whenever the previous chunk is
     exhausted (the tail clamp keeps the optimizer-step total exact), then
-    hand out the chunk's stacked metrics row by row. The slicing index is
+    hand out the chunk's stacked metrics row by row. The row index is
     easy to get subtly wrong in hand-maintained copies — an off-by-one
     would attribute metrics to the neighboring grad step.
+
+    A row is a VIEW (``_StepRow``): handing it out launches no device
+    program, and reading ``row[k]`` slices that one key then — the loops
+    read ``loss`` / ``q_mean`` once a log row, so the eleven-key token
+    family no longer pays 22 tiny programs a step for values nobody reads.
 
     ``dispatch_lock`` (optional lock, e.g. the ReplayFeed
     server's ``replay_lock``) is held across the dispatch only — the
@@ -282,6 +319,18 @@ class FusedStepStream:
     the window while the chunk executes on device. ``timer`` is the train
     loop's ``StepTimer`` (dispatch phase attribution).
     """
+
+    # How far the host may run ahead of the chip, in chunks: before chunk
+    # k+1 is dispatched, chunk k-1 has finished. Nothing in
+    # ``train_steps_device_per`` waits for the device, so without a bound
+    # the host queues chunks until the runtime's own launch queue stops it
+    # — minutes of work in flight past a deadline in the token family,
+    # and in ``train_distributed`` a dispatch that blocks while holding
+    # ``replay_lock``. Two, not one: with one chunk executing or queued
+    # while the host prepares the next, the device never waits for the
+    # host as long as a chunk's device time exceeds the host's dispatch
+    # time; a bound of one serialises the two.
+    RUN_AHEAD_CHUNKS = 2
 
     def __init__(self, solver: Solver, replay, chain: int,
                  dispatch_lock=None, timer=None):
@@ -293,9 +342,12 @@ class FusedStepStream:
         self._chunk: dict[str, Any] | None = None
         self._len = 0
         self._pending = 0
+        # one metric array of each chunk still allowed in flight, oldest
+        # first: what ``_wait_for_room`` blocks on
+        self._in_flight: collections.deque = collections.deque()
         # learning-dynamics planes (cfg.train.learn_metrics): one device
-        # array per dispatched chunk, popped out of the chunk so the
-        # per-step row slicing below never sees the odd-shaped leaf;
+        # array per dispatched chunk, popped out of the chunk so a row
+        # never carries the odd-shaped leaf;
         # drained by the train loop at log cadence (drain_planes)
         self._planes: list[Any] = []
 
@@ -306,8 +358,18 @@ class FusedStepStream:
         out, self._planes = self._planes, []
         return out
 
-    def next(self, steps_left: int) -> dict[str, Any]:
-        """Metrics for one grad step; dispatches a fresh chunk as needed.
+    def _wait_for_room(self) -> None:
+        """Hold the next dispatch until at most ``RUN_AHEAD_CHUNKS - 1``
+        chunks are unfinished. Called with no lock held and outside the
+        ``dispatch`` phase: the wait releases the interpreter, and RPC
+        writers keep ``replay_lock`` while the learner sleeps here."""
+        with tracing.span("learner_wait"):
+            if len(self._in_flight) >= self.RUN_AHEAD_CHUNKS:
+                jax.block_until_ready(self._in_flight.popleft())
+
+    def next(self, steps_left: int) -> Mapping[str, Any]:
+        """Metrics for one grad step (a ``_StepRow``: nothing is sliced
+        until a key is read); dispatches a fresh chunk as needed.
 
         ``steps_left`` counts THIS step: the final partial chunk compiles
         one extra (smaller) program pair — pick totals divisible by
@@ -318,6 +380,7 @@ class FusedStepStream:
                 f"steps_left={steps_left}: dispatching with a non-positive "
                 "budget would silently run an extra optimizer step")
             self._len = min(self.chain, int(steps_left))
+            self._wait_for_room()
             # the learner's wait for the lock is its own span
             # (lock_wait), outside the dispatch phase as before
             lock = (tracing.locked(self._lock) if self._lock is not None
@@ -331,9 +394,9 @@ class FusedStepStream:
                 plane = self._chunk.pop("learn_plane", None)
                 if plane is not None:
                     self._planes.append(plane)
+            self._in_flight.append(next(iter(self._chunk.values())))
             self._pending = self._len
         with tracing.span("learner_slice"):
-            m = {k: v[self._len - self._pending]
-                 for k, v in self._chunk.items()}
+            row = _StepRow(self._chunk, self._len - self._pending)
         self._pending -= 1
-        return m
+        return row

@@ -82,11 +82,13 @@ STAGES = (
     "stage_batch",     # DeviceStager cycle (sample + device_put)
     "device_put",      # host→device transfer of a sampled batch
     "train_step",      # train-step dispatch (fused chain or per-step)
+    "learner_wait",    # FusedStepStream's run-ahead bound: chunk k-1 done
+                       # before chunk k+1 is dispatched (no lock held)
     "learner_chunk",   # FusedStepStream.next when it dispatches a chunk
     "learner_flush",   # staged rows → device before a fused dispatch
     "learner_feed",    # cursors/sizes/betas/keys for the fused programs
     "learner_adopt",   # new device state taken, the donated one dropped
-    "learner_slice",   # one grad step's row out of the chunk's metrics
+    "learner_slice",   # one grad step's row handed out (a view: no launch)
     "learner_publish",  # θ → the RPC plane (param_sync_period)
     "learner_log",     # log row: loss fence, counters, telemetry, sink
     "learner_checkpoint",  # checkpoint (+ replay/server snapshot) save

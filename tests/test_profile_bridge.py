@@ -15,7 +15,6 @@ import numpy as np
 import pytest
 
 from distributed_deep_q_tpu import tracing
-from distributed_deep_q_tpu.config import Config, NetConfig, ReplayConfig
 
 pytestmark = [pytest.mark.tracing]
 
@@ -66,29 +65,9 @@ class _StubSolver:
 
 
 @pytest.fixture(scope="module")
-def toy():
+def toy(toy_fused_pair):
     """A real fused pair at toy size: the solver, its filled ring."""
-    from distributed_deep_q_tpu.replay.device_per import DevicePERFrameReplay
-    from distributed_deep_q_tpu.solver import Solver
-
-    cfg = Config()
-    cfg.mesh.backend = "cpu"
-    cfg.mesh.dp = 1
-    cfg.net = NetConfig(kind="nature_cnn", num_actions=4,
-                        frame_shape=(36, 36))
-    cfg.replay = ReplayConfig(capacity=512, batch_size=16, n_step=2,
-                              prioritized=True, device_per=True,
-                              write_chunk=16, fused_chain=CHAIN)
-    solver = Solver(cfg)
-    dev = DevicePERFrameReplay(cfg.replay, solver.mesh, (36, 36), stack=4,
-                               gamma=0.99, seed=0, write_chunk=16)
-    rng = np.random.default_rng(0)
-    for i in range(300):
-        dev.add(rng.integers(0, 255, (36, 36), dtype=np.uint8),
-                int(rng.integers(4)), float(rng.standard_normal()),
-                done=(i % 9 == 8))
-    dev.flush()
-    return solver, dev
+    return toy_fused_pair(CHAIN)
 
 
 # -- the two flags ----------------------------------------------------------
@@ -154,7 +133,9 @@ def test_fused_stream_spans_and_the_lock(lock):
     for left in range(6, 0, -1):
         stream.next(left)
     tracing.profile_stop()
-    chunk = [("ddq/learner_chunk", 0)]
+    # the run-ahead wait comes first: before the chunk's span, the lock
+    # and its spans (ISSUE 28)
+    chunk = [("ddq/learner_wait", 0), ("ddq/learner_chunk", 0)]
     if lock is not None:
         chunk += [("ddq/lock_wait", 1), ("ddq/lock_hold", 1)]
     inner = 1 if lock is None else 2
@@ -219,6 +200,7 @@ def test_trace_window_puts_the_loop_spans_on_the_device_clock(toy, tmp_path):
     chunks = named("learner_chunk")
     assert len(chunks) == 3
     assert len(named("learner_slice")) == steps      # one a step
+    assert len(named("learner_wait")) == 3           # one a chunk
     assert not named("lock_wait")                    # no lock was given
     for name in ("sample", "train_step", "learner_flush", "learner_feed",
                  "learner_adopt"):
@@ -226,9 +208,75 @@ def test_trace_window_puts_the_loop_spans_on_the_device_clock(toy, tmp_path):
                   for s, e in named(name)]
         assert inside and all(inside), name
     assert len(named("sample")) == len(named("train_step")) == 3
-    # slices sit between the chunks, never inside one
-    assert not any(cs < s < ce for s, _ in named("learner_slice")
+    # slices and the run-ahead wait sit between the chunks, never inside
+    assert not any(cs < s < ce
+                   for s, _ in named("learner_slice") + named("learner_wait")
                    for cs, ce in chunks)
+
+
+@pytest.fixture
+def profiler_calls(monkeypatch):
+    """``jax.profiler``'s start and stop replaced by a log of the calls."""
+    import jax
+
+    calls: list[str] = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda *a, **k: calls.append("start"))
+    monkeypatch.setattr(jax.profiler, "stop_trace",
+                        lambda: calls.append("stop"))
+    return calls
+
+
+def test_trace_window_stop_waits_for_the_device_first(
+        monkeypatch, tmp_path, profiler_calls):
+    """The loop runs up to two chunks ahead of the device (ISSUE 28): a
+    window that stopped the trace at once would cut its last program."""
+    import jax
+
+    from distributed_deep_q_tpu.profiling import TraceWindow
+
+    order = profiler_calls
+
+    class _Held:
+        def __init__(self, deleted=False, donated=False):
+            self._deleted, self._donated = deleted, donated
+
+        def is_deleted(self):
+            return self._deleted
+
+        def block_until_ready(self):
+            if self._donated:
+                raise RuntimeError("Array has been deleted.")
+            order.append("waited")
+
+    monkeypatch.setattr(jax, "live_arrays", lambda: [
+        _Held(), _Held(deleted=True), _Held(donated=True), _Held()])
+    trace = TraceWindow(str(tmp_path), start_step=0, num_steps=2)
+    trace.on_step(0), trace.on_step(1)
+    assert order == ["start"]
+    trace.on_step(2)
+    assert order == ["start", "waited", "waited", "stop"]
+    assert trace._done and not tracing.PROFILING
+
+
+def test_trace_window_min_seconds_holds_the_stop_back(
+        monkeypatch, tmp_path, profiler_calls):
+    """``train_distributed`` asks for a least wall time: the step count
+    alone no longer spans the RPC plane's bursts (ISSUE 28)."""
+    from distributed_deep_q_tpu import profiling
+
+    calls, now = profiler_calls, [100.0]
+    monkeypatch.setattr(profiling.time, "perf_counter", lambda: now[0])
+    trace = profiling.TraceWindow(str(tmp_path), start_step=0, num_steps=2,
+                                  min_seconds=1.0)
+    trace.on_step(0)
+    for step in (1, 2, 3):          # the steps are over at 2, the time not
+        now[0] += 0.3
+        trace.on_step(step)
+    assert calls == ["start"]
+    now[0] += 0.3                   # 1.2 s after the start
+    trace.on_step(4)
+    assert calls == ["start", "stop"] and trace._done
 
 
 # -- jax.named_scope on the four program bodies ----------------------------
