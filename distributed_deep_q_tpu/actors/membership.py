@@ -256,7 +256,7 @@ def export_shard(server, path: str,
     zero, then snapshots through ``GenerationStore`` — payload files
     first, manifest last, so the handoff either committed completely or
     (torn) fails CRC at import and falls back. Returns the handoff
-    receipt the churn gate and PERF bench consume."""
+    receipt the churn gate consumes."""
     t0 = time.perf_counter()
     with server.replay_lock:
         rows = len(server.replay) if server.replay is not None else 0

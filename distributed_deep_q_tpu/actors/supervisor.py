@@ -1196,7 +1196,7 @@ def _bring_up_health_plane(cfg: Config, server, infer_server=None,
     scrape is an in-process call (a remote member would register its
     client stub's ``.health`` instead; same wire dict either way). The
     MFU meter gets a flops-per-step census only on the fused device-PER
-    path (the flagship program bench's offline MFU times) and only when
+    path (the program the benchmark's ``train_mfu`` times) and only when
     the health plane is on — the census is one extra AOT compile, which
     a default run must not pay. Returns ``(fleet, meter)``; both are
     inert no-ops while ``health.ENABLED`` is off."""

@@ -27,7 +27,7 @@ from typing import Callable, Sequence
 
 import numpy as np
 
-from distributed_deep_q_tpu.actors.game import Env, make_envs
+from distributed_deep_q_tpu.actors.game import Env
 
 
 class VectorEnv:
@@ -139,19 +139,6 @@ class VectorStepLatencyEnv:
         return getattr(self._env, name)
 
 
-def make_vector_env(env_cfgs, seeds: Sequence[int],
-                    latency: bool = False):
-    """Build a ``VectorEnv`` from per-row (EnvConfig, seed) pairs.
-
-    ``env_cfgs`` is either one EnvConfig (replicated) or a sequence of
-    per-row configs (the multi-game fleet case — ``env_for_actor``
-    output per global id). Seeding stays the fleet's discipline: caller
-    passes exactly the seeds the per-env processes would have used.
-    """
-    venv = VectorEnv(make_envs(env_cfgs, seeds))
-    return VectorStepLatencyEnv(venv) if latency else venv
-
-
 def select_actions(obs: np.ndarray, rngs: Sequence[np.random.Generator],
                    epsilons: Sequence[float], num_actions: int,
                    greedy_fn: Callable[[np.ndarray], np.ndarray],
@@ -188,8 +175,8 @@ class VectorActing:
     ε-greedy rng streams; each ``tick(greedy_fn)`` selects N actions,
     steps the stack once, and returns the per-env transition records
     the supervisor flushes down the wire. Factored out of the
-    supervisor so the bitwise-parity tests (and the bench) can drive
-    the exact production tick without sockets.
+    supervisor so the bitwise-parity tests can drive the exact
+    production tick without sockets.
     """
 
     def __init__(self, env, stack: int,

@@ -1,17 +1,17 @@
 """Metric-name / span-name registry cross-check.
 
 Every gauge/counter/histogram the observability spine emits is read
-back BY NAME — ``scripts/telemetry_report.py`` section filters,
-``bench_diff``, the PERF tables. A typo at an emit site doesn't fail;
-the series silently vanishes from every report (emitted under one name,
-read under another). This pass pins the names:
+back BY NAME — ``scripts/telemetry_report.py`` section filters, the
+benchmark's log-row readers, the PERF tables. A typo at an emit site
+doesn't fail; the series silently vanishes from every report (emitted
+under one name, read under another). This pass pins the names:
 
 - ``REGISTRY`` declares every metric name the repo emits or reads as a
   string literal: the namespaced ``<ns>/...`` keys and the bare
   counters.
 - Any string literal matching a metric namespace (``rpc/…``,
-  ``trace/…``, …) anywhere in the package, ``bench.py``, or
-  ``scripts/`` must be declared → ``metric_keys.unknown-metric``.
+  ``trace/…``, …) anywhere in the package or ``scripts/`` must be
+  declared → ``metric_keys.unknown-metric``.
 - The first argument of ``metrics.count/gauge/observe/observe_many/
   histogram`` — when a literal — must be declared too (covers bare
   names like ``grad_steps`` that carry no namespace).
@@ -59,9 +59,7 @@ REGISTRY = frozenset({
     "env_steps",
     "grad_steps",
     # rpc server telemetry (scalar keys; per-method f-string keys are
-    # dynamic and unchecked — except names a reader spells out as a
-    # literal, which are declared so the read side stays registered)
-    "rpc/add_transitions_calls",
+    # dynamic and unchecked)
     "rpc/checksum_errors",
     "rpc/conn_timeouts",
     "rpc/dispatch_errors",
@@ -318,9 +316,6 @@ def check(repo_root: str,
 
     paths = iter_py_files(repo_root,
                           subdirs=("distributed_deep_q_tpu", "scripts"))
-    bench = os.path.join(repo_root, "bench.py")
-    if os.path.exists(bench):
-        paths.append(bench)
     srcs = load_sources(repo_root, paths)
     tracing_src = next(
         (s for s in srcs

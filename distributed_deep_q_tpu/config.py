@@ -129,12 +129,10 @@ class ReplayConfig:
     # occupancy is bounded in practice by staged_high_watermark)
     staging_depth: int = 4096
     # background staging→device drain thread (replay.start_drain): the
-    # server/bench attach it so writers never pay the device dispatch.
+    # replay server attaches it so writers never pay the device dispatch;
+    # it dispatches a batched flush once write_chunk rows are staged.
     # Ignored on multi-host meshes (flushes are lockstep collectives)
     ingest_drain: bool = True
-    # rows staged before the drain thread dispatches a batched flush
-    # (0 = write_chunk)
-    drain_min_rows: int = 0
     # optional replay persistence (SURVEY §5.4): when set, the buffer's
     # complete sampling state (rings, cursors, trees, RNG) is dumped to
     # this .npz alongside learner checkpoints and restored on
@@ -186,19 +184,12 @@ class TrainConfig:
     seed: int = 0
     # use the fused Pallas TD-loss kernel on TPU
     use_pallas_loss: bool = False
-    # batch the online net's s and s' forwards into ONE conv application
-    # (Double-DQN only): halves per-step weight reads and doubles conv
-    # batch (MXU utilization) at the cost of saving s' activations for
-    # the (zero-cotangent) backward — wins when the step is weight-read
-    # bound (small batch), loses nothing measurable at large batch
-    fuse_double_forward: bool = False
     # stack θ and θ⁻ on a leading axis and run ALL the step's Q-forwards
     # (θ(s), θ(s') for Double-DQN, θ⁻(s')) as ONE vmapped application —
     # the conv/dense chain count collapses to a single forward's worth
     # (PERF.md §3: the small-batch step is op-count-bound). "auto" turns
     # it on when the per-shard batch is ≤ 128 — at large batch the step
     # is HBM/flop-bound and the extra θ⁻(s) quarter stops being free.
-    # Supersedes fuse_double_forward when active.
     stack_forwards: str = "auto"  # auto | on | off
     # store Adam's first moment in bfloat16 (optax mu_dtype): trims
     # optimizer-state HBM traffic on the HBM-bound small-batch step
@@ -496,8 +487,6 @@ class InferenceConfig:
     # admission (reuses rpc/flowcontrol.py): queued rows beyond this shed
     # new requests with an explicit retry_after_ms reply
     queue_high_watermark: int = 4096
-    # reply-latency SLO for bench/chaos verdicts (not enforced inline)
-    slo_ms: float = 50.0
     # multi-tenant serving (ISSUE 20): extra tenant tags registered at
     # boot ("ab:<name>" arms join the actor-hash split once θ installs;
     # "shadow:<name>" tenants mirror primary traffic, replies never

@@ -1,9 +1,10 @@
 """Structured metrics (SURVEY.md §5.5).
 
 The reference logs via stdout prints + Spark UI [R]; here metrics are
-structured counters written as JSONL (machine-readable for the bench
-harness) with optional TensorBoard mirroring. The north-star counters —
-grad-steps/sec, env-steps/sec, eval return [M] — are first-class.
+structured counters written as JSONL (machine-readable: the benchmark's
+fleet driver reads its log rows) with optional TensorBoard mirroring.
+The north-star counters — grad-steps/sec, env-steps/sec, eval return
+[M] — are first-class.
 
 Telemetry layer (observability spine): ``Histogram`` is a streaming
 log-bucketed histogram (fixed bucket edges, O(1) observe, p50/p95/p99

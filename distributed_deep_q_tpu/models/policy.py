@@ -17,8 +17,8 @@ microbatching server sees every batch size from 1 to ``max_batch``; left
 alone that is ``max_batch`` compiled programs and an unbounded compile
 tail. Instead every batch pads (zero rows, sliced off after the forward)
 to the smallest of a few fixed ``buckets`` — at most ``len(buckets)``
-XLA programs ever, the set actually compiled is exposed for the bench
-census (``compiled_buckets``). Oversized batches fold into chunks of the
+XLA programs ever, the set actually compiled is exposed as a gauge
+(``compiled_buckets``). Oversized batches fold into chunks of the
 largest bucket, so the bound holds for any input.
 """
 
@@ -78,8 +78,9 @@ class BatchedPolicy:
         return self.buckets[-1]
 
     def compiled_buckets(self) -> list[int]:
-        """Bucket sizes that have actually compiled — the bench census
-        asserting the ≤ len(buckets) XLA-program bound."""
+        """Bucket sizes that have actually compiled — what the
+        ``inference/compiled_buckets`` gauge and the tests of the
+        ≤ len(buckets) XLA-program bound read."""
         return sorted(self._compiled)
 
     # -- forward ------------------------------------------------------------

@@ -466,16 +466,6 @@ class Learner:
                 q, q_next_o, q_next_t = stacked_q_forwards(
                     apply_fn, params, state.target_params,
                     batch["obs"], batch["next_obs"], cfg.double_dqn)
-            elif cfg.double_dqn and cfg.fuse_double_forward:
-                # one conv application for s AND s' (cfg docstring): the
-                # split's s' half carries zero cotangents back (action
-                # selection must not backprop into the online net)
-                qq = apply_fn(params, jnp.concatenate(
-                    [batch["obs"], batch["next_obs"]], axis=0))
-                q, q_next_o = jnp.split(qq, 2, axis=0)
-                q_next_o = lax.stop_gradient(q_next_o)
-                q_next_t = apply_fn(state.target_params,
-                                    batch["next_obs"])
             else:
                 q = apply_fn(params, batch["obs"])
                 q_next_o = (apply_fn(params, batch["next_obs"])

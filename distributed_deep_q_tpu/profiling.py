@@ -39,7 +39,7 @@ from distributed_deep_q_tpu import tracing
 from distributed_deep_q_tpu.metrics import Histogram
 
 
-# -- compiled-HLO op census (the op-count ratchet's measurement) -----------
+# -- compiled-HLO op census (what tests/test_op_count.py pins) -------------
 
 # NB: the param list may hold nested parens (tuple-typed while-body
 # params), so the body is matched greedily; op-definition lines can't
@@ -70,12 +70,10 @@ def hlo_op_census(hlo_text: str,
     computation plus control-flow computations (while/conditional bodies
     and outlined ``call`` targets — their ops run when the loop/branch
     does), EXCLUDING the sub-computations that fusions and reducers
-    merely wrap (their ops execute inside the one fused kernel, which is
-    the whole point of counting this way: the step cost model is
-    ~constant per *scheduled* op, PERF.md §3). A ``calls=``/``to_apply=``
-    reference excludes its target only when the referencing op is a
-    fusion/reduction-style wrapper — a ``call``'s target (XLA outlines
-    scan bodies this way on CPU) stays counted.
+    merely wrap (their ops execute inside the one fused kernel). A
+    ``calls=``/``to_apply=`` reference excludes its target only when the
+    referencing op is a fusion/reduction-style wrapper — a ``call``'s
+    target (XLA outlines scan bodies this way on CPU) stays counted.
 
     Returns ``{op: count for op in ops}`` plus ``"scheduled_total"``
     (all scheduled ops except parameter/constant declarations).
@@ -97,12 +95,11 @@ def hlo_scan_body_census(
     """``hlo_op_census`` of the LARGEST scheduled non-entry computation
     plus everything it reaches through call/while/conditional references
     — for a chained (``lax.scan``-over-grad-steps) train program that is
-    the loop body, i.e. the op count paid PER GRAD STEP (the quantity
-    PERF.md §3's per-op cost model prices; CPU XLA outlines e.g. each
-    threaded convolution into its own ``call``-referenced computation,
-    which executes per iteration and must count). Falls back to the
-    whole-module census when no substantial non-entry computation exists
-    (unchained programs)."""
+    the loop body, i.e. the ops run PER GRAD STEP (CPU XLA outlines e.g.
+    each threaded convolution into its own ``call``-referenced
+    computation, which executes per iteration and must count). Falls
+    back to the whole-module census when no substantial non-entry
+    computation exists (unchained programs)."""
     bodies, fused, refs = _parse_hlo_computations(hlo_text)
     best: str | None = None
     for name, opcodes in bodies.items():
@@ -167,13 +164,6 @@ def _count_into(counts: dict[str, int], opcodes: list[str]) -> None:
             counts["scheduled_total"] += 1
         if op in counts:
             counts[op] += 1
-
-
-def compiled_op_census(jitted, *args, **kwargs) -> dict[str, int]:
-    """``hlo_op_census`` of ``jitted.lower(*args).compile()``. ``kwargs``
-    are forwarded to ``hlo_op_census`` (e.g. ``ops=...``)."""
-    compiled = jitted.lower(*args).compile()
-    return hlo_op_census(compiled.as_text(), **kwargs)
 
 
 class StepTimer:
@@ -254,7 +244,7 @@ class StepTimer:
         return out
 
 
-# -- flops-per-step census (promoted from bench.py for live MFU) -----------
+# -- flops-per-step census (feeds the live ``train/mfu`` gauge) ------------
 
 # bf16 peak FLOP/s by device_kind prefix (public spec sheets)
 PEAK_FLOPS = {
@@ -295,28 +285,12 @@ def _cost_flops(compiled) -> float | None:
     return flops if flops > 0 else None
 
 
-def xla_flops(solver, replay, batch) -> float | None:
-    """FLOPs of the compiled ring train step, from XLA's cost model.
-    Fails loudly on ``backend="tpu"``; the CPU test path answers None
-    when the census cannot be taken."""
-    try:
-        fn = solver.learner._ring_steps[tuple(solver.config.net.frame_shape)]
-        clean = {k: v for k, v in batch.items()
-                 if k not in ("index", "_sampled_at")}
-        return _cost_flops(
-            fn.lower(solver.state, replay.ring, clean).compile())
-    except Exception:
-        if solver.backend == "tpu":
-            raise
-        return None
-
-
 def compile_fused_train(solver, replay, chain: int):
     """The FUSED train program for ``replay``'s geometry, compiled ahead
     of time from avals alone (``eval_shape`` of the sample program: no
     device sample execution, no sampling-key-stream side effect) — the
-    one artifact the flops census, the op-count census and the chip
-    smoke's all-reduce check read. Builds the program pair when the train
+    one artifact the flops census, the op census and the chip smoke's
+    all-reduce check read. Builds the program pair when the train
     loop has not run yet."""
     sample, train = solver.learner.device_per_programs(
         solver.device_per_spec(replay), chain)
@@ -350,18 +324,16 @@ def fused_train_flops(solver, replay, chain: int) -> float | None:
 class MFUMeter:
     """Live model-FLOPs-utilization gauge (health plane, ISSUE 13).
 
-    ``bench.py`` already derives MFU offline — flops-per-step (from the
-    compiled program's cost analysis) × measured steps/s ÷ the device's
-    peak — but a derivation over one bench window is not an ops signal.
-    This meter closes the loop at runtime: the learner calls
-    ``update(gstep)`` on its logging cadence, the meter converts the
-    grad-step delta over the wall-clock window into steps/s and emits
-    ``train/steps_per_s`` + ``train/mfu`` (and, fed the flow plane's
-    rates, ``train/ingest_utilization`` — the fraction of ingested rows
-    the learner actually consumes). ``peak_flops`` is None on devices
-    with no published peak (CPU containers): MFU is then simply absent
-    from the gauges rather than a made-up number — same honesty rule as
-    the bench.
+    MFU = flops-per-step (from the compiled fused train program's cost
+    analysis, ``fused_train_flops``) × measured steps/s ÷ the device's
+    peak. The learner calls ``update(gstep)`` on its logging cadence,
+    the meter converts the grad-step delta over the wall-clock window
+    into steps/s and emits ``train/steps_per_s`` + ``train/mfu`` in the
+    health tick (and, fed the flow plane's rates,
+    ``train/ingest_utilization`` — the fraction of ingested rows the
+    learner actually consumes). ``peak_flops`` is None on devices with
+    no published peak (CPU containers): MFU is then simply absent from
+    the gauges rather than a made-up number.
     """
 
     def __init__(self, flops_per_step: float | None,

@@ -183,7 +183,6 @@ class DeviceFrameReplay:
         self._prepared_rows = 0
         self._drain = None  # optional IngestDrain (start_drain)
         self._drain_enabled = bool(getattr(cfg, "ingest_drain", True))
-        self._drain_min = int(getattr(cfg, "drain_min_rows", 0))
 
     def _alloc_ring(self) -> None:
         """Allocate the HBM frame plane + its scatter-writer. Overridden by
@@ -232,9 +231,8 @@ class DeviceFrameReplay:
 
     def pending_rows(self) -> int:
         """Rows staged or pre-assembled but not yet flushed to HBM.
-        Public because writer backpressure (bench.py) and the solver's
-        flush gate key off it — callers must not reach into
-        ``_pending_rows`` (ADVICE r4)."""
+        Public because the solver's flush gate keys off it — callers
+        must not reach into ``_pending_rows``."""
         return sum(self._pending_rows) + self._prepared_rows
 
     def _staged_rows(self) -> int:
@@ -376,7 +374,7 @@ class DeviceFrameReplay:
         elif not self.defer_flush:
             self.flush()
 
-    def start_drain(self, lock, min_rows: int | None = None):
+    def start_drain(self, lock):
         """Attach a background staging→device drain thread sharing
         ``lock`` (the caller's replay lock — mutual exclusion with
         writers and the sampler is unchanged). Returns the drain, or
@@ -395,13 +393,12 @@ class DeviceFrameReplay:
         if not self._drain_enabled:
             return None
         from distributed_deep_q_tpu.replay.columnar import IngestDrain
-        min_r = min_rows or max(self.write_chunk, self._drain_min)
         if self.defer_flush:
-            self._drain = IngestDrain(self, lock, min_r,
+            self._drain = IngestDrain(self, lock, self.write_chunk,
                                       work=self.prepare_rounds,
                                       backlog=self._staged_rows)
         else:
-            self._drain = IngestDrain(self, lock, min_r)
+            self._drain = IngestDrain(self, lock, self.write_chunk)
         return self._drain
 
     def stop_drain(self) -> None:

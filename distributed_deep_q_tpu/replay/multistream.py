@@ -7,8 +7,7 @@ each actor stream its own ``FrameStackReplay`` shard (capacity split
 evenly), preserving the adjacency invariant per shard — the host-side
 analogue of the device ring's slot layout (replay/device_ring.py), with
 pixels gathered on host and shipped as full minibatches (the path the
-reference's Caffe blob loads took, SURVEY §3.1; measured cost in bench.py's
-host-replay variant).
+reference's Caffe blob loads took, SURVEY §3.1).
 
 Uniform sampling only: PER over cross-shard global indices belongs to the
 device ring; the distributed entry point rejects the

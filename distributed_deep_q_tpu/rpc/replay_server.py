@@ -858,7 +858,7 @@ class ReplayFeedServer:
                                     if self.replay is not None else 0),
                     "mean_return": self.mean_recent_return(),
                 }
-            # server health for actors/bench/tests without reaching into
+            # server health for actors/tests without reaching into
             # internals: per-method latency/size summaries, queue gauges,
             # and the fleet counters the actors flushed back
             out.update(self.telemetry_summary())
@@ -1043,7 +1043,7 @@ class ReplayFeedServer:
                 # learner process serves exactly its hash-assigned actor
                 # slice, so this server's replay IS the shard — expose
                 # its fill, its ingest rate, and which host owns it (the
-                # probe the linearity bench and ops dashboards key on).
+                # probe ops dashboards key on).
                 # _pid avoids importing jax here; 0 on host-RAM replays
                 out["shard/rows"] = len(self.replay)
                 out["shard/owner_host"] = int(

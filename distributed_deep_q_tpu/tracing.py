@@ -563,7 +563,7 @@ def export(path: str | None = None) -> str | None:
     return path
 
 
-# -- attribution (shared by bench --trace-ingest and trace_report) ---------
+# -- attribution (read by scripts/trace_report.py) -------------------------
 def self_times(events: list[dict]) -> dict:
     """Per-(pid, tid) SELF-time attribution: for every "X" event, self =
     dur − Σ(direct children) on the same thread. Returns::

@@ -1,7 +1,7 @@
 """Where the persistent XLA compile cache lives — decided from outside.
 
 One rule for every entry point that holds the chip (``main.main``,
-``chip_smoke.py``, ``bench.main``), applied before first backend use:
+``chip_smoke.py``), applied before first backend use:
 
 - ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself; nothing is set
   in code, so the environment is never overridden and no second
@@ -12,10 +12,7 @@ One rule for every entry point that holds the chip (``main.main``,
 
 Child processes: the in-code setting does not travel (spawned actors and
 multi-process workers start from a fresh import and never call this), so
-only an exported ``JAX_COMPILATION_CACHE_DIR`` reaches them. bench's
-multi-process CPU workers must run WITHOUT the cache (deserialized
-executables segfault inside the gloo collectives), so
-``bench._multihost_curve`` strips the variable from their environment.
+only an exported ``JAX_COMPILATION_CACHE_DIR`` reaches them.
 
 ``CompileClock`` is the one clock for what compiling costs: seconds of
 tracing + lowering + backend compile (or cache load), the number of

@@ -34,7 +34,7 @@ import sys
 def _load_tracing():
     """Load ``distributed_deep_q_tpu/tracing.py`` without importing the
     package (whose ``__init__`` pulls in jax): the attribution helpers
-    are shared with ``bench.py --trace-ingest``, not duplicated here."""
+    live beside the tracer, not duplicated here."""
     path = os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         "distributed_deep_q_tpu", "tracing.py")

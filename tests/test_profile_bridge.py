@@ -155,7 +155,7 @@ def test_every_stage_has_a_call_site_and_every_site_a_stage():
     used = {"lock_wait", "lock_hold"}
     paths = glob.glob(os.path.join(ROOT, "distributed_deep_q_tpu", "**",
                                    "*.py"), recursive=True)
-    for path in [*paths, os.path.join(ROOT, "bench.py")]:
+    for path in paths:
         with open(path) as fh:
             used.update(rx.findall(fh.read()))
     assert used == set(tracing.STAGES)
