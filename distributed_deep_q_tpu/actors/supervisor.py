@@ -380,6 +380,45 @@ class _RemoteInference:
 # ---------------------------------------------------------------------------
 
 
+def _log_crc_backend(actor_id: int) -> None:
+    """Say once which CRC-32C this actor process runs on every frame it
+    sends and every θ frame it pulls. ``numpy`` is a warning: the
+    fallback costs a closed-loop actor about a tenth of its time, and the
+    parent should have left the built library beside the source."""
+    from distributed_deep_q_tpu.utils.durability import crc_backend
+    backend = crc_backend()
+    logging.getLogger(__name__).log(
+        logging.INFO if backend == "native" else logging.WARNING,
+        "actor %d: crc32c backend %s", actor_id, backend)
+
+
+def actor_cores(actor_id: int, num_actors: int, cores) -> set[int]:
+    """The cores actor ``actor_id`` of ``num_actors`` on this host keeps
+    to: an even share of ``cores`` (those the process may run on), at
+    least one, wrapping when there are more actors than cores."""
+    cores = sorted(cores)
+    share = max(1, len(cores) // max(int(num_actors), 1))
+    return {cores[(actor_id * share + j) % len(cores)]
+            for j in range(share)}
+
+
+def _keep_to_core_share(actor_id: int, num_actors: int) -> None:
+    """Confine this actor process, and every thread it starts from here
+    on, to its share of the host's cores. An actor's work is one env and
+    one small forward, but XLA:CPU gives each process a thread pool over
+    EVERY core it may run on; four such pools on the learner's host
+    (52 threads on 13 cores) starve the one thread of the TPU runtime
+    that notices finished programs, which then finds them only at its
+    own 100 ms poll: the learner stalled ~40 ms ten times a second with
+    the chip idle (PERF.md §6, PR 30). An even split costs the actors
+    nothing measurable (three cores each: the same transitions/s) and
+    the stalls are gone. Called before the backend exists, so its pools
+    are sized and placed by the mask. Linux only; elsewhere a no-op."""
+    if hasattr(os, "sched_setaffinity"):
+        os.sched_setaffinity(
+            0, actor_cores(actor_id, num_actors, os.sched_getaffinity(0)))
+
+
 def actor_main(cfg: Config, host: str, port: int, actor_id: int,
                stop_event, max_env_steps: int = 0) -> None:
     """One CPU actor: play with ε-greedy policy, ship transitions, pull θ.
@@ -393,6 +432,8 @@ def actor_main(cfg: Config, host: str, port: int, actor_id: int,
     # tracing config rides the pickled cfg into the spawned child; spans
     # from this process export as their own shard (trace-<pid>.json)
     tracing.configure_from(cfg.trace)
+    _log_crc_backend(actor_id)
+    _keep_to_core_share(actor_id, cfg.actors.num_actors)
     # The env var alone is NOT enough: unpickling this function's module
     # in the spawned child has already imported jax, and jax reads
     # JAX_PLATFORMS once, at import — so on a host with no platform in the
@@ -1631,6 +1672,7 @@ def train_distributed(cfg: Config, metrics: Metrics | None = None,
     summary["rpc_duplicate_flushes"] = rpc["duplicate_flushes"]
     summary["rpc_shed_flushes"] = rpc["shed_flushes"]
     summary["rpc_checksum_errors"] = rpc["checksum_errors"]
+    summary["rpc_crc_native"] = rpc["crc_native"]
     summary["snapshot_quarantined"] = rpc["snapshot_quarantined"]
     summary["flow_degraded_trips"] = server.flow_counters()["degraded_trips"]
     if infer_server is not None:
@@ -1848,6 +1890,7 @@ def _train_distributed_recurrent(cfg: Config, metrics: Metrics | None = None,
     summary["rpc_duplicate_flushes"] = rpc["duplicate_flushes"]
     summary["rpc_shed_flushes"] = rpc["shed_flushes"]
     summary["rpc_checksum_errors"] = rpc["checksum_errors"]
+    summary["rpc_crc_native"] = rpc["crc_native"]
     summary["snapshot_quarantined"] = rpc["snapshot_quarantined"]
     summary["flow_degraded_trips"] = server.flow_counters()["degraded_trips"]
     summary["solver"] = solver

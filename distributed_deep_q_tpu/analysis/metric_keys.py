@@ -62,6 +62,7 @@ REGISTRY = frozenset({
     # dynamic and unchecked)
     "rpc/checksum_errors",
     "rpc/conn_timeouts",
+    "rpc/crc_native",
     "rpc/dispatch_errors",
     "rpc/duplicate_flushes",
     "rpc/shed_flushes",

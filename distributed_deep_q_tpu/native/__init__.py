@@ -6,6 +6,14 @@ one host-side pointer-chasing hot loop (SURVEY §7.3 item 2) — has a C++
 implementation compiled on first use with the baked-in g++ toolchain
 (no pybind11 in the image, so the ABI is plain C via ctypes).
 
+Entry points of ``replay_core.cpp``: ``st_set`` / ``st_sample_stratified``
+(sum tree, ``replay/prioritized.py``), ``staged_append`` (columnar ingest,
+``replay/columnar.py``) and ``crc32c_update`` (the CRC-32C of every wire
+frame and snapshot file, ``utils/durability.crc32c``; the CPU's own
+instruction where it has one, a slicing-by-8 table loop otherwise, which
+``crc32c_update_portable`` runs alone for the tests). The library is a
+``ctypes.CDLL``, so every call gives the interpreter lock up while it runs.
+
 ``load()`` returns the ctypes lib or None (missing compiler, failed build);
 callers fall back to the numpy implementation, which remains the semantic
 reference. The artifact is named by a hash of ``replay_core.cpp``, so what
@@ -107,6 +115,11 @@ def load() -> ctypes.CDLL | None:
             _c_u8pp, _c_u8pp, _c_int64_p, ctypes.c_int64, ctypes.c_int64,
             ctypes.c_int64]
         lib.staged_append.restype = ctypes.c_int64
+        # the address as a plain integer: crc32c hands over whatever
+        # memory the caller's buffer already occupies
+        for fn in (lib.crc32c_update, lib.crc32c_update_portable):
+            fn.argtypes = [ctypes.c_uint32, ctypes.c_void_p, ctypes.c_int64]
+            fn.restype = ctypes.c_uint32
         _lib = lib
         return _lib
 

@@ -40,7 +40,7 @@ from distributed_deep_q_tpu.rpc.protocol import (
     ChecksumError, ProtocolError, encode, recv_msg, recv_msg_sized, reframe,
     send_msg)
 from distributed_deep_q_tpu.utils.durability import (
-    GenerationStore, savez_bytes)
+    GenerationStore, crc_backend, savez_bytes)
 
 log = logging.getLogger(__name__)
 
@@ -102,6 +102,14 @@ class ServerTelemetry:
         # re-sends through its retry policy), snapshot cadence/size/stall
         # gauges, and generations quarantined by integrity checks
         self.checksum_errors = 0
+        # which CRC-32C verifies those frames in this process: 1 = the
+        # native core (the interpreter lock is given up around it), 0 =
+        # the numpy fallback, whose serve threads convoy on that lock
+        # (ISSUE 30). Fixed at construction, read-only afterwards. Asking
+        # builds the library if it must: the server comes up in the
+        # parent BEFORE the fleet is spawned, so the actors find the
+        # artifact instead of each running g++ on the same source
+        self.crc_native = int(crc_backend() == "native")
         self.snapshot_count = 0
         self.snapshot_skipped = 0
         self.snapshot_capture_ms = 0.0  # lock-hold time (the stall)
@@ -224,6 +232,7 @@ class ServerTelemetry:
             out["rpc/shed_flushes"] = self.shed_flushes
             out["rpc/conn_timeouts"] = self.conn_timeouts
             out["rpc/checksum_errors"] = self.checksum_errors
+            out["rpc/crc_native"] = self.crc_native
             out["durability/snapshot_count"] = self.snapshot_count
             out["durability/snapshot_skipped"] = self.snapshot_skipped
             out["durability/snapshot_capture_ms"] = self.snapshot_capture_ms
@@ -269,6 +278,7 @@ class ServerTelemetry:
                     "shed_flushes": self.shed_flushes,
                     "conn_timeouts": self.conn_timeouts,
                     "checksum_errors": self.checksum_errors,
+                    "crc_native": self.crc_native,
                     "snapshot_quarantined": self.snapshot_quarantined,
                     "snapshot_skipped": self.snapshot_skipped}
 

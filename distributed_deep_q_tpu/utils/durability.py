@@ -8,13 +8,18 @@ torn ``.npz`` at the final path that ``_restore()`` loaded blind or died
 on. This module is the one place persisted bytes are produced and checked:
 
 - ``crc32c``       — CRC-32C (Castagnoli), the checksum storage systems
-  use end to end. No native extension is available in this environment,
-  so the hot path is a numpy-vectorized chunked CRC: the buffer is split
-  into 2^k equal chunks front-padded with zeros (a no-op for the raw
-  CRC), all chunk states advance one byte per iteration as one table
-  lookup across the chunk axis, and the per-chunk remainders are folded
-  with GF(2) carry-less shift matrices. ~100-500 MB/s on large buffers
-  vs ~3 MB/s for a pure-Python byte loop.
+  use end to end. Where the native core loads (``native/``, built with
+  g++ on first use) it is ONE call of ``crc32c_update`` — the CPU's CRC
+  instruction, or a slicing-by-8 table loop — made with the interpreter
+  lock given up, so a serve thread's checksum no longer holds the
+  learner thread (ISSUE 30). Without the library the numpy-vectorized
+  chunked CRC below runs, and stays the semantic reference: the buffer
+  is split into 2^k equal chunks front-padded with zeros (a no-op for
+  the raw CRC), all chunk states advance one byte per iteration as one
+  table lookup across the chunk axis, and the per-chunk remainders are
+  folded with GF(2) carry-less shift matrices (~60 MB/s alone; far less
+  among threads, which queue for the interpreter at each of its ~2 600
+  numpy calls a flush). ``crc_backend()`` says which runs.
 - ``atomic_write`` — tmp file in the destination directory + flush +
   fsync + ``os.replace`` + directory fsync: a crash at any point leaves
   either the old file or the new file, never a torn one. The ``torn=``
@@ -45,6 +50,8 @@ import threading
 from typing import Any
 
 import numpy as np
+
+from distributed_deep_q_tpu import native
 
 log = logging.getLogger(__name__)
 
@@ -160,14 +167,33 @@ def _raw_crc(buf: np.ndarray) -> int:
 
 def crc32c(data, value: int = 0) -> int:
     """CRC-32C of ``data`` (bytes-like or uint8-viewable ndarray);
-    ``value`` continues a previous crc32c result (streaming use)."""
+    ``value`` continues a previous crc32c result (streaming use).
+
+    One algorithm, two implementations, chosen by what the process can
+    observe: where the native core loads (``crc_backend()`` says), one
+    call of its ``crc32c_update`` over the buffer's own memory, made with
+    the interpreter lock given up; else the numpy path below, which stays
+    the semantic reference. Bit-identical either way."""
     if isinstance(data, np.ndarray):
         buf = np.ascontiguousarray(data).view(np.uint8).ravel()
     else:
         buf = np.frombuffer(memoryview(data), np.uint8)
+    lib = native.load()
+    if lib is not None:
+        # buf views the caller's memory (a copy only of a non-contiguous
+        # ndarray) and stays referenced until the call returns
+        return lib.crc32c_update(value & 0xFFFFFFFF, buf.ctypes.data,
+                                 buf.size)
     init = (value ^ 0xFFFFFFFF) & 0xFFFFFFFF
     raw = _raw_crc(buf)
     return (raw ^ _shift_state(init, buf.size) ^ 0xFFFFFFFF) & 0xFFFFFFFF
+
+
+def crc_backend() -> str:
+    """Which implementation ``crc32c`` runs in this process: ``"native"``
+    (``native/replay_core.cpp``) or ``"numpy"`` (no library: no compiler,
+    or a failed build). The first call builds the library if it must."""
+    return native.backend()
 
 
 # ---------------------------------------------------------------------------

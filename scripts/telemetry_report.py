@@ -410,11 +410,14 @@ def render_report(records: list[dict], last: int = 0) -> str:
 
     # durability plane: snapshot cadence/stall/size, generation retention,
     # quarantines, and wire CRC rejections (any nonzero quarantine or
-    # checksum count deserves a look — it means damage was absorbed)
+    # checksum count deserves a look — it means damage was absorbed);
+    # rpc/crc_native 0 = the server checksums in numpy (serve threads
+    # convoy on the interpreter lock)
     rows = []
     for key in sorted({k for r in records for k in r
                        if k.startswith("durability/")
-                       or k == "rpc/checksum_errors"}):
+                       or k in ("rpc/checksum_errors",
+                                "rpc/crc_native")}):
         vals = [v for v in _series(records, key)
                 if isinstance(v, (int, float))]
         if vals:

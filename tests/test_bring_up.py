@@ -90,3 +90,46 @@ def test_chip_smoke_fails_without_a_tpu_and_prints_no_result():
         assert run.returncode != 0
         assert run.stdout == ""         # no result line, no "ok"
         assert "not a TPU" in run.stderr
+
+# ---------------------------------------------------------------------------
+# actors keep to their share of the host's cores (PR 30)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("actor_id, num_actors, cores, want", [
+    (0, 4, range(13), {0, 1, 2}),          # the benchmark's host: 3 each,
+    (3, 4, range(13), {9, 10, 11}),        # one core left over
+    (1, 2, {2, 3, 5, 7}, {5, 7}),          # a mask the process was given
+    (5, 16, range(4), {1}),                # more actors than cores: wrap
+    (0, 1, range(8), set(range(8))),       # alone: everything
+])
+def test_actor_cores_split_the_host_evenly(actor_id, num_actors, cores,
+                                           want):
+    from distributed_deep_q_tpu.actors.supervisor import actor_cores
+    assert actor_cores(actor_id, num_actors, cores) == want
+
+
+@pytest.mark.skipif(not hasattr(os, "sched_setaffinity"),
+                    reason="no CPU affinity on this platform")
+def test_actor_process_keeps_to_its_core_share():
+    """In a process of its own (the mask is inherited by every thread
+    started after it, and must not leak into this test worker)."""
+    import multiprocessing
+
+    from distributed_deep_q_tpu.actors import supervisor
+    ctx = multiprocessing.get_context("spawn")
+    q = ctx.Queue()
+    p = ctx.Process(target=_report_core_share, args=(q, 1, 2))
+    p.start()
+    before, after = q.get(timeout=60)
+    p.join(timeout=30)
+    assert not p.is_alive()
+    assert set(after) == supervisor.actor_cores(1, 2, before)
+    assert len(after) == max(1, len(before) // 2)
+
+
+def _report_core_share(q, actor_id, num_actors):
+    from distributed_deep_q_tpu.actors import supervisor
+    before = sorted(os.sched_getaffinity(0))
+    supervisor._keep_to_core_share(actor_id, num_actors)
+    q.put((before, sorted(os.sched_getaffinity(0))))

@@ -10,6 +10,8 @@ purpose — ``analysis/atomic_writes.py`` scans the package, not tests.
 
 from __future__ import annotations
 
+import contextlib
+import ctypes
 import json
 import os
 import socket
@@ -19,14 +21,16 @@ import time
 import numpy as np
 import pytest
 
+from distributed_deep_q_tpu import native
 from distributed_deep_q_tpu.rpc import faultinject
-from distributed_deep_q_tpu.rpc.protocol import HEADER_SIZE, encode
+from distributed_deep_q_tpu.rpc.protocol import (
+    HEADER_SIZE, WIRE_VERSION, ChecksumError, encode, recv_msg_sized)
 from distributed_deep_q_tpu.rpc.replay_server import (
     ReplayFeedClient, ReplayFeedServer)
 from distributed_deep_q_tpu.replay.replay_memory import ReplayMemory
 from distributed_deep_q_tpu.utils.durability import (
     GEN_PREFIX, MANIFEST_NAME, QUARANTINE_PREFIX, GenerationStore,
-    IntegrityError, atomic_write, crc32c, savez_bytes)
+    IntegrityError, atomic_write, crc32c, crc_backend, savez_bytes)
 
 
 @pytest.fixture(autouse=True)
@@ -62,11 +66,44 @@ def _vector_batch(n: int, base: float = 0.0) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# CRC-32C
+# CRC-32C — one algorithm, two implementations (ISSUE 30): the native
+# core's ``crc32c_update`` where it loads, else numpy, the reference
 # ---------------------------------------------------------------------------
 
+BACKENDS = ("native", "numpy")
 
-def test_crc32c_known_vectors():
+
+@contextlib.contextmanager
+def crc_backend_forced(name: str):
+    """Run the body with ``crc32c`` on the named implementation: numpy is
+    forced the way a host without g++ gets it (``native.load()`` answers
+    None); native needs the library this host built."""
+    with pytest.MonkeyPatch.context() as mp:
+        if name == "numpy":
+            mp.setattr(native, "load", lambda: None)
+        elif native.load() is None:
+            pytest.skip("the native core does not build on this host")
+        assert crc_backend() == name
+        yield
+
+
+@pytest.fixture(params=BACKENDS)
+def backend(request):
+    with crc_backend_forced(request.param):
+        yield request.param
+
+
+def _numpy_crc32c(data, value: int = 0) -> int:
+    with crc_backend_forced("numpy"):
+        return crc32c(data, value)
+
+
+def _random_bytes(n: int, seed: int) -> np.ndarray:
+    return np.random.default_rng(seed).integers(0, 256, size=n,
+                                                dtype=np.uint8)
+
+
+def _check_known_vectors():
     # RFC 3720 §B.4 test vectors
     assert crc32c(b"") == 0
     assert crc32c(b"123456789") == 0xE3069283
@@ -74,11 +111,16 @@ def test_crc32c_known_vectors():
     assert crc32c(b"\xff" * 32) == 0x62A8AB43
 
 
-def test_crc32c_chunked_matches_streaming_small_path():
+def test_crc32c_known_vectors(backend):
+    _check_known_vectors()
+
+
+def test_crc32c_chunked_matches_streaming_small_path(backend):
     """The numpy-chunked large-buffer path must agree with the ≤512-byte
     pure-python path for every size around the chunking boundaries —
     streamed 256 bytes at a time, only the small path runs, so the two
-    implementations cross-check each other."""
+    implementations cross-check each other. (Native: one call against
+    many continued ones.)"""
     rng = np.random.default_rng(0)
     for n in (1, 2, 511, 512, 513, 1000, 4096, 65537, 100003):
         data = rng.integers(0, 256, size=n, dtype=np.uint8).tobytes()
@@ -89,19 +131,19 @@ def test_crc32c_chunked_matches_streaming_small_path():
         assert whole == streamed, f"n={n}"
 
 
-def test_crc32c_streaming_split_invariance():
+def test_crc32c_streaming_split_invariance(backend):
     data = bytes(range(256)) * 20
     whole = crc32c(data)
     for cut in (0, 1, 100, len(data) // 2, len(data) - 1, len(data)):
         assert crc32c(data[cut:], crc32c(data[:cut])) == whole
 
 
-def test_crc32c_ndarray_equals_bytes():
+def test_crc32c_ndarray_equals_bytes(backend):
     arr = np.linspace(0, 1, 1000, dtype=np.float64).reshape(10, 100)
     assert crc32c(arr) == crc32c(arr.tobytes())
 
 
-def test_crc32c_detects_single_bit_flips():
+def test_crc32c_detects_single_bit_flips(backend):
     rng = np.random.default_rng(5)
     data = bytearray(rng.integers(0, 256, size=2048, dtype=np.uint8))
     ref = crc32c(bytes(data))
@@ -111,6 +153,99 @@ def test_crc32c_detects_single_bit_flips():
         got = crc32c(bytes(data))
         assert got != ref
         ref = got  # keep the flip: the next one must differ again
+
+
+@pytest.mark.parametrize(
+    "n", (0, 1, 7, 8, 9, 511, 512, 513, 4095, 451_584, 6_744_720))
+def test_crc32c_native_equals_numpy(n):
+    """Both native paths — the one ``crc32c`` takes on this CPU and the
+    portable table loop — against the numpy reference, at the sizes
+    around the 8-byte step and the numpy path's chunking, a 64-row flush
+    (451 584 B) and a θ frame (6 744 720 B)."""
+    buf = _random_bytes(n, seed=n)
+    want = _numpy_crc32c(buf)
+    with crc_backend_forced("native"):
+        assert crc32c(buf) == want
+        assert crc32c(buf.tobytes()) == want
+    assert native.load().crc32c_update_portable(
+        0, buf.ctypes.data, n) == want
+
+
+def test_crc32c_native_equals_numpy_on_views_offsets_and_continuation():
+    """What a pointer can get wrong: unaligned starts (odd offsets into a
+    larger buffer), a memoryview and a bytearray, a non-contiguous
+    ndarray (checksummed in C order, as ``tobytes`` lays it out), and a
+    non-zero ``value`` to continue from."""
+    big = _random_bytes(70_001, seed=30)
+    raw = big.tobytes()
+    with crc_backend_forced("native"):
+        portable = native.load().crc32c_update_portable
+        for off in (1, 3, 5, 7, 13):
+            for n in (0, 1, 9, 64, 4097, 65_537 - off):
+                want = _numpy_crc32c(raw[off:off + n])
+                assert crc32c(memoryview(raw)[off:off + n]) == want
+                assert crc32c(big[off:off + n]) == want
+                assert portable(0, big.ctypes.data + off, n) == want
+        assert crc32c(bytearray(raw[:1000])) == _numpy_crc32c(raw[:1000])
+        grid = big[:69_696].reshape(264, 264)
+        for view in (grid[::2, 1::3], grid.T, grid[5:, :-7],
+                     big[:4000].view(np.float32)[::-1]):
+            assert not view.flags.c_contiguous
+            assert crc32c(view) == _numpy_crc32c(view.tobytes())
+        for value in (1, 0xDEADBEEF, 0xFFFFFFFF):
+            assert crc32c(raw[:5000], value) \
+                == _numpy_crc32c(raw[:5000], value)
+            assert portable(value, big.ctypes.data, 5000) \
+                == _numpy_crc32c(raw[:5000], value)
+
+
+def test_crc32c_falls_back_to_numpy_without_the_library():
+    """A host without g++: ``native.load()`` answers None, ``crc32c``
+    takes the numpy path, says so, and every vector still holds."""
+    with crc_backend_forced("numpy"):
+        assert crc_backend() == "numpy"
+        _check_known_vectors()
+        buf = _random_bytes(10_000, seed=3)
+        assert crc32c(buf[5000:], crc32c(buf[:5000])) == crc32c(buf)
+
+
+def test_native_core_is_loaded_without_the_interpreter_lock():
+    """``ctypes.CDLL`` gives the interpreter lock up around every foreign
+    call; ``PyDLL`` (a CDLL subclass) keeps it. The checksum moved into
+    the library so that a serve thread's verify no longer holds the
+    learner thread — which only a plain CDLL delivers."""
+    with crc_backend_forced("native"):
+        lib = native.load()
+    assert isinstance(lib, ctypes.CDLL)
+    assert not isinstance(lib, ctypes.PyDLL)
+    assert not lib.crc32c_update._flags_ & ctypes._FUNCFLAG_PYTHONAPI
+
+
+def test_actor_says_which_crc_backend_it_runs(backend, caplog):
+    """Once at start, and loudly only when it fell back."""
+    import logging
+
+    from distributed_deep_q_tpu.actors import supervisor
+    with caplog.at_level(logging.INFO, logger=supervisor.__name__):
+        supervisor._log_crc_backend(3)
+    (rec,) = [r for r in caplog.records if "crc32c backend" in r.message]
+    assert rec.getMessage() == f"actor 3: crc32c backend {backend}"
+    assert rec.levelno == (logging.INFO if backend == "native"
+                           else logging.WARNING)
+
+
+def test_generations_cross_verify_between_crc_backends(tmp_path):
+    """A snapshot generation written under one implementation (the
+    parent tree's numpy, this tree's native) verifies under the other."""
+    files = {"a.bin": _random_bytes(100_000, seed=1).tobytes(),
+             "b.bin": b"x" * 17}
+    for i, (writer, reader) in enumerate((BACKENDS, BACKENDS[::-1])):
+        store = GenerationStore(str(tmp_path / f"s{i}"))
+        with crc_backend_forced(writer):
+            gen = store.commit(files)
+        with crc_backend_forced(reader):
+            paths, _ = store.verify(gen)  # raises IntegrityError if not
+        assert sorted(paths) == sorted(files)
 
 
 # ---------------------------------------------------------------------------
@@ -507,8 +642,48 @@ def test_snapshot_durability_telemetry_lands_in_summary(
 # ---------------------------------------------------------------------------
 
 
-def test_server_counts_checksum_errors_and_keeps_serving(feed_server):
+@pytest.mark.parametrize("sender, receiver",
+                         (BACKENDS, BACKENDS[::-1]), ids="->".join)
+def test_wire_frames_cross_verify_between_crc_backends(sender, receiver):
+    """A frame encoded under one implementation verifies under the
+    other through ``encode`` / ``recv_msg_sized`` — a v4 peer of the
+    parent tree (numpy) and this tree (native) interoperate both ways —
+    and a bit flipped in transit is still refused."""
+    msg = {"method": "add_transitions", "flush_seq": 7,
+           "obs": _random_bytes(64 * 7056, seed=2).reshape(64, 7056),
+           "reward": np.linspace(-1, 1, 64, dtype=np.float32)}
+    with crc_backend_forced(sender):
+        frame = encode(msg)
+    assert frame[1] == WIRE_VERSION == 4
+    torn = bytearray(frame)
+    torn[HEADER_SIZE + len(frame) // 2] ^= 0x04
+    for wire, ok in ((frame, True), (bytes(torn), False)):
+        a, b = socket.socketpair()
+        tx = threading.Thread(target=a.sendall, args=(wire,))
+        tx.start()
+        try:
+            with crc_backend_forced(receiver):
+                if ok:
+                    got, size = recv_msg_sized(b)
+                    assert size == len(frame) - HEADER_SIZE - 4
+                    assert got["flush_seq"] == 7
+                    np.testing.assert_array_equal(got["obs"], msg["obs"])
+                else:
+                    with pytest.raises(ChecksumError):
+                        recv_msg_sized(b)
+        finally:
+            tx.join(timeout=10)
+            a.close()
+            b.close()
+        assert not tx.is_alive()
+
+
+def test_server_counts_checksum_errors_and_keeps_serving(feed_server,
+                                                         backend):
     server = feed_server()
+    assert server.telemetry.crc_native == int(backend == "native")
+    assert server.telemetry_summary()["rpc/crc_native"] \
+        == server.telemetry.crc_native
     host, port = server.address
     frame = bytearray(encode({"method": "heartbeat", "actor_id": 0}))
     frame[HEADER_SIZE + 2] ^= 0x10  # payload flip in transit
