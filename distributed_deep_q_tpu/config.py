@@ -20,13 +20,21 @@ from typing import Any
 @dataclass
 class TokenQConfig:
     """Token-window Q-network backbone (``net.kind = "tokenq"``,
-    ``models/tokenq.py``): a decoder-only transformer whose head row ``a``
+    ``models/tokenq.py``): a decoder-only backbone whose head row ``a``
     is Q(token prefix, next token ``a``). The keys are the published
-    ``config.json`` keys of the architecture it runs; the defaults are a
-    toy. ``experts_held`` / ``expert_offset`` and ``net.num_actions`` (the
-    vocabulary rows held) say which SHARE of an expert-parallel deployment
-    this process computes: the router stays ``moe_num_primary_experts``
-    wide and what the absent experts would add is left out."""
+    ``config.json`` keys of the architectures it runs (SmallThinker's
+    names where two architectures name one thing differently), and three
+    mechanisms that a source's modelling code fixes and no key of its
+    publishes (``qk_norm``, ``hidden_act``, ``router_input``: a
+    configuration states them under ``assumed``); the defaults are a toy
+    in SmallThinker's settings. Each layer is a token
+    mixer (attention, full or windowed, or a gated short convolution) and
+    a feed-forward (dense, or the experts held here), read off these keys
+    by ``models/tokenq.layer_plan``. ``experts_held`` / ``expert_offset``
+    and ``net.num_actions`` (the vocabulary rows held) say which SHARE of
+    an expert-parallel deployment this process computes: the router stays
+    ``moe_num_primary_experts`` wide and what the absent experts would
+    add is left out."""
 
     hidden_size: int = 64
     num_hidden_layers: int = 4
@@ -42,9 +50,34 @@ class TokenQConfig:
     rope_layout: tuple[int, ...] = (0, 1, 1, 1)
     sliding_window_size: int = 8
     rope_theta: float = 10_000.0
-    # experts: ReGLU of width moe_ffn_hidden_size, router softmax over
-    # ALL moe_num_primary_experts, top moe_num_active_primary_experts
-    # kept and renormalised
+    # token mixer per layer (LFM2's ``layer_types``): "conv" = the gated
+    # short convolution (``ops/short_conv.py``; 3 taps, LFM2's
+    # ``conv_L_cache``: ``models/tokenq.CONV_TAPS``), "full_attention" =
+    # attention as the two layouts above say. Empty (SmallThinker
+    # publishes no such key): attention on every layer
+    layer_types: tuple[str, ...] = ()
+    # per-head RMSNorm of q and k before the rotary embedding (LFM2)
+    qk_norm: bool = False
+    # the first ``num_dense_layers`` layers carry a dense gated
+    # feed-forward of width ``intermediate_size`` and no router (LFM2);
+    # 0: every layer is an expert layer (SmallThinker)
+    num_dense_layers: int = 0
+    intermediate_size: int = 0
+    # the gate of every feed-forward, dense or expert: "relu" (ReGLU,
+    # SmallThinker) | "silu" (SwiGLU, LFM2)
+    hidden_act: str = "relu"
+    # experts of width moe_ffn_hidden_size. Router over ALL
+    # moe_num_primary_experts, top moe_num_active_primary_experts kept and
+    # renormalised to sum 1: a softmax
+    # (``moe_primary_router_apply_softmax``, SmallThinker's) or a sigmoid
+    # whose SELECTION adds a per-expert bias the weights leave out
+    # (``use_expert_bias``, LFM2's). ``router_input``: what the router
+    # reads — "pre_mixer", the layer's normed INPUT, before attention
+    # (SmallThinker) | "ffn_norm", the second norm's output, what the
+    # experts read (LFM2)
+    moe_primary_router_apply_softmax: bool = True
+    use_expert_bias: bool = False
+    router_input: str = "pre_mixer"
     moe_ffn_hidden_size: int = 32
     moe_num_primary_experts: int = 8
     moe_num_active_primary_experts: int = 2
@@ -52,7 +85,8 @@ class TokenQConfig:
     expert_offset: int = 0
     # kernel blocks: attention q/kv block (the window is padded to a
     # multiple) and the kv columns of one inner step (a divisor of it),
-    # tokens per block of the Q head + TD loss, gmm m-tile
+    # tokens per block of the Q head + TD loss (and of the dense
+    # feed-forward), gmm m-tile
     attn_block: int = 128
     attn_compute_block: int = 128
     head_block: int = 128
@@ -676,6 +710,39 @@ def smallthinker_tokenq_config() -> Config:
     return c
 
 
+def lfm2_tokenq_config() -> Config:
+    """LFM2-24B-A2B (LiquidAI, config.json, ``model_type`` lfm2_moe) as a
+    token-window Q-network, one chip's share of an 8-chip expert-parallel
+    deployment: every width as published (hidden 2048, 32/8 heads of 64
+    with q/k norms, rope theta 1e6, convolutions of 3 taps, dense width
+    11 776, SwiGLU experts of width 1 536, sigmoid router 64 wide with a
+    selection bias, top 4); 5 layers = the published layers 0, 2, 3, 4, 5
+    (one leading dense layer, then one period: full attention + three
+    gated short convolutions), 8 of the 64 experts and 8 192 of the
+    65 536 vocabulary rows held here. Windows of 8 192 steps (+1 token),
+    chain 4, batch 2: the chip's compiler puts the batch-4 train program
+    at 16.4 GB before the 1.2 GB ring, over one v5e chip's 16 GB."""
+    c = smallthinker_tokenq_config()
+    c.net = NetConfig(
+        kind="tokenq", num_actions=8_192, compute_dtype="bfloat16",
+        tokenq=TokenQConfig(
+            hidden_size=2048, num_hidden_layers=5, num_attention_heads=32,
+            num_key_value_heads=8, head_dim=64, rms_norm_eps=1e-5,
+            layer_types=("conv", "full_attention", "conv", "conv", "conv"),
+            sliding_window_layout=(0,) * 5, rope_layout=(1,) * 5,
+            rope_theta=1_000_000.0, qk_norm=True, num_dense_layers=1,
+            intermediate_size=11_776, hidden_act="silu",
+            moe_primary_router_apply_softmax=False, use_expert_bias=True,
+            router_input="ffn_norm",
+            moe_ffn_hidden_size=1536, moe_num_primary_experts=64,
+            moe_num_active_primary_experts=4, experts_held=8,
+            expert_offset=0, attn_block=1024, attn_compute_block=512,
+            head_block=1024, moe_tile=256))
+    c.replay = dataclasses.replace(c.replay, batch_size=2)
+    c.env = dataclasses.replace(c.env, token_vocab=8_192)
+    return c
+
+
 def env_for_actor(env: EnvConfig, actor_id: int) -> EnvConfig:
     """Per-actor game assignment (config 4 multi-game fleets): actor i
     plays ``games[i % len(games)]``; single-game configs pass through."""
@@ -693,6 +760,7 @@ PRESETS = {
     "r2d2": r2d2_config,
     "tokenq": tokenq_config,
     "smallthinker_tokenq": smallthinker_tokenq_config,
+    "lfm2_tokenq": lfm2_tokenq_config,
 }
 
 
@@ -717,8 +785,9 @@ def _coerce(old: Any, s: str) -> Any:
         return int(s)
     if isinstance(old, float):
         return float(s)
-    if isinstance(old, tuple):
-        return tuple(type(old[0])(v) for v in s.split(",")) if s else ()
+    if isinstance(old, tuple):     # an empty default holds strings
+        kind = type(old[0]) if old else str
+        return tuple(kind(v) for v in s.split(",")) if s else ()
     return s
 
 

@@ -1,4 +1,4 @@
-"""Token-window Q-network: a decoder-only transformer whose head row ``a``
+"""Token-window Q-network: a decoder-only backbone whose head row ``a``
 is Q(token prefix, next token ``a``) — ``net.kind = "tokenq"``.
 
 The state at position t is the prefix ``tok[0..t]``, the action is the
@@ -7,26 +7,47 @@ position. Every size comes from ``config.TokenQConfig`` (the published
 ``config.json`` keys of the architecture being run) and ``net.num_actions``
 (the vocabulary rows held); nothing is hard-coded here.
 
-Layer l, input x ``[B, T, h]`` (float32 residual stream):
+ONE backbone whose layers are data (``layer_plan``): each is a token
+mixer and a feed-forward in pre-norm residual form, input x ``[B, T, h]``
+(float32 residual stream), ``u = rmsnorm_1(x)``:
 
-- ``u = rmsnorm_1(x)``; the ROUTER reads ``u`` — the layer's normed
-  input, BEFORE attention: softmax over all experts, top k renormalised
-  (``ops/moe.route``);
-- grouped-query attention over ``u``, causal; ``sliding_window_layout[l]``
-  = 1 limits it to the last ``sliding_window_size`` keys and
-  ``rope_layout[l]`` = 1 rotates q/k (rotate-half convention, theta
-  ``rope_theta``); a 0/0 layer is full attention with no positional
-  encoding (``ops/attention.causal_attention`` serves both);
-  ``x' = x + attn · W_o``;
-- ``v = rmsnorm_2(x')``; ``y = x' + Σ_{e in top k, held here} p_e ·
-  ReGLU_e(v)`` (``ops/moe.held_experts_ffn``: this process's share of an
-  expert-parallel layer; no shared expert).
+- mixer, ``x' = x + m``:
+  - attention (grouped-query, causal; ``sliding_window_layout[l]`` = 1
+    limits it to the last ``sliding_window_size`` keys,
+    ``rope_layout[l]`` = 1 rotates q/k in the rotate-half convention,
+    theta ``rope_theta``; with ``qk_norm`` an RMSNorm of each head of q
+    and k before the rotation; ``ops/attention.causal_attention`` serves
+    every kind): ``m = attn(u) · W_o``;
+  - ``layer_types[l] == "conv"``: the gated short convolution,
+    ``m = short_conv_mix(u · W_in, w_conv) · W_out``
+    (``ops/short_conv.py``: ``CONV_TAPS`` causal taps between two
+    gates);
+- feed-forward over ``w = rmsnorm_2(x')``, ``y = x' + f``:
+  - ``l < num_dense_layers``: ``f = (act(w W_gate) * (w W_up)) W_down`` of
+    width ``intermediate_size``, blockwise over tokens;
+  - else ``f = Σ_{e in top k, held here} p_e · (act(w W_gate,e) * (w
+    W_up,e)) W_down,e`` (``ops/moe.held_experts_ffn``: this process's
+    share of an expert-parallel layer; no shared expert), ``act`` =
+    ``hidden_act``: relu (ReGLU) or silu (SwiGLU). The ROUTER
+    (``ops/moe.route``) reads what ``router_input`` says: ``w``
+    ("ffn_norm"), or ``u`` — the layer's normed input, BEFORE the mixer
+    ("pre_mixer").
+
+Every branch here is on a mechanism a ``TokenQConfig`` field names, never
+on which model is being run.
+
+SmallThinker's settings: attention on every layer (window + rope, or full
+with no positional encoding), ReGLU experts everywhere, a softmax router
+before attention. LFM2's: convolutions among full attention with q/k
+norms, a leading dense layer, SwiGLU, a sigmoid router with a selection
+bias reading the second norm.
 
 Then the final RMSNorm; the untied head ``[h, V]`` is applied by the
 learner, blockwise over tokens, together with the TD loss
 (``parallel/sequence_learner.py``). Matmuls run in ``net.compute_dtype``
-with float32 accumulation; norms, router, rotary and the residual stream
-are float32. Each layer is rematerialised in the backward pass.
+with float32 accumulation; norms, router, gates and convolution, rotary
+and the residual stream are float32. Each layer is rematerialised in the
+backward pass.
 
 Parameters are a plain nested dict; a leaf's name is its path
 (``layer_02/w_gate``), which is what weight IO uses.
@@ -42,22 +63,42 @@ import jax.numpy as jnp
 from distributed_deep_q_tpu.config import NetConfig, TokenQConfig
 from distributed_deep_q_tpu.ops import moe
 from distributed_deep_q_tpu.ops.attention import causal_attention
+from distributed_deep_q_tpu.ops.short_conv import short_conv_mix
 
 INIT_STD = 0.02
+BIAS_STD = 0.01     # the expert bias: seeded, and no gradient reaches it
+CONV_TAPS = 3       # a conv layer's taps (LFM2's ``conv_L_cache``)
+ACTS = {"relu": jax.nn.relu, "silu": jax.nn.silu}       # ``hidden_act``
+ROUTER_INPUTS = ("pre_mixer", "ffn_norm")
 
 
 def layer_name(i: int) -> str:
     return f"layer_{i:02d}"
 
 
-def layer_kinds(tq: TokenQConfig) -> list[tuple[bool, bool]]:
-    """Per layer ``(windowed, rotary)`` from the two layouts."""
+def layer_plan(tq: TokenQConfig) -> list[dict[str, bool]]:
+    """Per layer ``{windowed, rope, conv, dense}``: the mixer from
+    ``layer_types`` (absent: attention) and the two layouts, the
+    feed-forward from ``num_dense_layers``."""
     n = tq.num_hidden_layers
     if len(tq.sliding_window_layout) < n or len(tq.rope_layout) < n:
         raise ValueError(
             f"sliding_window_layout/rope_layout must cover "
             f"{n} layers: {tq.sliding_window_layout} {tq.rope_layout}")
-    return [(bool(tq.sliding_window_layout[i]), bool(tq.rope_layout[i]))
+    if tq.layer_types and (len(tq.layer_types) < n or set(
+            tq.layer_types[:n]) - {"conv", "full_attention"}):
+        raise ValueError(f"layer_types must name {n} layers as conv | "
+                         f"full_attention: {tq.layer_types}")
+    if tq.hidden_act not in ACTS:
+        raise ValueError(f"hidden_act must be one of {sorted(ACTS)}: "
+                         f"{tq.hidden_act!r}")
+    if tq.router_input not in ROUTER_INPUTS:
+        raise ValueError(f"router_input must be one of {ROUTER_INPUTS}: "
+                         f"{tq.router_input!r}")
+    return [{"windowed": bool(tq.sliding_window_layout[i]),
+             "rope": bool(tq.rope_layout[i]),
+             "conv": bool(tq.layer_types) and tq.layer_types[i] == "conv",
+             "dense": i < tq.num_dense_layers}
             for i in range(n)]
 
 
@@ -70,30 +111,45 @@ def param_shapes(cfg: NetConfig) -> dict[str, Any]:
         raise ValueError(
             f"experts [{tq.expert_offset}, {tq.expert_offset + e}) are not "
             f"among {tq.moe_num_primary_experts}")
-    layer = {
-        "norm_1": (h,), "norm_2": (h,),
-        "w_router": (h, tq.moe_num_primary_experts),
-        "w_q": (h, hq * d), "w_k": (h, hkv * d), "w_v": (h, hkv * d),
-        "w_o": (hq * d, h),
-        "w_gate": (e, h, f), "w_up": (e, h, f), "w_down": (e, f, h),
-    }
+    attention = {"w_q": (h, hq * d), "w_k": (h, hkv * d),
+                 "w_v": (h, hkv * d), "w_o": (hq * d, h)}
+    if tq.qk_norm:
+        attention.update({"q_norm": (d,), "k_norm": (d,)})
+    conv = {"w_in": (h, 3 * h), "w_conv": (h, CONV_TAPS),
+            "w_out": (h, h)}
+    experts = {"w_router": (h, tq.moe_num_primary_experts),
+               "w_gate": (e, h, f), "w_up": (e, h, f), "w_down": (e, f, h)}
+    if tq.use_expert_bias:
+        experts["expert_bias"] = (tq.moe_num_primary_experts,)
+    fi = tq.intermediate_size
+    dense = {"w_gate": (h, fi), "w_up": (h, fi), "w_down": (fi, h)}
     shapes: dict[str, Any] = {"embed": (v, h), "final_norm": (h,),
                               "head": (h, v)}
-    for i in range(tq.num_hidden_layers):
-        shapes[layer_name(i)] = dict(layer)
+    for i, kind in enumerate(layer_plan(tq)):
+        shapes[layer_name(i)] = {
+            "norm_1": (h,), "norm_2": (h,),
+            **(conv if kind["conv"] else attention),
+            **(dense if kind["dense"] else experts)}
     return shapes
 
 
 def init_params(cfg: NetConfig, seed: int) -> dict[str, Any]:
-    """Normal(0, 0.02) matrices, unit norms, float32."""
+    """Normal(0, 0.02) matrices, unit norms, a Normal(0, 0.01) expert
+    bias (it stays as seeded: no gradient reaches it), float32."""
     shapes = param_shapes(cfg)
-    leaves, treedef = jax.tree_util.tree_flatten(
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(
         shapes, is_leaf=lambda x: isinstance(x, tuple))
     keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
-    vals = [jnp.ones(s, jnp.float32) if len(s) == 1 else
-            INIT_STD * jax.random.normal(k, s, jnp.float32)
-            for k, s in zip(keys, leaves)]
-    return jax.tree_util.tree_unflatten(treedef, vals)
+
+    def leaf(key, path, shape):
+        if len(shape) > 1:
+            return INIT_STD * jax.random.normal(key, shape, jnp.float32)
+        if path[-1].key == "expert_bias":
+            return BIAS_STD * jax.random.normal(key, shape, jnp.float32)
+        return jnp.ones(shape, jnp.float32)
+
+    return jax.tree_util.tree_unflatten(
+        treedef, [leaf(k, path, s) for k, (path, s) in zip(keys, leaves)])
 
 
 def named_leaves(params: dict[str, Any]) -> dict[str, jax.Array]:
@@ -134,35 +190,92 @@ def _mm(a: jax.Array, w: jax.Array, dtype) -> jax.Array:
                    preferred_element_type=jnp.float32)
 
 
+def _route(w: jax.Array, p: dict[str, jax.Array], tq: TokenQConfig):
+    with jax.named_scope("ddq.router"):
+        return moe.route(
+            w.reshape(-1, w.shape[-1]), p["w_router"],
+            tq.moe_num_active_primary_experts,
+            softmax=tq.moe_primary_router_apply_softmax,
+            bias=p.get("expert_bias"))
+
+
+def dense_ffn(w: jax.Array, w_gate: jax.Array, w_up: jax.Array,
+              w_down: jax.Array, *, act, block: int, dtype) -> jax.Array:
+    """``(act(w W_gate) * (w W_up)) W_down`` over ``w`` [N, h], a block of
+    ``block`` tokens at a time, each recomputed in the backward pass: the
+    gate and up activations of a leading dense layer (LFM2: 5.75 x the
+    hidden size) never exist for the whole batch."""
+    n, h = w.shape
+    block = min(block, n)
+    nb = -(-n // block)
+    w_gu = jnp.concatenate([w_gate, w_up], axis=-1).astype(dtype)
+    w_down = w_down.astype(dtype)
+    f = w_gate.shape[1]
+
+    @jax.checkpoint
+    def one(wb):
+        gu = jnp.dot(wb.astype(dtype), w_gu,
+                     preferred_element_type=jnp.float32)
+        return _mm(act(gu[:, :f]) * gu[:, f:], w_down, dtype)
+
+    blocks = jnp.pad(w, ((0, nb * block - n), (0, 0))).reshape(nb, block, h)
+    return jax.lax.map(one, blocks).reshape(nb * block, h)[:n]
+
+
 def layer(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
-          windowed: bool, rope: bool, interpret: bool):
+          windowed: bool, rope: bool, interpret: bool, *,
+          conv: bool = False, dense: bool = False):
     """One block; ``x`` [B, T, h] float32 → (x, the expert layer's
-    counters)."""
+    counters; ``None`` from a dense layer). The mixer is attention
+    (``windowed``, ``rope``) or with ``conv`` the gated short convolution;
+    the feed-forward the held experts or with ``dense`` the dense one."""
     tq = cfg.tokenq
+    act = ACTS[tq.hidden_act]
+    router_first = tq.router_input == "pre_mixer"
     dtype = jnp.dtype(cfg.compute_dtype)
     b, t, h = x.shape
     hq, hkv, d = (tq.num_attention_heads, tq.num_key_value_heads,
                   tq.head_dim)
     u = rmsnorm(x, p["norm_1"], tq.rms_norm_eps)
-    with jax.named_scope("ddq.router"):
-        idx, prob = moe.route(u.reshape(b * t, h), p["w_router"],
-                              tq.moe_num_active_primary_experts)
-    with jax.named_scope("ddq.attn_window" if windowed else "ddq.attn_full"):
-        def heads(w, n):
-            return _mm(u, w, dtype).reshape(b, t, n, d).transpose(0, 2, 1, 3)
-        q, k, v = heads(p["w_q"], hq), heads(p["w_k"], hkv), heads(
-            p["w_v"], hkv)
-        if rope:
-            q, k = rotary(q, tq.rope_theta), rotary(k, tq.rope_theta)
-        a = causal_attention(
-            q.astype(dtype), k.astype(dtype), v.astype(dtype),
-            window=tq.sliding_window_size if windowed else 0,
-            block=tq.attn_block, compute_block=tq.attn_compute_block,
-            interpret=interpret)
-        a = a.transpose(0, 2, 1, 3).reshape(b, t, hq * d)
-        x = x + _mm(a, p["w_o"], dtype)
+    if router_first and not dense:
+        idx, prob = _route(u, p, tq)
+    if conv:
+        with jax.named_scope("ddq.short_conv"):
+            bcz = _mm(u, p["w_in"], dtype)
+            with jax.named_scope("ddq.short_conv_mix"):
+                mixed = short_conv_mix(bcz, p["w_conv"])
+            x = x + _mm(mixed, p["w_out"], dtype)
+    else:
+        with jax.named_scope(
+                "ddq.attn_window" if windowed else "ddq.attn_full"):
+            def heads(w, n):
+                return _mm(u, w, dtype).reshape(b, t, n, d).transpose(
+                    0, 2, 1, 3)
+            q, k, v = heads(p["w_q"], hq), heads(p["w_k"], hkv), heads(
+                p["w_v"], hkv)
+            if tq.qk_norm:
+                q = rmsnorm(q, p["q_norm"], tq.rms_norm_eps)
+                k = rmsnorm(k, p["k_norm"], tq.rms_norm_eps)
+            if rope:
+                q, k = rotary(q, tq.rope_theta), rotary(k, tq.rope_theta)
+            a = causal_attention(
+                q.astype(dtype), k.astype(dtype), v.astype(dtype),
+                window=tq.sliding_window_size if windowed else 0,
+                block=tq.attn_block, compute_block=tq.attn_compute_block,
+                interpret=interpret)
+            a = a.transpose(0, 2, 1, 3).reshape(b, t, hq * d)
+            x = x + _mm(a, p["w_o"], dtype)
+    if dense:
+        with jax.named_scope("ddq.dense_ffn"):
+            v2 = rmsnorm(x, p["norm_2"], tq.rms_norm_eps)
+            y = dense_ffn(v2.reshape(b * t, h), p["w_gate"], p["w_up"],
+                          p["w_down"], act=act,
+                          block=tq.head_block, dtype=dtype)
+        return x + y.reshape(b, t, h), None
     with jax.named_scope("ddq.experts"):
         v2 = rmsnorm(x, p["norm_2"], tq.rms_norm_eps)
+        if not router_first:    # its own scope, nested: innermost
+            idx, prob = _route(v2, p, tq)
         k = tq.moe_num_active_primary_experts
         # a SEQUENCE at a time: the held-slot buffer is sized for one
         # sequence's worst case (every token with min(k, held) slots
@@ -176,7 +289,7 @@ def layer(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
             return moe.held_experts_ffn(
                 v_s, idx_s, prob_s, p["w_gate"], p["w_up"], p["w_down"],
                 offset=tq.expert_offset, rows=rows, tile=tq.moe_tile,
-                compute_dtype=dtype, interpret=interpret)
+                compute_dtype=dtype, interpret=interpret, act=act)
 
         y, counters = jax.lax.map(
             one_sequence, (v2, idx.reshape(b, t, k), prob.reshape(b, t, k)))
@@ -187,17 +300,20 @@ def layer(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
 def backbone(params: dict[str, Any], tokens: jax.Array, cfg: NetConfig,
              interpret: bool = False):
     """``tokens`` [B, T] int32 → (final-normed hidden [B, T, h] float32,
-    expert counters stacked over layers). Only the attention kernel
-    pads the window (to its block); every other product runs on T."""
+    expert counters stacked over the EXPERT layers). Only the attention
+    kernel pads the window (to its block); every other product runs on
+    T."""
     tq = cfg.tokenq
     x = params["embed"][tokens]
     counters = []
-    for i, (windowed, rope) in enumerate(layer_kinds(tq)):
+    for i, kind in enumerate(layer_plan(tq)):
         fn = jax.checkpoint(
-            lambda x, p, w=windowed, r=rope: layer(x, p, cfg, w, r,
-                                                   interpret))
+            lambda x, p, kind=kind: layer(
+                x, p, cfg, kind["windowed"], kind["rope"], interpret,
+                conv=kind["conv"], dense=kind["dense"]))
         x, c = fn(x, params[layer_name(i)])
-        counters.append(c)
+        if c is not None:
+            counters.append(c)
     x = rmsnorm(x, params["final_norm"], tq.rms_norm_eps)
     return x, jax.tree.map(lambda *a: jnp.stack(a), *counters)
 

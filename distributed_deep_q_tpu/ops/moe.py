@@ -3,7 +3,10 @@
 The router is as wide as the model says (all ``E`` experts); this process
 is told it holds ``held`` of them from ``offset`` on — one member of an
 expert-parallel group. It routes over all ``E``, keeps the top ``k`` and
-adds ``p_e · f_e(x)`` only for chosen experts it holds. What the absent
+adds ``p_e · f_e(x)`` only for chosen experts it holds. How the router
+scores (a softmax, SmallThinker's; a sigmoid whose selection adds a
+per-expert bias, LFM2's) and which gate the experts carry (ReGLU,
+SmallThinker's; SwiGLU, LFM2's) come from the configuration. What the absent
 experts would add is left out (their owners add it, after an exchange this
 process does not stand in for).
 
@@ -28,14 +31,26 @@ import jax.numpy as jnp
 from jax import lax
 
 
-def route(u: jax.Array, w_router: jax.Array, top_k: int):
-    """Softmax over ALL experts in float32, the ``top_k`` largest kept and
-    renormalised to sum 1. ``u`` [N, h] float32 → (expert ids [N, k]
-    int32, weights [N, k] float32)."""
+def route(u: jax.Array, w_router: jax.Array, top_k: int, *,
+          softmax: bool = True, bias: jax.Array | None = None):
+    """Scores over ALL experts in float32, ``top_k`` kept and renormalised
+    to sum 1. ``softmax`` (SmallThinker's setting): softmax scores, the
+    largest kept. Otherwise (LFM2's): sigmoid scores ``s``; the experts
+    with the largest ``s + bias`` are CHOSEN and weighted by ``s`` without
+    the bias (no gradient reaches ``bias``: indices carry none), divided
+    by ``sum + 1e-6``. ``u`` [N, h] float32 → (expert ids [N, k] int32,
+    weights [N, k] float32)."""
     z = jnp.dot(u, w_router, precision=lax.Precision.HIGHEST,
                 preferred_element_type=jnp.float32)
-    top_p, top_i = lax.top_k(jax.nn.softmax(z, axis=-1), top_k)
-    return top_i.astype(jnp.int32), top_p / jnp.sum(top_p, -1, keepdims=True)
+    if softmax:
+        top_p, top_i = lax.top_k(jax.nn.softmax(z, axis=-1), top_k)
+        total = jnp.sum(top_p, -1, keepdims=True)
+    else:
+        s = jax.nn.sigmoid(z)
+        _, top_i = lax.top_k(s if bias is None else s + bias, top_k)
+        top_p = jnp.take_along_axis(s, top_i, axis=-1)
+        total = jnp.sum(top_p, -1, keepdims=True) + 1e-6
+    return top_i.astype(jnp.int32), top_p / total
 
 
 def buffer_rows(tokens: int, top_k: int, held: int, tile: int) -> int:
@@ -64,8 +79,11 @@ def _gmm(lhs, rhs, sizes, tile, interpret):
 def held_experts_ffn(x: jax.Array, idx: jax.Array, p: jax.Array,
                      w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
                      *, offset: int, rows: int, tile: int,
-                     compute_dtype=jnp.bfloat16, interpret: bool = False):
-    """Σ over the chosen experts HELD here of ``p_e · ReGLU_e(x)``.
+                     compute_dtype=jnp.bfloat16, interpret: bool = False,
+                     act=jax.nn.relu):
+    """Σ over the chosen experts HELD here of ``p_e · f_e(x)``, ``f_e(x) =
+    (act(x W_gate,e) * (x W_up,e)) W_down,e``: ReGLU with ``act`` relu,
+    SwiGLU with silu.
 
     ``x`` [N, h] float32, ``idx``/``p`` [N, k] from ``route``, weights
     ``[held, h, f]`` / ``[held, f, h]``. Returns ``(y [N, h] float32,
@@ -91,8 +109,8 @@ def held_experts_ffn(x: jax.Array, idx: jax.Array, p: jax.Array,
     xs = jnp.where(valid, x[tok], 0.0).astype(compute_dtype)
     w_gu = jnp.concatenate([w_gate, w_up], axis=-1).astype(compute_dtype)
     gu = _gmm(xs, w_gu, sizes, tile, interpret)            # [rows, 2f]
-    act = (jax.nn.relu(gu[:, :f]) * gu[:, f:]).astype(compute_dtype)
-    out = _gmm(act, w_down.astype(compute_dtype), sizes, tile, interpret)
+    hid = (act(gu[:, :f]) * gu[:, f:]).astype(compute_dtype)
+    out = _gmm(hid, w_down.astype(compute_dtype), sizes, tile, interpret)
     # rows past the last group were never visited by the kernels
     out = jnp.where(valid, out, 0.0) * jnp.where(
         valid, p.reshape(-1)[order][:, None], 0.0)

@@ -64,17 +64,21 @@ def test_every_configuration_resolves_its_family_modules(config):
     assert all(isinstance(v, (int, float)) for v in conf["limits"].values())
 
 
-def test_the_token_family_walks_its_cell_on_the_cpu():
-    """``rehearse.py``'s walk of the token-window cell at the family's toy
-    sizes (``families/tokenq/check.toy``): driver, recorder, reference and
+TOKEN_CELLS = ["smallthinker_21b_tokenq_ep8.seq_learner_only",
+               "lfm2_24b_tokenq_ep8.seq_learner_only"]
+
+
+@pytest.mark.parametrize("cell", TOKEN_CELLS)
+def test_the_token_family_walks_its_cell_on_the_cpu(cell):
+    """``rehearse.py``'s walk of a token-window cell at its family's toy
+    sizes (``families/<fam>/check.toy``): driver, recorder, reference and
     verdict, float32 on both sides."""
     import argparse
 
     from benchmark import rehearse, run
 
     ns = argparse.Namespace(
-        workload="smallthinker_21b_tokenq_ep8.seq_learner_only",
-        seed=2 ** 31 + 23, seconds=1.0, trace=0)
+        workload=cell, seed=2 ** 31 + 23, seconds=1.0, trace=0)
     line = run.run_cell(ns, backend="cpu", conf_patch=rehearse.toy)
     assert line["correct"] and line["failed"] == 0
     worst = max(v for k, (v, _) in line["compared"].items())
@@ -82,7 +86,19 @@ def test_the_token_family_walks_its_cell_on_the_cpu():
     assert line["metrics"]["grad_steps_per_s"]["value"] > 0
 
 
-def test_the_token_familys_control_is_not_correct():
+# the smallest gap the fp8 control may show on a separating number at the
+# toy sizes (LFM2's first loss is a signed mean that read 4.7e-4 there),
+# and the separating numbers a family judges (LFM2's prints the written
+# priority and does not judge it: ``families/lfm2/check.PRINTED_ONLY``)
+SEPARATING = ("loss_first_rel", "grad_norm_first_rel",
+              "moment_first_worst_leaf")
+CONTROL_FLOOR = dict(zip(TOKEN_CELLS, (1e-3, 1e-4)))
+CONTROL_READS = dict(zip(TOKEN_CELLS, (
+    (*SEPARATING, "priority_first_max_rel"), SEPARATING)))
+
+
+@pytest.mark.parametrize("cell", TOKEN_CELLS)
+def test_the_token_familys_control_is_not_correct(cell):
     """``control.py``'s readings at the toy sizes: the program (float32
     there) agrees with the reference to rounding, and the reference one
     precision down — fp8 operands where the configuration states bfloat16
@@ -91,15 +107,35 @@ def test_the_token_familys_control_is_not_correct():
     norm, Adam's first moment by the worst leaf)."""
     from benchmark import control, rehearse
 
-    rs = control.readings("smallthinker_21b_tokenq_ep8.seq_learner_only",
-                          [2 ** 31 + 5], backend="cpu",
+    rs = control.readings(cell, [2 ** 31 + 5], backend="cpu",
                           conf_patch=rehearse.toy, prefill=256)
     table = control.summarize(rs)
     assert table["sound_all_correct"] and table["control_all_not_correct"]
     n = table["numbers"]
-    for k in ("loss_first_rel", "priority_first_max_rel",
-              "grad_norm_first_rel", "moment_first_worst_leaf"):
-        assert n[k]["sound_max"] < 1e-5 < 1e-3 < n[k]["control_min"], k
+    assert ("priority_first_max_rel" in n) == (
+        "priority_first_max_rel" in CONTROL_READS[cell])
+    for k in CONTROL_READS[cell]:
+        assert n[k]["sound_max"] < 1e-5 < CONTROL_FLOOR[cell] < \
+            n[k]["control_min"], k
     for k in ("windows_illegal", "token_window_mismatch",
               "validity_mismatch", "expert_buffer_overflow"):
         assert n[k]["sound_max"] == 0
+
+
+def test_the_lfm2_familys_planted_faults_move_what_they_are_read_for():
+    """``families/lfm2/faults.py`` at the toy sizes: each planted fault of
+    the reference moves the number it is read for far past what the sound
+    program reads there (under 1e-5), and a wrong priority eta moves the
+    written priority ALONE — loss and gradients are the sound run's."""
+    from benchmark import rehearse
+    from benchmark.families.lfm2 import faults
+
+    rs = faults.readings(TOKEN_CELLS[1], [2 ** 31 + 5], backend="cpu",
+                         conf_patch=rehearse.toy, prefill=256)
+    table = faults.summarize(rs)
+    assert set(table) == set(faults.FAULTS)
+    for name, row in table.items():
+        assert row["smallest"][row["planted_for"]] > 1e-3, name
+    eta = table["priority_eta_1"]["smallest"]
+    assert max(eta[k] for k in ("loss_max_rel", "grad_norm_max_rel",
+                                "held_share_max_abs")) < 1e-5

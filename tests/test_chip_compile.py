@@ -120,15 +120,25 @@ def test_fused_huber_fwd_and_grad_compile_for_v5e(one_chip, batch, actions):
 # windows of 8 193 tokens (padded to the 1 024 block); experts of width 768
 # over hidden 2 560, 8 held: the sizes, the buffer and the tiles are read
 # from the preset (config.smallthinker_tokenq_config), not restated here.
+# LFM2-24B-A2B (config.lfm2_tokenq_config): 32 / 8 heads of 64 — half the
+# 128 lanes — full attention; SwiGLU experts of width 1 536 over hidden
+# 2 048, top 4.
 
-@pytest.mark.parametrize("window", [0, 4096], ids=["full", "window4096"])
-def test_window_attention_compiles_for_v5e(one_chip, window):
+@pytest.mark.parametrize(
+    "preset,window,sizes",      # sizes: T + 1, head size, the preset's window
+    [("smallthinker_tokenq", 0, (8193, 128, 4096)),
+     ("smallthinker_tokenq", 4096, (8193, 128, 4096)),
+     ("lfm2_tokenq", 0, (8193, 64, 0))],
+    ids=["full", "window4096", "lfm2-head64"])
+def test_window_attention_compiles_for_v5e(one_chip, preset, window, sizes):
     from distributed_deep_q_tpu.config import PRESETS
     from distributed_deep_q_tpu.ops.attention import causal_attention
 
-    cfg = PRESETS["smallthinker_tokenq"]()
+    cfg = PRESETS[preset]()
     tq, t = cfg.net.tokenq, cfg.replay.sequence_length + 1
-    assert (t, tq.sliding_window_size) == (8193, 4096)
+    windowed = any(tq.sliding_window_layout[:tq.num_hidden_layers])
+    assert (t, tq.head_dim,
+            tq.sliding_window_size if windowed else 0) == sizes
     q = jax.ShapeDtypeStruct(
         (1, tq.num_attention_heads, t, tq.head_dim), jnp.bfloat16,
         sharding=one_chip)
@@ -148,25 +158,33 @@ def test_window_attention_compiles_for_v5e(one_chip, window):
     assert "tpu_custom_call" in text
 
 
-def test_held_experts_grouped_matmul_compiles_for_v5e(one_chip):
+@pytest.mark.parametrize(
+    "preset,buffer_rows", [("smallthinker_tokenq", 49408),   # 8 193 x 6
+                           ("lfm2_tokenq", 33024)],          # 8 193 x 4
+    ids=["smallthinker-reglu", "lfm2-swiglu"])
+def test_held_experts_grouped_matmul_compiles_for_v5e(one_chip, preset,
+                                                      buffer_rows):
     """One sequence's expert layer as ``models/tokenq.layer`` calls it:
-    the preset's worst-case buffer at the preset's m-tile, bfloat16."""
+    the preset's worst-case buffer (tile-rounded) at the preset's m-tile,
+    bfloat16, with its model's gate."""
     from distributed_deep_q_tpu.config import PRESETS
+    from distributed_deep_q_tpu.models.tokenq import ACTS
     from distributed_deep_q_tpu.ops import moe
 
-    cfg = PRESETS["smallthinker_tokenq"]()
+    cfg = PRESETS[preset]()
     tq, n = cfg.net.tokenq, cfg.replay.sequence_length + 1
     k, held = tq.moe_num_active_primary_experts, tq.experts_held
     h, f = tq.hidden_size, tq.moe_ffn_hidden_size
     rows = moe.buffer_rows(n, k, held, tq.moe_tile)
-    assert (rows, tq.moe_tile) == (49408, 256)  # 8 193 x 6, tile-rounded
+    assert (rows, tq.moe_tile) == (buffer_rows, 256)
     S = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
 
     def fwd_bwd(x, idx, p, wg, wu, wd):
         f_ = lambda x, wg, wu, wd: jnp.sum(moe.held_experts_ffn(  # noqa: E731
             x, idx, p, wg, wu, wd, offset=tq.expert_offset, rows=rows,
             tile=tq.moe_tile, compute_dtype=jnp.dtype(
-                cfg.net.compute_dtype))[0])
+                cfg.net.compute_dtype),
+            act=ACTS[tq.hidden_act])[0])
         return jax.grad(f_, argnums=(0, 1, 2, 3))(x, wg, wu, wd)
 
     text = _compiled_text(
@@ -176,3 +194,26 @@ def test_held_experts_grouped_matmul_compiles_for_v5e(one_chip):
     # forward gate+up (the down product's VALUE is not needed under a
     # sum); backward: two input-side products and two weight-side ones
     assert text.count('custom_call_target="tpu_custom_call"') >= 5
+
+
+def test_short_conv_mix_compiles_for_v5e(one_chip):
+    """LFM2's gates and 3-tap convolution, forward and backward, on one
+    sequence of the cell's batch at the published width: plain XLA
+    fusions, no custom call."""
+    from distributed_deep_q_tpu.config import PRESETS
+    from distributed_deep_q_tpu.models.tokenq import CONV_TAPS
+    from distributed_deep_q_tpu.ops.short_conv import short_conv_mix
+
+    cfg = PRESETS["lfm2_tokenq"]()
+    tq, t = cfg.net.tokenq, cfg.replay.sequence_length + 1
+    S = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32,
+                          sharding=one_chip)
+
+    def fwd_bwd(bcz, w):
+        return jax.grad(lambda *a: jnp.sum(short_conv_mix(*a)),
+                        argnums=(0, 1))(bcz, w)
+
+    text = _compiled_text(
+        fwd_bwd, S((cfg.replay.batch_size, t, 3 * tq.hidden_size)),
+        S((tq.hidden_size, CONV_TAPS)))
+    assert "fusion" in text and "tpu_custom_call" not in text
