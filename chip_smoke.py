@@ -14,8 +14,10 @@ widths (Nature-DQN CNN, 84×84×4 uint8, bf16 torso, Double-DQN, n_step 3,
 PER α=0.6, batch 512, fused_chain 8). Then the same with the inference
 plane on (actors send observations, the learner process answers from the
 chip while it trains), then ``main(["eval", ...])``. Before them: the three
-Pallas kernels, Mosaic-compiled, against plain references, and a check
-that ``block_until_ready`` really waits for the device.
+Pallas kernels, Mosaic-compiled, against plain references; the train
+program's byte-plane unpack against the bitcast it replaced, at batch 512
+(PERF.md §6, PR 32); and a check that ``block_until_ready`` really waits
+for the device.
 
 This process is the ONE that holds the chip; the actor children it starts
 pin themselves to the CPU. Every line but the last is one JSON object of
@@ -169,6 +171,85 @@ def phase_kernels(seed: int, interpret: bool) -> dict:
     np.testing.assert_allclose(gp, gj, rtol=1e-5, atol=1e-8)
     return {"interpret": interpret, "mosaic_compiled": compiled,
             "agree_with_reference": True}
+
+
+# ---------------------------------------------------------------------------
+# phase: unpack_planes — the train program's byte-plane unpack against the
+# bitcast it replaced, at the published sizes
+# ---------------------------------------------------------------------------
+
+
+def phase_unpack_planes(cfg, seed: int) -> dict:
+    """Seeded packed windows (the preset's batch, 84x84, window stack +
+    n_step, older frames cut by the mask) through ``window_to_obs`` and
+    through the bitcast to uint8: the pixels bit for bit, and through the
+    preset's network in its compute dtype Q-values and conv-1 gradients
+    that agree to the dtype's rounding."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import lax
+
+    from distributed_deep_q_tpu.models.qnet import build_qnet, init_params
+    from distributed_deep_q_tpu.ops.ring_gather import padded_row_bytes
+    from distributed_deep_q_tpu.replay.device_per import (
+        stack_rows_to_obs, window_to_obs)
+
+    frame, stack = (84, 84), cfg.net.stack
+    first, batch = cfg.replay.n_step, cfg.replay.batch_size
+    row_len = frame[0] * frame[1]
+    rng = np.random.default_rng(seed)
+    rows = np.zeros((batch, stack + first, padded_row_bytes(row_len)),
+                    np.uint8)
+    rows[..., :row_len] = rng.integers(
+        0, 256, (batch, stack + first, row_len), dtype=np.uint8)
+    win = jnp.asarray(rows.view(np.int32))
+    valid = jnp.asarray(np.triu(np.ones((stack, stack), np.uint8))[
+        rng.integers(0, stack, batch)])       # older frames cut, as drawn
+    module = build_qnet(cfg.net)
+    params = init_params(module, cfg.net, seed)
+
+    def by_bitcast(win):
+        pix = lax.bitcast_convert_type(win, jnp.uint8)
+        pix = pix.reshape(win.shape[:2] + (-1,))[:, :, :row_len]
+        return stack_rows_to_obs(
+            pix[:, first:first + stack] * valid[..., None], frame)
+
+    def by_planes(win):
+        return window_to_obs(win, first, valid, row_len, frame)
+
+    def q_and_conv1_grads(unpack):
+        obs = unpack(win)
+
+        def loss(p):
+            q = module.apply({"params": p}, obs)
+            return jnp.mean(jnp.square(q)), q
+        (_, q), g = jax.value_and_grad(loss, has_aux=True)(params)
+        return obs, q, g["torso"]["conv1"]
+
+    got, want = (jax.jit(q_and_conv1_grads, static_argnums=0)(u)
+                 for u in (by_planes, by_bitcast))
+    check(np.array_equal(np.asarray(got[0]), np.asarray(want[0])),
+          "unpack_planes: pixels differ from the bitcast path's")
+
+    def rel_l2(a, b):
+        a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+        return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
+
+    # the same pixels into two separately compiled programs: the compiler
+    # may lay conv-1's input out differently in each, so the rest is held
+    # to a few roundings of the compute dtype (bf16 eps 2^-8), not to bits
+    out = {"compute_dtype": cfg.net.compute_dtype, "batch": batch,
+           "pixels_equal": True,
+           "q_rel_l2": rel_l2(got[1], want[1]),
+           "conv1_bias_grad_rel_l2": rel_l2(got[2]["bias"], want[2]["bias"]),
+           "conv1_kernel_grad_rel_l2": rel_l2(got[2]["kernel"],
+                                              want[2]["kernel"])}
+    tol = 2e-2 if cfg.net.compute_dtype == "bfloat16" else 1e-5
+    for key in ("q_rel_l2", "conv1_bias_grad_rel_l2",
+                "conv1_kernel_grad_rel_l2"):
+        check(out[key] < tol, f"unpack_planes: {key} = {out[key]} >= {tol}")
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -346,6 +427,8 @@ def one_chip(args, clock: PhaseClock, dev) -> None:
 
     with clock.phase("kernels") as out:
         out.update(phase_kernels(args.seed, interpret))
+    with clock.phase("unpack_planes") as out:
+        out.update(phase_unpack_planes(cfg, args.seed))
     if not rehearse:
         with clock.phase("fence") as out:
             out.update(phase_fence(peak_flops_for(dev, backend="tpu")))

@@ -1649,6 +1649,7 @@ def train_distributed(cfg: Config, metrics: Metrics | None = None,
                     metrics.log(gstep, **summary, **timer.summary(),
                                 **server.telemetry_summary(), **infer_tm,
                                 **metrics.telemetry(), **hk,
+                                **solver.fused_gauges(),
                                 **compile_clock.row())
     finally:
         trace.close()
@@ -1673,6 +1674,7 @@ def train_distributed(cfg: Config, metrics: Metrics | None = None,
     summary["rpc_shed_flushes"] = rpc["shed_flushes"]
     summary["rpc_checksum_errors"] = rpc["checksum_errors"]
     summary["rpc_crc_native"] = rpc["crc_native"]
+    summary["train_unpack_planes"] = solver.learner.unpack_planes
     summary["snapshot_quarantined"] = rpc["snapshot_quarantined"]
     summary["flow_degraded_trips"] = server.flow_counters()["degraded_trips"]
     if infer_server is not None:

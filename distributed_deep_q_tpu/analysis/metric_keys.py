@@ -147,6 +147,9 @@ REGISTRY = frozenset({
     "train/steps_per_s",
     "train/mfu",
     "train/ingest_utilization",
+    # static gauge of the fused step (Solver.fused_gauges): 1 = its train
+    # program unpacks the pixel windows by byte planes, 0 = by a bitcast
+    "train/unpack_planes",
     # learning-dynamics plane (ISSUE 16): learn/* gauges the on-device
     # metrics plane accumulates inside the fused-chain / Anakin scan
     # bodies (learning.LearnAccumulator.gauges) + the TD-|error|

@@ -13,7 +13,9 @@ Why these exist — measured XLA:TPU gather pathology on the fused PER path
 The fix is layout, not lowering: store the ring as ONE flat **int32**
 array (pixel bytes packed 4-per-element, little-endian — round-trips
 ``np.uint8.view(int32)`` ↔ ``lax.bitcast_convert_type``, verified on TPU
-and CPU) whose rows are padded to a multiple of the 1024-element 1-D
+and CPU; byte ``k`` of a word is ``(word >> 8k) & 0xff``, which is how the
+fused train program reads them: ``replay/device_per.window_to_obs``)
+whose rows are padded to a multiple of the 1024-element 1-D
 tile, so every window's element range ``[idx·rowp, idx·rowp + w·rowp)``
 is provably tile-aligned and a plain async DMA copies exactly the wanted
 bytes. int32 rather than uint8 because Mosaic's scalar index arithmetic
@@ -108,7 +110,11 @@ def gather_windows(idx: jax.Array, ring: jax.Array, *, n: int, w: int,
     ``idx`` [n] int32 — window-start ROW indices (callers guarantee
     ``idx + w`` stays inside the ring via ghost rows); ``ring`` [S] int32
     (packed pixel bytes); ``rowb`` row stride in BYTES. Returns
-    [n · w · rowb/4] int32 (flat; reshape/bitcast at the consumer).
+    [n · w · rowb/4] int32 (flat; the consumer reshapes it and takes the
+    bytes out of the words — the fused train program by shift and mask
+    into byte planes where the frame width is a multiple of 4 and the
+    batch fills the lanes, else by a bitcast to uint8:
+    ``replay/device_per.window_to_obs``).
     """
     rowp = rowb // 4
     wsz = w * rowp

@@ -44,6 +44,8 @@ from distributed_deep_q_tpu.parallel.multihost import (
     global_batch, put_replicated)
 
 
+LANES = 128     # a TPU vector register's minor dimension
+
 # Adam moment decays, shared by ``make_optimizer`` (the state-structure
 # builder) and ``fused_adam_step`` (the hot path) so the two can never
 # drift apart — their bitwise equivalence is load-bearing for checkpoints.
@@ -415,6 +417,10 @@ class Learner:
         self.apply_fn = apply_fn
         self.cfg = cfg
         self.mesh = mesh
+        # static gauge ``train/unpack_planes``: 1 = the last fused step
+        # built unpacks its pixel windows by byte planes, 0 = by a bitcast
+        # to uint8, None = none built yet
+        self.unpack_planes: int | None = None
         self._interpret = pallas_interpret(mesh)
         self.opt = make_optimizer(cfg)
         self._replicated = NamedSharding(mesh, P())
@@ -586,8 +592,15 @@ class Learner:
         copies each sample's combined obs+next-obs pixel window with the
         Pallas row-DMA kernel (``ops/ring_gather.py`` — 3 ms vs 44 ms
         for the tiled XLA gathers it replaced at the 1M-ring shape); the
-        train program slices obs/next-obs out of the windows, applies the
-        validity bit-planes, and runs the DQN step. Keys stay
+        train program takes obs/next-obs out of the windows under the
+        scope ``ddq.unpack``, applies the validity masks, and runs the DQN
+        step. Where the frame width is a multiple of 4 and a shard's
+        batch fills the lanes the packed int32 words are split into their
+        four BYTE PLANES by shift and mask and the planes laid side by
+        side (``window_to_obs``); anything else is bitcast to uint8 and
+        restacked. Both hand the model the same
+        ``u8 [B, H, W, stack]``; ``self.unpack_planes`` says which was
+        built (gauge ``train/unpack_planes``). Keys stay
         host-generated (a fold_in-keyed program executed the ring gather
         ~200× slower — measured minimal pair, r3)."""
         (slot_cap, slot_pad, rowb, row_len, stack, n_step, gamma,
@@ -595,7 +608,7 @@ class Learner:
         from distributed_deep_q_tpu.ops.ring_gather import gather_windows
         from distributed_deep_q_tpu.replay.device_per import (
             build_meta_pack, fused_sample_draw_packed, fused_sample_prep,
-            scatter_priorities, stack_rows_to_obs)
+            scatter_priorities, stack_rows_to_obs, window_to_obs)
 
         S = P(AXIS_DP)
         SK = P(None, AXIS_DP)   # [chain, B]-stacked outputs, batch-sharded
@@ -648,19 +661,39 @@ class Learner:
         use_plane = (use_stacked and cfg.optimizer == "adam"
                      and self.mesh.shape[AXIS_MODEL] <= 1)
 
+        # a frame row packs four pixels to a word, so a width that is a
+        # multiple of 4 starts every image row on a word: the bytes then
+        # come out of the words as four planes, no bitcast (window_to_obs).
+        # It pays where the compiler keeps the batch in the 128 lanes —
+        # from 128 rows a shard: the planes then stack without moving a
+        # pixel (b512: 0.51 -> 0.16 ms a step). Below it lays the window
+        # out pixel-minor and has to interleave them, slower than the
+        # bitcast (b32: +0.02 ms a step; PERF.md §6, PR 32)
+        by_planes = frame_shape[1] % 4 == 0 and per_shard >= LANES
+        self.unpack_planes = int(by_planes)
+
         def unpack_batch(batch, w):
             batch = dict(batch)
             ovalid = batch.pop("ovalid")
             nvalid = batch.pop("nvalid")
-            # unpack int32 → pixel bytes (little-endian round trip
-            # with the host's uint8.view(int32), verified both
-            # platforms), drop the DMA row padding
-            pix = lax.bitcast_convert_type(w, jnp.uint8)
-            pix = pix.reshape(w.shape[:2] + (rowp * 4,))[:, :, :row_len]
-            obs = pix[:, :stack] * ovalid[..., None]
-            nobs = pix[:, n_step:n_step + stack] * nvalid[..., None]
-            batch["obs"] = stack_rows_to_obs(obs, frame_shape)
-            batch["next_obs"] = stack_rows_to_obs(nobs, frame_shape)
+            with jax.named_scope("ddq.unpack"):
+                if by_planes:
+                    obs = window_to_obs(w, 0, ovalid, row_len, frame_shape)
+                    nobs = window_to_obs(w, n_step, nvalid, row_len,
+                                         frame_shape)
+                else:
+                    # int32 → pixel bytes (little-endian round trip with
+                    # the host's uint8.view(int32), verified both
+                    # platforms), drop the DMA row padding
+                    pix = lax.bitcast_convert_type(w, jnp.uint8)
+                    pix = pix.reshape(
+                        w.shape[:2] + (rowp * 4,))[:, :, :row_len]
+                    obs = stack_rows_to_obs(
+                        pix[:, :stack] * ovalid[..., None], frame_shape)
+                    nobs = stack_rows_to_obs(
+                        pix[:, n_step:n_step + stack] * nvalid[..., None],
+                        frame_shape)
+            batch["obs"], batch["next_obs"] = obs, nobs
             return batch
 
         def tree_train_fn(state: TrainState, metas, win, idxs, prio, maxp):

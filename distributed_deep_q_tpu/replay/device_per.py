@@ -159,6 +159,36 @@ def stack_rows_to_obs(rows: jax.Array,
     return jnp.moveaxis(rows, 1, -1)
 
 
+def window_to_obs(win: jax.Array, first: int, valid: jax.Array,
+                  row_len: int, frame_shape: tuple[int, int]) -> jax.Array:
+    """Frames ``first .. first+stack-1`` of [B, window, rowp] packed ring
+    rows → [B, H, W, stack] uint8 CNN input, by BYTE PLANES: for frame
+    widths that are a multiple of 4. Frames whose ``valid`` [B, stack] is
+    0 are zeroed as ``gather_rows`` zeroes them.
+
+    A row is H·W pixel bytes packed little-endian four to an int32, so
+    every image row starts on a word and word ``(W/4)·h + j`` holds the
+    pixels at columns ``4j .. 4j+3``: byte ``k`` of every word,
+    ``(word >> 8k) & 0xff``, is the image sub-sampled at columns
+    ``≡ k (mod 4)`` — a plane, by an elementwise shift and mask. The four
+    planes stacked behind ``j`` ARE the image (``[..., W/4, 4]`` is
+    ``[..., W]``). ``lax.bitcast_convert_type`` to uint8 gives the same
+    bytes, but the TPU compiler lowers it by broadcasting every word four
+    times into a ``u32[..., 4]`` copy of the whole window and shifting
+    that (0.51 of the b512 step's 1.20 ms, PERF.md §6, PR 32). Here it
+    keeps the batch in the lanes and ``k`` a major dimension, so stacking
+    the planes moves no pixel: each is written once, in the layout the
+    first convolution reads."""
+    h, w = frame_shape
+    stack = valid.shape[1]
+    words = win[:, first:first + stack, :row_len // 4]
+    words = words * valid[..., None].astype(words.dtype)
+    words = words.reshape(words.shape[:2] + (h, w // 4))
+    planes = jnp.stack([(words >> (8 * k)) & 0xFF for k in range(4)],
+                       axis=-1).astype(jnp.uint8)
+    return jnp.moveaxis(planes.reshape(planes.shape[:3] + (w,)), 1, -1)
+
+
 def gather_rows(frames: jax.Array, flat_idx: jax.Array,
                 valid: jax.Array) -> jax.Array:
     """``frames[flat_idx]`` with invalid stack positions zeroed — the ONE

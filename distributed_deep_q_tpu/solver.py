@@ -238,6 +238,15 @@ class Solver:
     def _next_sample_keys(self, num_shards: int, chain: int) -> np.ndarray:
         return next_fused_keys(self, num_shards, chain)
 
+    def fused_gauges(self) -> dict[str, int]:
+        """Static gauges of the fused step for a train loop's log rows:
+        ``train/unpack_planes`` 1 = its train program unpacks the pixel
+        windows by byte planes, 0 = by a bitcast to uint8
+        (``Learner.unpack_planes``); nothing before a fused step was
+        built."""
+        planes = self.learner.unpack_planes
+        return {} if planes is None else {"train/unpack_planes": planes}
+
     # -- inference (actor path) -------------------------------------------
 
     def q_values(self, obs: np.ndarray) -> np.ndarray:

@@ -321,6 +321,7 @@ def train_single_process(cfg: Config, metrics: Metrics | None = None,
                             metrics.log(solver.step, **summary,
                                         **timer.summary(),
                                         **metrics.telemetry(),
+                                        **solver.fused_gauges(),
                                         **compile_clock.row())
 
             if (cfg.train.eval_every and t % cfg.train.eval_every == 0):
@@ -350,6 +351,7 @@ def train_single_process(cfg: Config, metrics: Metrics | None = None,
         from distributed_deep_q_tpu.replay.persistence import save_replay
         save_replay(replay, persist)
     summary["eval_return"] = final_ret
+    summary["train_unpack_planes"] = solver.learner.unpack_planes
     summary["solver"] = solver
     return summary
 

@@ -217,3 +217,58 @@ def test_short_conv_mix_compiles_for_v5e(one_chip):
         fwd_bwd, S((cfg.replay.batch_size, t, 3 * tq.hidden_size)),
         S((tq.hidden_size, CONV_TAPS)))
     assert "fusion" in text and "tpu_custom_call" not in text
+
+
+# -- the fused CNN train program at the b512 cell's sizes -------------------
+
+def test_b512_train_program_unpacks_by_planes_for_v5e(topo, one_chip):
+    """The breakout preset's whole train program (batch 512, 84x84, bf16,
+    window 7, chain 8): the pixel unpack sits under ``ddq.unpack``, and no
+    instruction of the program makes the window four words wide — the
+    ``u32[512,7,2048,4]`` broadcast, and its ``u8[512,7,8192]`` consumer,
+    by which the chip's compiler lowers a ``bitcast_convert_type`` to
+    uint8 (PERF.md §6, PR 32). ~25 s."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from distributed_deep_q_tpu.config import PRESETS
+    from distributed_deep_q_tpu.models.qnet import build_qnet, init_params
+    from distributed_deep_q_tpu.parallel.learner import Learner, TrainState
+
+    cfg = PRESETS["breakout"]()
+    rep = cfg.replay
+    stack, chain, batch = cfg.net.stack, rep.fused_chain, rep.batch_size
+    window, rowp = stack + rep.n_step, ROWB // 4
+    mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1), ("dp", "model"))
+    module = build_qnet(cfg.net)
+    learner = Learner(lambda p, o: module.apply({"params": p}, o),
+                      cfg.train, mesh)
+    spec = (250_000, 250_000 + window - 1, ROWB, 84 * 84, stack, rep.n_step,
+            cfg.train.gamma, (84, 84), batch, rep.priority_alpha,
+            rep.priority_eps, 1, False)
+    _, train = learner._build_device_per_step(spec, chain)
+    assert learner.unpack_planes == 1
+
+    def S(shape, dtype, *axes):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, P(*axes)))
+
+    params = jax.eval_shape(lambda: init_params(module, cfg.net, 0, 4))
+    state = jax.tree.map(
+        lambda x: S(x.shape, x.dtype),
+        jax.eval_shape(lambda p: TrainState(
+            params=p, target_params=p, opt_state=learner.opt.init(p),
+            step=jnp.zeros((), jnp.int32)), params))
+    row = lambda dtype: S((chain, batch), dtype, None, "dp")  # noqa: E731
+    mask = S((chain, batch, stack), jnp.uint8, None, "dp", None)
+    metas = {"action": row(jnp.int32), "reward": row(jnp.float32),
+             "discount": row(jnp.float32), "weight": row(jnp.float32),
+             "ovalid": mask, "nvalid": mask}
+    text = train.lower(
+        state, metas,
+        S((chain, batch, window, rowp), jnp.int32, None, "dp", None, None),
+        row(jnp.int32), S((1_000_000,), jnp.float32, "dp"),
+        S((), jnp.float32)).compile().as_text()
+    assert "ddq.unpack" in text
+    assert f"[{batch},{window},{rowp},4]" not in text
+    assert f"u8[{batch},{window},{4 * rowp}]" not in text
