@@ -24,7 +24,8 @@ jit roots and walks their call graphs statically:
   ``purity.time``, ``purity.host-rng`` (``random``/``np.random``),
   ``purity.host-sync`` (``.item()``, ``np.asarray``/``np.array``), and
   ``purity.captured-write`` (assignment through an attribute/subscript
-  whose base is not a local, ``global``/``nonlocal``).
+  whose base is not a local of the function or of a traced function it
+  is nested in, ``global``/``nonlocal``).
 
 Scope: ``parallel/``, ``ops/``, ``models/``.
 """
@@ -275,8 +276,13 @@ def _lint_calls(fn: _FuncNode, src: Source, out: list[Finding]) -> None:
                         "leak inside a traced function", out)
 
 
-def _lint_writes(fn: _FuncNode, src: Source, out: list[Finding]) -> None:
-    locals_ = _scope_locals(fn)
+def _lint_writes(fn: _FuncNode, src: Source, out: list[Finding],
+                 enclosing: frozenset[str] = frozenset()) -> None:
+    # a nested function sees its enclosing traced function's names: a
+    # write through one of THOSE (a Pallas kernel's ``@pl.when`` body
+    # storing to the kernel's own ref) is no more captured state than the
+    # same write one level up
+    locals_ = _scope_locals(fn) | enclosing
     body = fn.body if isinstance(fn.body, list) else [fn.body]
 
     def check_target(t: ast.AST, node: ast.AST) -> None:
@@ -294,7 +300,7 @@ def _lint_writes(fn: _FuncNode, src: Source, out: list[Finding]) -> None:
 
     def handle(node: ast.AST) -> None:
         if isinstance(node, _FuncNode):
-            _lint_writes(node, src, out)  # fresh scope, own locals
+            _lint_writes(node, src, out, frozenset(locals_))
             return
         if isinstance(node, ast.Assign):
             for t in node.targets:

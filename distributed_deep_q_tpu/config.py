@@ -28,9 +28,10 @@ class TokenQConfig:
     publishes (``qk_norm``, ``hidden_act``, ``router_input``: a
     configuration states them under ``assumed``); the defaults are a toy
     in SmallThinker's settings. Each layer is a token
-    mixer (attention, full or windowed, or a gated short convolution) and
-    a feed-forward (dense, or the experts held here), read off these keys
-    by ``models/tokenq.layer_plan``. ``experts_held`` / ``expert_offset``
+    mixer (attention, full or windowed; a gated short convolution; or
+    attention over the keys a learned indexer selects) and a feed-forward
+    (dense, or the experts held here), read off these keys by
+    ``models/tokenq.layer_plan``. ``experts_held`` / ``expert_offset``
     and ``net.num_actions`` (the vocabulary rows held) say which SHARE of
     an expert-parallel deployment this process computes: the router stays
     ``moe_num_primary_experts`` wide and what the absent experts would
@@ -53,9 +54,24 @@ class TokenQConfig:
     # token mixer per layer (LFM2's ``layer_types``): "conv" = the gated
     # short convolution (``ops/short_conv.py``; 3 taps, LFM2's
     # ``conv_L_cache``: ``models/tokenq.CONV_TAPS``), "full_attention" =
-    # attention as the two layouts above say. Empty (SmallThinker
-    # publishes no such key): attention on every layer
+    # attention as the two layouts above say, "sparse_attention" = the
+    # same heads over the ``indexer_topk`` keys a query's INDEXER scores
+    # highest (``ops/sparse_attention.py``; Keye-VL-2.0's ``sa_config``).
+    # Empty (SmallThinker publishes no such key): attention on every layer
     layer_types: tuple[str, ...] = ()
+    # the indexer of a "sparse_attention" layer (``sa_config``'s
+    # ``indexer_num_heads`` / ``indexer_head_dim`` / ``topk`` /
+    # ``q_chunk_size``; ONE key head, ``indexer_num_kv_heads`` = 1):
+    # query heads of ``indexer_head_dim`` against one key head score every
+    # earlier key, the ``indexer_topk`` highest are attended (every key
+    # while a query has no more). It learns from its own loss, which the
+    # step adds to the TD loss; ``indexer_q_chunk`` (a multiple of 32; on
+    # the chip of 256) is the query and key block of the selection, of
+    # that loss and of the attention kernels, which pad the window to it
+    indexer_num_heads: int = 4
+    indexer_head_dim: int = 8
+    indexer_topk: int = 8
+    indexer_q_chunk: int = 16
     # per-head RMSNorm of q and k before the rotary embedding (LFM2)
     qk_norm: bool = False
     # the first ``num_dense_layers`` layers carry a dense gated
@@ -743,6 +759,39 @@ def lfm2_tokenq_config() -> Config:
     return c
 
 
+def keye_tokenq_config() -> Config:
+    """Keye-VL-2.0-30B-A3B's language model (Kwai-Keye, config.json,
+    ``model_type`` KeyeVL2) as a token-window Q-network, one chip's share
+    of a 16-chip expert-parallel deployment: every width as published
+    (hidden 2048, 32/4 heads of 128 with q/k norms, rope theta 1e7, the
+    ``sa_config`` indexer of 16 heads of 64 with one key head, top 2 048
+    keys a query, SwiGLU experts of width 768, softmax router 128 wide,
+    top 8); 4 of the 48 layers (the period is one layer), 8 of the 128
+    experts and 18 992 of the 151 936 vocabulary rows held here. Windows
+    of 16 384 steps (+1 token: a query keeps 2 048 of 8 192 earlier keys
+    on average), batch 2, chain 4, a ring of 8 192 windows."""
+    c = smallthinker_tokenq_config()
+    c.net = NetConfig(
+        kind="tokenq", num_actions=18_992, compute_dtype="bfloat16",
+        tokenq=TokenQConfig(
+            hidden_size=2048, num_hidden_layers=4, num_attention_heads=32,
+            num_key_value_heads=4, head_dim=128, rms_norm_eps=1e-6,
+            layer_types=("sparse_attention",) * 4,
+            sliding_window_layout=(0,) * 4, rope_layout=(1,) * 4,
+            rope_theta=10_000_000.0, qk_norm=True,
+            indexer_num_heads=16, indexer_head_dim=64, indexer_topk=2048,
+            indexer_q_chunk=512, hidden_act="silu",
+            router_input="ffn_norm",
+            moe_ffn_hidden_size=768, moe_num_primary_experts=128,
+            moe_num_active_primary_experts=8, experts_held=8,
+            expert_offset=0, head_block=1024, moe_tile=256))
+    c.replay = dataclasses.replace(
+        c.replay, capacity=8_192 * 16_384, batch_size=2,
+        sequence_length=16_384, learn_start=64 * 16_384)
+    c.train = dataclasses.replace(c.train, train_every=16_384)
+    return c
+
+
 def env_for_actor(env: EnvConfig, actor_id: int) -> EnvConfig:
     """Per-actor game assignment (config 4 multi-game fleets): actor i
     plays ``games[i % len(games)]``; single-game configs pass through."""
@@ -761,6 +810,7 @@ PRESETS = {
     "tokenq": tokenq_config,
     "smallthinker_tokenq": smallthinker_tokenq_config,
     "lfm2_tokenq": lfm2_tokenq_config,
+    "keye_tokenq": keye_tokenq_config,
 }
 
 

@@ -22,6 +22,16 @@ mixer and a feed-forward in pre-norm residual form, input x ``[B, T, h]``
     ``m = short_conv_mix(u · W_in, w_conv) · W_out``
     (``ops/short_conv.py``: ``CONV_TAPS`` causal taps between two
     gates);
+  - ``layer_types[l] == "sparse_attention"``: the same heads over the
+    ``indexer_topk`` keys a learned INDEXER selects for each query
+    (``ops/sparse_attention.py``): ``qI = ū W_iq`` [``indexer_num_heads``
+    x ``indexer_head_dim``], ``kI = rmsnorm(ū W_ik)`` (one key head),
+    ``wI = ū W_iw``, ``ū = stop_gradient(u)``, the layer's rotary on
+    both; index scores, an exact top-k, attention over the kept keys;
+    the indexer's own loss comes back with the layer's counters and the
+    learner adds it to the TD loss. The whole indexer is float32; the
+    window is padded to whole blocks of ``indexer_q_chunk`` inside the
+    mixer;
 - feed-forward over ``w = rmsnorm_2(x')``, ``y = x' + f``:
   - ``l < num_dense_layers``: ``f = (act(w W_gate) * (w W_up)) W_down`` of
     width ``intermediate_size``, blockwise over tokens;
@@ -40,14 +50,20 @@ SmallThinker's settings: attention on every layer (window + rope, or full
 with no positional encoding), ReGLU experts everywhere, a softmax router
 before attention. LFM2's: convolutions among full attention with q/k
 norms, a leading dense layer, SwiGLU, a sigmoid router with a selection
-bias reading the second norm.
+bias reading the second norm. Keye-VL-2.0's: sparse attention with q/k
+norms and rope on every layer, SwiGLU experts behind a softmax router
+reading the second norm.
 
 Then the final RMSNorm; the untied head ``[h, V]`` is applied by the
 learner, blockwise over tokens, together with the TD loss
 (``parallel/sequence_learner.py``). Matmuls run in ``net.compute_dtype``
 with float32 accumulation; norms, router, gates and convolution, rotary
 and the residual stream are float32. Each layer is rematerialised in the
-backward pass.
+backward pass; a sparse layer's two halves apart (``mixer``,
+``feed_forward``: what the mixer's backward needs does not stand beside
+the expert layer's buffers), and its mixer keeps its selection (bits, no
+gradient) and its indexer's loss with that loss's gradients across it, so
+the top-k search and the loss run once a forward pass.
 
 Parameters are a plain nested dict; a leaf's name is its path
 (``layer_02/w_gate``), which is what weight IO uses.
@@ -61,7 +77,7 @@ import jax
 import jax.numpy as jnp
 
 from distributed_deep_q_tpu.config import NetConfig, TokenQConfig
-from distributed_deep_q_tpu.ops import moe
+from distributed_deep_q_tpu.ops import moe, sparse_attention
 from distributed_deep_q_tpu.ops.attention import causal_attention
 from distributed_deep_q_tpu.ops.short_conv import short_conv_mix
 
@@ -70,6 +86,7 @@ BIAS_STD = 0.01     # the expert bias: seeded, and no gradient reaches it
 CONV_TAPS = 3       # a conv layer's taps (LFM2's ``conv_L_cache``)
 ACTS = {"relu": jax.nn.relu, "silu": jax.nn.silu}       # ``hidden_act``
 ROUTER_INPUTS = ("pre_mixer", "ffn_norm")
+MIXERS = ("conv", "full_attention", "sparse_attention")  # ``layer_types``
 
 
 def layer_name(i: int) -> str:
@@ -77,8 +94,8 @@ def layer_name(i: int) -> str:
 
 
 def layer_plan(tq: TokenQConfig) -> list[dict[str, bool]]:
-    """Per layer ``{windowed, rope, conv, dense}``: the mixer from
-    ``layer_types`` (absent: attention) and the two layouts, the
+    """Per layer ``{windowed, rope, conv, sparse, dense}``: the mixer
+    from ``layer_types`` (absent: attention) and the two layouts, the
     feed-forward from ``num_dense_layers``."""
     n = tq.num_hidden_layers
     if len(tq.sliding_window_layout) < n or len(tq.rope_layout) < n:
@@ -86,20 +103,26 @@ def layer_plan(tq: TokenQConfig) -> list[dict[str, bool]]:
             f"sliding_window_layout/rope_layout must cover "
             f"{n} layers: {tq.sliding_window_layout} {tq.rope_layout}")
     if tq.layer_types and (len(tq.layer_types) < n or set(
-            tq.layer_types[:n]) - {"conv", "full_attention"}):
-        raise ValueError(f"layer_types must name {n} layers as conv | "
-                         f"full_attention: {tq.layer_types}")
+            tq.layer_types[:n]) - set(MIXERS)):
+        raise ValueError(f"layer_types must name {n} layers as "
+                         f"{' | '.join(MIXERS)}: {tq.layer_types}")
     if tq.hidden_act not in ACTS:
         raise ValueError(f"hidden_act must be one of {sorted(ACTS)}: "
                          f"{tq.hidden_act!r}")
     if tq.router_input not in ROUTER_INPUTS:
         raise ValueError(f"router_input must be one of {ROUTER_INPUTS}: "
                          f"{tq.router_input!r}")
-    return [{"windowed": bool(tq.sliding_window_layout[i]),
+    kinds = tq.layer_types[:n] or ("full_attention",) * n
+    plan = [{"windowed": bool(tq.sliding_window_layout[i]),
              "rope": bool(tq.rope_layout[i]),
-             "conv": bool(tq.layer_types) and tq.layer_types[i] == "conv",
+             "conv": kinds[i] == "conv",
+             "sparse": kinds[i] == "sparse_attention",
              "dense": i < tq.num_dense_layers}
             for i in range(n)]
+    if any(k["sparse"] and k["windowed"] for k in plan):
+        raise ValueError("a sparse_attention layer takes no sliding "
+                         f"window: {tq.sliding_window_layout}")
+    return plan
 
 
 def param_shapes(cfg: NetConfig) -> dict[str, Any]:
@@ -117,6 +140,9 @@ def param_shapes(cfg: NetConfig) -> dict[str, Any]:
         attention.update({"q_norm": (d,), "k_norm": (d,)})
     conv = {"w_in": (h, 3 * h), "w_conv": (h, CONV_TAPS),
             "w_out": (h, h)}
+    hi, di = tq.indexer_num_heads, tq.indexer_head_dim
+    indexer = {"w_iq": (h, hi * di), "w_ik": (h, di), "w_iw": (h, hi),
+               "ik_norm": (di,)}
     experts = {"w_router": (h, tq.moe_num_primary_experts),
                "w_gate": (e, h, f), "w_up": (e, h, f), "w_down": (e, f, h)}
     if tq.use_expert_bias:
@@ -129,6 +155,7 @@ def param_shapes(cfg: NetConfig) -> dict[str, Any]:
         shapes[layer_name(i)] = {
             "norm_1": (h,), "norm_2": (h,),
             **(conv if kind["conv"] else attention),
+            **(indexer if kind["sparse"] else {}),
             **(dense if kind["dense"] else experts)}
     return shapes
 
@@ -222,23 +249,44 @@ def dense_ffn(w: jax.Array, w_gate: jax.Array, w_up: jax.Array,
     return jax.lax.map(one, blocks).reshape(nb * block, h)[:n]
 
 
-def layer(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
+def _indexer_inputs(u: jax.Array, p: dict[str, jax.Array],
+                    tq: TokenQConfig, rope: bool):
+    """The indexer's query heads, head weights and one key head from the
+    layer's normed input, float32: ``(qI [B, T, Hi, Di], wI [B, T, Hi],
+    kI [B, T, Di])``. No gradient reaches ``u`` from here."""
+    b, t, _ = u.shape
+    hi, di = tq.indexer_num_heads, tq.indexer_head_dim
+    u = jax.lax.stop_gradient(u)
+
+    def proj(w):
+        return jnp.dot(u, w, precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
+    q_i = proj(p["w_iq"]).reshape(b, t, hi, di).transpose(0, 2, 1, 3)
+    k_i = rmsnorm(proj(p["w_ik"]), p["ik_norm"], tq.rms_norm_eps)[:, None]
+    if rope:
+        q_i, k_i = rotary(q_i, tq.rope_theta), rotary(k_i, tq.rope_theta)
+    return q_i.transpose(0, 2, 1, 3), proj(p["w_iw"]), k_i[:, 0]
+
+
+def mixer(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
           windowed: bool, rope: bool, interpret: bool, *,
-          conv: bool = False, dense: bool = False):
-    """One block; ``x`` [B, T, h] float32 → (x, the expert layer's
-    counters; ``None`` from a dense layer). The mixer is attention
-    (``windowed``, ``rope``) or with ``conv`` the gated short convolution;
-    the feed-forward the held experts or with ``dense`` the dense one."""
+          conv: bool = False, dense: bool = False, sparse: bool = False,
+          index_loss: bool = True):
+    """A block's first half, ``x' = x + m``; ``x`` [B, T, h] float32 →
+    (x', the routing where the router reads the mixer's input — else
+    ``None`` —, a sparse mixer's counters — else ``None``). The mixer is
+    attention (``windowed``, ``rope``), with ``conv`` the gated short
+    convolution, with ``sparse`` attention over the keys its indexer
+    selects (``index_loss``: with the indexer's loss)."""
     tq = cfg.tokenq
-    act = ACTS[tq.hidden_act]
     router_first = tq.router_input == "pre_mixer"
     dtype = jnp.dtype(cfg.compute_dtype)
-    b, t, h = x.shape
+    b, t, _ = x.shape
     hq, hkv, d = (tq.num_attention_heads, tq.num_key_value_heads,
                   tq.head_dim)
     u = rmsnorm(x, p["norm_1"], tq.rms_norm_eps)
-    if router_first and not dense:
-        idx, prob = _route(u, p, tq)
+    route = _route(u, p, tq) if router_first and not dense else None
+    dsa = None
     if conv:
         with jax.named_scope("ddq.short_conv"):
             bcz = _mm(u, p["w_in"], dtype)
@@ -247,9 +295,16 @@ def layer(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
             x = x + _mm(mixed, p["w_out"], dtype)
     else:
         with jax.named_scope(
+                "ddq.attn_sparse" if sparse else
                 "ddq.attn_window" if windowed else "ddq.attn_full"):
+            # the sparse core takes whole query blocks: the window is
+            # padded here, once (padded keys lie in every real query's
+            # future), so every projection comes out padded
+            tm = t + (-t % tq.indexer_q_chunk if sparse else 0)
+            um = jnp.pad(u, ((0, 0), (0, tm - t), (0, 0))) if tm > t else u
+
             def heads(w, n):
-                return _mm(u, w, dtype).reshape(b, t, n, d).transpose(
+                return _mm(um, w, dtype).reshape(b, tm, n, d).transpose(
                     0, 2, 1, 3)
             q, k, v = heads(p["w_q"], hq), heads(p["w_k"], hkv), heads(
                 p["w_v"], hkv)
@@ -258,13 +313,37 @@ def layer(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
                 k = rmsnorm(k, p["k_norm"], tq.rms_norm_eps)
             if rope:
                 q, k = rotary(q, tq.rope_theta), rotary(k, tq.rope_theta)
-            a = causal_attention(
-                q.astype(dtype), k.astype(dtype), v.astype(dtype),
-                window=tq.sliding_window_size if windowed else 0,
-                block=tq.attn_block, compute_block=tq.attn_compute_block,
-                interpret=interpret)
-            a = a.transpose(0, 2, 1, 3).reshape(b, t, hq * d)
+            if sparse:
+                with jax.named_scope("ddq.indexer"):
+                    indexer = _indexer_inputs(um, p, tq, rope)
+                a, dsa = sparse_attention.sparse_attention(
+                    q.astype(dtype), k.astype(dtype), v.astype(dtype),
+                    *indexer, topk=tq.indexer_topk,
+                    block=tq.indexer_q_chunk, t_real=t,
+                    with_loss=index_loss, interpret=interpret)
+                a = a[:, :t]
+            else:
+                a = causal_attention(
+                    q.astype(dtype), k.astype(dtype), v.astype(dtype),
+                    window=tq.sliding_window_size if windowed else 0,
+                    block=tq.attn_block,
+                    compute_block=tq.attn_compute_block,
+                    interpret=interpret)
+                a = a.transpose(0, 2, 1, 3).reshape(b, t, hq * d)
             x = x + _mm(a, p["w_o"], dtype)
+    return x, route, dsa
+
+
+def feed_forward(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
+                 interpret: bool, *, dense: bool = False, route=None):
+    """A block's second half, ``y = x' + f``: the held experts (``route``:
+    the routing, where the mixer's half made it) or with ``dense`` the
+    dense feed-forward → (y, the expert layer's counters; ``None`` from a
+    dense one)."""
+    tq = cfg.tokenq
+    act = ACTS[tq.hidden_act]
+    dtype = jnp.dtype(cfg.compute_dtype)
+    b, t, h = x.shape
     if dense:
         with jax.named_scope("ddq.dense_ffn"):
             v2 = rmsnorm(x, p["norm_2"], tq.rms_norm_eps)
@@ -274,8 +353,8 @@ def layer(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
         return x + y.reshape(b, t, h), None
     with jax.named_scope("ddq.experts"):
         v2 = rmsnorm(x, p["norm_2"], tq.rms_norm_eps)
-        if not router_first:    # its own scope, nested: innermost
-            idx, prob = _route(v2, p, tq)
+        # the router in its own scope, nested: the innermost
+        idx, prob = route if route is not None else _route(v2, p, tq)
         k = tq.moe_num_active_primary_experts
         # a SEQUENCE at a time: the held-slot buffer is sized for one
         # sequence's worst case (every token with min(k, held) slots
@@ -297,25 +376,71 @@ def layer(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
     return x + y, counters
 
 
+def layer(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
+          windowed: bool, rope: bool, interpret: bool, *,
+          conv: bool = False, dense: bool = False, sparse: bool = False,
+          index_loss: bool = True):
+    """One block, ``mixer`` then ``feed_forward``; ``x`` [B, T, h] float32
+    → (x, the layer's counters: the expert layer's, and under ``"dsa"`` a
+    sparse mixer's; ``None`` where it has neither)."""
+    x, route, dsa = mixer(x, p, cfg, windowed, rope, interpret, conv=conv,
+                          dense=dense, sparse=sparse, index_loss=index_loss)
+    x, counters = feed_forward(x, p, cfg, interpret, dense=dense,
+                               route=route)
+    if sparse:
+        counters = {**(counters or {}), "dsa": dsa}
+    return x, counters
+
+
+# a sparse layer's selection outlives the layer's rematerialisation
+KEEP_SELECTION = jax.checkpoint_policies.save_only_these_names(
+    sparse_attention.SELECTION_NAME, sparse_attention.LOSS_NAME)
+
+
 def backbone(params: dict[str, Any], tokens: jax.Array, cfg: NetConfig,
-             interpret: bool = False):
+             interpret: bool = False, *, index_loss: bool = True):
     """``tokens`` [B, T] int32 → (final-normed hidden [B, T, h] float32,
-    expert counters stacked over the EXPERT layers). Only the attention
-    kernel pads the window (to its block); every other product runs on
-    T."""
+    counters: the expert layers' stacked over the EXPERT layers and,
+    where there are sparse layers, theirs stacked over those as
+    ``dsa_selected`` / ``dsa_causal`` (pairs), ``dsa_index_loss`` (0
+    without ``index_loss``: θ⁻ and the acting path) and ``dsa_bits`` (the
+    selected pairs as bits: a caller that does not read them drops them,
+    and the compiler with it). Only the attention kernels pad the window
+    (to their blocks); every other product runs on T."""
     tq = cfg.tokenq
     x = params["embed"][tokens]
-    counters = []
+    counters, dsa = [], []
     for i, kind in enumerate(layer_plan(tq)):
-        fn = jax.checkpoint(
-            lambda x, p, kind=kind: layer(
-                x, p, cfg, kind["windowed"], kind["rope"], interpret,
-                conv=kind["conv"], dense=kind["dense"]))
-        x, c = fn(x, params[layer_name(i)])
+        p = params[layer_name(i)]
+        kw = dict(conv=kind["conv"], dense=kind["dense"],
+                  sparse=kind["sparse"], index_loss=index_loss)
+        if kind["sparse"]:
+            # the two halves rematerialised apart: what the mixer's
+            # backward needs (q, k, v, the indexer's inputs, o: 2.5 GB at
+            # 2 x 16 896 tokens) need not stand beside the expert layer's
+            # buffers, at the price of one more [B, T, h] a layer
+            x, route, dsa_i = jax.checkpoint(
+                lambda x, p, kind=kind, kw=kw: mixer(
+                    x, p, cfg, kind["windowed"], kind["rope"], interpret,
+                    **kw), policy=KEEP_SELECTION)(x, p)
+            x, c = jax.checkpoint(
+                lambda x, p, route, kind=kind: feed_forward(
+                    x, p, cfg, interpret, dense=kind["dense"],
+                    route=route))(x, p, route)
+            dsa.append(dsa_i)
+        else:
+            x, c = jax.checkpoint(
+                lambda x, p, kind=kind, kw=kw: layer(
+                    x, p, cfg, kind["windowed"], kind["rope"], interpret,
+                    **kw))(x, p)
         if c is not None:
             counters.append(c)
     x = rmsnorm(x, params["final_norm"], tq.rms_norm_eps)
-    return x, jax.tree.map(lambda *a: jnp.stack(a), *counters)
+    out = jax.tree.map(lambda *a: jnp.stack(a), *counters)
+    if dsa:
+        out.update({f"dsa_{k}": jnp.stack([d[k] for d in dsa])
+                    for k in dsa[0]})
+    return x, out
 
 
 def q_at(params: dict[str, Any], tokens: jax.Array, pos: jax.Array,
@@ -323,5 +448,5 @@ def q_at(params: dict[str, Any], tokens: jax.Array, pos: jax.Array,
     """Q(prefix, ·) at position ``pos`` of each row: ``[B, V]`` — the
     acting path (no cache: the whole window is run; what lies after
     ``pos`` cannot reach it through causal attention)."""
-    hid, _ = backbone(params, tokens, cfg, interpret)
+    hid, _ = backbone(params, tokens, cfg, interpret, index_loss=False)
     return _mm(hid[:, pos], params["head"], jnp.dtype(cfg.compute_dtype))

@@ -455,7 +455,7 @@ class SequenceLearner:
         actions = jnp.concatenate(
             [tokens[:, 1:], jnp.zeros((b, 1), tokens.dtype)], axis=1)
         hid_tg, _ = tokenq.backbone(state.target_params, tokens, net,
-                                    interpret)
+                                    interpret, index_loss=False)
 
         def loss_fn(params):
             hid_on, counters = tokenq.backbone(params, tokens, net,
@@ -478,9 +478,15 @@ class SequenceLearner:
                     eta=cfg.priority_eta)
                 q_mean = jnp.mean(q_row.reshape(b, t1)[:, :-1]) / \
                     params["head"].shape[1]
-            return loss, (priority, q_mean, counters)
+            # a sparse layer's indexer learns from its own loss (no other
+            # gradient reaches it): the step minimises the sum, and
+            # reports the TD loss as ``loss``
+            total = loss
+            if "dsa_index_loss" in counters:
+                total = loss + jnp.sum(counters["dsa_index_loss"])
+            return total, (loss, priority, q_mean, counters)
 
-        (loss, (priority, q_mean, counters)), grads = jax.value_and_grad(
+        (_, (loss, priority, q_mean, counters)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state.params)
         grads = lax.pmean(grads, AXIS_DP)
         with jax.named_scope("ddq.optimizer"):
@@ -517,6 +523,17 @@ class SequenceLearner:
                 jnp.max(load, -1) / jnp.maximum(jnp.mean(load, -1), 1.0)),
                 AXIS_DP),
         }
+        if "dsa_selected" in counters:
+            # the sparse layers' counters: (query, key) pairs attended and
+            # inside the causal mask, over layers and shards, and the
+            # indexers' loss summed over layers
+            metrics.update({
+                "dsa_pairs_selected": lax.psum(
+                    jnp.sum(counters["dsa_selected"]), AXIS_DP),
+                "dsa_pairs_causal": lax.psum(
+                    jnp.sum(counters["dsa_causal"]), AXIS_DP),
+                "dsa_index_loss": lax.pmean(
+                    jnp.sum(counters["dsa_index_loss"]), AXIS_DP)})
         return (TrainState(params, target_params, opt_state, step), metrics,
                 priority)
 

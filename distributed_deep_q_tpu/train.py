@@ -712,6 +712,12 @@ def train_tokenq(cfg: Config, metrics: Metrics | None = None,
                             m["moe_load_max_over_mean"]),
                         "moe_overflow": float(m["moe_overflow"]),
                     }
+                    if "dsa_pairs_selected" in m:   # sparse layers only
+                        summary.update({
+                            "dsa_pairs_selected_share": float(
+                                m["dsa_pairs_selected"])
+                            / max(float(m["dsa_pairs_causal"]), 1.0),
+                            "dsa_index_loss": float(m["dsa_index_loss"])})
                     metrics.gauge("queue/replay_size", len(replay))
                     metrics.log(gsteps, **summary, **metrics.telemetry())
     trace.close()

@@ -163,6 +163,30 @@ def test_purity_impure_jit_body_caught():
                                "purity.host-sync", "purity.captured-write"}
 
 
+def test_purity_nested_body_may_store_to_the_enclosing_kernels_refs():
+    """A Pallas kernel's ``@pl.when`` body stores to the kernel's own ref
+    arguments: names of the traced function it is nested in, not captured
+    state. The same body writing module state is still caught."""
+    findings = purity.check_sources([src("""
+        from jax.experimental import pallas as pl
+
+        seen = {}
+
+        def kernel(x_ref, o_ref, acc_ref):
+            @pl.when(pl.program_id(0) == 0)
+            def _():
+                acc_ref[...] = x_ref[...]
+                seen["first"] = True      # module state: still a finding
+
+            o_ref[...] = acc_ref[...]
+
+        def run(x):
+            return pl.pallas_call(kernel, out_shape=x)(x)
+    """)])
+    assert [f.rule for f in findings] == ["purity.captured-write"]
+    assert "'seen'" in findings[0].message
+
+
 def test_purity_non_jitted_function_not_flagged():
     findings = purity.check_sources([src("""
         import numpy as np

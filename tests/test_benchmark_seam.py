@@ -65,7 +65,8 @@ def test_every_configuration_resolves_its_family_modules(config):
 
 
 TOKEN_CELLS = ["smallthinker_21b_tokenq_ep8.seq_learner_only",
-               "lfm2_24b_tokenq_ep8.seq_learner_only"]
+               "lfm2_24b_tokenq_ep8.seq_learner_only",
+               "keye_vl2_30b_tokenq_ep16.seq_learner_only"]
 
 
 @pytest.mark.parametrize("cell", TOKEN_CELLS)
@@ -92,9 +93,10 @@ def test_the_token_family_walks_its_cell_on_the_cpu(cell):
 # priority and does not judge it: ``families/lfm2/check.PRINTED_ONLY``)
 SEPARATING = ("loss_first_rel", "grad_norm_first_rel",
               "moment_first_worst_leaf")
-CONTROL_FLOOR = dict(zip(TOKEN_CELLS, (1e-3, 1e-4)))
+CONTROL_FLOOR = dict(zip(TOKEN_CELLS, (1e-3, 1e-4, 1e-4)))
 CONTROL_READS = dict(zip(TOKEN_CELLS, (
-    (*SEPARATING, "priority_first_max_rel"), SEPARATING)))
+    (*SEPARATING, "priority_first_max_rel"), SEPARATING,
+    (*SEPARATING, "priority_first_max_rel"))))
 
 
 @pytest.mark.parametrize("cell", TOKEN_CELLS)
@@ -139,3 +141,24 @@ def test_the_lfm2_familys_planted_faults_move_what_they_are_read_for():
     eta = table["priority_eta_1"]["smallest"]
     assert max(eta[k] for k in ("loss_max_rel", "grad_norm_max_rel",
                                 "held_share_max_abs")) < 1e-5
+
+
+def test_the_keye_familys_planted_faults_move_the_selection():
+    """``families/keye/faults.py`` at the toy sizes: a window of the most
+    recent keys in place of the indexer's selection reads as another set
+    of pairs (and another loss); ``lax.approx_max_k`` is exact on the CPU,
+    so there it reads what the sound program reads."""
+    from benchmark import rehearse
+    from benchmark.families.keye import faults
+
+    rs = faults.readings(TOKEN_CELLS[2], [2 ** 31 + 5], backend="cpu",
+                         conf_patch=rehearse.toy, prefill=256)
+    table = faults.summarize(rs)
+    assert set(table) == set(faults.FAULTS)
+    recent = table["selection_recent"]["smallest"]
+    assert recent["selection_mismatch_share"] > 0.1
+    assert recent["index_loss_first_rel"] > 1e-2
+    assert recent["loss_first_rel"] > 1e-4
+    approx = table["selection_approx"]["smallest"]
+    assert approx["selection_mismatch_share"] == 0
+    assert max(approx[k] for k in SEPARATING) < 1e-5

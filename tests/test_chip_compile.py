@@ -219,6 +219,44 @@ def test_short_conv_mix_compiles_for_v5e(one_chip):
     assert "fusion" in text and "tpu_custom_call" not in text
 
 
+@pytest.mark.parametrize("with_loss", [False, True],
+                         ids=["core", "core_and_indexer_loss"])
+def test_sparse_attention_compiles_for_v5e(one_chip, with_loss):
+    """The learned sparse attention at the Keye preset's widths (32 / 4
+    heads of 128, an indexer of 16 heads of 64, top 2 048, blocks of 512)
+    on a window of 4 096 + 1 tokens padded to whole blocks: the selection,
+    the three flash kernels that read it as bits (forward, dq, dk + dv)
+    and with ``with_loss`` the kernel that adds up the heads'
+    probabilities for the indexer's loss, forward and backward."""
+    from distributed_deep_q_tpu.config import PRESETS
+    from distributed_deep_q_tpu.ops import sparse_attention as sa
+
+    tq = PRESETS["keye_tokenq"]().net.tokenq
+    block, t = tq.indexer_q_chunk, 4096 + 1
+    tm = -(-t // block) * block
+    hq, hkv, d = (tq.num_attention_heads, tq.num_key_value_heads,
+                  tq.head_dim)
+    hi, di = tq.indexer_num_heads, tq.indexer_head_dim
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(q, k, v, q_i, w_i, k_i):
+        o, c = sa.sparse_attention(
+            q, k, v, q_i, w_i, k_i, topk=tq.indexer_topk, block=block,
+            t_real=t, with_loss=with_loss)
+        return jnp.sum(o.astype(jnp.float32) ** 2) + c["index_loss"]
+
+    text = _compiled_text(
+        jax.grad(loss, argnums=tuple(range(6))),
+        S((1, hq, tm, d), jnp.bfloat16), S((1, hkv, tm, d), jnp.bfloat16),
+        S((1, hkv, tm, d), jnp.bfloat16), S((1, tm, hi, di), jnp.float32),
+        S((1, tm, hi), jnp.float32), S((1, tm, di), jnp.float32))
+    for kernel in ("sparse_core_fwd", "sparse_core_dq", "sparse_core_dkv"):
+        assert kernel in text
+    assert ("sparse_head_probs" in text) == with_loss
+
+
 # -- the fused CNN train program at the b512 cell's sizes -------------------
 
 def test_b512_train_program_unpacks_by_planes_for_v5e(topo, one_chip):
