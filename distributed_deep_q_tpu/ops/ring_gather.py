@@ -48,6 +48,7 @@ boundary.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -60,6 +61,7 @@ from jax.experimental.pallas import tpu as pltpu
 # dynamic slice starts/sizes provably divisible by the tile) — all row
 # strides here must be multiples of this.
 I32_TILE = 1024
+LANES = 128     # minor dim of the (8, 128) tile every >= 2-D int32 array gets
 NBUF = 64  # outstanding DMAs (depth sweep: 8→1.2 µs/DMA, 64→~0.2 µs)
 
 
@@ -110,11 +112,14 @@ def gather_windows(idx: jax.Array, ring: jax.Array, *, n: int, w: int,
     ``idx`` [n] int32 — window-start ROW indices (callers guarantee
     ``idx + w`` stays inside the ring via ghost rows); ``ring`` [S] int32
     (packed pixel bytes); ``rowb`` row stride in BYTES. Returns
-    [n · w · rowb/4] int32 (flat; the consumer reshapes it and takes the
-    bytes out of the words — the fused train program by shift and mask
-    into byte planes where the frame width is a multiple of 4 and the
-    batch fills the lanes, else by a bitcast to uint8:
-    ``replay/device_per.window_to_obs``).
+    [n · w · rowb/4] int32, FLAT: the consumer gives it its dims. A view
+    that puts the ``w`` rows next to the row's words (``[n, w, rowp]``) is
+    a physical copy on the chip; ``tile_rows`` is the view that is free,
+    and the one the fused sample program hands to the train program. The
+    consumer then takes the bytes out of the words — the fused train
+    program by shift and mask into byte planes where the frame width is a
+    multiple of 4 and the batch fills the lanes, else by a bitcast to
+    uint8: ``replay/device_per.window_to_obs``.
     """
     rowp = rowb // 4
     wsz = w * rowp
@@ -132,6 +137,36 @@ def gather_windows(idx: jax.Array, ring: jax.Array, *, n: int, w: int,
         compiler_params=pltpu.CompilerParams(has_side_effects=True),
         interpret=interpret,
     )(idx.astype(jnp.int32), ring)
+
+
+def tile_rows(flat: jax.Array, *lead: int) -> jax.Array:
+    """``gather_windows``' flat output as ``[*lead, rowp // 128, 128]``
+    (``lead`` e.g. ``(chain, batch, w)``, ``rowp`` what is left over): the
+    view in which the windows cross a program boundary, and a bitcast.
+
+    The kernel writes a 1-D int32 array, tiled ``T(1024)``; anything with
+    two dims or more is tiled ``T(8, 128)`` over its LAST TWO. One 1-D
+    tile and one (8, 128) tile are the same 4 096 bytes in the same order
+    (``I32_TILE == 8 * LANES``), and ``padded_row_bytes`` makes a row a
+    whole number of them, so with a row's words as the last two dims —
+    ``(rowp // 128, 128)``, the first a multiple of 8 — no byte moves.
+    ``[*lead, rowp]`` instead leaves ``(w, rowp)`` in the tiled dims, and a
+    window of 7 (or 5) rows is no multiple of 8 sublanes: the reshape pads
+    every window to 8 rows, a copy of the whole chunk, and the compiler
+    then copies it once more into a result layout without the padding
+    (1.53 of the b512 chunk's 10.39 ms, PERF.md §6, PR 35).
+    ``flat_rows`` undoes it."""
+    rowp, rest = divmod(flat.size, math.prod(lead))
+    assert rest == 0 and rowp % I32_TILE == 0, (
+        f"{flat.size} words are not {lead} rows of whole 1-D tiles")
+    return flat.reshape(*lead, rowp // LANES, LANES)
+
+
+def flat_rows(tiled: jax.Array) -> jax.Array:
+    """``tile_rows``' view back to ``[*lead, rowp]``: a row's words in
+    one dim again. Inside the train program this costs nothing of its own:
+    the one relayout a step that feeds the unpack reads either form."""
+    return tiled.reshape(tiled.shape[:-2] + (-1,))
 
 
 def scatter_rows(src_idx: jax.Array, dst_idx: jax.Array, staged: jax.Array,

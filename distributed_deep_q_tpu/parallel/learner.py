@@ -591,10 +591,17 @@ class Learner:
         the per-row ``build_meta_pack`` (two row gathers per sample) and
         copies each sample's combined obs+next-obs pixel window with the
         Pallas row-DMA kernel (``ops/ring_gather.py`` — 3 ms vs 44 ms
-        for the tiled XLA gathers it replaced at the 1M-ring shape); the
-        train program takes obs/next-obs out of the windows under the
-        scope ``ddq.unpack``, applies the validity masks, and runs the DQN
-        step. Where the frame width is a multiple of 4 and a shard's
+        for the tiled XLA gathers it replaced at the 1M-ring shape). The
+        windows cross to the train program as
+        ``[chain, per_shard, window, rowp // 128, 128]``
+        (``ring_gather.tile_rows``): a row's words fill the two tiled
+        dims, so the view is a bitcast of what the kernel wrote. With the
+        window's 7 (or 5) rows in the sublanes, ``[..., window, rowp]``,
+        the chip pads each window to 8 and copies the whole chunk twice
+        (PERF.md §6, PR 35). The train program scans over the chunk,
+        flattens a step's rows back (``flat_rows``), takes obs/next-obs
+        out of the windows under the scope ``ddq.unpack``, applies the
+        validity masks, and runs the DQN step. Where the frame width is a multiple of 4 and a shard's
         batch fills the lanes the packed int32 words are split into their
         four BYTE PLANES by shift and mask and the planes laid side by
         side (``window_to_obs``); anything else is bitcast to uint8 and
@@ -605,7 +612,8 @@ class Learner:
         ~200× slower — measured minimal pair, r3)."""
         (slot_cap, slot_pad, rowb, row_len, stack, n_step, gamma,
          frame_shape, per_shard, alpha, eps, num_shards, interpret) = spec
-        from distributed_deep_q_tpu.ops.ring_gather import gather_windows
+        from distributed_deep_q_tpu.ops.ring_gather import (
+            flat_rows, gather_windows, tile_rows)
         from distributed_deep_q_tpu.replay.device_per import (
             build_meta_pack, fused_sample_draw_packed, fused_sample_prep,
             scatter_priorities, stack_rows_to_obs, window_to_obs)
@@ -613,10 +621,9 @@ class Learner:
         S = P(AXIS_DP)
         SK = P(None, AXIS_DP)   # [chain, B]-stacked outputs, batch-sharded
         SK3 = P(None, AXIS_DP, None)
-        SWIN = P(None, AXIS_DP, None, None)
+        SWIN = P(None, AXIS_DP, None, None, None)
         window = stack + n_step
         n_win = chain * per_shard
-        rowp = rowb // 4        # int32 elements per padded frame row
 
         def sample_fn(keys, frames, action, reward, done, boundary, prio,
                       cursors, sizes, betas):
@@ -638,7 +645,9 @@ class Learner:
             # kernel as ``%sample_fn.N`` (PERF.md §7)
             win = gather_windows(ws.reshape(-1), frames, n=n_win,
                                  w=window, rowb=rowb, interpret=interpret)
-            return metas, win.reshape(chain, per_shard, window, rowp), idxs
+            # the view that is a bitcast of the kernel's tiles, whatever
+            # the batch and the window are (ring_gather.tile_rows)
+            return metas, tile_rows(win, chain, per_shard, window), idxs
 
         meta_spec = {"action": SK, "reward": SK, "discount": SK,
                      "weight": SK, "ovalid": SK3, "nvalid": SK3}
@@ -677,6 +686,7 @@ class Learner:
             ovalid = batch.pop("ovalid")
             nvalid = batch.pop("nvalid")
             with jax.named_scope("ddq.unpack"):
+                w = flat_rows(w)    # one step's [per_shard, window, rowp]
                 if by_planes:
                     obs = window_to_obs(w, 0, ovalid, row_len, frame_shape)
                     nobs = window_to_obs(w, n_step, nvalid, row_len,
@@ -687,7 +697,7 @@ class Learner:
                     # platforms), drop the DMA row padding
                     pix = lax.bitcast_convert_type(w, jnp.uint8)
                     pix = pix.reshape(
-                        w.shape[:2] + (rowp * 4,))[:, :, :row_len]
+                        w.shape[:2] + (-1,))[:, :, :row_len]
                     obs = stack_rows_to_obs(
                         pix[:, :stack] * ovalid[..., None], frame_shape)
                     nobs = stack_rows_to_obs(

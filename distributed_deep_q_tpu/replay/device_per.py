@@ -164,7 +164,11 @@ def window_to_obs(win: jax.Array, first: int, valid: jax.Array,
     """Frames ``first .. first+stack-1`` of [B, window, rowp] packed ring
     rows → [B, H, W, stack] uint8 CNN input, by BYTE PLANES: for frame
     widths that are a multiple of 4. Frames whose ``valid`` [B, stack] is
-    0 are zeroed as ``gather_rows`` zeroes them.
+    0 are zeroed as ``gather_rows`` zeroes them. ``win`` is one scan
+    step's share of what crossed from the sample program, its rows
+    flattened back (``ops/ring_gather.flat_rows``): between the programs
+    the windows travel as ``[..., window, rowp // 128, 128]``, because 7
+    rows in a tiled dim would be padded to 8 (``tile_rows``).
 
     A row is H·W pixel bytes packed little-endian four to an int32, so
     every image row starts on a word and word ``(W/4)·h + j`` holds the

@@ -10,7 +10,9 @@ not a chip run.
 """
 
 import functools
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -257,39 +259,108 @@ def test_sparse_attention_compiles_for_v5e(one_chip, with_loss):
     assert ("sparse_head_probs" in text) == with_loss
 
 
-# -- the fused CNN train program at the b512 cell's sizes -------------------
+# -- the fused CNN programs at the b512 cell's sizes ------------------------
 
-def test_b512_train_program_unpacks_by_planes_for_v5e(topo, one_chip):
-    """The breakout preset's whole train program (batch 512, 84x84, bf16,
-    window 7, chain 8): the pixel unpack sits under ``ddq.unpack``, and no
-    instruction of the program makes the window four words wide — the
-    ``u32[512,7,2048,4]`` broadcast, and its ``u8[512,7,8192]`` consumer,
-    by which the chip's compiler lowers a ``bitcast_convert_type`` to
-    uint8 (PERF.md §6, PR 32). ~25 s."""
+def _b512_learner(topo, window=None):
+    """The breakout preset's learner on one described chip and the fused
+    programs' spec for it (batch 512, 84x84, bf16, chain 8); ``window``
+    moves ``n_step`` off the preset's 3 (window 7) to give another."""
     import numpy as np
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from jax.sharding import Mesh
 
     from distributed_deep_q_tpu.config import PRESETS
-    from distributed_deep_q_tpu.models.qnet import build_qnet, init_params
-    from distributed_deep_q_tpu.parallel.learner import Learner, TrainState
+    from distributed_deep_q_tpu.models.qnet import build_qnet
+    from distributed_deep_q_tpu.parallel.learner import Learner
 
     cfg = PRESETS["breakout"]()
     rep = cfg.replay
-    stack, chain, batch = cfg.net.stack, rep.fused_chain, rep.batch_size
-    window, rowp = stack + rep.n_step, ROWB // 4
+    stack = cfg.net.stack
+    n_step = rep.n_step if window is None else window - stack
     mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1), ("dp", "model"))
     module = build_qnet(cfg.net)
     learner = Learner(lambda p, o: module.apply({"params": p}, o),
                       cfg.train, mesh)
-    spec = (250_000, 250_000 + window - 1, ROWB, 84 * 84, stack, rep.n_step,
-            cfg.train.gamma, (84, 84), batch, rep.priority_alpha,
-            rep.priority_eps, 1, False)
-    _, train = learner._build_device_per_step(spec, chain)
-    assert learner.unpack_planes == 1
+    spec = (250_000, 250_000 + stack + n_step - 1, ROWB, 84 * 84, stack,
+            n_step, cfg.train.gamma, (84, 84), rep.batch_size,
+            rep.priority_alpha, rep.priority_eps, 1, False)
+    return cfg, module, learner, mesh, spec
+
+
+def _sharded_aval(mesh):
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
     def S(shape, dtype, *axes):
         return jax.ShapeDtypeStruct(
             shape, dtype, sharding=NamedSharding(mesh, P(*axes)))
+    return S
+
+
+def _elements(shape: str) -> int:
+    """Element count of an HLO array shape like ``s32[8,512,7]{2,1,0}``;
+    0 for a tuple or a scalar."""
+    m = re.match(r"\w+\[([\d,]+)\]", shape)
+    return math.prod(int(d) for d in m[1].split(",")) if m else 0
+
+
+@pytest.mark.parametrize("window", [7, 5], ids=["window7", "window5"])
+def test_b512_sample_program_hands_the_windows_over_as_a_bitcast_for_v5e(
+        topo, one_chip, window):
+    """The sample program at the b512 cell's sizes (1M metadata rows in 4
+    slots, the ring of ``test_gather_windows``' breakout case, chain 8):
+    the chunk's windows — 8 x 512 x window padded rows of 2 048 words —
+    are written ONCE, by the Mosaic DMA, and leave the program as a
+    bitcast of that. ``[chain, batch, window, rowp]`` put 7 rows in the
+    sublanes of an (8, 128) tile: a ``reshape`` into the padded layout and
+    a ``copy`` out of it, each the whole chunk (PERF.md §6, PR 35).
+    25-110 s a case here: the draw over 1M rows is what compiles long."""
+    cfg, _, learner, mesh, spec = _b512_learner(topo, window)
+    chain, batch = cfg.replay.fused_chain, cfg.replay.batch_size
+    slots, slot_cap, slot_pad, rowp = 4, spec[0], spec[1], ROWB // 4
+    sample, _ = learner._build_device_per_step(spec, chain)
+    S = _sharded_aval(mesh)
+    meta = lambda dtype: S((slots * slot_cap,), dtype, "dp")  # noqa: E731
+    text = sample.lower(
+        S((1, chain, 2), jnp.uint32, "dp"),
+        S(((slots * slot_pad + 1) * rowp,), jnp.int32, "dp"),
+        meta(jnp.int32), meta(jnp.float32), meta(jnp.uint8),
+        meta(jnp.uint8), meta(jnp.float32),
+        S((slots,), jnp.int32, "dp"), S((slots,), jnp.int32, "dp"),
+        S((chain,), jnp.float32)).compile().as_text()
+
+    words = chain * batch * window * rowp
+    assert words == (58_720_256 if window == 7 else 41_943_040)
+    tiled = f"s32[{chain},{batch},{window},{rowp // 128},128]"
+    # every instruction whose RESULT is chunk-wide: its name and opcode
+    wide = [(m[1], m[3]) for m in re.finditer(
+        r"^\s*(?:ROOT )?(%[\w.-]+) = (\S+) ([\w-]+)\(", text, re.M)
+        if words == _elements(m[2])]
+    ops = sorted(op for _, op in wide)
+    assert ops == ["bitcast", "custom-call"], wide
+    assert next(n for n, op in wide if op == "custom-call").startswith(
+        "%sample_fn")
+    assert re.search(
+        re.escape(tiled) + r"\S* bitcast\(%sample_fn", text), wide
+
+
+def test_b512_train_program_unpacks_by_planes_for_v5e(topo, one_chip):
+    """The breakout preset's whole train program (batch 512, 84x84, bf16,
+    window 7, chain 8), fed the windows in the view the sample program
+    hands over: the pixel unpack sits under ``ddq.unpack``, and no
+    instruction of the program makes the window four words wide — the
+    ``u32[512,7,2048,4]`` broadcast, and its ``u8[512,7,8192]`` consumer,
+    by which the chip's compiler lowers a ``bitcast_convert_type`` to
+    uint8 (PERF.md §6, PR 32) — in the row's flat spelling or in its
+    ``(16, 128)`` one. ~25 s."""
+    from distributed_deep_q_tpu.models.qnet import init_params
+    from distributed_deep_q_tpu.parallel.learner import TrainState
+
+    cfg, module, learner, mesh, spec = _b512_learner(topo)
+    rep = cfg.replay
+    stack, chain, batch = cfg.net.stack, rep.fused_chain, rep.batch_size
+    window, rowp = stack + rep.n_step, ROWB // 4
+    _, train = learner._build_device_per_step(spec, chain)
+    assert learner.unpack_planes == 1
+    S = _sharded_aval(mesh)
 
     params = jax.eval_shape(lambda: init_params(module, cfg.net, 0, 4))
     state = jax.tree.map(
@@ -304,9 +375,12 @@ def test_b512_train_program_unpacks_by_planes_for_v5e(topo, one_chip):
              "ovalid": mask, "nvalid": mask}
     text = train.lower(
         state, metas,
-        S((chain, batch, window, rowp), jnp.int32, None, "dp", None, None),
+        S((chain, batch, window, rowp // 128, 128), jnp.int32,
+          None, "dp", None, None, None),
         row(jnp.int32), S((1_000_000,), jnp.float32, "dp"),
         S((), jnp.float32)).compile().as_text()
     assert "ddq.unpack" in text
-    assert f"[{batch},{window},{rowp},4]" not in text
-    assert f"u8[{batch},{window},{4 * rowp}]" not in text
+    for words, bytes_ in ((f"{rowp}", f"{4 * rowp}"),
+                          (f"{rowp // 128},128", f"{rowp // 128},512")):
+        assert f"[{batch},{window},{words},4]" not in text
+        assert f"u8[{batch},{window},{bytes_}]" not in text

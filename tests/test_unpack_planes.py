@@ -11,6 +11,11 @@ step's loss, Q-values and gradients on numpy-decoded pixels (float32
 compute here, so the comparison is tight), a width off the grid of 4 must
 take the bitcast and say so in ``train/unpack_planes``, and the parameter
 tree must be what it was.
+
+The windows reach the train program as the sample program hands them over
+since PR 35: ``[chain, batch, window, rowp // 128, 128]``, a row's words in
+the two tiled dims (``ops/ring_gather.tile_rows``) — word for word the
+ring's rows, which the last test here holds the sample program to.
 """
 
 import jax
@@ -19,8 +24,10 @@ import numpy as np
 import pytest
 
 from distributed_deep_q_tpu.config import Config, NetConfig, ReplayConfig
-from distributed_deep_q_tpu.ops.ring_gather import padded_row_bytes
-from distributed_deep_q_tpu.replay.device_per import window_to_obs
+from distributed_deep_q_tpu.ops.ring_gather import (
+    LANES, flat_rows, padded_row_bytes, tile_rows)
+from distributed_deep_q_tpu.replay.device_per import (
+    DevicePERFrameReplay, window_to_obs)
 from distributed_deep_q_tpu.solver import Solver
 
 STACK, N_STEP, CAP = 4, 3, 256
@@ -30,7 +37,7 @@ ALPHA, EPS = 0.6, 1e-6
 
 
 def _solver(frame, kind="nature_cnn", stack_forwards="auto", double=True,
-            batch=BATCH):
+            batch=BATCH, n_step=N_STEP):
     cfg = Config()
     cfg.mesh.backend = "cpu"
     cfg.mesh.dp = 1
@@ -38,7 +45,7 @@ def _solver(frame, kind="nature_cnn", stack_forwards="auto", double=True,
                         compute_dtype="float32", hidden=(32,))
     cfg.train.stack_forwards = stack_forwards
     cfg.train.double_dqn = double
-    cfg.replay = ReplayConfig(capacity=CAP, batch_size=batch, n_step=N_STEP,
+    cfg.replay = ReplayConfig(capacity=CAP, batch_size=batch, n_step=n_step,
                               prioritized=True, device_per=True)
     return Solver(cfg, obs_dim=frame[0] * frame[1] * STACK)   # mlp's input
 
@@ -73,7 +80,8 @@ def _windows(frame, chain, seed=0, batch=BATCH):
         "ovalid": ovalid, "nvalid": nvalid}
     idxs = np.stack([rng.permutation(CAP)[:batch] for _ in range(chain)]
                     ).astype(np.int32)
-    return pix, padded.view(np.int32), metas, idxs
+    win = tile_rows(padded.view(np.int32).reshape(-1), chain, batch, WINDOW)
+    return pix, win, metas, idxs
 
 
 def _pixels(frame, pix, first, valid):
@@ -116,8 +124,8 @@ def test_window_to_obs_is_the_pixels(frame, first):
     the window's pixels bit for bit, masked frames zero."""
     pix, win, metas, _ = _windows(frame, 1, seed=3, batch=6)
     valid = metas["nvalid" if first else "ovalid"][0]
-    got = window_to_obs(jnp.asarray(win[0]), first, jnp.asarray(valid),
-                        frame[0] * frame[1], frame)
+    got = window_to_obs(flat_rows(jnp.asarray(win[0])), first,
+                        jnp.asarray(valid), frame[0] * frame[1], frame)
     assert got.dtype == jnp.uint8
     np.testing.assert_array_equal(np.asarray(got),
                                   _pixels(frame, pix[0], first, valid))
@@ -198,3 +206,42 @@ def test_parameter_tree_is_what_it_was():
         "['torso']['fc4']['bias']": (512,),
         "['torso']['fc4']['kernel']": (3136, 512),
     }
+
+
+@pytest.mark.parametrize("window,batch", [(7, 128), (5, 6)],
+                         ids=["window7-b128", "window5-b6"])
+def test_sample_program_hands_over_the_ring_rows_word_for_word(window,
+                                                               batch):
+    """The sample program's windows (the DMA kernel in interpret mode),
+    their last two dims flattened, are ``[chain, batch, window, rowp]`` of
+    the ring's own words: row ``k`` of sample ``b`` is ring row
+    ``start(b) + k``, padding included — what the program returned before
+    the view changed (PR 35). Both batch sizes, both windows: the view
+    depends on neither."""
+    frame, chain, n_step = (36, 36), 2, window - STACK
+    solver = _solver(frame, batch=batch, n_step=n_step)
+    cfg = solver.config
+    dev = DevicePERFrameReplay(cfg.replay, solver.mesh, frame, stack=STACK,
+                               gamma=0.99, seed=0, write_chunk=16)
+    rng = np.random.default_rng(window)
+    for i in range(CAP + 40):       # past one lap: the ghost rows matter
+        dev.add(rng.integers(0, 256, frame, dtype=np.uint8),
+                int(rng.integers(4)), float(rng.standard_normal()),
+                done=(i % 11 == 10))
+    dev.flush()
+    sample, _ = solver.learner.device_per_programs(
+        solver.device_per_spec(dev), chain)
+    cursors, sizes = dev.device_inputs()
+    keys = rng.integers(0, 2**32, (dev.num_shards, chain, 2), np.uint32)
+    rows = dev.dstate
+    _, win, idx = sample(keys, rows.frames, rows.action, rows.reward,
+                         rows.done, rows.boundary, rows.prio, cursors,
+                         sizes, np.full(chain, 0.4, np.float32))
+    rowp = dev.rowb // 4
+    assert win.shape == (chain, batch, window, rowp // LANES, LANES)
+    ring = np.asarray(rows.frames).reshape(-1, rowp)
+    sub, local = np.divmod(np.asarray(idx), dev.slot_cap)
+    start = sub * dev.slot_pad + (local - (STACK - 1)) % dev.slot_cap
+    want = ring[start[..., None] + np.arange(window)]
+    assert want.shape == (chain, batch, window, rowp) and want.any()
+    np.testing.assert_array_equal(np.asarray(flat_rows(win)), want)
