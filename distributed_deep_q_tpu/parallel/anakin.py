@@ -359,7 +359,8 @@ class AnakinRunner:
 
                 (loss, (td_abs, q)), gv = jax.value_and_grad(
                     loss_fn, has_aux=True)(plane_stacked_views(meta, pt))
-                g = jnp.concatenate([x[0].reshape(-1) for x in gv])
+                with jax.named_scope("ddq.grad_plane"):
+                    g = jnp.concatenate([x[0].reshape(-1) for x in gv])
                 g = lax.pmean(g, AXIS_DP)
                 loss = lax.pmean(loss, AXIS_DP)
                 q_mean = lax.pmean(jnp.mean(q), AXIS_DP)

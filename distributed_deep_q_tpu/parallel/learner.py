@@ -139,6 +139,7 @@ def fused_adam_step(cfg: TrainConfig, grads: Any, opt_state: Any,
     return opt_state, params
 
 
+@jax.named_scope("ddq.optimizer")
 def fused_adam_target_step(
     cfg: TrainConfig, grads: Any, opt_state: Any, params: Any,
     target_params: Any, gnorm: jax.Array, step: jax.Array | None,
@@ -270,6 +271,7 @@ def plane_meta(params: Any) -> PlaneMeta:
     return PlaneMeta(treedef, shapes, sizes, offsets, int(sum(sizes)))
 
 
+@jax.named_scope("ddq.plane_pack")
 def params_to_plane(meta: PlaneMeta, params: Any,
                     target_params: Any) -> jax.Array:
     """Interleave θ/θ⁻ into the [2N] PT plane (leaf blocks adjacent)."""
@@ -281,6 +283,7 @@ def params_to_plane(meta: PlaneMeta, params: Any,
     return jnp.concatenate(blocks)
 
 
+@jax.named_scope("ddq.plane_pack")
 def tree_to_plane(tree: Any) -> jax.Array:
     """Ravel-and-concat a tree into its [N] plane (moment planes keep
     their storage dtype so per-step round trips stay bitwise)."""
@@ -296,6 +299,7 @@ def plane_stacked_views(meta: PlaneMeta, pt: jax.Array) -> tuple:
         for off, size, shape in zip(meta.offsets, meta.sizes, meta.shapes))
 
 
+@jax.named_scope("ddq.plane_unpack")
 def plane_to_param_trees(meta: PlaneMeta, pt: jax.Array,
                          params: Any, target_params: Any) -> tuple:
     """Inverse of ``params_to_plane`` — dtypes restored per template."""
@@ -311,6 +315,7 @@ def plane_to_param_trees(meta: PlaneMeta, pt: jax.Array,
             jax.tree_util.tree_unflatten(meta.treedef, new_t))
 
 
+@jax.named_scope("ddq.plane_unpack")
 def plane_to_tree(meta: PlaneMeta, plane: jax.Array,
                   template: Any) -> Any:
     """Slice an [N] plane back into ``template``'s tree structure."""
@@ -322,6 +327,7 @@ def plane_to_tree(meta: PlaneMeta, plane: jax.Array,
     return jax.tree_util.tree_unflatten(meta.treedef, leaves)
 
 
+@jax.named_scope("ddq.optimizer")
 def fused_plane_adam_target_step(
     cfg: TrainConfig, meta: PlaneMeta, g: jax.Array, m: jax.Array,
     v: jax.Array, count: jax.Array, pt: jax.Array, step: jax.Array,
@@ -381,6 +387,7 @@ def fused_plane_adam_target_step(
     return m2.astype(jnp.dtype(cfg.adam_mu_dtype)), v2, pt2, count2
 
 
+@jax.named_scope("ddq.loss")
 def q_step_loss(cfg: TrainConfig, q: jax.Array, q_next_o: jax.Array | None,
                 q_next_t: jax.Array, batch: dict[str, jax.Array],
                 interpret: bool):
@@ -763,7 +770,8 @@ class Learner:
                     loss_fn, has_aux=True)(plane_stacked_views(meta, pt))
                 # online halves only — the target halves carry zero
                 # cotangents (targets are stop-gradded in the loss)
-                g = jnp.concatenate([x[0].reshape(-1) for x in gv])
+                with jax.named_scope("ddq.grad_plane"):
+                    g = jnp.concatenate([x[0].reshape(-1) for x in gv])
                 g = lax.pmean(g, AXIS_DP)
                 loss = lax.pmean(loss, AXIS_DP)
                 q_mean = lax.pmean(jnp.mean(q), AXIS_DP)

@@ -52,6 +52,8 @@ from distributed_deep_q_tpu.parallel.learner import (
     TrainState, clip_grads, fused_adam_target_step, make_optimizer,
     refresh_target)
 from distributed_deep_q_tpu.parallel.mesh import AXIS_DP
+from distributed_deep_q_tpu.profiling import (
+    outputs_as_avals, ran_executable)
 from distributed_deep_q_tpu.parallel.multihost import (
     global_batch, put_replicated)
 
@@ -776,6 +778,25 @@ class SequenceSolver:
             replay.dmeta["prio"] = prio
             replay.dmaxp = maxp
         return dict(metrics)
+
+    def fused_executables(self, replay, chain: int) -> dict[str, Any]:
+        """``Solver.fused_executables`` for the token ring's fused step:
+        the two executables the loop ran, found again from the types and
+        shardings of its arguments (nothing executes, no key is drawn)."""
+        if getattr(replay, "window_kind", "frames") != "tokens":
+            raise NotImplementedError(
+                "only the token ring's fused programs are found again")
+        sample, train = self.learner.token_fused_programs(
+            replay, self.config.replay.batch_size, chain)
+        sampled = ran_executable(
+            sample, np.zeros((replay.num_shards, chain, 2), np.uint32),
+            replay.ring, replay.dmeta["prio"],
+            np.asarray(replay.device_inputs()),
+            np.full(chain, 0.5, np.float32))
+        batch, idx = outputs_as_avals(sampled)
+        return {"sample": sampled, "train": ran_executable(
+            train, self.state, batch, idx, replay.dmeta["prio"],
+            replay.dmaxp)}
 
     # -- recurrent actor path ----------------------------------------------
 

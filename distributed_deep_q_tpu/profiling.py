@@ -27,10 +27,13 @@ rebuild gets two first-class tools:
 from __future__ import annotations
 
 import contextlib
+import json
+import os
 import re
 import time
+import weakref
 from collections import defaultdict
-from typing import Iterator
+from typing import Any, Callable, Iterator
 
 import jax
 import numpy as np
@@ -166,6 +169,190 @@ def _count_into(counts: dict[str, int], opcodes: list[str]) -> None:
             counts[op] += 1
 
 
+# -- the scope table: which device operation belongs to which ddq.* name ----
+#
+# A device trace names every event by its HLO instruction (``%fusion.319``),
+# and the numbering moves with every change to a program. The name a piece
+# of work was TRACED under (``jax.named_scope("ddq.conv_in")``) is kept in
+# the compiled module's text, in the instruction's ``op_name``; this turns
+# that text into the table a reader needs to give a trace's device time to
+# the program's own names. ``TraceWindow`` writes it beside every trace.
+
+SCOPES_FILE = "ddq_scopes.json"
+_SCOPE_RE = re.compile(r"ddq\.[a-z_]+")
+_HLO_INSTR_RE = re.compile(
+    r"^\s*(ROOT )?%(\S+) = (?:\(.*?\)|\S+) ([a-z][\w\-]*)\(", re.M)
+_HLO_HEADER_RE = re.compile(_HLO_COMP_RE.pattern, re.M)
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+_FUSION_CALLS_RE = re.compile(r"\bcalls=%([\w.\-]+)")
+_HLO_OPERAND_RE = re.compile(r"%([\w.\-]+)")
+_HLO_MODULE_RE = re.compile(r"^HloModule ([\w.\-]+)")
+# their device time is their bodies': a reader that summed them too would
+# count every operation of a scan twice
+_HLO_CONTAINERS = frozenset({"while", "conditional", "call"})
+_HLO_NO_EVENT = _HLO_CONTAINERS | {"parameter", "constant"}
+
+
+def _scope_stack(body: str) -> list[str] | None:
+    """The ``ddq.*`` names of an instruction's ``op_name``, outermost
+    first; ``None`` for an instruction the COMPILER made (no ``op_name``
+    of the traced program: none at all, or an expander's own, such as
+    ``reduce_window_sum``). Transform wrappers
+    (``transpose(jvp(ddq.conv_in))``) keep the name inside them; a name
+    nested in itself is given once."""
+    m = _OP_NAME_RE.search(body)
+    if m is None or not m.group(1).startswith(("jit(", "pjit(")):
+        return None
+    stack: list[str] = []
+    for name in _SCOPE_RE.findall(m.group(1)):
+        if not stack or stack[-1] != name:
+            stack.append(name)
+    return stack
+
+
+def scope_table(hlo_text: str) -> dict[str, dict]:
+    """From a compiled module's text::
+
+        {"scopes": {instruction: [ddq.* scopes, outermost first]},
+         "mixed": {fusion: [the innermost scopes of its fused instructions]},
+         "inherited": {instruction: the neighbour its scopes are from}}
+
+    An instruction's text runs to the next one's (a Mosaic call's
+    metadata spans lines). A ``fusion`` carries one event for everything
+    it fused: it takes its own ``op_name`` (XLA copies the root's), where
+    that has no scope the scopes of the last scoped instruction of the
+    computation it ``calls``; and where its fused instructions lie under
+    MORE than one innermost scope it is listed under ``mixed`` too, so a
+    reader can say how much time sits in fusions that no single name
+    owns. An instruction the compiler made (a relayout ``copy``, the
+    ``reduce-window`` passes a ``cumsum`` expands to, an asynchronous
+    ``slice-start``) has no name stack of its own: it takes the scopes of
+    its first operand that has any, else of its first user's, and is
+    listed under ``inherited``. What the program traced under no
+    ``ddq.*`` scope stays out (the window gather's Mosaic call), as do
+    containers (``while`` / ``conditional`` / ``call``), parameters and
+    constants: none of them is a device event of its own."""
+    starts = list(_HLO_INSTR_RE.finditer(hlo_text))
+    headers = [(m.start(), m.group(1).lstrip("%"))
+               for m in _HLO_HEADER_RE.finditer(hlo_text)]
+    headers.append((len(hlo_text), ""))
+    stacks: dict[str, list[str] | None] = {}
+    operands: dict[str, list[str]] = {}
+    members: dict[str, list[str]] = {}     # computation -> its instructions
+    fusions: dict[str, str] = {}           # fusion -> computation it calls
+    no_event: set[str] = set()
+    comp, h = "", 0
+    for m, nxt in zip(starts, starts[1:] + [None]):
+        while headers[h][0] < m.start():    # a computation opened above
+            comp = headers[h][1]
+            h += 1
+        end = nxt.start() if nxt else len(hlo_text)
+        body = hlo_text[m.end():min(end, headers[h][0])]
+        name, opcode = m.group(2), m.group(3)
+        members.setdefault(comp, []).append(name)
+        operands[name] = _HLO_OPERAND_RE.findall(body[:body.find(")") + 1])
+        stacks[name] = _scope_stack(body)
+        if opcode in _HLO_NO_EVENT:
+            no_event.add(name)      # a neighbour to inherit from, no more
+        elif opcode == "fusion":
+            called = _FUSION_CALLS_RE.search(body)
+            if called:
+                fusions[name] = called.group(1)
+    mixed: dict[str, list[str]] = {}
+    for name, called in fusions.items():
+        inside = [stacks[i] for i in members.get(called, ())
+                  if stacks.get(i)]
+        if not stacks[name] and inside:
+            stacks[name] = inside[-1]
+        innermost = sorted({st[-1] for st in inside})
+        if len(innermost) > 1:
+            mixed[name] = innermost
+    inherited: dict[str, str] = {}
+    for names in members.values():
+        users: dict[str, list[str]] = {}
+        for name in names:                  # operands come first in a text
+            for op in operands[name]:
+                users.setdefault(op, []).append(name)
+            _inherit(name, operands[name], stacks, inherited)
+        for name in reversed(names):
+            _inherit(name, users.get(name, ()), stacks, inherited)
+    return {"scopes": {k: v for k, v in stacks.items()
+                       if v and k not in no_event},
+            "mixed": mixed,
+            "inherited": {k: v for k, v in inherited.items()
+                          if k not in no_event}}
+
+
+def _inherit(name: str, neighbours, stacks: dict, inherited: dict) -> None:
+    if name in stacks and stacks[name] is None:
+        for other in neighbours:
+            if stacks.get(other):
+                stacks[name], inherited[name] = stacks[other], other
+                return
+
+
+def hlo_module_name(hlo_text: str) -> str:
+    """``jit_tree_train_fn``: the name a trace's ``XLA Modules`` line
+    gives the program's executions."""
+    m = _HLO_MODULE_RE.match(hlo_text)
+    return m.group(1) if m else "unknown"
+
+
+# Whoever owns device programs says so once, where it is built — never on
+# a step's path: ``owner`` is kept weakly, and ``source(owner)`` answers
+# ``{label: executable}`` for the programs it runs, without executing or
+# compiling any (``ran_executable``). ``source`` must not hold the owner.
+_PROGRAM_SOURCES: "weakref.WeakKeyDictionary[Any, Callable[[Any], dict]]" \
+    = weakref.WeakKeyDictionary()
+
+
+def register_programs(owner: Any, source: Callable[[Any], dict]) -> None:
+    _PROGRAM_SOURCES[owner] = source
+
+
+def ran_executable(jitted, *args):
+    """The executable ``jitted`` already holds for these arguments (arrays,
+    or avals with the shardings the call had): lowering finds the traced
+    program, ``compile`` the executable of the call that RAN — nothing is
+    compiled again and nothing executes. Arguments the program never met
+    are lowered and compiled like any first call."""
+    return jitted.lower(*args).compile()
+
+
+def outputs_as_avals(compiled):
+    """A program's outputs as the next program's arguments: shapes with
+    the shardings the executable gives them."""
+    return jax.tree.map(
+        lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+        compiled.out_info, compiled.output_shardings)
+
+
+def write_scope_tables(logdir: str) -> str:
+    """``<logdir>/ddq_scopes.json``: the scope table of every program the
+    registered loops run, by module name; a program no table could be
+    made for is named under ``unavailable`` with the reason."""
+    t0 = time.perf_counter()
+    programs: dict[str, Any] = {}
+    unavailable: dict[str, str] = {}
+    sources = list(_PROGRAM_SOURCES.items())
+    for owner, source in sources:
+        try:
+            for exe in source(owner).values():
+                text = exe.as_text()
+                programs[hlo_module_name(text)] = scope_table(text)
+        except Exception as e:  # noqa: BLE001 — the reason is the record
+            unavailable[source.__qualname__] = f"{type(e).__name__}: {e}"
+    if not sources:
+        unavailable["*"] = ("no loop registered its programs "
+                            "(profiling.register_programs)")
+    path = os.path.join(logdir, SCOPES_FILE)
+    os.makedirs(logdir, exist_ok=True)
+    with open(path, "w") as fh:
+        json.dump({"programs": programs, "unavailable": unavailable,
+                   "scope_table_s": time.perf_counter() - t0}, fh)
+    return path
+
+
 class StepTimer:
     """Accumulates per-phase wall time across train-loop steps.
 
@@ -285,25 +472,32 @@ def _cost_flops(compiled) -> float | None:
     return flops if flops > 0 else None
 
 
-def compile_fused_train(solver, replay, chain: int):
-    """The FUSED train program for ``replay``'s geometry, compiled ahead
-    of time from avals alone (``eval_shape`` of the sample program: no
-    device sample execution, no sampling-key-stream side effect) — the
-    one artifact the flops census, the op census and the chip smoke's
-    all-reduce check read. Builds the program pair when the train
-    loop has not run yet."""
+def fused_executables(solver, replay, chain: int) -> dict[str, Any]:
+    """The fused step's ``sample`` and ``train`` executables for
+    ``replay``'s geometry, from avals alone: no device sample execution,
+    no sampling-key-stream side effect. The arguments have the types and
+    shardings the train loop's have, so where the loop has run these ARE
+    its executables (``ran_executable``); before it the pair is built and
+    compiled."""
     sample, train = solver.learner.device_per_programs(
         solver.device_per_spec(replay), chain)
     cursors, sizes = replay.device_inputs()
     betas = np.full(chain, 0.5, np.float32)
     keys = np.zeros((replay.num_shards, chain, 2), np.uint32)
     rows = replay.dstate
-    metas, win, idx = jax.eval_shape(
-        sample, keys, rows.frames, rows.action, rows.reward,
-        rows.done, rows.boundary, rows.prio, np.asarray(cursors),
-        np.asarray(sizes), betas)
-    return train.lower(solver.state, metas, win, idx, rows.prio,
-                       rows.maxp).compile()
+    sampled = ran_executable(
+        sample, keys, rows.frames, rows.action, rows.reward, rows.done,
+        rows.boundary, rows.prio, np.asarray(cursors), np.asarray(sizes),
+        betas)
+    metas, win, idx = outputs_as_avals(sampled)
+    return {"sample": sampled, "train": ran_executable(
+        train, solver.state, metas, win, idx, rows.prio, rows.maxp)}
+
+
+def compile_fused_train(solver, replay, chain: int):
+    """The fused TRAIN program's executable — the one artifact the flops
+    census, the op census and the chip smoke's all-reduce check read."""
+    return fused_executables(solver, replay, chain)["train"]
 
 
 def fused_train_flops(solver, replay, chain: int) -> float | None:
@@ -419,6 +613,9 @@ class TraceWindow:
             jax.profiler.stop_trace()
             self._active = False
             self._done = True
+            # after the capture, so outside the traced span: which device
+            # operation of the trace belongs to which ddq.* name
+            write_scope_tables(self.logdir)
 
     close = stop
 

@@ -37,6 +37,8 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from distributed_deep_q_tpu.parallel.mesh import AXIS_DP
+from distributed_deep_q_tpu.profiling import (
+    ran_executable, register_programs)
 from distributed_deep_q_tpu.replay.device_ring import DeviceFrameReplay
 
 
@@ -257,6 +259,7 @@ def compose_from_state(state_rows: dict[str, jax.Array], local: jax.Array,
     }
 
 
+@jax.named_scope("ddq.sample_prep")
 def fused_sample_prep(shard_rows: dict[str, jax.Array],
                       cursors: jax.Array, sizes: jax.Array,
                       slot_cap: int, stack: int, n_step: int):
@@ -370,6 +373,7 @@ def stratified_is_weights(p: jax.Array, mass: jax.Array,
     return (w / jnp.maximum(w_max[:, None], 1e-12)).astype(jnp.float32)
 
 
+@jax.named_scope("ddq.meta_pack")
 def build_meta_pack(action: jax.Array, reward: jax.Array, done: jax.Array,
                     boundary: jax.Array, slot_cap: int, stack: int,
                     n_step: int, gamma: float) -> jax.Array:
@@ -414,6 +418,7 @@ def build_meta_pack(action: jax.Array, reward: jax.Array, done: jax.Array,
     return jnp.stack(lanes, axis=-1).reshape(-1, 3 + stack)
 
 
+@jax.named_scope("ddq.draw")
 def fused_sample_draw_packed(keys: jax.Array, pack: jax.Array,
                              pm: jax.Array, cdf: jax.Array, mass: jax.Array,
                              n_glob: jax.Array, per_shard: int,
@@ -489,6 +494,7 @@ def fused_sample(key: jax.Array, shard_rows: dict[str, jax.Array],
     return batch, idx
 
 
+@jax.named_scope("ddq.priority_writeback")
 def scatter_priorities(prio: jax.Array, maxp: jax.Array, idx: jax.Array,
                        td_abs: jax.Array, alpha: float, eps: float,
                        ) -> tuple[jax.Array, jax.Array]:
@@ -688,6 +694,7 @@ class DevicePERFrameReplay(DeviceFrameReplay):
             in_shardings=(state_fmt,) + (None,) * 8,
             out_shardings=state_fmt,
             donate_argnums=0)
+        register_programs(self, DevicePERFrameReplay._write_executable)
 
     # -- padded frame plane --------------------------------------------------
 
@@ -746,11 +753,12 @@ class DevicePERFrameReplay(DeviceFrameReplay):
             m.boundary[local].astype(np.uint8)))
         self._di_cache = None  # cursors/sizes moved
 
-    def _apply_write(self, idx, cols) -> None:
-        """Route each padded chunk ([local_shards, k] planes) to the fused
-        write: metadata scatters (real coords, fresh-row priorities seeded
-        from the device max) + the frame-row DMA plane (padded coords,
-        ghost duplicates, padding lanes → the scratch row). Multi-host:
+    def _write_args(self, idx, cols) -> tuple:
+        """Each padded chunk ([local_shards, k] planes) as the fused
+        write's arguments: metadata scatters (real coords, fresh-row
+        priorities seeded from the device max) + the frame-row DMA plane
+        (padded coords, ghost duplicates, padding lanes → the scratch
+        row). Multi-host:
         every plane assembles this process's local rows into the global
         P('dp') arrays; every process enters this program in lockstep
         (``flush``'s agreed round count)."""
@@ -774,14 +782,27 @@ class DevicePERFrameReplay(DeviceFrameReplay):
         else:
             staged = np.ascontiguousarray(cols[0]).reshape(dl, -1).view(
                 np.int32)
-        self.dstate = self._write_full(
-            self.dstate,
-            self.to_global(idx.reshape(-1)),
-            *(self.to_global(c.reshape((dl * k,) + t))
-              for c, (t, _) in zip(cols[1:], self._stage_columns[1:])),
-            self.to_global(sidx.reshape(-1)),
-            self.to_global(didx.reshape(-1)),
-            self.to_global(staged.reshape(-1)))
+        return (self.to_global(idx.reshape(-1)),
+                *(self.to_global(c.reshape((dl * k,) + t))
+                  for c, (t, _) in zip(cols[1:], self._stage_columns[1:])),
+                self.to_global(sidx.reshape(-1)),
+                self.to_global(didx.reshape(-1)),
+                self.to_global(staged.reshape(-1)))
+
+    def _apply_write(self, idx, cols) -> None:
+        self.dstate = self._write_full(self.dstate,
+                                       *self._write_args(idx, cols))
+
+    def _write_executable(self) -> dict:
+        """The flush program's executable, found again from an all-padding
+        round's arguments (what a ``TraceWindow`` writes the scope table
+        of): nothing is written."""
+        dl, k = len(self.local_shards), self.write_chunk
+        idx = np.full((dl, k), self.cap_local, np.int32)
+        cols = [np.zeros((dl, k) + tail, dt)
+                for tail, dt in self._stage_columns]
+        return {"write": ran_executable(
+            self._write_full, self.dstate, *self._write_args(idx, cols))}
 
     # -- multi-host plumbing -------------------------------------------------
 

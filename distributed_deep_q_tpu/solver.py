@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from distributed_deep_q_tpu import tracing
+from distributed_deep_q_tpu import profiling, tracing
 from distributed_deep_q_tpu.config import Config
 from distributed_deep_q_tpu.models.qnet import build_qnet, init_params
 from distributed_deep_q_tpu.parallel.learner import Learner, TrainState
@@ -238,6 +238,11 @@ class Solver:
     def _next_sample_keys(self, num_shards: int, chain: int) -> np.ndarray:
         return next_fused_keys(self, num_shards, chain)
 
+    def fused_executables(self, replay, chain: int) -> dict[str, Any]:
+        """The executables of the fused step's two programs (the ones the
+        loop ran, where it has run: nothing executes)."""
+        return profiling.fused_executables(self, replay, chain)
+
     def fused_gauges(self) -> dict[str, int]:
         """Static gauges of the fused step for a train loop's log rows:
         ``train/unpack_planes`` 1 = its train program unpacks the pixel
@@ -359,6 +364,12 @@ class FusedStepStream:
         # never carries the odd-shaped leaf;
         # drained by the train loop at log cadence (drain_planes)
         self._planes: list[Any] = []
+        profiling.register_programs(self, FusedStepStream._executables)
+
+    def _executables(self) -> dict[str, Any]:
+        """What a ``TraceWindow`` writes the scope tables of."""
+        return self._solver.fused_executables(self._replay,
+                                              self._len or self.chain)
 
     def drain_planes(self) -> list[Any]:
         """Hand back (and clear) the accumulated learning-dynamics

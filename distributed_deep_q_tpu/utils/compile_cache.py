@@ -10,6 +10,15 @@ One rule for every entry point that holds the chip (``main.main``,
   the directory is part of the cache key, so a temp name, a pid or a
   timestamp would never hit.
 
+Whichever it is, a program's metadata is part of its cache key
+(``jax_compilation_cache_include_metadata_in_key``): the ``ddq.*`` scope
+names live in that metadata and ``profiling.scope_table`` reads them back
+out of the executable, so an entry another commit wrote for the same
+operations under OTHER names must not be handed to this one (JAX's default
+would: PERF.md §6, PR 36). The price: a commit that moves a traced line
+compiles that program cold once — as the programs that hold a Mosaic
+kernel already did, whose serialized body carries its locations.
+
 Child processes: the in-code setting does not travel (spawned actors and
 multi-process workers start from a fresh import and never call this), so
 only an exported ``JAX_COMPILATION_CACHE_DIR`` reaches them.
@@ -33,11 +42,12 @@ _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
 
 def place_compile_cache() -> str:
     """Apply the rule above; returns the directory in use."""
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     path = os.environ.get(ENV_VAR)
     if path:
         return path
-    import jax
-
     path = os.path.join(_CHECKOUT, ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     return path

@@ -22,7 +22,7 @@ def toy_fused_pair():
     """Builder of a real fused pair at toy size — ``build(chain)`` gives
     the solver and its filled device-PER ring — for the tests that drive
     ``FusedStepStream`` over real programs."""
-    def build(chain: int):
+    def build(chain: int, patch=None):
         import numpy as np
 
         from distributed_deep_q_tpu.config import (
@@ -39,6 +39,8 @@ def toy_fused_pair():
         cfg.replay = ReplayConfig(capacity=512, batch_size=16, n_step=2,
                                   prioritized=True, device_per=True,
                                   write_chunk=16, fused_chain=chain)
+        if patch is not None:
+            patch(cfg)          # e.g. the tree body at a toy batch
         solver = Solver(cfg)
         dev = DevicePERFrameReplay(cfg.replay, solver.mesh, (36, 36),
                                    stack=4, gamma=0.99, seed=0,

@@ -26,11 +26,32 @@ def test_cpu_mesh_interprets_pallas_kernels():
     assert pallas_interpret(mesh) is True
 
 
+METADATA_IN_KEY = "jax_compilation_cache_include_metadata_in_key"
+
+
 @pytest.fixture
 def restore_cache_dir():
     was = jax.config.jax_compilation_cache_dir
+    keyed = getattr(jax.config, METADATA_IN_KEY)
     yield
     jax.config.update("jax_compilation_cache_dir", was)
+    jax.config.update(METADATA_IN_KEY, keyed)
+
+
+@pytest.mark.parametrize("env", [True, False], ids=["env_dir", "checkout"])
+def test_cache_helper_keys_programs_by_their_scope_names(
+        monkeypatch, tmp_path, restore_cache_dir, env):
+    """The ``ddq.*`` names are read back out of executables
+    (``profiling.scope_table``): an entry another commit wrote for the same
+    operations under other names must miss (ISSUE 36), wherever the cache
+    lives."""
+    if env:
+        monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
+    else:
+        monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    jax.config.update(METADATA_IN_KEY, False)
+    compile_cache.place_compile_cache()
+    assert getattr(jax.config, METADATA_IN_KEY) is True
 
 
 def test_cache_helper_leaves_config_alone_when_env_names_a_dir(

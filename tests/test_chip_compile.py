@@ -22,6 +22,7 @@ from jax.sharding import SingleDeviceSharding
 from distributed_deep_q_tpu.ops.pallas_kernels import fused_dqn_loss
 from distributed_deep_q_tpu.ops.ring_gather import (
     gather_windows, padded_row_bytes, scatter_rows)
+from distributed_deep_q_tpu.profiling import scope_table
 
 ROWB = padded_row_bytes(84 * 84)        # 8192 B per 84x84 frame row
 # breakout/apex preset: 1M frames, 4 sub-rings, window = stack 4 + n_step 3
@@ -336,8 +337,21 @@ def test_b512_sample_program_hands_the_windows_over_as_a_bitcast_for_v5e(
         if words == _elements(m[2])]
     ops = sorted(op for _, op in wide)
     assert ops == ["bitcast", "custom-call"], wide
-    assert next(n for n, op in wide if op == "custom-call").startswith(
-        "%sample_fn")
+    kernel = next(n for n, op in wide if op == "custom-call")
+    assert kernel.startswith("%sample_fn")
+    # the program's scope table of this text (ISSUE 36): the three sample
+    # scopes partition what ran under ``ddq.sample``, the compiler's own
+    # passes (the prefix sums' ``reduce-window``s, relayout copies) took a
+    # neighbour's scope, and the kernel stays outside every scope — XLA
+    # would name it after one, and ``gather_windows_roofline`` finds it as
+    # ``%sample_fn.N``
+    table = scope_table(text)
+    assert {st[-1] for st in table["scopes"].values()} == {
+        "ddq.sample_prep", "ddq.meta_pack", "ddq.draw"}
+    assert kernel.lstrip("%") not in table["scopes"]
+    made = [i for i in table["inherited"] if i.startswith("reduce-window")]
+    assert made and all(
+        table["scopes"][i][-1] == "ddq.sample_prep" for i in made)
     assert re.search(
         re.escape(tiled) + r"\S* bitcast\(%sample_fn", text), wide
 
@@ -380,6 +394,10 @@ def test_b512_train_program_unpacks_by_planes_for_v5e(topo, one_chip):
         row(jnp.int32), S((1_000_000,), jnp.float32, "dp"),
         S((), jnp.float32)).compile().as_text()
     assert "ddq.unpack" in text
+    innermost = {st[-1] for st in scope_table(text)["scopes"].values()}
+    assert innermost == {
+        "ddq.train", "ddq.unpack", "ddq.conv_in", "ddq.conv_mid", "ddq.fc",
+        "ddq.loss", "ddq.optimizer", "ddq.priority_writeback"}
     for words, bytes_ in ((f"{rowp}", f"{4 * rowp}"),
                           (f"{rowp // 128},128", f"{rowp // 128},512")):
         assert f"[{batch},{window},{words},4]" not in text
