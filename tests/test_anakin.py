@@ -144,6 +144,7 @@ def test_anakin_trains_signal_end_to_end():
     from distributed_deep_q_tpu.parallel.anakin import AnakinRunner
 
     cfg = _anakin_config(capacity=2048)
+    cfg.mesh.dp = 1
     cfg.train.lr = 3e-3
     runner = AnakinRunner(cfg)
     for _ in range(39):

@@ -128,7 +128,7 @@ def test_train_loop_checkpoint_and_resume(tmp_path):
     cfg.train = TrainConfig(
         total_steps=300, train_every=1, target_update_period=50,
         checkpoint_dir=str(tmp_path / "ck"), checkpoint_every=100)
-    cfg.mesh = MeshConfig(backend="cpu", num_fake_devices=2, dp=2)
+    cfg.mesh = MeshConfig(backend="cpu", dp=1)
     cfg.env.id = "CartPole-v1"
     s1 = train_single_process(cfg, log_every=100)
     assert s1["solver"].step == 201  # 300 env steps - 100 warmup + final

@@ -22,7 +22,7 @@ R2D2_TINY = [
     "replay.prioritized=false",
     "train.total_steps=250", "train.eval_episodes=2",
     "env.id=CartPole-v1", "env.kind=gym", "env.stack=1",
-    "actors.num_actors=1",
+    "actors.num_actors=1", "mesh.dp=1",
 ]
 
 

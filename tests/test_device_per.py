@@ -286,6 +286,7 @@ def test_device_per_pixel_path_learns():
 
     cfg = Config()
     cfg.mesh.backend = "cpu"
+    cfg.mesh.dp = 1
     cfg.env = EnvConfig(id="signal", kind="signal_atari",
                         frame_shape=(36, 36), stack=4, reward_clip=0.0)
     cfg.net = NetConfig(kind="nature_cnn", num_actions=4,

@@ -250,27 +250,29 @@ def test_single_stream_reaches_all_shards():
     dev.sample(8)  # draws 2 per shard without raising
 
 
-def test_train_loop_with_device_ring_fake_atari():
+@pytest.mark.parametrize("prioritized", [False, True],
+                         ids=["uniform", "per"])
+def test_train_loop_with_device_ring_fake_atari(prioritized):
     """End-to-end: single-process train loop on FakeAtari with the device
-    ring (uniform and PER) runs and produces finite losses."""
+    ring (uniform or PER) over two shards, 26 grad steps, runs and
+    produces finite losses."""
     from distributed_deep_q_tpu.config import pong_config
     from distributed_deep_q_tpu.train import train_single_process
 
-    for prioritized in (False, True):
-        cfg = pong_config()
-        cfg.mesh.backend = "cpu"
-        cfg.mesh.dp = 2
-        cfg.env.id = "fake"
-        cfg.env.kind = "fake_atari"
-        cfg.env.frame_shape = (36, 36)
-        cfg.net.frame_shape = (36, 36)
-        cfg.net.compute_dtype = "float32"
-        cfg.replay = ReplayConfig(
-            capacity=2048, batch_size=16, learn_start=200, n_step=2,
-            prioritized=prioritized, write_chunk=16)
-        cfg.train.total_steps = 400
-        cfg.train.train_every = 8
-        cfg.train.target_update_period = 10
-        summary = train_single_process(cfg, log_every=10)
-        assert np.isfinite(summary["loss"])
-        assert summary["solver"].step == pytest.approx(25, abs=1)
+    cfg = pong_config()
+    cfg.mesh.backend = "cpu"
+    cfg.mesh.dp = 2
+    cfg.env.id = "fake"
+    cfg.env.kind = "fake_atari"
+    cfg.env.frame_shape = (36, 36)
+    cfg.net.frame_shape = (36, 36)
+    cfg.net.compute_dtype = "float32"
+    cfg.replay = ReplayConfig(
+        capacity=2048, batch_size=16, learn_start=200, n_step=2,
+        prioritized=prioritized, write_chunk=16)
+    cfg.train.total_steps = 400
+    cfg.train.train_every = 8
+    cfg.train.target_update_period = 10
+    summary = train_single_process(cfg, log_every=10)
+    assert np.isfinite(summary["loss"])
+    assert summary["solver"].step == pytest.approx(25, abs=1)

@@ -134,6 +134,7 @@ def test_real_ale_pipeline():
 
     cfg = pong_config()
     cfg.mesh.backend = "cpu"
+    cfg.mesh.dp = 1
     cfg.env.id = "ALE/Pong-v5"
     cfg.net.compute_dtype = "float32"
     env = AtariEnv(cfg.env, seed=0)

@@ -54,6 +54,7 @@ def test_evaluate_per_game_single_and_multi():
 
     cfg = Config()
     cfg.mesh.backend = "cpu"
+    cfg.mesh.dp = 1
     cfg.env = EnvConfig(id="signal", kind="signal_atari",
                         games=("signal", "signal-h"), frame_shape=(36, 36),
                         stack=4)

@@ -64,6 +64,7 @@ def test_solver_with_pallas_loss_trains():
     def run(use_pallas):
         cfg = Config()
         cfg.mesh.backend = "cpu"
+        cfg.mesh.dp = 1
         cfg.train.use_pallas_loss = use_pallas
         solver = Solver(cfg, obs_dim=4)
         losses = [float(solver.train_step(dict(b))["loss"]) for b in batches]

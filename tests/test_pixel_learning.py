@@ -77,8 +77,8 @@ def test_signal_games_differ():
 @pytest.mark.slow
 def test_pixel_path_learns_through_device_ring():
     """THE gate for the pixel topology: Nature-CNN learner fed by the
-    device-resident HBM ring on the 8-device CPU mesh beats the random
-    policy (≈8/episode) by ≥2× on SignalAtari greedy eval."""
+    device-resident HBM ring beats the random policy (≈8/episode) by ≥2×
+    on SignalAtari greedy eval."""
     from distributed_deep_q_tpu.train import train_single_process
 
     cfg = Config()
@@ -98,6 +98,7 @@ def test_pixel_path_learns_through_device_ring():
     cfg.actors.eps_end = 0.05
     cfg.actors.eval_eps = 0.0
     cfg.mesh.backend = "cpu"
+    cfg.mesh.dp = 1
 
     summary = train_single_process(cfg, log_every=500)
     # random ≈ 8/episode, perfect = 32; demand ≥2× random with margin

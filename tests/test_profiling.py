@@ -59,6 +59,7 @@ def test_train_loop_emits_time_breakdown(tmp_path):
     jsonl = tmp_path / "m.jsonl"
     cfg = cartpole_config()
     cfg.mesh.backend = "cpu"
+    cfg.mesh.dp = 1
     cfg.train.total_steps = 1_200
     cfg.train.train_every = 4
     cfg.train.grad_steps_per_train = 1

@@ -37,6 +37,7 @@ def test_stager_feeds_learner_end_to_end():
     replay = _filled_replay()
     cfg = Config()
     cfg.mesh.backend = "cpu"
+    cfg.mesh.dp = 1
     solver = Solver(cfg, obs_dim=4)
     stager = DeviceStager(lambda: replay.sample(64),
                           sharding=solver.learner._batch_sharding, depth=2)

@@ -427,6 +427,7 @@ def test_train_run_jsonl_valid_monotonic_with_telemetry(tmp_path):
     jsonl = tmp_path / "m.jsonl"
     cfg = cartpole_config()
     cfg.mesh.backend = "cpu"
+    cfg.mesh.dp = 1
     cfg.train.total_steps = 700
     cfg.train.train_every = 4
     cfg.train.grad_steps_per_train = 1

@@ -10,6 +10,7 @@ from distributed_deep_q_tpu.train import train_single_process, evaluate
 def test_cartpole_smoke_runs_and_improves():
     cfg = cartpole_config()
     cfg.mesh.backend = "cpu"
+    cfg.mesh.dp = 1
     cfg.train.total_steps = 3_000
     cfg.replay.learn_start = 300
     out = train_single_process(cfg, log_every=1000)
@@ -17,13 +18,31 @@ def test_cartpole_smoke_runs_and_improves():
     assert out["eval_return"] > 15  # random policy ≈ 9.3 on CartPole
 
 
+def test_host_batch_loop_on_the_sharded_mesh():
+    """The loop of the two CartPole tests around this one, for 19 grad
+    steps over the 8-device mesh: they learn, on one device (conftest's
+    docstring says why), so this is where the loop places a host batch on
+    the shards and its step's `psum` runs."""
+    cfg = cartpole_config()
+    cfg.mesh.backend = "cpu"
+    cfg.mesh.dp = 8
+    cfg.train.total_steps = 320
+    cfg.replay.learn_start = 300
+    out = train_single_process(cfg, log_every=10)
+    assert out["solver"].mesh.size == 8
+    assert out["solver"].step == 19
+    assert np.isfinite(out["loss"])
+
+
 def test_fake_atari_pixel_path():
-    """FrameStackReplay + CNN learner end to end on FakeAtari frames."""
+    """Device ring + CNN learner end to end on FakeAtari frames, sharded
+    over the 8-device mesh for its 16 grad steps."""
     cfg = Config()
     cfg.net = NetConfig(kind="nature_cnn", num_actions=4,
                         frame_shape=(84, 84), stack=4)
     cfg.env = EnvConfig(id="fake", kind="fake_atari", stack=4)
     cfg.mesh.backend = "cpu"
+    cfg.mesh.dp = 8
     cfg.replay.capacity = 2_000
     cfg.replay.batch_size = 16
     cfg.replay.learn_start = 200
@@ -39,6 +58,7 @@ def test_cartpole_fast_proxy_reaches_150():
     that breaks learning can never ship on the fast suite alone again."""
     cfg = cartpole_config()
     cfg.mesh.backend = "cpu"
+    cfg.mesh.dp = 1
     cfg.train.total_steps = 10_000
     out = train_single_process(cfg, log_every=5000)
     assert out["eval_return"] >= 150
@@ -51,6 +71,7 @@ def test_cartpole_solves():
     by the sweep logs (seeds 0–3 all ≥475, scripts/diag_cartpole.py)."""
     cfg = cartpole_config()
     cfg.mesh.backend = "cpu"
+    cfg.mesh.dp = 1
     out = train_single_process(cfg, log_every=5000)
     solver = out["solver"]
     assert evaluate(solver, cfg, episodes=10) >= 475

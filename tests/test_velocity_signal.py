@@ -124,6 +124,7 @@ def _pixel_cfg(vel_id: str = "signal-vel", total_steps: int = 6000,
                **replay_kw) -> Config:
     cfg = Config()
     cfg.mesh.backend = "cpu"
+    cfg.mesh.dp = 1
     cfg.env = EnvConfig(id=vel_id, kind="signal_atari", frame_shape=FRAME,
                         stack=4, reward_clip=0.0)
     cfg.net = NetConfig(kind="nature_cnn", num_actions=A, frame_shape=FRAME,
@@ -178,6 +179,7 @@ def test_velocity_learns_through_r2d2_stack1():
 
     cfg = Config()
     cfg.mesh.backend = "cpu"
+    cfg.mesh.dp = 1
     cfg.env = EnvConfig(id="signal-vel-ep", kind="signal_atari",
                         frame_shape=FRAME, stack=1, reward_clip=0.0)
     cfg.net = NetConfig(kind="r2d2", num_actions=A, frame_shape=FRAME,
