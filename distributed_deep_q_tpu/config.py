@@ -28,9 +28,12 @@ class TokenQConfig:
     publishes (``qk_norm``, ``hidden_act``, ``router_input``: a
     configuration states them under ``assumed``); the defaults are a toy
     in SmallThinker's settings. Each layer is a token
-    mixer (attention, full or windowed; a gated short convolution; or
-    attention over the keys a learned indexer selects) and a feed-forward
-    (dense, or the experts held here), read off these keys by
+    mixer (attention, full or windowed; a gated short convolution;
+    attention over the keys a learned indexer selects; or latent
+    attention, keys and values expanded from one low-rank latent) and a
+    feed-forward (dense, or the experts held here, with
+    ``n_shared_experts`` beside a shared expert every token takes), read
+    off these keys by
     ``models/tokenq.layer_plan``. ``experts_held`` / ``expert_offset``
     and ``net.num_actions`` (the vocabulary rows held) say which SHARE of
     an expert-parallel deployment this process computes: the router stays
@@ -56,9 +59,24 @@ class TokenQConfig:
     # ``conv_L_cache``: ``models/tokenq.CONV_TAPS``), "full_attention" =
     # attention as the two layouts above say, "sparse_attention" = the
     # same heads over the ``indexer_topk`` keys a query's INDEXER scores
-    # highest (``ops/sparse_attention.py``; Keye-VL-2.0's ``sa_config``).
-    # Empty (SmallThinker publishes no such key): attention on every layer
+    # highest (``ops/sparse_attention.py``; Keye-VL-2.0's ``sa_config``),
+    # "latent_attention" = the latent heads below. Empty (SmallThinker
+    # publishes no such key): attention on every layer
     layer_types: tuple[str, ...] = ()
+    # a "latent_attention" layer (``deepseek_v3``'s keys; a full-rank
+    # query, no ``q_lora_rank``): every query head is ``qk_nope_head_dim``
+    # + ``qk_rope_head_dim`` wide; keys and values come from ONE latent of
+    # ``kv_lora_rank`` a token (RMSNorm with a learned gain, then expanded
+    # to ``qk_nope_head_dim`` + ``v_head_dim`` a head) and ONE rotary key
+    # head of ``qk_rope_head_dim`` that all query heads share. Scores run
+    # over the two parts together (scale: their width to the -1/2), values
+    # are ``v_head_dim`` wide; ``num_key_value_heads`` and ``head_dim``
+    # are not read. Its rotary embedding turns the interleaved pairs
+    # (2i, 2i+1), not rotate-half's (i, i + d/2): a fact of the mixer
+    kv_lora_rank: int = 32
+    qk_nope_head_dim: int = 16
+    qk_rope_head_dim: int = 8
+    v_head_dim: int = 16
     # the indexer of a "sparse_attention" layer (``sa_config``'s
     # ``indexer_num_heads`` / ``indexer_head_dim`` / ``topk`` /
     # ``q_chunk_size``; ONE key head, ``indexer_num_kv_heads`` = 1):
@@ -99,6 +117,13 @@ class TokenQConfig:
     moe_num_active_primary_experts: int = 2
     experts_held: int = 8
     expert_offset: int = 0
+    # the renormalised gates of the chosen experts are multiplied by
+    # ``routed_scaling_factor`` (1.0: they sum to 1); with
+    # ``n_shared_experts`` > 0 every expert layer adds ONE ungated
+    # feed-forward of width ``n_shared_experts * moe_ffn_hidden_size``
+    # that every token takes, whole on every member of the group
+    routed_scaling_factor: float = 1.0
+    n_shared_experts: int = 0
     # kernel blocks: attention q/kv block (the window is padded to a
     # multiple) and the kv columns of one inner step (a divisor of it),
     # tokens per block of the Q head + TD loss (and of the dense
@@ -792,6 +817,46 @@ def keye_tokenq_config() -> Config:
     return c
 
 
+def moonlight_tokenq_config() -> Config:
+    """Moonlight-16B-A3B (moonshotai, config.json, ``model_type``
+    deepseek_v3) as a token-window Q-network, one chip's share of an
+    8-chip expert-parallel deployment: every width as published (hidden
+    2048, 16 heads of latent attention: scores over 128 + 64, values of
+    128, from a latent of rank 512 and one shared rotary key head, rope
+    theta 5e4 on interleaved pairs; dense width 11 264; SwiGLU experts of
+    width 1 408, sigmoid router 64 wide with a selection bias, top 6,
+    gates x 2.446, and a shared expert of width 2 x 1 408 beside them);
+    5 layers = the published layers 0-4 (one leading dense layer, four
+    expert layers), 8 of the 64 experts and 20 480 of the 163 840
+    vocabulary rows held here. Windows of 8 191 steps (+1 token = the
+    published 8 192 positions, whole attention blocks), chain 4,
+    batch 2."""
+    c = smallthinker_tokenq_config()
+    c.net = NetConfig(
+        kind="tokenq", num_actions=20_480, compute_dtype="bfloat16",
+        tokenq=TokenQConfig(
+            hidden_size=2048, num_hidden_layers=5, num_attention_heads=16,
+            num_key_value_heads=16, rms_norm_eps=1e-5,
+            layer_types=("latent_attention",) * 5,
+            sliding_window_layout=(0,) * 5, rope_layout=(1,) * 5,
+            rope_theta=50_000.0, kv_lora_rank=512,
+            qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+            num_dense_layers=1, intermediate_size=11_264,
+            hidden_act="silu", moe_primary_router_apply_softmax=False,
+            use_expert_bias=True, router_input="ffn_norm",
+            moe_ffn_hidden_size=1408, moe_num_primary_experts=64,
+            moe_num_active_primary_experts=6, experts_held=8,
+            expert_offset=0, routed_scaling_factor=2.446,
+            n_shared_experts=2, attn_block=1024, attn_compute_block=512,
+            head_block=1024, moe_tile=256))
+    c.replay = dataclasses.replace(
+        c.replay, capacity=16_384 * 8_191, batch_size=2,
+        sequence_length=8_191, learn_start=64 * 8_191)
+    c.train = dataclasses.replace(c.train, train_every=8_191)
+    c.env = dataclasses.replace(c.env, token_vocab=20_480)
+    return c
+
+
 def env_for_actor(env: EnvConfig, actor_id: int) -> EnvConfig:
     """Per-actor game assignment (config 4 multi-game fleets): actor i
     plays ``games[i % len(games)]``; single-game configs pass through."""
@@ -811,6 +876,7 @@ PRESETS = {
     "smallthinker_tokenq": smallthinker_tokenq_config,
     "lfm2_tokenq": lfm2_tokenq_config,
     "keye_tokenq": keye_tokenq_config,
+    "moonlight_tokenq": moonlight_tokenq_config,
 }
 
 

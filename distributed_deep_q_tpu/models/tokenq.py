@@ -32,16 +32,31 @@ mixer and a feed-forward in pre-norm residual form, input x ``[B, T, h]``
     learner adds it to the TD loss. The whole indexer is float32; the
     window is padded to whole blocks of ``indexer_q_chunk`` inside the
     mixer;
+  - ``layer_types[l] == "latent_attention"``: ``num_attention_heads``
+    query heads ``q = u W_q`` of ``qk_nope_head_dim`` +
+    ``qk_rope_head_dim``; ``[c | k_r] = u W_kva`` (``kv_lora_rank`` |
+    ``qk_rope_head_dim``), ``[k_n | v] = rmsnorm(c; kv_norm) W_kvb`` a
+    head (``qk_nope_head_dim`` | ``v_head_dim``); the rotary embedding on
+    each head's ``q_r`` and on the ONE ``k_r`` every head shares, over
+    the interleaved pairs (2i, 2i+1) as ``deepseek_v3`` turns them (a
+    fact of this mixer, no option); causal scores over
+    ``[q_n | q_r] · [k_n | k_r]`` at their width to the -1/2, values
+    ``v_head_dim`` wide: ``m = attn · W_o``. Keys and values are expanded
+    a head (the form that is not absorbed);
 - feed-forward over ``w = rmsnorm_2(x')``, ``y = x' + f``:
   - ``l < num_dense_layers``: ``f = (act(w W_gate) * (w W_up)) W_down`` of
     width ``intermediate_size``, blockwise over tokens;
   - else ``f = Σ_{e in top k, held here} p_e · (act(w W_gate,e) * (w
     W_up,e)) W_down,e`` (``ops/moe.held_experts_ffn``: this process's
-    share of an expert-parallel layer; no shared expert), ``act`` =
-    ``hidden_act``: relu (ReGLU) or silu (SwiGLU). The ROUTER
+    share of an expert-parallel layer), ``act`` = ``hidden_act``: relu
+    (ReGLU) or silu (SwiGLU); with ``n_shared_experts`` > 0 plus ``S(w)``,
+    ONE ungated feed-forward of width ``n_shared_experts ·
+    moe_ffn_hidden_size`` that every token takes, whole on every member
+    of the group (``dense_ffn``, blockwise). The ROUTER
     (``ops/moe.route``) reads what ``router_input`` says: ``w``
     ("ffn_norm"), or ``u`` — the layer's normed input, BEFORE the mixer
-    ("pre_mixer").
+    ("pre_mixer"); its renormalised gates are multiplied by
+    ``routed_scaling_factor``.
 
 Every branch here is on a mechanism a ``TokenQConfig`` field names, never
 on which model is being run.
@@ -52,7 +67,9 @@ before attention. LFM2's: convolutions among full attention with q/k
 norms, a leading dense layer, SwiGLU, a sigmoid router with a selection
 bias reading the second norm. Keye-VL-2.0's: sparse attention with q/k
 norms and rope on every layer, SwiGLU experts behind a softmax router
-reading the second norm.
+reading the second norm. Moonlight's (``deepseek_v3``): latent attention
+on every layer, a leading dense layer, SwiGLU experts behind LFM2's
+router with scaled gates, and a shared expert beside them.
 
 Then the final RMSNorm; the untied head ``[h, V]`` is applied by the
 learner, blockwise over tokens, together with the TD loss
@@ -86,7 +103,8 @@ BIAS_STD = 0.01     # the expert bias: seeded, and no gradient reaches it
 CONV_TAPS = 3       # a conv layer's taps (LFM2's ``conv_L_cache``)
 ACTS = {"relu": jax.nn.relu, "silu": jax.nn.silu}       # ``hidden_act``
 ROUTER_INPUTS = ("pre_mixer", "ffn_norm")
-MIXERS = ("conv", "full_attention", "sparse_attention")  # ``layer_types``
+MIXERS = ("conv", "full_attention", "sparse_attention",     # ``layer_types``
+          "latent_attention")
 
 
 def layer_name(i: int) -> str:
@@ -94,9 +112,9 @@ def layer_name(i: int) -> str:
 
 
 def layer_plan(tq: TokenQConfig) -> list[dict[str, bool]]:
-    """Per layer ``{windowed, rope, conv, sparse, dense}``: the mixer
-    from ``layer_types`` (absent: attention) and the two layouts, the
-    feed-forward from ``num_dense_layers``."""
+    """Per layer ``{windowed, rope, conv, sparse, latent, dense}``: the
+    mixer from ``layer_types`` (absent: attention) and the two layouts,
+    the feed-forward from ``num_dense_layers``."""
     n = tq.num_hidden_layers
     if len(tq.sliding_window_layout) < n or len(tq.rope_layout) < n:
         raise ValueError(
@@ -117,11 +135,16 @@ def layer_plan(tq: TokenQConfig) -> list[dict[str, bool]]:
              "rope": bool(tq.rope_layout[i]),
              "conv": kinds[i] == "conv",
              "sparse": kinds[i] == "sparse_attention",
+             "latent": kinds[i] == "latent_attention",
              "dense": i < tq.num_dense_layers}
             for i in range(n)]
-    if any(k["sparse"] and k["windowed"] for k in plan):
-        raise ValueError("a sparse_attention layer takes no sliding "
-                         f"window: {tq.sliding_window_layout}")
+    if any((k["sparse"] or k["latent"]) and k["windowed"] for k in plan):
+        raise ValueError("a sparse_attention or latent_attention layer "
+                         f"takes no sliding window: "
+                         f"{tq.sliding_window_layout}")
+    if tq.qk_norm and any(k["latent"] for k in plan):
+        raise ValueError("a latent_attention layer takes no qk_norm (its "
+                         "latent has a norm of its own: kv_norm)")
     return plan
 
 
@@ -138,6 +161,11 @@ def param_shapes(cfg: NetConfig) -> dict[str, Any]:
                  "w_v": (h, hkv * d), "w_o": (hq * d, h)}
     if tq.qk_norm:
         attention.update({"q_norm": (d,), "k_norm": (d,)})
+    dn, dr, dv, r = (tq.qk_nope_head_dim, tq.qk_rope_head_dim,
+                     tq.v_head_dim, tq.kv_lora_rank)
+    latent = {"w_q": (h, hq * (dn + dr)), "w_kva": (h, r + dr),
+              "kv_norm": (r,), "w_kvb": (r, hq * (dn + dv)),
+              "w_o": (hq * dv, h)}
     conv = {"w_in": (h, 3 * h), "w_conv": (h, CONV_TAPS),
             "w_out": (h, h)}
     hi, di = tq.indexer_num_heads, tq.indexer_head_dim
@@ -147,6 +175,10 @@ def param_shapes(cfg: NetConfig) -> dict[str, Any]:
                "w_gate": (e, h, f), "w_up": (e, h, f), "w_down": (e, f, h)}
     if tq.use_expert_bias:
         experts["expert_bias"] = (tq.moe_num_primary_experts,)
+    if tq.n_shared_experts:
+        fs = tq.n_shared_experts * f
+        experts.update({"shared_gate": (h, fs), "shared_up": (h, fs),
+                        "shared_down": (fs, h)})
     fi = tq.intermediate_size
     dense = {"w_gate": (h, fi), "w_up": (h, fi), "w_down": (fi, h)}
     shapes: dict[str, Any] = {"embed": (v, h), "final_norm": (h,),
@@ -154,7 +186,8 @@ def param_shapes(cfg: NetConfig) -> dict[str, Any]:
     for i, kind in enumerate(layer_plan(tq)):
         shapes[layer_name(i)] = {
             "norm_1": (h,), "norm_2": (h,),
-            **(conv if kind["conv"] else attention),
+            **(conv if kind["conv"] else latent if kind["latent"]
+               else attention),
             **(indexer if kind["sparse"] else {}),
             **(dense if kind["dense"] else experts)}
     return shapes
@@ -200,12 +233,19 @@ def rmsnorm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
     return x * jax.lax.rsqrt(var + eps) * w
 
 
-def rotary(x: jax.Array, theta: float) -> jax.Array:
-    """Rotate-half rotary embedding over ``[B, H, T, D]`` at positions
-    0..T-1, float32."""
+def rotary(x: jax.Array, theta: float,
+           interleave: bool = False) -> jax.Array:
+    """Rotary embedding over ``[B, H, T, D]`` at positions 0..T-1,
+    float32: pair i turns by ``t · theta^(-2i/D)``. Rotate-half pairs
+    element i with i + D/2; ``interleave`` pairs 2i with 2i + 1."""
     d, t = x.shape[-1], x.shape[-2]
     inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
+    if interleave:
+        cos, sin = (jnp.repeat(f(ang), 2, -1) for f in (jnp.cos, jnp.sin))
+        pairs = x.reshape(*x.shape[:-1], d // 2, 2)
+        rot = jnp.stack([-pairs[..., 1], pairs[..., 0]], -1).reshape(x.shape)
+        return x * cos + rot * sin
     cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)
     sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)
     x1, x2 = x[..., :d // 2], x[..., d // 2:]
@@ -223,7 +263,7 @@ def _route(w: jax.Array, p: dict[str, jax.Array], tq: TokenQConfig):
             w.reshape(-1, w.shape[-1]), p["w_router"],
             tq.moe_num_active_primary_experts,
             softmax=tq.moe_primary_router_apply_softmax,
-            bias=p.get("expert_bias"))
+            bias=p.get("expert_bias"), scale=tq.routed_scaling_factor)
 
 
 def dense_ffn(w: jax.Array, w_gate: jax.Array, w_up: jax.Array,
@@ -268,16 +308,57 @@ def _indexer_inputs(u: jax.Array, p: dict[str, jax.Array],
     return q_i.transpose(0, 2, 1, 3), proj(p["w_iw"]), k_i[:, 0]
 
 
+def latent_attention(u: jax.Array, p: dict[str, jax.Array],
+                     cfg: NetConfig, rope: bool, interpret: bool):
+    """Latent attention over the layer's normed input ``u`` [B, T, h] →
+    ``attn · W_o`` [B, T, h]: keys and values of every head expanded from
+    one latent a token, one rotary key head shared by all heads (the form
+    that is not absorbed). Matmuls and the kernel in ``compute_dtype``
+    with float32 accumulation; the latent's norm and the rotations
+    float32."""
+    tq = cfg.tokenq
+    dtype = jnp.dtype(cfg.compute_dtype)
+    b, t, _ = u.shape
+    hq, r = tq.num_attention_heads, tq.kv_lora_rank
+    dn, dr, dv = tq.qk_nope_head_dim, tq.qk_rope_head_dim, tq.v_head_dim
+
+    def heads(x, d):
+        return x.reshape(b, t, hq, d).transpose(0, 2, 1, 3)
+    with jax.named_scope("ddq.mla_down"):
+        q = heads(_mm(u, p["w_q"], dtype), dn + dr)
+        q_n, q_r = q[..., :dn], q[..., dn:]
+        ckr = _mm(u, p["w_kva"], dtype)
+        c = rmsnorm(ckr[..., :r], p["kv_norm"], tq.rms_norm_eps)
+        k_r = ckr[:, None, :, r:]               # ONE head: [B, 1, T, dr]
+        if rope:
+            q_r = rotary(q_r, tq.rope_theta, interleave=True)
+            k_r = rotary(k_r, tq.rope_theta, interleave=True)
+    with jax.named_scope("ddq.mla_up"):
+        kv = heads(_mm(c, p["w_kvb"], dtype), dn + dv)
+        q = jnp.concatenate([q_n, q_r], -1).astype(dtype)
+        k = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(k_r, (b, hq, t, dr))],
+            -1).astype(dtype)
+        v = kv[..., dn:].astype(dtype)
+    with jax.named_scope("ddq.mla_core"):
+        a = causal_attention(q, k, v, block=tq.attn_block,
+                             compute_block=tq.attn_compute_block,
+                             interpret=interpret)
+    return _mm(a.transpose(0, 2, 1, 3).reshape(b, t, hq * dv), p["w_o"],
+               dtype)
+
+
 def mixer(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
           windowed: bool, rope: bool, interpret: bool, *,
           conv: bool = False, dense: bool = False, sparse: bool = False,
-          index_loss: bool = True):
+          latent: bool = False, index_loss: bool = True):
     """A block's first half, ``x' = x + m``; ``x`` [B, T, h] float32 →
     (x', the routing where the router reads the mixer's input — else
     ``None`` —, a sparse mixer's counters — else ``None``). The mixer is
     attention (``windowed``, ``rope``), with ``conv`` the gated short
     convolution, with ``sparse`` attention over the keys its indexer
-    selects (``index_loss``: with the indexer's loss)."""
+    selects (``index_loss``: with the indexer's loss), with ``latent``
+    latent attention."""
     tq = cfg.tokenq
     router_first = tq.router_input == "pre_mixer"
     dtype = jnp.dtype(cfg.compute_dtype)
@@ -293,6 +374,9 @@ def mixer(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
             with jax.named_scope("ddq.short_conv_mix"):
                 mixed = short_conv_mix(bcz, p["w_conv"])
             x = x + _mm(mixed, p["w_out"], dtype)
+    elif latent:
+        with jax.named_scope("ddq.attn_latent"):
+            x = x + latent_attention(u, p, cfg, rope, interpret)
     else:
         with jax.named_scope(
                 "ddq.attn_sparse" if sparse else
@@ -337,9 +421,10 @@ def mixer(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
 def feed_forward(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
                  interpret: bool, *, dense: bool = False, route=None):
     """A block's second half, ``y = x' + f``: the held experts (``route``:
-    the routing, where the mixer's half made it) or with ``dense`` the
-    dense feed-forward → (y, the expert layer's counters; ``None`` from a
-    dense one)."""
+    the routing, where the mixer's half made it) and the shared expert
+    where the configuration has one, or with ``dense`` the dense
+    feed-forward → (y, the expert layer's counters; ``None`` from a dense
+    one)."""
     tq = cfg.tokenq
     act = ACTS[tq.hidden_act]
     dtype = jnp.dtype(cfg.compute_dtype)
@@ -373,18 +458,28 @@ def feed_forward(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
         y, counters = jax.lax.map(
             one_sequence, (v2, idx.reshape(b, t, k), prob.reshape(b, t, k)))
         counters = jax.tree.map(lambda c: jnp.sum(c, axis=0), counters)
+        if tq.n_shared_experts:
+            # what every token takes, ungated and whole on every member
+            # of the group: blockwise, so its activations (2 x 1 408 wide
+            # for Moonlight) never stand for the whole batch
+            with jax.named_scope("ddq.shared_expert"):
+                y = y + dense_ffn(
+                    v2.reshape(b * t, h), p["shared_gate"], p["shared_up"],
+                    p["shared_down"], act=act, block=tq.head_block,
+                    dtype=dtype).reshape(b, t, h)
     return x + y, counters
 
 
 def layer(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
           windowed: bool, rope: bool, interpret: bool, *,
           conv: bool = False, dense: bool = False, sparse: bool = False,
-          index_loss: bool = True):
+          latent: bool = False, index_loss: bool = True):
     """One block, ``mixer`` then ``feed_forward``; ``x`` [B, T, h] float32
     → (x, the layer's counters: the expert layer's, and under ``"dsa"`` a
     sparse mixer's; ``None`` where it has neither)."""
     x, route, dsa = mixer(x, p, cfg, windowed, rope, interpret, conv=conv,
-                          dense=dense, sparse=sparse, index_loss=index_loss)
+                          dense=dense, sparse=sparse, latent=latent,
+                          index_loss=index_loss)
     x, counters = feed_forward(x, p, cfg, interpret, dense=dense,
                                route=route)
     if sparse:
@@ -413,12 +508,15 @@ def backbone(params: dict[str, Any], tokens: jax.Array, cfg: NetConfig,
     for i, kind in enumerate(layer_plan(tq)):
         p = params[layer_name(i)]
         kw = dict(conv=kind["conv"], dense=kind["dense"],
-                  sparse=kind["sparse"], index_loss=index_loss)
-        if kind["sparse"]:
+                  sparse=kind["sparse"], latent=kind["latent"],
+                  index_loss=index_loss)
+        if kind["sparse"] or kind["latent"]:
             # the two halves rematerialised apart: what the mixer's
             # backward needs (q, k, v, the indexer's inputs, o: 2.5 GB at
-            # 2 x 16 896 tokens) need not stand beside the expert layer's
-            # buffers, at the price of one more [B, T, h] a layer
+            # 2 x 16 896 tokens; a latent mixer's queries, expanded keys
+            # and values: 1.3 GB at 2 x 8 192) need not stand beside the
+            # expert layer's buffers, at the price of one more [B, T, h]
+            # a layer
             x, route, dsa_i = jax.checkpoint(
                 lambda x, p, kind=kind, kw=kw: mixer(
                     x, p, cfg, kind["windowed"], kind["rope"], interpret,
@@ -427,7 +525,8 @@ def backbone(params: dict[str, Any], tokens: jax.Array, cfg: NetConfig,
                 lambda x, p, route, kind=kind: feed_forward(
                     x, p, cfg, interpret, dense=kind["dense"],
                     route=route))(x, p, route)
-            dsa.append(dsa_i)
+            if kind["sparse"]:
+                dsa.append(dsa_i)
         else:
             x, c = jax.checkpoint(
                 lambda x, p, kind=kind, kw=kw: layer(

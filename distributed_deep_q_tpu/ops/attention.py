@@ -56,14 +56,17 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                      window: int = 0, block: int = 128,
                      compute_block: int = 0,
                      interpret: bool = False) -> jax.Array:
-    """softmax(q kᵀ · D^-½ + mask) v over ``[B, H, T, D]`` queries and
-    ``[B, Hkv, T, D]`` keys/values (``H`` a multiple of ``Hkv``); causal,
-    and with ``window`` > 0 limited to the last ``window`` keys. Returns
-    ``[B, H, T, D]`` in ``q``'s dtype. ``block`` (a multiple of 128) is
-    the kernel's q and kv block and ``compute_block`` (a divisor of it, 0
-    = the block) the kv columns one inner step multiplies; ``T`` need not
-    be a multiple of the block."""
+    """softmax(q kᵀ · D^-½ + mask) v over ``[B, H, T, D]`` queries,
+    ``[B, Hkv, T, D]`` keys and ``[B, Hkv, T, Dv]`` values (``H`` a
+    multiple of ``Hkv``; ``v`` may be narrower or wider than ``q`` / ``k``:
+    latent attention scores over 192 and mixes values of 128; the scale
+    is the SCORE width's); causal, and with ``window`` > 0 limited to the
+    last ``window`` keys. Returns ``[B, H, T, Dv]`` in ``q``'s dtype.
+    ``block`` (a multiple of 128) is the kernel's q and kv block and
+    ``compute_block`` (a divisor of it, 0 = the block) the kv columns one
+    inner step multiplies; ``T`` need not be a multiple of the block."""
     b, h, t, d = q.shape
+    dv = v.shape[-1]
     hkv = k.shape[1]
     group = h // hkv
     t_pad = -(-t // block) * block
@@ -75,4 +78,4 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                    int(compute_block) or int(block), bool(interpret))
     per_kv_head = jax.vmap(kern)          # [Hkv, group, T, D], [Hkv, T, D]
     out = jax.vmap(per_kv_head)(q.reshape(b, hkv, group, t_pad, d), k, v)
-    return out.reshape(b, h, t_pad, d)[:, :, :t]
+    return out.reshape(b, h, t_pad, dv)[:, :, :t]

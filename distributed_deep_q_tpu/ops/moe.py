@@ -32,14 +32,18 @@ from jax import lax
 
 
 def route(u: jax.Array, w_router: jax.Array, top_k: int, *,
-          softmax: bool = True, bias: jax.Array | None = None):
-    """Scores over ALL experts in float32, ``top_k`` kept and renormalised
-    to sum 1. ``softmax`` (SmallThinker's setting): softmax scores, the
-    largest kept. Otherwise (LFM2's): sigmoid scores ``s``; the experts
-    with the largest ``s + bias`` are CHOSEN and weighted by ``s`` without
-    the bias (no gradient reaches ``bias``: indices carry none), divided
-    by ``sum + 1e-6``. ``u`` [N, h] float32 → (expert ids [N, k] int32,
-    weights [N, k] float32)."""
+          softmax: bool = True, bias: jax.Array | None = None,
+          scale: float = 1.0):
+    """Scores over ALL experts in float32, ``top_k`` kept, renormalised
+    to sum 1 and multiplied by ``scale`` (``routed_scaling_factor``: 1.0
+    for SmallThinker, LFM2 and Keye-VL-2.0: a product with 1.0 is
+    exact and the compiler drops it, so their gates stay the renormalised
+    scores bit for bit). ``softmax`` (SmallThinker's
+    setting): softmax scores, the largest kept. Otherwise (LFM2's):
+    sigmoid scores ``s``; the experts with the largest ``s + bias`` are
+    CHOSEN and weighted by ``s`` without the bias (no gradient reaches
+    ``bias``: indices carry none), divided by ``sum + 1e-6``. ``u`` [N, h]
+    float32 → (expert ids [N, k] int32, weights [N, k] float32)."""
     z = jnp.dot(u, w_router, precision=lax.Precision.HIGHEST,
                 preferred_element_type=jnp.float32)
     if softmax:
@@ -50,7 +54,7 @@ def route(u: jax.Array, w_router: jax.Array, top_k: int, *,
         _, top_i = lax.top_k(s if bias is None else s + bias, top_k)
         top_p = jnp.take_along_axis(s, top_i, axis=-1)
         total = jnp.sum(top_p, -1, keepdims=True) + 1e-6
-    return top_i.astype(jnp.int32), top_p / total
+    return top_i.astype(jnp.int32), top_p / total * scale
 
 
 def buffer_rows(tokens: int, top_k: int, held: int, tile: int) -> int:

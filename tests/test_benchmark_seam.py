@@ -86,7 +86,8 @@ def test_every_configuration_resolves_its_family_modules(config):
 
 TOKEN_CELLS = ["smallthinker_21b_tokenq_ep8.seq_learner_only",
                "lfm2_24b_tokenq_ep8.seq_learner_only",
-               "keye_vl2_30b_tokenq_ep16.seq_learner_only"]
+               "keye_vl2_30b_tokenq_ep16.seq_learner_only",
+               "moonlight_16b_tokenq_ep8.seq_learner_only"]
 
 
 @pytest.mark.parametrize("cell", TOKEN_CELLS)
@@ -109,14 +110,14 @@ def test_the_token_family_walks_its_cell_on_the_cpu(cell):
 
 # the smallest gap the fp8 control may show on a separating number at the
 # toy sizes (LFM2's first loss is a signed mean that read 4.7e-4 there),
-# and the separating numbers a family judges (LFM2's prints the written
-# priority and does not judge it: ``families/lfm2/check.PRINTED_ONLY``)
+# and the separating numbers a family judges (LFM2's and Moonlight's print
+# the written priority and do not judge it: their ``check.PRINTED_ONLY``)
 SEPARATING = ("loss_first_rel", "grad_norm_first_rel",
               "moment_first_worst_leaf")
-CONTROL_FLOOR = dict(zip(TOKEN_CELLS, (1e-3, 1e-4, 1e-4)))
+CONTROL_FLOOR = dict(zip(TOKEN_CELLS, (1e-3, 1e-4, 1e-4, 1e-4)))
 CONTROL_READS = dict(zip(TOKEN_CELLS, (
     (*SEPARATING, "priority_first_max_rel"), SEPARATING,
-    (*SEPARATING, "priority_first_max_rel"))))
+    (*SEPARATING, "priority_first_max_rel"), SEPARATING)))
 
 
 @pytest.mark.parametrize("cell", TOKEN_CELLS)

@@ -126,6 +126,10 @@ def test_fused_huber_fwd_and_grad_compile_for_v5e(one_chip, batch, actions):
 # LFM2-24B-A2B (config.lfm2_tokenq_config): 32 / 8 heads of 64 — half the
 # 128 lanes — full attention; SwiGLU experts of width 1 536 over hidden
 # 2 048, top 4.
+# Moonlight-16B-A3B (config.moonlight_tokenq_config): 16 latent heads whose
+# scores run over 128 + 64 = 192 — one and a half lane tiles — and whose
+# values are 128 wide, on windows of 8 192 tokens (whole blocks); SwiGLU
+# experts of width 1 408 over hidden 2 048, top 6.
 
 @pytest.mark.parametrize(
     "preset,window,sizes",      # sizes: T + 1, head size, the preset's window
@@ -161,10 +165,41 @@ def test_window_attention_compiles_for_v5e(one_chip, preset, window, sizes):
     assert "tpu_custom_call" in text
 
 
+def test_latent_attention_core_compiles_for_v5e(one_chip):
+    """The latent mixer's kernel call at the Moonlight preset's sizes:
+    scores 192 wide over values of 128, 16 key/value heads (group 1), the
+    preset's blocks, forward and the fused backward. Mosaic takes the
+    192-wide contraction as it is: nothing is padded to 256."""
+    from distributed_deep_q_tpu.config import PRESETS
+    from distributed_deep_q_tpu.ops.attention import causal_attention
+
+    cfg = PRESETS["moonlight_tokenq"]()
+    tq, t = cfg.net.tokenq, cfg.replay.sequence_length + 1
+    d_qk = tq.qk_nope_head_dim + tq.qk_rope_head_dim
+    assert (t, tq.num_attention_heads, d_qk, tq.v_head_dim,
+            t % tq.attn_block) == (8192, 16, 192, 128, 0)
+    S = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.bfloat16,
+                          sharding=one_chip)
+    qk = S((1, tq.num_attention_heads, t, d_qk))
+    v = S((1, tq.num_attention_heads, t, tq.v_head_dim))
+
+    def fwd_bwd(q, k, v):
+        f = lambda *a: jnp.sum(causal_attention(  # noqa: E731
+            *a, block=tq.attn_block,
+            compute_block=tq.attn_compute_block).astype(jnp.float32))
+        return jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+
+    text = _compiled_text(fwd_bwd, qk, qk, v)
+    for kernel in ("splash_mqa_fwd", "splash_mqa_dkv"):    # dq is fused in
+        assert kernel in text
+    assert f"bf16[1,16,{t},{tq.v_head_dim}]" in text
+
+
 @pytest.mark.parametrize(
     "preset,buffer_rows", [("smallthinker_tokenq", 49408),   # 8 193 x 6
-                           ("lfm2_tokenq", 33024)],          # 8 193 x 4
-    ids=["smallthinker-reglu", "lfm2-swiglu"])
+                           ("lfm2_tokenq", 33024),           # 8 193 x 4
+                           ("moonlight_tokenq", 49152)],     # 8 192 x 6
+    ids=["smallthinker-reglu", "lfm2-swiglu", "moonlight-swiglu"])
 def test_held_experts_grouped_matmul_compiles_for_v5e(one_chip, preset,
                                                       buffer_rows):
     """One sequence's expert layer as ``models/tokenq.layer`` calls it:
@@ -197,6 +232,75 @@ def test_held_experts_grouped_matmul_compiles_for_v5e(one_chip, preset,
     # forward gate+up (the down product's VALUE is not needed under a
     # sum); backward: two input-side products and two weight-side ones
     assert text.count('custom_call_target="tpu_custom_call"') >= 5
+
+
+def _instructions(text: str) -> str:
+    """The optimised module's computations alone: no module header, no
+    ``metadata={...}``, none of the source tables after the computations
+    (they carry the Python lines of whoever traced it)."""
+    keep, table = [], False
+    for ln in re.sub(r", metadata=\{[^}]*\}", "", text).splitlines():
+        if ln.startswith(("FileNames", "FunctionNames", "FileLocations",
+                          "StackFrames")):
+            table = True
+        elif ln and not ln[0].isdigit() and not ln[0].isspace():
+            table = False
+        if not table and not ln.startswith("HloModule"):
+            keep.append(ln)
+    return "\n".join(keep)
+
+
+@pytest.mark.parametrize("preset", ["smallthinker_tokenq", "lfm2_tokenq"])
+def test_route_scale_one_is_the_unscaled_router_for_v5e(one_chip, preset):
+    """``ops/moe.route`` multiplies the renormalised gates by ``scale``
+    with no branch on 1.0: the chip's compiler drops a product with 1.0,
+    so forward and backward of the router at a sibling's settings (one
+    sequence of the preset, its router's width and top k) optimise to the
+    instructions the router had BEFORE it had a scale."""
+    from jax import lax
+
+    from distributed_deep_q_tpu.config import PRESETS
+    from distributed_deep_q_tpu.ops import moe
+
+    cfg = PRESETS[preset]()
+    tq, n = cfg.net.tokenq, cfg.replay.sequence_length + 1
+    k, e, h = (tq.moe_num_active_primary_experts,
+               tq.moe_num_primary_experts, tq.hidden_size)
+    soft = tq.moe_primary_router_apply_softmax
+
+    def unscaled(u, w, bias):
+        z = jnp.dot(u, w, precision=lax.Precision.HIGHEST,
+                    preferred_element_type=jnp.float32)
+        if soft:
+            top_p, top_i = lax.top_k(jax.nn.softmax(z, -1), k)
+            total = jnp.sum(top_p, -1, keepdims=True)
+        else:
+            s = jax.nn.sigmoid(z)
+            _, top_i = lax.top_k(s + bias, k)
+            top_p = jnp.take_along_axis(s, top_i, axis=-1)
+            total = jnp.sum(top_p, -1, keepdims=True) + 1e-6
+        return top_i.astype(jnp.int32), top_p / total
+
+    def scaled(u, w, bias, scale=1.0):
+        return moe.route(u, w, k, softmax=soft,
+                         bias=None if soft else bias, scale=scale)
+
+    def program(route):
+        def fwd_bwd(u, w, bias, g):
+            def loss(u, w):
+                idx, p = route(u, w, bias)
+                return jnp.sum(p * g), idx
+            return jax.value_and_grad(loss, argnums=(0, 1),
+                                      has_aux=True)(u, w)
+        S = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32,
+                              sharding=one_chip)
+        return _instructions(_compiled_text(
+            fwd_bwd, S((n, h)), S((h, e)), S((e,)), S((n, k))))
+
+    before = program(unscaled)
+    assert program(scaled) == before
+    # and the comparison sees a product that stays
+    assert program(functools.partial(scaled, scale=2.446)) != before
 
 
 def test_short_conv_mix_compiles_for_v5e(one_chip):
