@@ -127,7 +127,9 @@ class TokenQConfig:
     # kernel blocks: attention q/kv block (the window is padded to a
     # multiple) and the kv columns of one inner step (a divisor of it),
     # tokens per block of the Q head + TD loss (and of the dense
-    # feed-forward), gmm m-tile
+    # feed-forward), gmm m-tile (the expert layer walks the batch's sorted
+    # held slots in blocks of 16 of them and runs those that hold a slot:
+    # ``ops/moe.block_rows``)
     attn_block: int = 128
     attn_compute_block: int = 128
     head_block: int = 128

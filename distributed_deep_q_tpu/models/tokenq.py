@@ -441,23 +441,17 @@ def feed_forward(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
         # the router in its own scope, nested: the innermost
         idx, prob = route if route is not None else _route(v2, p, tq)
         k = tq.moe_num_active_primary_experts
-        # a SEQUENCE at a time: the held-slot buffer is sized for one
-        # sequence's worst case (every token with min(k, held) slots
-        # here), so nothing can overflow, at a quarter of the batch's
-        # worst case in memory (batch 4); each is recomputed in backward
-        rows = moe.buffer_rows(t, k, tq.experts_held, tq.moe_tile)
-
-        @jax.checkpoint
-        def one_sequence(xs):
-            v_s, idx_s, prob_s = xs
-            return moe.held_experts_ffn(
-                v_s, idx_s, prob_s, p["w_gate"], p["w_up"], p["w_down"],
-                offset=tq.expert_offset, rows=rows, tile=tq.moe_tile,
-                compute_dtype=dtype, interpret=interpret, act=act)
-
-        y, counters = jax.lax.map(
-            one_sequence, (v2, idx.reshape(b, t, k), prob.reshape(b, t, k)))
-        counters = jax.tree.map(lambda c: jnp.sum(c, axis=0), counters)
+        # the whole batch's token-slots, sorted once: a held expert's rows
+        # lie together across the sequences. The bound is the worst case
+        # (every token with min(k, held) slots here), so nothing can
+        # overflow; the layer walks it in blocks and runs those that hold
+        # a slot, so no buffer of that size ever stands
+        y, counters = moe.held_experts_ffn(
+            v2, idx, prob, p["w_gate"], p["w_up"], p["w_down"],
+            offset=tq.expert_offset,
+            rows=moe.buffer_rows(b * t, k, tq.experts_held, tq.moe_tile),
+            tile=tq.moe_tile, compute_dtype=dtype, interpret=interpret,
+            act=act)
         if tq.n_shared_experts:
             # what every token takes, ungated and whole on every member
             # of the group: blockwise, so its activations (2 x 1 408 wide

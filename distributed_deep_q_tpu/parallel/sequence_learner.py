@@ -519,6 +519,11 @@ class SequenceLearner:
             "moe_slots_held": lax.psum(jnp.sum(counters["slots_held"]),
                                        AXIS_DP),
             "moe_slots": lax.psum(jnp.sum(counters["slots"]), AXIS_DP),
+            # rows of the blocks the walk ran: over ``moe_slots_held`` it
+            # says how well the blocks fit the load (1.0: no row for
+            # nothing)
+            "moe_rows_run": lax.psum(jnp.sum(counters["rows_run"]),
+                                     AXIS_DP),
             "moe_overflow": lax.psum(jnp.sum(counters["overflow"]),
                                      AXIS_DP),
             "moe_load_max_over_mean": lax.pmean(jnp.mean(

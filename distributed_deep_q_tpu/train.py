@@ -708,6 +708,8 @@ def train_tokenq(cfg: Config, metrics: Metrics | None = None,
                         # the expert layer's counters (ops/moe.py)
                         "moe_slots_held_share": float(m["moe_slots_held"])
                         / max(float(m["moe_slots"]), 1.0),
+                        "moe_rows_run_over_held": float(m["moe_rows_run"])
+                        / max(float(m["moe_slots_held"]), 1.0),
                         "moe_load_max_over_mean": float(
                             m["moe_load_max_over_mean"]),
                         "moe_overflow": float(m["moe_overflow"]),

@@ -196,42 +196,54 @@ def test_latent_attention_core_compiles_for_v5e(one_chip):
 
 
 @pytest.mark.parametrize(
-    "preset,buffer_rows", [("smallthinker_tokenq", 49408),   # 8 193 x 6
-                           ("lfm2_tokenq", 33024),           # 8 193 x 4
-                           ("moonlight_tokenq", 49152)],     # 8 192 x 6
+    "preset,buffer_rows", [("smallthinker_tokenq", 196864),  # 4 x 8 193 x 6
+                           ("lfm2_tokenq", 65792),           # 2 x 8 193 x 4
+                           ("moonlight_tokenq", 98304)],     # 2 x 8 192 x 6
     ids=["smallthinker-reglu", "lfm2-swiglu", "moonlight-swiglu"])
 def test_held_experts_grouped_matmul_compiles_for_v5e(one_chip, preset,
                                                       buffer_rows):
-    """One sequence's expert layer as ``models/tokenq.layer`` calls it:
-    the preset's worst-case buffer (tile-rounded) at the preset's m-tile,
-    bfloat16, with its model's gate."""
+    """The expert layer as ``models/tokenq.feed_forward`` calls it: the
+    whole batch's token-slots, the preset's worst-case bound (tile-rounded)
+    walked in blocks of 16 m-tiles, bfloat16, with its model's gate. The
+    bound is never a buffer: no array of that many rows stands, float32
+    or bfloat16, forward or backward."""
     from distributed_deep_q_tpu.config import PRESETS
     from distributed_deep_q_tpu.models.tokenq import ACTS
     from distributed_deep_q_tpu.ops import moe
 
     cfg = PRESETS[preset]()
-    tq, n = cfg.net.tokenq, cfg.replay.sequence_length + 1
+    tq = cfg.net.tokenq
+    b, t = cfg.replay.batch_size, cfg.replay.sequence_length + 1
+    n = b * t
     k, held = tq.moe_num_active_primary_experts, tq.experts_held
     h, f = tq.hidden_size, tq.moe_ffn_hidden_size
     rows = moe.buffer_rows(n, k, held, tq.moe_tile)
     assert (rows, tq.moe_tile) == (buffer_rows, 256)
+    assert moe.block_rows(rows, tq.moe_tile) == 4096
     S = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
 
     def fwd_bwd(x, idx, p, wg, wu, wd):
-        f_ = lambda x, wg, wu, wd: jnp.sum(moe.held_experts_ffn(  # noqa: E731
-            x, idx, p, wg, wu, wd, offset=tq.expert_offset, rows=rows,
-            tile=tq.moe_tile, compute_dtype=jnp.dtype(
-                cfg.net.compute_dtype),
-            act=ACTS[tq.hidden_act])[0])
+        f_ = lambda x, wg, wu, wd: jnp.sum(jnp.sin(  # noqa: E731
+            moe.held_experts_ffn(
+                x, idx, p, wg, wu, wd, offset=tq.expert_offset, rows=rows,
+                tile=tq.moe_tile, compute_dtype=jnp.dtype(
+                    cfg.net.compute_dtype),
+                act=ACTS[tq.hidden_act])[0]))
         return jax.grad(f_, argnums=(0, 1, 2, 3))(x, wg, wu, wd)
 
     text = _compiled_text(
-        fwd_bwd, S((n, h), jnp.float32), S((n, k), jnp.int32),
+        fwd_bwd, S((b, t, h), jnp.float32), S((n, k), jnp.int32),
         S((n, k), jnp.float32), S((held, h, f), jnp.float32),
         S((held, h, f), jnp.float32), S((held, f, h), jnp.float32))
-    # forward gate+up (the down product's VALUE is not needed under a
-    # sum); backward: two input-side products and two weight-side ones
+    # a block's two forward products, again in the backward's recomputed
+    # block, and there two input-side products and two weight-side ones
     assert text.count('custom_call_target="tpu_custom_call"') >= 5
+    # the pin that the whole buffer never stands: f32[49408,2560] (a
+    # sequence's) and its twins are gone, and none batch-wide took their
+    # place (the sort's keys and the load's count are integers a slot)
+    wide = re.findall(rf"\b(?:f32|bf16)\[(?:{rows}|{n * k}),\d+\]", text)
+    assert not wide, sorted(set(wide))
+    assert f"f32[4096,{h}]" in text
 
 
 def _instructions(text: str) -> str:

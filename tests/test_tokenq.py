@@ -301,6 +301,86 @@ def test_held_experts_gradients_match_dense():
         np.testing.assert_allclose(g, w, atol=2e-5, err_msg=name)
 
 
+def _forced_inputs(first, second, h=64, f=32, e=8):
+    """Inputs whose router picks expert ``first[i]`` and then ``second[i]``
+    for token ``i``: the first ``e`` features of ``x`` name the two, and
+    the router reads them by an identity."""
+    first, second = np.asarray(first), np.asarray(second)
+    x, wr, wg, wu, wd = _moe_inputs(len(first), h, f, e)
+    x = x.at[:, :e].set(
+        6.0 * jax.nn.one_hot(first, e) + 4.0 * jax.nn.one_hot(second, e))
+    wr = (wr / 6.0).at[:e].set(2.0 * jnp.eye(e))
+    return x, wr, wg, wu, wd
+
+
+_I = np.arange(128)
+# name: (first choice, second choice, experts held, load of the held);
+# 128 tokens top 2 at m-tile 8: blocks of 128 rows
+WALKS = {
+    "no_slot_held": (0 * _I, 0 * _I + 1, (4, 8), [0, 0, 0, 0]),
+    "every_slot_held": (_I % 8, (_I + 1) % 8, (0, 8), [32] * 8),
+    "one_expert_takes_everything": (0 * _I + 1, 0 * _I + 5, (0, 4),
+                                    [0, 128, 0, 0]),
+    "group_boundary_on_a_block_edge": (0 * _I, np.where(_I < 64, 1, 5),
+                                       (0, 4), [128, 64, 0, 0]),
+    "last_block_held_by_one_row": (0 * _I, np.where(_I == 0, 1, 5), (0, 4),
+                                   [128, 1, 0, 0]),
+}
+
+
+@pytest.mark.parametrize("case", list(WALKS))
+def test_the_walk_is_exact_under_any_routing(case):
+    """The blocks that hold a slot run, no other, and what they add up to
+    is the dense share: value and all five gradients, nothing beyond the
+    buffer, whatever the router does."""
+    first, second, (lo, hi), load = WALKS[case]
+    a = _forced_inputs(first, second)
+    rows = moe.buffer_rows(128, 2, hi - lo, 8)
+    block = moe.block_rows(rows, 8)
+    assert (rows, block) == (256, 128)
+    y, c = _held(*a, lo, hi)
+    held = sum(load)
+    assert list(np.asarray(c["load"])) == load
+    assert int(c["slots_held"]) == held and int(c["overflow"]) == 0
+    assert int(c["rows_run"]) == -(-held // block) * block
+    if case == "every_slot_held":
+        assert int(c["rows_run"]) == rows
+    f = lambda fn: jax.grad(  # noqa: E731
+        lambda *b: jnp.sum(jnp.sin(fn(*b))), argnums=(0, 1, 2, 3, 4))(*a)
+    got = f(lambda *b: _held(*b, lo, hi)[0])
+    want = f(lambda *b: _dense_share(*b, lo, hi))
+    np.testing.assert_allclose(y, _dense_share(*a, lo, hi), atol=2e-5)
+    for g, w, name in zip(got, want, "x wr wg wu wd".split()):
+        # one expert's gradient sums 128 tokens here: entries of 1e2
+        np.testing.assert_allclose(g, w, atol=2e-5, rtol=1e-5, err_msg=name)
+    if not held:        # zero blocks ran: nothing, exactly
+        assert not np.asarray(y).any()
+        assert not any(np.asarray(g).any() for g in got)
+
+
+def test_a_batch_is_walked_whole_and_adds_up_to_its_sequences():
+    """``feed_forward`` sorts the whole batch's token-slots once: what it
+    returns is each sequence's own layer, and its counters are the
+    batch's."""
+    cfg = toy_cfg(experts_held=4)
+    w = ref.init_weights(SEED, toy_hp(toy_cfg()))
+    lp = {k[len("layer_01/"):]: jnp.asarray(v) for k, v in w.items()
+          if k.startswith("layer_01/")}
+    lp = {**lp, **{n: lp[n][:4] for n in ("w_gate", "w_up", "w_down")}}
+    x = jax.random.normal(jax.random.PRNGKey(3), (3, T + 1, 64))
+    whole, c = tokenq.feed_forward(x, lp, cfg.net, True)
+    parts = [tokenq.feed_forward(x[i:i + 1], lp, cfg.net, True)
+             for i in range(3)]
+    np.testing.assert_allclose(
+        whole, jnp.concatenate([y for y, _ in parts]), atol=2e-5)
+    for name in ("load", "slots_held", "slots"):
+        assert np.array_equal(c[name], sum(ci[name] for _, ci in parts))
+    assert int(c["overflow"]) == 0
+    tile = cfg.net.tokenq.moe_tile
+    block = moe.block_rows(moe.buffer_rows(3 * (T + 1), 2, 4, tile), tile)
+    assert int(c["rows_run"]) == -(-int(c["slots_held"]) // block) * block
+
+
 def test_vocabulary_slice_is_a_smaller_vocabulary(solver_and_hp):
     """Ids, argmax and loss are over the rows held: the head has V
     columns, the embedding V rows, and greedy actions lie in [0, V)."""
@@ -346,6 +426,8 @@ def test_token_ring_round_trip_and_per_writeback(solver_and_hp):
                                    ).astype(np.float32))
     assert np.isfinite(np.asarray(m["loss"])).all()
     assert np.asarray(m["moe_overflow"]).max() == 0
+    assert (np.asarray(m["moe_rows_run"]) >= np.asarray(
+        m["moe_slots_held"])).all()
     prio = np.asarray(ring.dmeta["prio"])
     drawn = np.zeros(64, bool)
     drawn[idx.reshape(-1)] = True
