@@ -18,6 +18,42 @@ from typing import Any
 
 
 @dataclass
+class RopeParameters:
+    """The rotary embedding of ONE kind of attention layer, under the
+    published keys of ``rope_parameters`` (laguna). ``rope_type`` "" =
+    none stated: the layer turns all of ``head_dim`` at
+    ``TokenQConfig.rope_theta``, as every layer did before a kind had
+    parameters of its own. "default": the first ``partial_rotary_factor ·
+    head_dim`` columns of each head turn at ``rope_theta``, the others
+    pass through. "yarn": the same columns at frequencies blended between
+    ``rope_theta``'s and those divided by ``factor`` (the ramp between the
+    pairs that turn ``beta_fast`` and ``beta_slow`` times inside
+    ``original_max_position_embeddings``), cos and sin multiplied by
+    ``attention_factor`` (``models/tokenq.rotary_table``)."""
+
+    rope_type: str = ""     # "" | default | yarn
+    rope_theta: float = 10_000.0
+    partial_rotary_factor: float = 1.0
+    factor: float = 1.0
+    original_max_position_embeddings: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+@dataclass
+class RopeKinds:
+    """``rope_parameters``: one ``RopeParameters`` a kind of attention
+    layer, named as ``layer_types`` names them in the source (a layer with
+    a sliding window is ``sliding_attention``, one without
+    ``full_attention``)."""
+
+    full_attention: RopeParameters = field(default_factory=RopeParameters)
+    sliding_attention: RopeParameters = field(
+        default_factory=RopeParameters)
+
+
+@dataclass
 class TokenQConfig:
     """Token-window Q-network backbone (``net.kind = "tokenq"``,
     ``models/tokenq.py``): a decoder-only backbone whose head row ``a``
@@ -28,9 +64,13 @@ class TokenQConfig:
     publishes (``qk_norm``, ``hidden_act``, ``router_input``: a
     configuration states them under ``assumed``); the defaults are a toy
     in SmallThinker's settings. Each layer is a token
-    mixer (attention, full or windowed; a gated short convolution;
-    attention over the keys a learned indexer selects; or latent
-    attention, keys and values expanded from one low-rank latent) and a
+    mixer (attention, full or windowed, with ``num_attention_heads`` query
+    heads or with ``num_attention_heads_per_layer[l]`` of them, a rotary
+    embedding whose parameters may belong to the KIND of layer, and with
+    ``gating`` a sigmoid gate a head on its output; a gated short
+    convolution; attention over the keys a learned indexer selects; or
+    latent attention, keys and values expanded from one low-rank latent)
+    and a
     feed-forward (dense, or the experts held here, with
     ``n_shared_experts`` beside a shared expert every token takes), read
     off these keys by
@@ -54,6 +94,21 @@ class TokenQConfig:
     rope_layout: tuple[int, ...] = (0, 1, 1, 1)
     sliding_window_size: int = 8
     rope_theta: float = 10_000.0
+    # a head count a LAYER (laguna): layer l's attention has entry l query
+    # heads of ``head_dim`` over the same ``num_key_value_heads`` (a longer
+    # published list is cut to the depth). Empty: ``num_attention_heads``
+    # on every layer
+    num_attention_heads_per_layer: tuple[int, ...] = ()
+    # the rotary embedding's parameters by KIND of attention layer
+    # (``RopeParameters``; a kind whose ``rope_type`` is "" turns all of
+    # ``head_dim`` at ``rope_theta`` above). ``rope_layout`` still says
+    # which layers turn at all
+    rope_parameters: RopeKinds = field(default_factory=RopeKinds)
+    # a sigmoid gate a HEAD a token on the attention output before W_o,
+    # read from the layer's normed input: ``o[h, t] *= sigmoid(u W_g)[t,
+    # h]``, ``W_g`` [hidden, heads of that layer], float32 (laguna's
+    # ``gating``)
+    gating: bool = False
     # token mixer per layer (LFM2's ``layer_types``): "conv" = the gated
     # short convolution (``ops/short_conv.py``; 3 taps, LFM2's
     # ``conv_L_cache``: ``models/tokenq.CONV_TAPS``), "full_attention" =
@@ -132,6 +187,13 @@ class TokenQConfig:
     # ``ops/moe.block_rows``)
     attn_block: int = 128
     attn_compute_block: int = 128
+    # the block of the layers with a sliding window, compute block and
+    # all (0: the two above): a window far under the block leaves most of
+    # a block's pairs outside the band
+    sliding_attn_block: int = 0
+    # the attention backward as ONE kernel, which keeps a partial dq for
+    # every kv block, or as two (``ops/attention.py``)
+    attn_fused_bwd: bool = True
     head_block: int = 128
     moe_tile: int = 128
 
@@ -859,6 +921,56 @@ def moonlight_tokenq_config() -> Config:
     return c
 
 
+def laguna_tokenq_config() -> Config:
+    """Laguna-XS.2 (poolside, config.json, ``model_type`` laguna) as a
+    token-window Q-network, one chip's share of a 16-chip expert-parallel
+    deployment: every width as published (hidden 2048, heads of 128 over 8
+    key/value heads: 48 on the full layers, 64 on the layers with a
+    sliding window of 512; the full layers turn half of each head under
+    YaRN at base 5e5, the sliding ones all of it at 1e4; a sigmoid gate a
+    head on the attention output; dense width 8 192; SwiGLU experts of
+    width 512, sigmoid router 256 wide, top 8, gates x 2.5, and a shared
+    expert of width 512 beside them); 5 layers = the published layers 0-4
+    (the leading dense layer, full; three sliding; one full: a whole
+    period), 16 of the 256 experts and 12 544 of the 100 352 vocabulary
+    rows held here. Windows of 16 384 steps (+1 token), chain 4, batch 1,
+    a ring of 8 192 windows."""
+    c = smallthinker_tokenq_config()
+    c.net = NetConfig(
+        kind="tokenq", num_actions=12_544, compute_dtype="bfloat16",
+        tokenq=TokenQConfig(
+            hidden_size=2048, num_hidden_layers=5, num_attention_heads=48,
+            num_attention_heads_per_layer=(48, 64, 64, 64, 48),
+            num_key_value_heads=8, head_dim=128, rms_norm_eps=1e-6,
+            sliding_window_layout=(0, 1, 1, 1, 0), rope_layout=(1,) * 5,
+            sliding_window_size=512,
+            rope_parameters=RopeKinds(
+                full_attention=RopeParameters(
+                    rope_type="yarn", rope_theta=500_000.0,
+                    partial_rotary_factor=0.5, factor=64.0,
+                    original_max_position_embeddings=4096,
+                    beta_fast=64.0, beta_slow=1.0,
+                    attention_factor=1.4158883083359672),
+                sliding_attention=RopeParameters(
+                    rope_type="default", rope_theta=10_000.0,
+                    partial_rotary_factor=1.0)),
+            gating=True, num_dense_layers=1, intermediate_size=8192,
+            hidden_act="silu", moe_primary_router_apply_softmax=False,
+            router_input="ffn_norm", moe_ffn_hidden_size=512,
+            moe_num_primary_experts=256,
+            moe_num_active_primary_experts=8, experts_held=16,
+            expert_offset=0, routed_scaling_factor=2.5, n_shared_experts=1,
+            attn_block=1024, attn_compute_block=512,
+            sliding_attn_block=512, attn_fused_bwd=False,
+            head_block=1024, moe_tile=256))
+    c.replay = dataclasses.replace(
+        c.replay, capacity=8_192 * 16_384, batch_size=1,
+        sequence_length=16_384, learn_start=64 * 16_384)
+    c.train = dataclasses.replace(c.train, train_every=16_384)
+    c.env = dataclasses.replace(c.env, token_vocab=12_544)
+    return c
+
+
 def env_for_actor(env: EnvConfig, actor_id: int) -> EnvConfig:
     """Per-actor game assignment (config 4 multi-game fleets): actor i
     plays ``games[i % len(games)]``; single-game configs pass through."""
@@ -879,6 +991,7 @@ PRESETS = {
     "lfm2_tokenq": lfm2_tokenq_config,
     "keye_tokenq": keye_tokenq_config,
     "moonlight_tokenq": moonlight_tokenq_config,
+    "laguna_tokenq": laguna_tokenq_config,
 }
 
 

@@ -15,9 +15,16 @@ mixer and a feed-forward in pre-norm residual form, input x ``[B, T, h]``
   - attention (grouped-query, causal; ``sliding_window_layout[l]`` = 1
     limits it to the last ``sliding_window_size`` keys,
     ``rope_layout[l]`` = 1 rotates q/k in the rotate-half convention,
-    theta ``rope_theta``; with ``qk_norm`` an RMSNorm of each head of q
-    and k before the rotation; ``ops/attention.causal_attention`` serves
-    every kind): ``m = attn(u) · W_o``;
+    theta ``rope_theta`` — or, where ``rope_parameters`` states the
+    layer's KIND (full / sliding), the first ``partial_rotary_factor ·
+    head_dim`` columns at that kind's base, blended and scaled under YaRN
+    (``rotary_table``, ``rotary_by_table``), the other columns unturned;
+    with ``qk_norm`` an RMSNorm of each head of q and k before the
+    rotation; ``num_attention_heads_per_layer[l]`` query heads where the
+    configuration counts them a layer; ``ops/attention.causal_attention``
+    serves every kind): ``m = attn(u) · W_o``, and with ``gating``
+    ``m = (sigmoid(u W_g) ⊙_head attn(u)) · W_o``, one float32 gate a
+    head a token;
   - ``layer_types[l] == "conv"``: the gated short convolution,
     ``m = short_conv_mix(u · W_in, w_conv) · W_out``
     (``ops/short_conv.py``: ``CONV_TAPS`` causal taps between two
@@ -69,7 +76,11 @@ bias reading the second norm. Keye-VL-2.0's: sparse attention with q/k
 norms and rope on every layer, SwiGLU experts behind a softmax router
 reading the second norm. Moonlight's (``deepseek_v3``): latent attention
 on every layer, a leading dense layer, SwiGLU experts behind LFM2's
-router with scaled gates, and a shared expert beside them.
+router with scaled gates, and a shared expert beside them. Laguna's:
+window and full attention at a head count and a rotary embedding of
+their own (YaRN over half of each head on the full layers), a gate a head
+on every attention output, a leading dense layer, SwiGLU experts behind a
+sigmoid router with no bias and scaled gates, a shared expert.
 
 Then the final RMSNorm; the untied head ``[h, V]`` is applied by the
 learner, blockwise over tokens, together with the TD loss
@@ -92,8 +103,10 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from distributed_deep_q_tpu.config import NetConfig, TokenQConfig
+from distributed_deep_q_tpu.config import (
+    NetConfig, RopeParameters, TokenQConfig)
 from distributed_deep_q_tpu.ops import moe, sparse_attention
 from distributed_deep_q_tpu.ops.attention import causal_attention
 from distributed_deep_q_tpu.ops.short_conv import short_conv_mix
@@ -105,16 +118,20 @@ ACTS = {"relu": jax.nn.relu, "silu": jax.nn.silu}       # ``hidden_act``
 ROUTER_INPUTS = ("pre_mixer", "ffn_norm")
 MIXERS = ("conv", "full_attention", "sparse_attention",     # ``layer_types``
           "latent_attention")
+ROPE_TYPES = ("default", "yarn")
 
 
 def layer_name(i: int) -> str:
     return f"layer_{i:02d}"
 
 
-def layer_plan(tq: TokenQConfig) -> list[dict[str, bool]]:
-    """Per layer ``{windowed, rope, conv, sparse, latent, dense}``: the
-    mixer from ``layer_types`` (absent: attention) and the two layouts,
-    the feed-forward from ``num_dense_layers``."""
+def layer_plan(tq: TokenQConfig) -> list[dict[str, Any]]:
+    """Per layer ``{windowed, rope, conv, sparse, latent, dense}`` (bools):
+    the mixer from ``layer_types`` (absent: attention) and the two
+    layouts, the feed-forward from ``num_dense_layers``; ``heads``, the
+    layer's query heads (``num_attention_heads_per_layer``; absent:
+    ``num_attention_heads``); ``rope_params``, the ``RopeParameters`` of
+    the layer's kind (``None``: ``rope_theta`` over all of ``head_dim``)."""
     n = tq.num_hidden_layers
     if len(tq.sliding_window_layout) < n or len(tq.rope_layout) < n:
         raise ValueError(
@@ -131,13 +148,38 @@ def layer_plan(tq: TokenQConfig) -> list[dict[str, bool]]:
         raise ValueError(f"router_input must be one of {ROUTER_INPUTS}: "
                          f"{tq.router_input!r}")
     kinds = tq.layer_types[:n] or ("full_attention",) * n
+    per_layer = tuple(int(x) for x in tq.num_attention_heads_per_layer)
+    if per_layer and len(per_layer) < n:
+        raise ValueError(f"num_attention_heads_per_layer must cover {n} "
+                         f"layers: {per_layer}")
     plan = [{"windowed": bool(tq.sliding_window_layout[i]),
              "rope": bool(tq.rope_layout[i]),
              "conv": kinds[i] == "conv",
              "sparse": kinds[i] == "sparse_attention",
              "latent": kinds[i] == "latent_attention",
-             "dense": i < tq.num_dense_layers}
+             "dense": i < tq.num_dense_layers,
+             "heads": per_layer[i] if per_layer
+             else tq.num_attention_heads,
+             "rope_params": _rope_params(
+                 tq, bool(tq.sliding_window_layout[i]))}
             for i in range(n)]
+    plain = [not (k["conv"] or k["sparse"] or k["latent"]) for k in plan]
+    if any(k["heads"] % tq.num_key_value_heads
+           for k, p in zip(plan, plain) if p):
+        raise ValueError(
+            f"{tq.num_key_value_heads} key/value heads do not divide the "
+            f"query heads of every layer: {[k['heads'] for k in plan]}")
+    if (per_layer or any(k["rope_params"] for k in plan)) and any(
+            k["sparse"] or k["latent"] for k in plan):
+        raise ValueError("a head count a layer and rotary parameters a "
+                         "kind of layer are the plain attention mixer's: "
+                         f"{kinds}")
+    if tq.gating and not all(plain):
+        raise ValueError(f"gating is the plain attention mixer's gate: "
+                         f"{kinds}")
+    if tq.gating and tq.qk_norm:
+        raise ValueError("gating together with qk_norm is held to no "
+                         "reference")
     if any((k["sparse"] or k["latent"]) and k["windowed"] for k in plan):
         raise ValueError("a sparse_attention or latent_attention layer "
                          f"takes no sliding window: "
@@ -146,6 +188,55 @@ def layer_plan(tq: TokenQConfig) -> list[dict[str, bool]]:
         raise ValueError("a latent_attention layer takes no qk_norm (its "
                          "latent has a norm of its own: kv_norm)")
     return plan
+
+
+def _rope_params(tq: TokenQConfig, windowed: bool) -> RopeParameters | None:
+    """The rotary parameters of a layer's kind where the configuration
+    states them, checked; ``None`` where it states none."""
+    rp = (tq.rope_parameters.sliding_attention if windowed
+          else tq.rope_parameters.full_attention)
+    if not rp.rope_type:
+        return None
+    if rp.rope_type not in ROPE_TYPES:
+        raise ValueError(f"rope_type must be one of {ROPE_TYPES}: "
+                         f"{rp.rope_type!r}")
+    width = rp.partial_rotary_factor * tq.head_dim
+    if width != int(width) or int(width) % 2 or not 0 < width <= tq.head_dim:
+        raise ValueError(
+            f"partial_rotary_factor {rp.partial_rotary_factor} of head_dim "
+            f"{tq.head_dim} is no even number of columns")
+    if rp.rope_type == "yarn" and not (
+            rp.factor >= 1 and rp.original_max_position_embeddings > 0):
+        raise ValueError(f"yarn needs a factor >= 1 and the original "
+                         f"positions: {rp}")
+    return rp
+
+
+def rotary_table(rp: RopeParameters, head_dim: int):
+    """A kind's rotary embedding as constants of the program → (inverse
+    frequencies [r / 2] float32, the factor on cos and sin); r =
+    ``partial_rotary_factor · head_dim`` columns turn. "default":
+    ``rope_theta^(-2i/r)``, factor 1. "yarn" (as ``transformers`` computes
+    it): pair i's frequency is ``e_i = rope_theta^(-2i/r)`` below ``low``,
+    ``e_i / factor`` above ``high`` and a linear blend between, ``low`` /
+    ``high`` the pairs that turn ``beta_fast`` / ``beta_slow`` times
+    inside the original positions; the factor is ``attention_factor``.
+    Computed with numpy in float64 and rounded once."""
+    r = int(rp.partial_rotary_factor * head_dim)
+    i = np.arange(r // 2, dtype=np.float64)
+    extra = float(rp.rope_theta) ** (-2.0 * i / r)
+    if rp.rope_type != "yarn":
+        return extra.astype(np.float32), 1.0
+
+    def pair(turns: float) -> float:    # the pair that turns ``turns`` times
+        return (r * np.log(rp.original_max_position_embeddings
+                           / (turns * 2.0 * np.pi))
+                / (2.0 * np.log(rp.rope_theta)))
+    low = max(int(np.floor(pair(rp.beta_fast))), 0)
+    high = min(int(np.ceil(pair(rp.beta_slow))), r - 1)
+    ramp = np.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    inv = extra / rp.factor * ramp + extra * (1.0 - ramp)
+    return inv.astype(np.float32), float(rp.attention_factor)
 
 
 def param_shapes(cfg: NetConfig) -> dict[str, Any]:
@@ -157,10 +248,14 @@ def param_shapes(cfg: NetConfig) -> dict[str, Any]:
         raise ValueError(
             f"experts [{tq.expert_offset}, {tq.expert_offset + e}) are not "
             f"among {tq.moe_num_primary_experts}")
-    attention = {"w_q": (h, hq * d), "w_k": (h, hkv * d),
-                 "w_v": (h, hkv * d), "w_o": (hq * d, h)}
-    if tq.qk_norm:
-        attention.update({"q_norm": (d,), "k_norm": (d,)})
+    def attention(heads: int) -> dict[str, tuple]:
+        out = {"w_q": (h, heads * d), "w_k": (h, hkv * d),
+               "w_v": (h, hkv * d), "w_o": (heads * d, h)}
+        if tq.qk_norm:
+            out.update({"q_norm": (d,), "k_norm": (d,)})
+        if tq.gating:
+            out["w_g"] = (h, heads)
+        return out
     dn, dr, dv, r = (tq.qk_nope_head_dim, tq.qk_rope_head_dim,
                      tq.v_head_dim, tq.kv_lora_rank)
     latent = {"w_q": (h, hq * (dn + dr)), "w_kva": (h, r + dr),
@@ -187,7 +282,7 @@ def param_shapes(cfg: NetConfig) -> dict[str, Any]:
         shapes[layer_name(i)] = {
             "norm_1": (h,), "norm_2": (h,),
             **(conv if kind["conv"] else latent if kind["latent"]
-               else attention),
+               else attention(kind["heads"])),
             **(indexer if kind["sparse"] else {}),
             **(dense if kind["dense"] else experts)}
     return shapes
@@ -233,11 +328,29 @@ def rmsnorm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
     return x * jax.lax.rsqrt(var + eps) * w
 
 
+def rotary_by_table(x: jax.Array, inv, factor: float) -> jax.Array:
+    """Rotary embedding over ``[B, H, T, D]`` at positions 0..T-1 from a
+    kind's table (``rotary_table``: ``inv`` [r / 2]), float32: only the
+    FIRST r columns of each head turn, rotate-half among themselves
+    (i with i + r/2), pair i by ``t · inv[i]``, cos and sin multiplied by
+    ``factor``; the other D - r pass through unturned and unscaled."""
+    d, t = x.shape[-1], x.shape[-2]
+    r = 2 * inv.shape[0]
+    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos = jnp.concatenate([jnp.cos(ang)] * 2, -1) * factor
+    sin = jnp.concatenate([jnp.sin(ang)] * 2, -1) * factor
+    x1, x2 = x[..., :r // 2], x[..., r // 2:r]
+    turned = x[..., :r] * cos + jnp.concatenate([-x2, x1], -1) * sin
+    return jnp.concatenate([turned, x[..., r:]], -1) if r < d else turned
+
+
 def rotary(x: jax.Array, theta: float,
            interleave: bool = False) -> jax.Array:
     """Rotary embedding over ``[B, H, T, D]`` at positions 0..T-1,
-    float32: pair i turns by ``t · theta^(-2i/D)``. Rotate-half pairs
-    element i with i + D/2; ``interleave`` pairs 2i with 2i + 1."""
+    float32, ONE base over all of ``D`` and no scaling (a kind of layer
+    with rotary parameters of its own turns by ``rotary_by_table``): pair
+    i turns by ``t · theta^(-2i/D)``. Rotate-half pairs element i with
+    i + D/2; ``interleave`` pairs 2i with 2i + 1."""
     d, t = x.shape[-1], x.shape[-2]
     inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
@@ -351,23 +464,27 @@ def latent_attention(u: jax.Array, p: dict[str, jax.Array],
 def mixer(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
           windowed: bool, rope: bool, interpret: bool, *,
           conv: bool = False, dense: bool = False, sparse: bool = False,
-          latent: bool = False, index_loss: bool = True):
+          latent: bool = False, index_loss: bool = True, heads: int = 0,
+          rope_params: RopeParameters | None = None):
     """A block's first half, ``x' = x + m``; ``x`` [B, T, h] float32 →
     (x', the routing where the router reads the mixer's input — else
-    ``None`` —, a sparse mixer's counters — else ``None``). The mixer is
-    attention (``windowed``, ``rope``), with ``conv`` the gated short
-    convolution, with ``sparse`` attention over the keys its indexer
-    selects (``index_loss``: with the indexer's loss), with ``latent``
-    latent attention."""
+    ``None`` —, the mixer's counters: a sparse mixer's, under ``gating``
+    the gate's mean over tokens and heads — else ``None``). The mixer is
+    attention (``windowed``, ``rope``; ``heads`` query heads, 0:
+    ``num_attention_heads``; ``rope_params``: the rotary parameters of
+    the layer's kind), with ``conv`` the gated short convolution, with
+    ``sparse`` attention over the keys its indexer selects
+    (``index_loss``: with the indexer's loss), with ``latent`` latent
+    attention."""
     tq = cfg.tokenq
     router_first = tq.router_input == "pre_mixer"
     dtype = jnp.dtype(cfg.compute_dtype)
     b, t, _ = x.shape
-    hq, hkv, d = (tq.num_attention_heads, tq.num_key_value_heads,
+    hq, hkv, d = (heads or tq.num_attention_heads, tq.num_key_value_heads,
                   tq.head_dim)
     u = rmsnorm(x, p["norm_1"], tq.rms_norm_eps)
     route = _route(u, p, tq) if router_first and not dense else None
-    dsa = None
+    counters = None
     if conv:
         with jax.named_scope("ddq.short_conv"):
             bcz = _mm(u, p["w_in"], dtype)
@@ -395,27 +512,43 @@ def mixer(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
             if tq.qk_norm:
                 q = rmsnorm(q, p["q_norm"], tq.rms_norm_eps)
                 k = rmsnorm(k, p["k_norm"], tq.rms_norm_eps)
-            if rope:
+            if rope and rope_params is not None:
+                with jax.named_scope("ddq.rotary"):
+                    table = rotary_table(rope_params, d)
+                    q = rotary_by_table(q, *table)
+                    k = rotary_by_table(k, *table)
+            elif rope:
                 q, k = rotary(q, tq.rope_theta), rotary(k, tq.rope_theta)
             if sparse:
                 with jax.named_scope("ddq.indexer"):
                     indexer = _indexer_inputs(um, p, tq, rope)
-                a, dsa = sparse_attention.sparse_attention(
+                a, counters = sparse_attention.sparse_attention(
                     q.astype(dtype), k.astype(dtype), v.astype(dtype),
                     *indexer, topk=tq.indexer_topk,
                     block=tq.indexer_q_chunk, t_real=t,
                     with_loss=index_loss, interpret=interpret)
                 a = a[:, :t]
             else:
+                block, compute = (
+                    (tq.sliding_attn_block, 0)
+                    if windowed and tq.sliding_attn_block
+                    else (tq.attn_block, tq.attn_compute_block))
                 a = causal_attention(
                     q.astype(dtype), k.astype(dtype), v.astype(dtype),
                     window=tq.sliding_window_size if windowed else 0,
-                    block=tq.attn_block,
-                    compute_block=tq.attn_compute_block,
-                    interpret=interpret)
-                a = a.transpose(0, 2, 1, 3).reshape(b, t, hq * d)
+                    block=block, compute_block=compute,
+                    fused_bwd=tq.attn_fused_bwd, interpret=interpret)
+                a = a.transpose(0, 2, 1, 3)
+                if tq.gating:
+                    with jax.named_scope("ddq.attn_gate"):
+                        gate = jax.nn.sigmoid(jnp.dot(
+                            u, p["w_g"], precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32))
+                        a = a * gate[..., None]
+                        counters = {"attn_gate_mean": jnp.mean(gate)}
+                a = a.reshape(b, t, hq * d)
             x = x + _mm(a, p["w_o"], dtype)
-    return x, route, dsa
+    return x, route, counters
 
 
 def feed_forward(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
@@ -467,17 +600,22 @@ def feed_forward(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
 def layer(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
           windowed: bool, rope: bool, interpret: bool, *,
           conv: bool = False, dense: bool = False, sparse: bool = False,
-          latent: bool = False, index_loss: bool = True):
+          latent: bool = False, index_loss: bool = True, heads: int = 0,
+          rope_params: RopeParameters | None = None):
     """One block, ``mixer`` then ``feed_forward``; ``x`` [B, T, h] float32
-    → (x, the layer's counters: the expert layer's, and under ``"dsa"`` a
-    sparse mixer's; ``None`` where it has neither)."""
-    x, route, dsa = mixer(x, p, cfg, windowed, rope, interpret, conv=conv,
-                          dense=dense, sparse=sparse, latent=latent,
-                          index_loss=index_loss)
+    → (x, the layer's counters: the expert layer's, under ``"dsa"`` a
+    sparse mixer's, and a gated mixer's ``attn_gate_mean``; ``None`` where
+    it has none)."""
+    x, route, mixed = mixer(x, p, cfg, windowed, rope, interpret, conv=conv,
+                            dense=dense, sparse=sparse, latent=latent,
+                            index_loss=index_loss, heads=heads,
+                            rope_params=rope_params)
     x, counters = feed_forward(x, p, cfg, interpret, dense=dense,
                                route=route)
     if sparse:
-        counters = {**(counters or {}), "dsa": dsa}
+        counters = {**(counters or {}), "dsa": mixed}
+    elif mixed is not None:
+        counters = {**(counters or {}), **mixed}
     return x, counters
 
 
@@ -494,16 +632,19 @@ def backbone(params: dict[str, Any], tokens: jax.Array, cfg: NetConfig,
     ``dsa_selected`` / ``dsa_causal`` (pairs), ``dsa_index_loss`` (0
     without ``index_loss``: θ⁻ and the acting path) and ``dsa_bits`` (the
     selected pairs as bits: a caller that does not read them drops them,
-    and the compiler with it). Only the attention kernels pad the window
+    and the compiler with it); under ``gating`` ``attn_gate_mean``, the
+    gate's mean over tokens and heads stacked over the layers. Only the
+    attention kernels pad the window
     (to their blocks); every other product runs on T."""
     tq = cfg.tokenq
     x = params["embed"][tokens]
-    counters, dsa = [], []
+    counters, dsa, gates = [], [], []
     for i, kind in enumerate(layer_plan(tq)):
         p = params[layer_name(i)]
         kw = dict(conv=kind["conv"], dense=kind["dense"],
                   sparse=kind["sparse"], latent=kind["latent"],
-                  index_loss=index_loss)
+                  index_loss=index_loss, heads=kind["heads"],
+                  rope_params=kind["rope_params"])
         if kind["sparse"] or kind["latent"]:
             # the two halves rematerialised apart: what the mixer's
             # backward needs (q, k, v, the indexer's inputs, o: 2.5 GB at
@@ -526,10 +667,15 @@ def backbone(params: dict[str, Any], tokens: jax.Array, cfg: NetConfig,
                 lambda x, p, kind=kind, kw=kw: layer(
                     x, p, cfg, kind["windowed"], kind["rope"], interpret,
                     **kw))(x, p)
-        if c is not None:
+        if c is not None and "attn_gate_mean" in c:
+            # a dense layer has a gate too: stacked over the GATED layers
+            gates.append(c.pop("attn_gate_mean"))
+        if c:
             counters.append(c)
     x = rmsnorm(x, params["final_norm"], tq.rms_norm_eps)
     out = jax.tree.map(lambda *a: jnp.stack(a), *counters)
+    if gates:
+        out["attn_gate_mean"] = jnp.stack(gates)
     if dsa:
         out.update({f"dsa_{k}": jnp.stack([d[k] for d in dsa])
                     for k in dsa[0]})

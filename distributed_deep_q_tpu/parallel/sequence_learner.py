@@ -541,6 +541,12 @@ class SequenceLearner:
                     jnp.sum(counters["dsa_causal"]), AXIS_DP),
                 "dsa_index_loss": lax.pmean(
                     jnp.sum(counters["dsa_index_loss"]), AXIS_DP)})
+        if "attn_gate_mean" in counters:
+            # the attention output gates: their mean over tokens, heads
+            # and the gated layers (0.5 at zero weights; where training
+            # closes heads it falls)
+            metrics["attn_gate_mean"] = lax.pmean(
+                jnp.mean(counters["attn_gate_mean"]), AXIS_DP)
         return (TrainState(params, target_params, opt_state, step), metrics,
                 priority)
 

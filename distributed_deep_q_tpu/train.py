@@ -720,6 +720,9 @@ def train_tokenq(cfg: Config, metrics: Metrics | None = None,
                                 m["dsa_pairs_selected"])
                             / max(float(m["dsa_pairs_causal"]), 1.0),
                             "dsa_index_loss": float(m["dsa_index_loss"])})
+                    if "attn_gate_mean" in m:       # gated attention only
+                        summary["attn_gate_mean"] = float(
+                            m["attn_gate_mean"])
                     metrics.gauge("queue/replay_size", len(replay))
                     metrics.log(gsteps, **summary, **metrics.telemetry())
     trace.close()

@@ -9,7 +9,9 @@ so every xdist worker collects the same tests. A compile that passes is
 not a chip run.
 """
 
+import collections
 import functools
+import json
 import math
 import os
 import re
@@ -518,3 +520,179 @@ def test_b512_train_program_unpacks_by_planes_for_v5e(topo, one_chip):
                           (f"{rowp // 128},128", f"{rowp // 128},512")):
         assert f"[{batch},{window},{words},4]" not in text
         assert f"u8[{batch},{window},{bytes_}]" not in text
+
+
+# -- Laguna-XS.2's two kinds of attention layer (config.laguna_tokenq_config):
+# 48 heads under the causal mask and 64 under a band of 512 keys over the
+# same 8 key/value heads, on windows of 16 385 tokens.
+
+@pytest.mark.parametrize("windowed", [False, True], ids=["full", "sliding"])
+def test_laguna_attention_kinds_compile_for_v5e(one_chip, windowed):
+    """Each kind at its own head count, block and backward. The fused
+    backward keeps one partial dq a KV BLOCK whatever the mask: under the
+    band at a block of 512 that is 33 x 277 MB = 9.1 GB of temporaries for
+    ONE layer and under the causal mask at 1 024 17 x 214 MB = 3.6 GB,
+    which is why both kinds ask for the two separate kernels here: with
+    them the whole train program stands at 12.8 GB beside a ring of 1.2
+    (PERF.md §6, PR 40)."""
+    from distributed_deep_q_tpu.config import PRESETS
+    from distributed_deep_q_tpu.models import tokenq
+    from distributed_deep_q_tpu.ops.attention import causal_attention
+
+    cfg = PRESETS["laguna_tokenq"]()
+    tq, t = cfg.net.tokenq, cfg.replay.sequence_length + 1
+    kind = next(k for k in tokenq.layer_plan(tq) if k["windowed"] == windowed)
+    block, compute, window = (
+        (tq.sliding_attn_block, 0, tq.sliding_window_size) if windowed
+        else (tq.attn_block, tq.attn_compute_block, 0))
+    fused = tq.attn_fused_bwd
+    assert (t, kind["heads"], window, block, fused) == (
+        (16385, 64, 512, 512, False) if windowed
+        else (16385, 48, 0, 1024, False))
+    S = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.bfloat16,
+                          sharding=one_chip)
+    q = S((1, kind["heads"], t, tq.head_dim))
+    kv = S((1, tq.num_key_value_heads, t, tq.head_dim))
+
+    def fwd_bwd(fused_bwd):
+        def run(q, k, v):
+            f = lambda *a: jnp.sum(causal_attention(  # noqa: E731
+                *a, window=window, block=block, compute_block=compute,
+                fused_bwd=fused_bwd).astype(jnp.float32))
+            return jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+        return jax.jit(run).lower(q, kv, kv).compile()
+
+    two = fwd_bwd(fused)
+    for kernel in ("splash_mqa_fwd", "splash_mqa_dkv", "splash_mqa_dq"):
+        assert kernel in two.as_text()
+    blocks = -(-t // block)
+    dq = 2 * kind["heads"] * blocks * block * tq.head_dim      # bfloat16
+    one = fwd_bwd(True).memory_analysis().temp_size_in_bytes
+    assert one > blocks * dq                # a partial dq a kv block
+    assert two.memory_analysis().temp_size_in_bytes < one / 3
+
+
+def test_the_sibling_presets_state_none_of_lagunas_mechanisms():
+    """What this family added is DATA whose defaults are what the four
+    presets ran before: the head count of the configuration on every
+    layer, one ``rope_theta`` over the whole head (no table, so no
+    ``ddq.rotary`` scope), no gate leaf, the one attention block and the
+    fused backward."""
+    from distributed_deep_q_tpu.config import PRESETS
+    from distributed_deep_q_tpu.models import tokenq
+
+    for preset in ("tokenq", "smallthinker_tokenq", "lfm2_tokenq",
+                   "keye_tokenq", "moonlight_tokenq"):
+        cfg = PRESETS[preset]()
+        tq = cfg.net.tokenq
+        assert all(k["heads"] == tq.num_attention_heads
+                   and k["rope_params"] is None
+                   for k in tokenq.layer_plan(tq)), preset
+        assert (tq.gating, tq.sliding_attn_block,
+                tq.attn_fused_bwd) == (False, 0, True)
+        shapes = tokenq.param_shapes(cfg.net)
+        assert not any("w_g" in v for v in shapes.values()
+                       if isinstance(v, dict)), preset
+
+
+# -- the token families' whole train programs, lowered for one chip ----------
+
+def _lowered_token_train_program(topo, preset: str):
+    """``SequenceLearner``'s fused token TRAIN program of a preset at its
+    own sizes, lowered (not compiled) for one described chip."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from distributed_deep_q_tpu.config import PRESETS
+    from distributed_deep_q_tpu.models import tokenq
+    from distributed_deep_q_tpu.parallel.learner import TrainState
+    from distributed_deep_q_tpu.parallel.sequence_learner import (
+        SequenceLearner)
+
+    cfg = PRESETS[preset]()
+    mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1), ("dp", "model"))
+    S = _sharded_aval(mesh)
+    learner = SequenceLearner(None, cfg.train, cfg.replay, mesh,
+                              net_cfg=cfg.net)
+    rep = cfg.replay
+    chain, b, t = rep.fused_chain, rep.batch_size, rep.sequence_length
+    caps = rep.capacity // t
+    _, train = learner._build_token_fused_steps(
+        (caps, t, b, rep.priority_alpha, rep.priority_eps, 1,
+         cfg.train.gamma), chain)
+    params = jax.tree.map(
+        lambda s: S(s, jnp.float32), tokenq.param_shapes(cfg.net),
+        is_leaf=lambda x: isinstance(x, tuple))
+    opt = jax.tree.map(lambda a: S(a.shape, a.dtype),
+                       jax.eval_shape(learner.opt.init, params))
+    state = TrainState(params=params, target_params=params, opt_state=opt,
+                       step=S((), jnp.int32))
+    steps = {k: S((chain, b, t), jnp.float32, None, "dp", None)
+             for k in ("reward", "discount", "mask")}
+    batch = {"tokens": S((chain, b, t + 1), jnp.int32, None, "dp", None),
+             "weight": S((chain, b), jnp.float32, None, "dp"), **steps}
+    return train.lower(state, batch, S((chain, b), jnp.int32, None, "dp"),
+                       S((caps,), jnp.float32, "dp"), S((), jnp.float32))
+
+
+# What each sibling's lowered train program hands the chip's compiler, in
+# a form a diff can read (``tests/fixtures/sibling_train_programs.json``):
+# how often each operation stands in it, and every Mosaic kernel by name
+# with its operand and result types (a kernel's serialised body carries
+# the Python line numbers of whoever called it and is not read). Read on
+# the parent of PR 40 and on its change: equal. Written anew by
+# ``PYTHONPATH=. python tests/test_chip_compile.py``.
+SIBLING_PRESETS = ("keye_tokenq", "lfm2_tokenq", "moonlight_tokenq",
+                   "smallthinker_tokenq")
+SIBLING_PROGRAMS = os.path.join(os.path.dirname(__file__), "fixtures",
+                                "sibling_train_programs.json")
+
+
+def _program_summary(text: str) -> dict:
+    """{"ops": {operation: count}, "kernels": {"name (operands) ->
+    results": count}} of a lowered program's text."""
+    ops = collections.Counter(re.findall(
+        r"\b((?:stablehlo|chlo|func|sdy)\.[a-z_0-9]+)\b", text))
+    kernels = collections.Counter(
+        f"{name} {types}" for name, types in re.findall(
+            r'@tpu_custom_call\(.*kernel_name = "([^"]*)".* : (\(.*)$',
+            text, re.M))
+    assert sum(kernels.values()) == text.count("@tpu_custom_call(")
+    return {"ops": dict(sorted(ops.items())),
+            "kernels": dict(sorted(kernels.items()))}
+
+
+def _moved(was: dict, now: dict) -> dict:
+    """What differs between two summaries: {part: {entry: (was, now)}}."""
+    return {part: moved for part in ("ops", "kernels") if (moved := {
+        k: (was[part].get(k, 0), now[part].get(k, 0))
+        for k in sorted({*was[part], *now[part]})
+        if was[part].get(k, 0) != now[part].get(k, 0)})}
+
+
+@pytest.mark.parametrize("preset", SIBLING_PRESETS)
+def test_a_sibling_train_program_is_the_one_it_was_for_v5e(
+        topo, one_chip, preset):
+    """A mechanism added to the shared backbone for ONE family is data
+    whose default is what the others ran: their whole train programs lower
+    to the operations and kernels they had. A change that MEANS to move
+    one (shared code made faster) writes the file anew and says so; the
+    failure names each operation and kernel that moved, with both counts."""
+    with open(SIBLING_PROGRAMS) as fh:
+        was = json.load(fh)[preset]
+    now = _program_summary(
+        _lowered_token_train_program(topo, preset).as_text())
+    assert not _moved(was, now), (
+        f"{preset}'s train program moved (was, now): {_moved(was, now)}")
+
+
+if __name__ == "__main__":      # write the fixture anew
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    described = topologies.get_topology_desc(platform="tpu",
+                                             topology_name="v5e:2x2")
+    with open(SIBLING_PROGRAMS, "w") as fh:
+        json.dump({p: _program_summary(_lowered_token_train_program(
+            described, p).as_text()) for p in SIBLING_PRESETS}, fh,
+            indent=1, sort_keys=True)
+        fh.write("\n")
