@@ -249,6 +249,17 @@ def refresh_target(cfg: TrainConfig, params: Any, target_params: Any,
 # were 86 of the batch-32 step's 86.8 ms, PERF.md §6 PR 25).
 # Tree↔plane conversion happens once per chunk at the scan boundary,
 # amortized over ``chain`` grad steps.
+# "Contiguous copies" holds for the slices, not for what the TPU compiler
+# makes of ``plane[off:off + size].reshape(shape)``: it commutes the two
+# into a slice of a RESHAPED PLANE, and for a leaf whose minor dimension
+# is under the 128 lanes (the head's [512, 4] kernel) that is the whole
+# plane laid out 4 wide, each (8, 128) tile padded 32x — 431 MB written
+# to cut 8 KB out of the [2N] plane, 215 MB more a moment plane: three
+# reshapes that were 0.319 of the batch-32 step's 0.808 ms (PERF.md §6
+# PR 41). XLA:CPU never commutes them, so no CPU census saw it. Hence
+# ``_plane_blocks``: every leaf's 1-D block is cut first, ONE
+# ``optimization_barrier`` over the blocks of a plane holds the compiler
+# from moving a reshape across the cut, and each block is reshaped alone.
 
 class PlaneMeta(NamedTuple):
     """Static layout of the flat planes, derived from the param treedef:
@@ -299,18 +310,26 @@ def plane_stacked_views(meta: PlaneMeta, pt: jax.Array) -> tuple:
         for off, size, shape in zip(meta.offsets, meta.sizes, meta.shapes))
 
 
+def _plane_blocks(plane: jax.Array, bounds) -> tuple:
+    """The 1-D blocks ``plane[lo:hi]``, cut BEFORE anything reshapes them:
+    one barrier over them all, so each later reshape moves its own block
+    and never the plane (the comment over ``PlaneMeta`` has the numbers)."""
+    return lax.optimization_barrier(
+        tuple(plane[lo:hi] for lo, hi in bounds))
+
+
 @jax.named_scope("ddq.plane_unpack")
 def plane_to_param_trees(meta: PlaneMeta, pt: jax.Array,
                          params: Any, target_params: Any) -> tuple:
     """Inverse of ``params_to_plane`` — dtypes restored per template."""
+    halves = _plane_blocks(pt, [
+        (2 * off + h * size, 2 * off + (h + 1) * size)
+        for off, size in zip(meta.offsets, meta.sizes) for h in (0, 1)])
     new_p, new_t = [], []
-    for off, size, shape, tmpl in zip(
-            meta.offsets, meta.sizes, meta.shapes,
-            jax.tree_util.tree_leaves(params)):
-        o2 = 2 * off
-        new_p.append(pt[o2:o2 + size].reshape(shape).astype(tmpl.dtype))
-        new_t.append(
-            pt[o2 + size:o2 + 2 * size].reshape(shape).astype(tmpl.dtype))
+    for i, (shape, tmpl) in enumerate(zip(
+            meta.shapes, jax.tree_util.tree_leaves(params))):
+        new_p.append(halves[2 * i].reshape(shape).astype(tmpl.dtype))
+        new_t.append(halves[2 * i + 1].reshape(shape).astype(tmpl.dtype))
     return (jax.tree_util.tree_unflatten(meta.treedef, new_p),
             jax.tree_util.tree_unflatten(meta.treedef, new_t))
 
@@ -319,11 +338,12 @@ def plane_to_param_trees(meta: PlaneMeta, pt: jax.Array,
 def plane_to_tree(meta: PlaneMeta, plane: jax.Array,
                   template: Any) -> Any:
     """Slice an [N] plane back into ``template``'s tree structure."""
+    blocks = _plane_blocks(plane, [
+        (off, off + size) for off, size in zip(meta.offsets, meta.sizes)])
     leaves = [
-        plane[off:off + size].reshape(shape).astype(tmpl.dtype)
-        for off, size, shape, tmpl in zip(
-            meta.offsets, meta.sizes, meta.shapes,
-            jax.tree_util.tree_leaves(template))]
+        block.reshape(shape).astype(tmpl.dtype)
+        for block, shape, tmpl in zip(
+            blocks, meta.shapes, jax.tree_util.tree_leaves(template))]
     return jax.tree_util.tree_unflatten(meta.treedef, leaves)
 
 

@@ -380,21 +380,17 @@ def test_sparse_attention_compiles_for_v5e(one_chip, with_loss):
 
 # -- the fused CNN programs at the b512 cell's sizes ------------------------
 
-def _b512_learner(topo, window=None):
-    """The breakout preset's learner on one described chip and the fused
-    programs' spec for it (batch 512, 84x84, bf16, chain 8); ``window``
-    moves ``n_step`` off the preset's 3 (window 7) to give another."""
+def _cnn_learner(topo, cfg, n_step):
+    """``cfg``'s learner on one described chip and the fused programs'
+    spec for it (84x84 frames, 1M rows in 4 slots)."""
     import numpy as np
     from jax.sharding import Mesh
 
-    from distributed_deep_q_tpu.config import PRESETS
     from distributed_deep_q_tpu.models.qnet import build_qnet
     from distributed_deep_q_tpu.parallel.learner import Learner
 
-    cfg = PRESETS["breakout"]()
     rep = cfg.replay
     stack = cfg.net.stack
-    n_step = rep.n_step if window is None else window - stack
     mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1), ("dp", "model"))
     module = build_qnet(cfg.net)
     learner = Learner(lambda p, o: module.apply({"params": p}, o),
@@ -405,6 +401,31 @@ def _b512_learner(topo, window=None):
     return cfg, module, learner, mesh, spec
 
 
+def _b512_learner(topo, window=None):
+    """The breakout preset's learner (batch 512, bf16, chain 8);
+    ``window`` moves ``n_step`` off the preset's 3 (window 7) to give
+    another."""
+    from distributed_deep_q_tpu.config import PRESETS
+
+    cfg = PRESETS["breakout"]()
+    return _cnn_learner(topo, cfg, cfg.replay.n_step if window is None
+                        else window - cfg.net.stack)
+
+
+def _b32_learner(topo):
+    """The ``dqn_b32`` cell's learner: the pong preset with the signal
+    env's 4 actions at batch 32 (``benchmark/configs/dqn_b32.json``),
+    n-step 1 (window 5), chain 8 — 32 rows a shard, so the plane body."""
+    import dataclasses
+
+    from distributed_deep_q_tpu.config import PRESETS
+
+    cfg = PRESETS["pong"]()
+    cfg.net = dataclasses.replace(cfg.net, num_actions=4)
+    cfg.replay = dataclasses.replace(cfg.replay, batch_size=32)
+    return _cnn_learner(topo, cfg, cfg.replay.n_step)
+
+
 def _sharded_aval(mesh):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -412,6 +433,13 @@ def _sharded_aval(mesh):
         return jax.ShapeDtypeStruct(
             shape, dtype, sharding=NamedSharding(mesh, P(*axes)))
     return S
+
+
+def _instructions(text: str) -> list:
+    """``(name, result shape with its layout, opcode)`` of every
+    instruction of a compiled program's text, fused ones included."""
+    return [(m[1], m[2], m[3]) for m in re.finditer(
+        r"^\s*(?:ROOT )?(%[\w.-]+) = (\S+) ([\w-]+)\(", text, re.M)]
 
 
 def _elements(shape: str) -> int:
@@ -450,9 +478,8 @@ def test_b512_sample_program_hands_the_windows_over_as_a_bitcast_for_v5e(
     assert words == (58_720_256 if window == 7 else 41_943_040)
     tiled = f"s32[{chain},{batch},{window},{rowp // 128},128]"
     # every instruction whose RESULT is chunk-wide: its name and opcode
-    wide = [(m[1], m[3]) for m in re.finditer(
-        r"^\s*(?:ROOT )?(%[\w.-]+) = (\S+) ([\w-]+)\(", text, re.M)
-        if words == _elements(m[2])]
+    wide = [(name, op) for name, shape, op in _instructions(text)
+            if words == _elements(shape)]
     ops = sorted(op for _, op in wide)
     assert ops == ["bitcast", "custom-call"], wide
     kernel = next(n for n, op in wide if op == "custom-call")
@@ -474,24 +501,17 @@ def test_b512_sample_program_hands_the_windows_over_as_a_bitcast_for_v5e(
         re.escape(tiled) + r"\S* bitcast\(%sample_fn", text), wide
 
 
-def test_b512_train_program_unpacks_by_planes_for_v5e(topo, one_chip):
-    """The breakout preset's whole train program (batch 512, 84x84, bf16,
-    window 7, chain 8), fed the windows in the view the sample program
-    hands over: the pixel unpack sits under ``ddq.unpack``, and no
-    instruction of the program makes the window four words wide — the
-    ``u32[512,7,2048,4]`` broadcast, and its ``u8[512,7,8192]`` consumer,
-    by which the chip's compiler lowers a ``bitcast_convert_type`` to
-    uint8 (PERF.md §6, PR 32) — in the row's flat spelling or in its
-    ``(16, 128)`` one. ~25 s."""
+def _train_program_text(cfg, module, learner, mesh, spec) -> str:
+    """The fused TRAIN program of ``_cnn_learner``'s pair, compiled for
+    the described chip and fed the windows in the view the sample program
+    hands over."""
     from distributed_deep_q_tpu.models.qnet import init_params
     from distributed_deep_q_tpu.parallel.learner import TrainState
 
-    cfg, module, learner, mesh, spec = _b512_learner(topo)
     rep = cfg.replay
     stack, chain, batch = cfg.net.stack, rep.fused_chain, rep.batch_size
-    window, rowp = stack + rep.n_step, ROWB // 4
+    window, rowp = stack + spec[5], ROWB // 4     # spec[5]: n_step
     _, train = learner._build_device_per_step(spec, chain)
-    assert learner.unpack_planes == 1
     S = _sharded_aval(mesh)
 
     params = jax.eval_shape(lambda: init_params(module, cfg.net, 0, 4))
@@ -505,12 +525,29 @@ def test_b512_train_program_unpacks_by_planes_for_v5e(topo, one_chip):
     metas = {"action": row(jnp.int32), "reward": row(jnp.float32),
              "discount": row(jnp.float32), "weight": row(jnp.float32),
              "ovalid": mask, "nvalid": mask}
-    text = train.lower(
+    return train.lower(
         state, metas,
         S((chain, batch, window, rowp // 128, 128), jnp.int32,
           None, "dp", None, None, None),
         row(jnp.int32), S((1_000_000,), jnp.float32, "dp"),
         S((), jnp.float32)).compile().as_text()
+
+
+def test_b512_train_program_unpacks_by_planes_for_v5e(topo, one_chip):
+    """The breakout preset's whole train program (batch 512, 84x84, bf16,
+    window 7, chain 8), fed the windows in the view the sample program
+    hands over: the pixel unpack sits under ``ddq.unpack``, and no
+    instruction of the program makes the window four words wide — the
+    ``u32[512,7,2048,4]`` broadcast, and its ``u8[512,7,8192]`` consumer,
+    by which the chip's compiler lowers a ``bitcast_convert_type`` to
+    uint8 (PERF.md §6, PR 32) — in the row's flat spelling or in its
+    ``(16, 128)`` one. ~25 s."""
+    cfg, module, learner, mesh, spec = _b512_learner(topo)
+    rep = cfg.replay
+    batch, window, rowp = rep.batch_size, cfg.net.stack + rep.n_step, \
+        ROWB // 4
+    text = _train_program_text(cfg, module, learner, mesh, spec)
+    assert learner.unpack_planes == 1
     assert "ddq.unpack" in text
     innermost = {st[-1] for st in scope_table(text)["scopes"].values()}
     assert innermost == {
@@ -520,6 +557,96 @@ def test_b512_train_program_unpacks_by_planes_for_v5e(topo, one_chip):
                           (f"{rowp // 128},128", f"{rowp // 128},512")):
         assert f"[{batch},{window},{words},4]" not in text
         assert f"u8[{batch},{window},{bytes_}]" not in text
+
+
+def _off_the_lanes(text: str, at_least: int) -> list:
+    """Every instruction of a compiled program whose result holds
+    ``at_least`` elements or more with fewer than 128 of them along its
+    minor-most dimension: laid out in (8, 128) tiles, an array that size
+    is padded up to 32x. A 1-D plane is never one."""
+    found = []
+    for name, shape, op in _instructions(text):
+        m = re.match(r"\w+\[([\d,]+)\](?:\{(\d+))?", shape)
+        if not m:
+            continue
+        dims = [int(d) for d in m[1].split(",")]
+        minor = dims[int(m[2])] if m[2] else dims[-1]
+        if math.prod(dims) >= at_least and minor < 128:
+            found.append((name, shape, op))
+    return found
+
+
+def test_b32_plane_conversions_move_leaf_sized_blocks_for_v5e(one_chip):
+    """``plane_to_param_trees`` + ``plane_to_tree`` (twice: Adam's two
+    moments) over the ``dqn_b32`` cell's ten leaves: what the chip's
+    compiler makes of the cut-then-reshape. It used to commute the head
+    kernel's ``[512, 4]`` reshape with its slice and lay the WHOLE plane
+    out 4 wide — ``f32[843090,4]`` and two ``f32[421545,4]``, (8, 128)
+    tiles padded 32x, 0.319 of the 0.808 ms step (PERF.md §6, PR 41) —
+    which XLA:CPU never does, so only this compile can see it come back.
+    Now whatever is larger than the largest leaf is a 1-D plane (the
+    arguments and the compiler's prefetches of them) and the head leaf is
+    reshaped from its own 2 048 elements. ~5 s (the parent's form took
+    50 s: the compiler laboured over those reshapes too)."""
+    from distributed_deep_q_tpu.config import PRESETS
+    from distributed_deep_q_tpu.models.qnet import build_qnet, init_params
+    from distributed_deep_q_tpu.parallel.learner import (
+        plane_meta, plane_to_param_trees, plane_to_tree)
+
+    net = PRESETS["pong"]().net
+    net.num_actions = 4
+    params = jax.eval_shape(lambda: init_params(build_qnet(net), net, 0))
+    meta = plane_meta(params)
+    assert meta.n == 1_686_180 and (512, 4) in meta.shapes
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    tmpl = jax.tree.map(lambda x: S(x.shape, x.dtype), params)
+    mu = jax.tree.map(lambda x: S(x.shape, jnp.bfloat16), params)
+
+    def unpack(pt, m, v, params, target, mu):
+        return (plane_to_param_trees(meta, pt, params, target),
+                plane_to_tree(meta, m, mu), plane_to_tree(meta, v, params))
+
+    text = _compiled_text(
+        unpack, S((2 * meta.n,), jnp.float32), S((meta.n,), jnp.bfloat16),
+        S((meta.n,), jnp.float32), tmpl, tmpl, mu)
+    wide = [(name, shape) for name, shape, _ in _instructions(text)
+            if _elements(shape) > max(meta.sizes)]
+    assert wide and all(
+        re.match(r"\w+\[\d+\]", shape) for _, shape in wide), wide
+    assert re.search(r"f32\[512,4\]\S* reshape\(", text)
+    assert "ddq.plane_unpack" in text
+
+
+def test_b32_train_program_keeps_its_planes_flat_for_v5e(topo, one_chip):
+    """The ``dqn_b32`` cell's whole train program (the plane body: batch
+    32, window 5, chain 8): no instruction lays a plane's worth of
+    elements out under 128 lanes wide — where the planes are a scan's
+    output and not an argument, the barrier of ``_plane_blocks`` holds
+    too — and the three relayout scopes are still there to be read
+    (``plane_relayout_ms_per_step``). ~20 s; with the plane-sized
+    reshapes in it this program took 270 s to compile here."""
+    from distributed_deep_q_tpu.models.qnet import init_params
+    from distributed_deep_q_tpu.parallel.learner import plane_meta
+
+    cfg, module, learner, mesh, spec = _b32_learner(topo)
+    text = _train_program_text(cfg, module, learner, mesh, spec)
+    assert learner.unpack_planes == 0
+    assert "plane_train_fn" in text
+    innermost = {st[-1] for st in scope_table(text)["scopes"].values()}
+    assert innermost >= {"ddq.plane_pack", "ddq.plane_unpack",
+                         "ddq.grad_plane", "ddq.optimizer"}
+    n = plane_meta(
+        jax.eval_shape(lambda: init_params(module, cfg.net, 0))).n
+    assert n == 1_686_180
+    # the one array that size which IS under the lanes: conv 1's stacked
+    # observations, batch-minor with 32 rows of 128 (``ddq.conv_in``,
+    # 0.060 ms a step: the model's matter, not the planes')
+    obs = f"[2,{cfg.replay.batch_size},84,84,{cfg.net.stack}]"
+    assert [i for i in _off_the_lanes(text, n) if obs not in i[1]] == []
+    assert "[843090,4]" not in text and "[421545,4]" not in text
 
 
 # -- Laguna-XS.2's two kinds of attention layer (config.laguna_tokenq_config):

@@ -382,16 +382,22 @@ def _filled_dev_replay(solver, cfg, alpha_seed=0, n=300):
     return dev
 
 
-def test_chained_fused_steps_match_sequential_alpha0():
+@pytest.mark.parametrize("mu_dtype", ["float32", "bfloat16"])
+def test_chained_fused_steps_match_sequential_alpha0(mu_dtype):
     """α=0 makes sampling independent of priorities, so a chain=3 chunk
     must reproduce THREE sequential single-step dispatches bit-for-bit
-    (same keys/βs) — optimizer state, params, and priorities included."""
+    (same keys/βs) — optimizer state, params, and priorities included.
+    Both run the plane body (8 rows a shard), so the sequential side goes
+    trees -> planes -> trees three times where the chunk goes once: the
+    conversions at the chunk's boundary (``plane_to_param_trees``,
+    ``plane_to_tree``) have to be exact, a bfloat16 first moment too."""
     from distributed_deep_q_tpu.solver import Solver
 
     def build():
         cfg = Config()
         cfg.mesh.backend = "cpu"
         cfg.mesh.dp = 2
+        cfg.train.adam_mu_dtype = mu_dtype
         cfg.net = NetConfig(kind="nature_cnn", num_actions=4,
                             frame_shape=(36, 36))
         cfg.replay = ReplayConfig(capacity=512, batch_size=16, n_step=2,

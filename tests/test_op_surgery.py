@@ -156,6 +156,58 @@ def test_plane_body_matches_tree_body():
                                rtol=1e-4, atol=1e-5)
 
 
+def _raw_bits(x):
+    """An array's storage, so -0.0 and NaN payloads count too."""
+    x = np.asarray(x)
+    return x.view({2: np.uint16, 4: np.uint32}[x.dtype.itemsize])
+
+
+@pytest.mark.parametrize("mu_dtype", ["float32", "bfloat16"])
+def test_plane_tree_conversions_return_their_inputs_bitwise(mu_dtype):
+    """``plane_to_param_trees(params_to_plane(θ, θ⁻))`` and
+    ``plane_to_tree(tree_to_plane(m))`` are the identity, bit for bit and
+    dtype for dtype, on the Nature net's ten leaves at 84x84 with the
+    [512, 4] head (the leaf whose reshape the TPU compiler used to carry
+    across its slice, PERF.md §6 PR 41) and Adam's first moment in its
+    storage dtype: the conversions cut, hold and reshape — they move
+    bytes and compute nothing."""
+    from distributed_deep_q_tpu.models.qnet import build_qnet, init_params
+    from distributed_deep_q_tpu.parallel.learner import (
+        params_to_plane, plane_meta, plane_to_param_trees, plane_to_tree,
+        tree_to_plane)
+
+    net = NetConfig(kind="nature_cnn", num_actions=4)
+    module = build_qnet(net)
+    params = init_params(module, net, 0)
+    target = init_params(module, net, 1)
+    meta = plane_meta(params)
+    assert len(meta.shapes) == 10 and (512, 4) in meta.shapes
+    assert meta.n == 1_686_180
+    rng = np.random.default_rng(41)
+
+    def moment(x):
+        m = jnp.asarray(rng.standard_normal(x.shape) * 1e-3,
+                        jnp.dtype(mu_dtype))
+        # bits no arithmetic would hand back: -0.0 first, a NaN last
+        return m.reshape(-1).at[0].set(-0.0).at[-1].set(jnp.nan) \
+            .reshape(x.shape)
+
+    mu = jax.tree.map(moment, params)
+
+    @jax.jit
+    def round_trip(params, target, mu):
+        pt = params_to_plane(meta, params, target)
+        return (plane_to_param_trees(meta, pt, params, target),
+                plane_to_tree(meta, tree_to_plane(mu), mu))
+
+    (p2, t2), mu2 = round_trip(params, target, mu)
+    for want, got in ((params, p2), (target, t2), (mu, mu2)):
+        assert jax.tree.structure(want) == jax.tree.structure(got)
+        for x, y in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+            assert x.dtype == y.dtype and x.shape == y.shape
+            np.testing.assert_array_equal(_raw_bits(x), _raw_bits(y))
+
+
 def _gather_plane_step(cfg, meta, g, m, v, count, pt, step, gnorm):
     """The formulation ``fused_plane_adam_target_step`` replaced (PR 4 to
     PR 24), kept here as its reference: [2N] position maps baked in as
