@@ -91,7 +91,10 @@ backward pass; a sparse layer's two halves apart (``mixer``,
 ``feed_forward``: what the mixer's backward needs does not stand beside
 the expert layer's buffers), and its mixer keeps its selection (bits, no
 gradient) and its indexer's loss with that loss's gradients across it, so
-the top-k search and the loss run once a forward pass.
+the top-k search and the loss run once a forward pass — θ's index scores
+three times a step in all: the selection's (which also hands the loss
+each row's normaliser), the loss's one product a chunk of keys, and that
+product's pull-back; θ⁻ and the acting path score once and take no loss.
 
 Parameters are a plain nested dict; a leaf's name is its path
 (``layer_02/w_gate``), which is what weight IO uses.

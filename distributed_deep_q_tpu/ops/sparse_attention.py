@@ -27,18 +27,22 @@ rotary embedding:
   s])``, ``p_t`` = the main heads' probabilities over ``S_t`` summed over
   the heads and normalised, under ``stop_gradient`` (a fourth kernel adds
   them up a block of queries at a time from the forward's log-sum-exp),
-  computed WITH its gradients in one pass over the scores. No gradient
-  flows through ``S_t``.
+  computed WITH its gradients in one pass over the scores: the row's
+  normaliser ``logsumexp_{s in S_t} I[t, s]`` comes out of the selection
+  pass (``select(with_loss=True)`` reads it off the search's keys as it
+  keeps them), so the loss multiplies a chunk of keys once and takes the
+  KL's terms, their gradient and its pull-back from that one product. No
+  gradient flows through ``S_t``.
 
 Nothing ``[T, T]`` exists but the selection's bits, and the causal half
 is not multiplied in full: scores, selection and loss each run ONE loop
 over the query blocks, and inside it over the chunks of ``KEY_CHUNK_BLOCKS``
 blocks of keys the block can see — a loop whose length is data
-(``causal_scores``, ``threshold``, ``keep_chunk``), so the program holds
-one body whatever the window's length. The selection is ``[T / 32, T]``
-int32 (``pack`` / ``unpack``: bit ``b`` of row ``r`` of query block ``i``
-is query ``i · block + b · block / 32 + r``, so a kernel unpacks a block
-with 32 aligned row slabs): the caller keeps THAT across its
+(``causal_scores``, ``threshold``, ``keep_chunk``, ``index_loss``), so the
+program holds one body whatever the window's length. The selection is
+``[T / 32, T]`` int32 (``pack`` / ``unpack``: bit ``b`` of row ``r`` of
+query block ``i`` is query ``i · block + b · block / 32 + r``, so a kernel
+unpacks a block with 32 aligned row slabs): the caller keeps THAT across its
 rematerialised backward (``SELECTION_NAME``), and the loss with its
 gradients (``LOSS_NAME``), so the search and the loss run once a forward
 pass.
@@ -89,6 +93,14 @@ def _ordered_bits(x: jax.Array) -> jax.Array:
     return lax.bitcast_convert_type(b, jnp.uint32) ^ jnp.uint32(0x80000000)
 
 
+def _scores_of(u: jax.Array) -> jax.Array:
+    """``_ordered_bits``' inverse: the score a key was made from (-0, and
+    whatever else compares equal to 0, reads +0)."""
+    b = lax.bitcast_convert_type(u ^ jnp.uint32(0x80000000), jnp.int32)
+    return lax.bitcast_convert_type(
+        b ^ ((b >> 31) & jnp.int32(0x7FFFFFFF)), jnp.float32)
+
+
 def ordered_keys(scores: jax.Array, valid: jax.Array) -> jax.Array:
     """What the search compares: ``_ordered_bits`` of the valid entries,
     0 (below every score) elsewhere."""
@@ -132,6 +144,19 @@ def keep_chunk(uc: jax.Array, tau: jax.Array, room: jax.Array,
     rank = seen[:, None] + jnp.cumsum(equal, axis=-1, dtype=jnp.int32)
     keep = (uc > tau[:, None]) | (equal & (rank <= room[:, None]))
     return keep & (uc > 0), seen + jnp.sum(equal, axis=-1, dtype=jnp.int32)
+
+
+def kept_logsumexp(norm, uc: jax.Array, kept: jax.Array):
+    """The running log-sum-exp of each row's KEPT scores as (maximum,
+    sum of ``exp(score - maximum)``), both [R] float32, after one more
+    chunk: ``uc`` [R, C] the chunk's keys, ``kept`` bool [R, C]."""
+    m, l = norm
+    s = jnp.where(kept, _scores_of(uc), MASKED)
+    m_next = jnp.maximum(m, jnp.max(s, axis=-1))
+    # a row with nothing kept so far has m_next = MASKED: exp(s - m_next)
+    # reads 1 on what it dropped, so the sum takes the kept entries only
+    return m_next, l * jnp.exp(m - m_next) + jnp.sum(
+        jnp.where(kept, jnp.exp(s - m_next[:, None]), 0.0), axis=-1)
 
 
 def topk_mask(scores: jax.Array, valid: jax.Array, topk: int) -> jax.Array:
@@ -213,13 +238,17 @@ def causal_scores(qb: jax.Array, wb: jax.Array, k_i: jax.Array, chunks,
 
 
 def select(q_i: jax.Array, w_i: jax.Array, k_i: jax.Array, *, topk: int,
-           block: int, t_real: int):
+           block: int, t_real: int, with_loss: bool = False):
     """The selection of one sequence padded to ``T`` (a multiple of
     ``block``): ``q_i`` [T, Hi, Di], ``w_i`` [T, Hi], ``k_i`` [T, Di]
     float32 → (bits int32 [T/32, T], pairs selected by the first
-    ``t_real`` queries, int32). ONE loop over the query blocks; inside it
-    the scores, the search's counting passes and the keep each run over
-    the key chunks the block can see, and over no other."""
+    ``t_real`` queries, int32, and with ``with_loss`` what the indexer's
+    loss divides by: the log-sum-exp of each query's index scores over
+    the keys it KEPT, [T] float32 — ``None`` without). ONE loop over the
+    query blocks; inside it the scores, the search's counting passes and
+    the keep each run over the key chunks the block can see, and over no
+    other; the normaliser is read off the search's keys as the keep walks
+    them, so the loss never scores the block for it."""
     t_pad, hi, di = q_i.shape
     nb, chunk = t_pad // block, _key_chunk(t_pad, block)
 
@@ -237,21 +266,29 @@ def select(q_i: jax.Array, w_i: jax.Array, k_i: jax.Array, *, topk: int,
             tau, room = threshold(u, topk, chunk, chunks)
 
             def keep(c, carry):
-                bits, seen, kept = carry
-                kc, seen = keep_chunk(_at(u, c, chunk, 1), tau, room, seen)
+                bits, seen, kept, norm = carry
+                uc = _at(u, c, chunk, 1)
+                kc, seen = keep_chunk(uc, tau, room, seen)
+                if with_loss:
+                    norm = kept_logsumexp(norm, uc, kc)
                 return (_put(bits, pack(kc), c, chunk, 1), seen,
                         kept + jnp.sum(kc & (t_pos < t_real),
-                                       dtype=jnp.int32))
+                                       dtype=jnp.int32), norm)
 
-            bits, _, kept = lax.fori_loop(0, chunks, keep, (
+            bits, _, kept, norm = lax.fori_loop(0, chunks, keep, (
                 jnp.zeros((block // WORD, t_pad), jnp.int32),
-                jnp.zeros(block, jnp.int32), jnp.zeros((), jnp.int32)))
-            return bits, kept
+                jnp.zeros(block, jnp.int32), jnp.zeros((), jnp.int32),
+                (jnp.full(block, MASKED, jnp.float32),
+                 jnp.zeros(block, jnp.float32)) if with_loss else None))
+            # every query keeps itself at least: the sum is positive
+            return bits, kept, (norm[0] + jnp.log(norm[1])
+                                if with_loss else None)
 
-    bits, kept = lax.map(one_block, (
+    bits, kept, lse_i = lax.map(one_block, (
         q_i.reshape(nb, block, hi, di), w_i.reshape(nb, block, hi),
         jnp.arange(nb)))
-    return bits.reshape(t_pad // WORD, t_pad), jnp.sum(kept)
+    return (bits.reshape(t_pad // WORD, t_pad), jnp.sum(kept),
+            lse_i.reshape(t_pad) if with_loss else None)
 
 
 # ---- the core: attention over the selected keys (Pallas) ----------------
@@ -532,15 +569,19 @@ def head_probabilities(i, q_blk, k, bits_blk, lse_blk, block: int,
 # ---- the indexer's loss --------------------------------------------------
 
 def index_loss(q_i: jax.Array, w_i: jax.Array, k_i: jax.Array, q: jax.Array,
-               k: jax.Array, lse: jax.Array, bits: jax.Array, *, block: int,
-               t_real: int, interpret: bool):
+               k: jax.Array, lse: jax.Array, bits: jax.Array,
+               lse_i: jax.Array, *, block: int, t_real: int,
+               interpret: bool):
     """``Σ_{t < t_real} KL(p_t ‖ softmax_{S_t} I[t, ·])`` of one sequence
     AND its gradients by ``q_i``, ``w_i`` and ``k_i`` (the caller divides
-    by the count), in ONE loop over the query blocks: a block's index
-    scores again (``causal_scores``) against the main heads'
-    probabilities over the same keys, the KL and its gradient by the
-    scores, and that gradient taken back through the score product a
-    chunk of keys at a time — forward and backward before the next block,
+    by the count), in ONE loop over the query blocks and inside it ONE
+    over the key chunks the block can see: with the main heads'
+    probabilities ``p`` over the kept keys and ``select``'s normaliser
+    ``lse_i`` [T] in hand, a chunk's score product (the one its pull-back
+    needs anyway) gives ``log softmax = score - lse_i``, the chunk's KL
+    terms, the gradient by its scores ``exp(log softmax) · Σ_S p - p`` on
+    the kept pairs, and that gradient taken back through the product —
+    each pair is scored once, forward and backward before the next chunk,
     nothing [T, T] waits for a backward pass. ``q`` [Hkv, G, T, D] scaled,
     ``k`` [Hkv, T, D], ``lse`` [Hkv, G, T]; nothing differentiates
     through this."""
@@ -548,38 +589,38 @@ def index_loss(q_i: jax.Array, w_i: jax.Array, k_i: jax.Array, q: jax.Array,
     hi, di = q_i.shape[1:]
     nb, chunk = t_pad // block, _key_chunk(t_pad, block)
 
-    def block_kl(scores, keep, p, real):
-        log_q = jax.nn.log_softmax(jnp.where(keep, scores, MASKED), -1)
-        kl = jnp.sum(jnp.where(
-            keep, jax.scipy.special.xlogy(p, p) - p * log_q, 0.0), -1)
-        return jnp.sum(jnp.where(real, kl, 0.0))
-
     def one_block(g_k, xs):
-        qb_i, wb_i, qb, lse_b, words, i = xs
-        chunks = _chunks(i, block, chunk)
+        qb_i, wb_i, qb, lse_b, words, norm, i = xs
+        real = i * block + jnp.arange(block) < t_real
         p = head_probabilities(i, qb, k, words, lse_b, block,
                                interpret) / (hkv * group)
-        kl, g_scores = jax.value_and_grad(block_kl)(
-            causal_scores(qb_i, wb_i, k_i, chunks, chunk), unpack(words), p,
-            i * block + jnp.arange(block) < t_real)
+        p_sum = jnp.sum(p, axis=-1, keepdims=True)  # 0 outside the kept
 
-        def back(c, carry):
-            g_q, g_w, g_k = carry
-            keys = _at(k_i, c, chunk)
-            dq, dw, dk = jax.vjp(index_scores, qb_i, wb_i, keys)[1](
-                _at(g_scores, c, chunk, 1))
-            return g_q + dq, g_w + dw, _put(g_k, _at(g_k, c, chunk) + dk,
-                                            c, chunk)
+        def one_chunk(c, carry):
+            kl, g_q, g_w, g_k = carry
+            scores, pull = jax.vjp(index_scores, qb_i, wb_i,
+                                   _at(k_i, c, chunk))
+            keep, pc = unpack(_at(words, c, chunk, 1)), _at(p, c, chunk, 1)
+            log_q = scores - norm[:, None]
+            kl = kl + jnp.sum(jnp.where(
+                keep, jax.scipy.special.xlogy(pc, pc) - pc * log_q, 0.0), -1)
+            dq, dw, dk = pull(jnp.where(
+                keep & real[:, None], jnp.exp(log_q) * p_sum - pc, 0.0))
+            return kl, g_q + dq, g_w + dw, _put(
+                g_k, _at(g_k, c, chunk) + dk, c, chunk)
 
-        g_q, g_w, g_k = lax.fori_loop(0, chunks, back, (
-            jnp.zeros_like(qb_i), jnp.zeros_like(wb_i), g_k))
-        return g_k, (kl, g_q, g_w)
+        kl, g_q, g_w, g_k = lax.fori_loop(
+            0, _chunks(i, block, chunk), one_chunk,
+            (jnp.zeros(block, jnp.float32), jnp.zeros_like(qb_i),
+             jnp.zeros_like(wb_i), g_k))
+        return g_k, (jnp.sum(jnp.where(real, kl, 0.0)), g_q, g_w)
 
     g_k, (kl, g_q, g_w) = lax.scan(one_block, jnp.zeros_like(k_i), (
         q_i.reshape(nb, block, hi, di), w_i.reshape(nb, block, hi),
         jnp.moveaxis(q.reshape(hkv, group, nb, block, d), 2, 0),
         jnp.moveaxis(lse.reshape(hkv, group, nb, block), 2, 0),
-        bits.reshape(nb, block // WORD, t_pad), jnp.arange(nb)))
+        bits.reshape(nb, block // WORD, t_pad), lse_i.reshape(nb, block),
+        jnp.arange(nb)))
     return jnp.sum(kl), (g_q.reshape(q_i.shape), g_w.reshape(w_i.shape), g_k)
 
 
@@ -604,10 +645,11 @@ def sparse_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     k, v = k.reshape(b * hkv, t, d), v.reshape(b * hkv, t, d)
 
     def selection(xs):
-        bits, kept = select(*xs, topk=topk, block=block, t_real=t_real)
-        return bits, kept.astype(jnp.float32)
+        bits, kept, lse_i = select(*xs, topk=topk, block=block,
+                                   t_real=t_real, with_loss=with_loss)
+        return bits, kept.astype(jnp.float32), lse_i
 
-    bits, kept = lax.map(selection, (q_i, w_i, k_i))
+    bits, kept, lse_i = lax.map(selection, (q_i, w_i, k_i))
     bits = checkpoint_name(lax.stop_gradient(bits), SELECTION_NAME)
     with jax.named_scope("ddq.sparse_core"):
         out, lse = sparse_core(q, k, v, bits, block, interpret)
@@ -623,7 +665,7 @@ def sparse_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             total, grads = lax.map(lambda xs: index_loss(
                 *xs, block=block, t_real=t_real, interpret=interpret),
                 lax.stop_gradient((*indexer, per_seq(q), per_seq(k),
-                                   per_seq(lse), bits)))
+                                   per_seq(lse), bits, lse_i)))
             value, grads = checkpoint_name(jax.tree.map(
                 lambda x: x / (b * t_real), (jnp.sum(total), grads)),
                 LOSS_NAME)

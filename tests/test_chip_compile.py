@@ -348,7 +348,11 @@ def test_sparse_attention_compiles_for_v5e(one_chip, with_loss):
     on a window of 4 096 + 1 tokens padded to whole blocks: the selection,
     the three flash kernels that read it as bits (forward, dq, dk + dv)
     and with ``with_loss`` the kernel that adds up the heads'
-    probabilities for the indexer's loss, forward and backward."""
+    probabilities for the indexer's loss, forward and backward. The
+    indexer's float32 ``highest`` products, one body a loop: the
+    selection's one, and the loss's THREE — a chunk's scores once and the
+    two products of their pull-back (a fourth is the loss scoring the
+    block a second time, which ``select``'s normaliser took away)."""
     from distributed_deep_q_tpu.config import PRESETS
     from distributed_deep_q_tpu.ops import sparse_attention as sa
 
@@ -376,6 +380,9 @@ def test_sparse_attention_compiles_for_v5e(one_chip, with_loss):
     for kernel in ("sparse_core_fwd", "sparse_core_dq", "sparse_core_dkv"):
         assert kernel in text
     assert ("sparse_head_probs" in text) == with_loss
+    products = re.findall(
+        r"convolution\(.*operand_precision=\{highest,highest\}", text)
+    assert len(products) == (1 + 3 if with_loss else 1)
 
 
 # -- the fused CNN programs at the b512 cell's sizes ------------------------

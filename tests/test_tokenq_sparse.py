@@ -310,6 +310,15 @@ def test_pack_and_unpack_are_inverse_and_the_host_reads_the_same():
     assert np.array_equal(sa.unpack_selection(words, BLOCK), keep)
 
 
+def _kept_by_hand(scores, topk):
+    """Row ``t``'s ``topk`` largest of ``scores[t, :t + 1]``, ties to the
+    smaller key."""
+    keep = np.zeros(scores.shape, bool)
+    for i in range(scores.shape[0]):
+        keep[i, np.argsort(-scores[i, :i + 1], kind="stable")[:topk]] = True
+    return keep
+
+
 def _naive(q, k, v, qi, wi, ki, topk):
     """One batch in float64 numpy: outputs, kept pairs, Σ_t KL_t / (B T)."""
     b, h, t, d = q.shape
@@ -319,9 +328,7 @@ def _naive(q, k, v, qi, wi, ki, topk):
         scores = (hi ** -.5 * di ** -.5) * np.einsum(
             "th,ths->ts", wi[s_], np.maximum(np.einsum(
                 "thd,sd->ths", qi[s_], ki[s_]), 0))
-        keep = np.zeros((t, t), bool)
-        for i in range(t):
-            keep[i, np.argsort(-scores[i, :i + 1], kind="stable")[:topk]] = 1
+        keep = _kept_by_hand(scores, topk)
         rep = h // k.shape[1]
         s = np.einsum("htd,hsd->hts", q[s_], np.repeat(k[s_], rep, 0)
                       ) * d ** -.5
@@ -405,6 +412,141 @@ def test_sparse_attention_forward_backward_interpret(t, topk):
         np.testing.assert_allclose(np.asarray(a) / scale,
                                    np.asarray(e) / scale, atol=2e-5,
                                    err_msg=name)
+
+
+def _indexer_case(t, seed, planted):
+    """One sequence's indexer inputs padded to whole blocks and the main
+    heads' queries (scaled) and keys, float32. The rows past ``t_real``
+    are seeded like the rest: what a padded query holds must not matter.
+    ``planted``: keys 5, 12, 19, … are key 3 (scores tie along every row,
+    at the ``topk``-th too), query 9 is zero (every score of its row 0)
+    and key 11 is zero (a column of zeros)."""
+    rng = np.random.default_rng(seed)
+    tp = t + (-t % BLOCK)
+    hkv, group, d, hi, di = 2, 2, 16, 4, 8
+    mk = lambda *s: rng.standard_normal(s).astype(np.float32)  # noqa: E731
+    qi, wi, ki = mk(tp, hi, di), mk(tp, hi), mk(tp, di)
+    if planted:
+        ki[5::7] = ki[3]
+        ki[11] = 0.0
+        qi[9] = 0.0
+    q = mk(hkv, group, tp, d) * d ** -0.5
+    return tuple(jnp.asarray(x) for x in (qi, wi, ki, q, mk(hkv, tp, d)))
+
+
+# t, t_real, topk, planted ties and zeros
+INDEXER_CASES = pytest.mark.parametrize("t,t_real,topk,planted", [
+    (40, 40, 64, False),        # chunk = 2 blocks; no row has topk keys
+    (180, 180, 16, True),       # chunk = 3 blocks; ties at the threshold
+    (160, 130, 16, False),      # chunk = 1 block; 30 padded queries
+    (192, 192, 16, False),      # block 0's one chunk lies 2/3 above it
+], ids=["rows_short_of_topk", "ties_at_the_threshold", "t_real_short_of_T",
+        "last_chunk_above_the_diagonal"])
+
+
+def test_the_searchs_keys_give_their_scores_back():
+    """``_scores_of`` inverts ``_ordered_bits`` on every normal float and
+    zero, -0.0 read as +0.0 (the search orders them as one), and the running
+    log-sum-exp over chunks is the rows' over what they kept — a row that
+    keeps nothing of its first chunk, equal scores and zeros of both signs
+    among them."""
+    rng = np.random.default_rng(0)
+    x = np.concatenate([
+        rng.standard_normal(64) * 10.0 ** rng.integers(-30, 30, 64),
+        [0.0, -0.0, 1.2e-38, -1.2e-38, 3.4e38, -3.4e38, 1.0, 1.0, -0.0]]
+    ).astype(np.float32).reshape(1, -1)
+    u = sa._ordered_bits(jnp.asarray(x))
+    back = np.asarray(sa._scores_of(u))
+    assert np.array_equal(back, x) and not np.signbit(back[x == 0]).any()
+    assert (np.asarray(u) > 0).all()
+
+    x = np.tile(np.clip(x, -80, 80), (3, 1))
+    kept = rng.random(x.shape) < 0.5
+    kept[1, :40] = False
+    kept[2] = x[2] == 0
+    norm = (jnp.full(3, sa.MASKED), jnp.zeros(3))
+    for lo in (0, 40):
+        cols = slice(lo, lo + 40) if lo == 0 else slice(lo, None)
+        norm = sa.kept_logsumexp(norm, sa._ordered_bits(
+            jnp.asarray(x[:, cols])), jnp.asarray(kept[:, cols]))
+    want = jax.scipy.special.logsumexp(jnp.asarray(x), axis=-1,
+                                       where=jnp.asarray(kept))
+    np.testing.assert_allclose(np.asarray(norm[0] + jnp.log(norm[1])),
+                               np.asarray(want), rtol=1e-6)
+    assert float(want[2]) == pytest.approx(np.log(3.0), rel=1e-6)
+
+
+@INDEXER_CASES
+def test_select_hands_out_the_log_sum_exp_of_the_kept_scores(
+        t, t_real, topk, planted):
+    """``select(with_loss=True)``'s normaliser is ``logsumexp`` of
+    ``index_scores`` over the keys its bits keep, row by row (equal
+    scores among them); without the loss it returns none, and the bits
+    are the same bits either way: the exact top-k."""
+    qi, wi, ki, _, _ = _indexer_case(t, t, planted)
+    run = lambda with_loss: jax.jit(lambda *a: sa.select(  # noqa: E731
+        *a, topk=topk, block=BLOCK, t_real=t_real, with_loss=with_loss))(
+            qi, wi, ki)
+    bits, kept, lse_i = run(True)
+    plain_bits, plain_kept, none = run(False)
+    assert none is None
+    assert np.array_equal(np.asarray(bits), np.asarray(plain_bits))
+    assert int(kept) == int(plain_kept)
+
+    scores = np.asarray(sa.index_scores(qi, wi, ki))
+    keep = sa.unpack_selection(bits, BLOCK)
+    assert np.array_equal(keep, _kept_by_hand(scores, topk))
+    assert int(kept) == keep[:t_real].sum()
+    if planted:
+        assert (scores[9] == 0).all() and (scores[:, 11] == 0).all()
+        edge = np.where(keep, scores, np.inf).min(-1, keepdims=True)
+        tied = (scores == edge) & np.tri(len(scores), dtype=bool)
+        assert (tied & ~keep).any()     # a tie the threshold cuts through
+    want = jax.scipy.special.logsumexp(
+        jnp.asarray(scores), axis=-1, where=jnp.asarray(keep))
+    assert lse_i.shape == (len(scores),) and lse_i.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(lse_i), np.asarray(want),
+                               rtol=1e-6, atol=1e-6)
+
+
+@INDEXER_CASES
+def test_index_loss_in_one_loop_is_the_dense_formula_and_its_gradients(
+        t, t_real, topk, planted):
+    """``index_loss`` — a score product a chunk, the normaliser from
+    ``select`` — against autodiff of ``Σ_t KL(p_t ‖ softmax_keep(scores))``
+    over the dense scores: the value and the gradients by ``q_i``, ``w_i``
+    and ``k_i``, to float32 rounding; a padded query adds nothing."""
+    qi, wi, ki, q, k = _indexer_case(t, t + 1, planted)
+    tp, (hkv, group) = qi.shape[0], q.shape[:2]
+    bits, _, lse_i = jax.jit(lambda *a: sa.select(
+        *a, topk=topk, block=BLOCK, t_real=t_real, with_loss=True))(
+            qi, wi, ki)
+    keep = jnp.asarray(sa.unpack_selection(bits, BLOCK))
+    s = jnp.where(keep, jnp.einsum("hgtd,hsd->hgts", q, k,
+                                   precision="highest"), -jnp.inf)
+    lse = jax.scipy.special.logsumexp(s, axis=-1)
+    p = jnp.sum(jnp.exp(s - lse[..., None]), (0, 1)) / (hkv * group)
+
+    def dense(qi, wi, ki):
+        log_q = jax.nn.log_softmax(
+            jnp.where(keep, sa.index_scores(qi, wi, ki), -1e30), -1)
+        kl = jnp.sum(jnp.where(
+            keep, jax.scipy.special.xlogy(p, p) - p * log_q, 0.0), -1)
+        return jnp.sum(jnp.where(jnp.arange(tp) < t_real, kl, 0.0))
+
+    want, want_g = jax.jit(jax.value_and_grad(dense, (0, 1, 2)))(qi, wi, ki)
+    got, got_g = jax.jit(lambda *a: sa.index_loss(
+        *a, block=BLOCK, t_real=t_real, interpret=True))(
+            qi, wi, ki, q, k, lse, bits, lse_i)
+    assert float(want) > 1.0
+    np.testing.assert_allclose(float(got), float(want), rtol=2e-6)
+    for name, a, e in zip(("q_i", "w_i", "k_i"), got_g, want_g):
+        scale = float(jnp.abs(e).max())
+        np.testing.assert_allclose(np.asarray(a) / scale,
+                                   np.asarray(e) / scale, atol=2e-6,
+                                   err_msg=name)
+    for a in got_g[:2]:     # a padded query's own leaves: exactly nothing
+        assert not np.asarray(a)[t_real:].any()
 
 
 # ---- the share test at 128-wide routing ---------------------------------
