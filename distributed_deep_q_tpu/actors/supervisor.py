@@ -68,7 +68,7 @@ def _probe_envs(cfg: Config):
 
 
 def _split_fleet_across_processes(cfg: Config, pixel: bool, metrics,
-                                  ring_desc: str, fused_ok: bool = False):
+                                  single_controller_ring: str = ""):
     """Config 5 FULL shape (SURVEY §7.3 item 6): every learner process runs
     its own ReplayFeed server + actor slice + replay shard; each samples
     its batch/pc local rows into the train step, whose pmean spans hosts
@@ -76,8 +76,10 @@ def _split_fleet_across_processes(cfg: Config, pixel: bool, metrics,
     step — actor RPC fans into the local host only, shards never overlap
     (dedup-free sampling). Local actor ids 0..k-1 double as the host's
     replay streams; global identity (ε ladder / env seeds / multi-game
-    assignment) comes from the offset. ``ring_desc`` names the
-    single-controller device ring in the rejection message.
+    assignment) comes from the offset. ``single_controller_ring`` names
+    the device ring this config would build where that ring cannot span
+    processes (the sequence loop's host-sampled one); the transition
+    loop's only device ring, the fused one, can, and passes nothing.
 
     Returns (cfg, local_batch, metrics, pc, pid) — metrics swapped to a
     sink-less instance on non-zero processes (file/TB sinks live on
@@ -96,18 +98,12 @@ def _split_fleet_across_processes(cfg: Config, pixel: bool, metrics,
         if cfg.actors.num_actors % pc:
             raise ValueError(f"actors.num_actors={cfg.actors.num_actors} "
                              f"must divide across {pc} processes")
-        if pixel and cfg.replay.device_resident and not (
-                fused_ok and cfg.replay.prioritized
-                and cfg.replay.device_per):
-            hint = ("the FUSED ring (replay.prioritized=true + "
-                    "replay.device_per=true — per-host staging into the "
-                    "global mesh ring, lockstep flush) or " if fused_ok
-                    else "")
+        if pixel and cfg.replay.device_resident and single_controller_ring:
             raise ValueError(
-                f"the {ring_desc}'s host-sampled path is "
-                f"single-controller; multi-host --distributed pixel runs "
-                f"need either {hint}replay.device_resident=false "
-                "(per-host host-RAM shards feeding global_batch)")
+                f"the {single_controller_ring} is single-controller; "
+                "multi-host --distributed pixel runs need "
+                "replay.device_resident=false (per-host host-RAM shards "
+                "feeding global_batch)")
         local_batch = cfg.replay.batch_size // pc
         k = cfg.actors.num_actors // pc
         if cfg.actors.assignment == "hash":
@@ -1373,7 +1369,6 @@ def train_distributed(cfg: Config, metrics: Metrics | None = None,
     import dataclasses
 
     from distributed_deep_q_tpu.actors.game import make_env
-    from distributed_deep_q_tpu.replay.device_ring import DeviceFrameReplay
 
     if cfg.replay.persist_path:
         raise ValueError(
@@ -1416,21 +1411,17 @@ def train_distributed(cfg: Config, metrics: Metrics | None = None,
     from distributed_deep_q_tpu.parallel.multihost import (
         all_processes_ready, local_rows)
     cfg, local_batch, metrics, pc, pid = _split_fleet_across_processes(
-        cfg, pixel, metrics, "mesh-sharded HBM ring", fused_ok=True)
-    from distributed_deep_q_tpu.replay.device_per import DevicePERFrameReplay
+        cfg, pixel, metrics)
+    from distributed_deep_q_tpu.replay.device_per import (
+        DevicePERFrameReplay, pixel_device_ring)
     if pixel and cfg.replay.device_resident:
-        # fused device PER (prioritized + device_per): the learner step
-        # samples/updates in HBM, so the lock below covers flush + dispatch
-        cls = (DevicePERFrameReplay
-               if cfg.replay.prioritized and cfg.replay.device_per
-               else DeviceFrameReplay)
-        # vector mode: every stacked env row is its own replay stream
-        # (slot ownership + flush_seq dedup key on it), so the ring is
-        # built for num_actors * V writers
-        replay = cls(
+        # the fused ring: the learner step samples/updates in HBM, so the
+        # lock below covers flush + dispatch. Vector mode: every stacked
+        # env row is its own replay stream (slot ownership + flush_seq
+        # dedup key on it), so the ring is built for num_actors * V writers
+        replay = pixel_device_ring(
             replay_cfg, solver.mesh, obs_shape, cfg.env.stack,
             cfg.train.gamma, seed=cfg.train.seed,
-            write_chunk=cfg.replay.write_chunk,
             num_streams=cfg.actors.num_actors
             * max(int(cfg.actors.vector_envs), 1))
     elif pixel:
@@ -1498,8 +1489,7 @@ def train_distributed(cfg: Config, metrics: Metrics | None = None,
             while not all_processes_ready(
                     replay.ready(cfg.replay.learn_start)):
                 time.sleep(0.05)
-        if not (isinstance(replay, DeviceFrameReplay) or fused_per) \
-                and pc == 1:
+        if not fused_per and pc == 1:
             # host-batch path: double-buffered sample → device_put pipeline
             # (SURVEY §7.3 item 1); shares the server's replay lock so the
             # background sampler serializes with RPC writers and with PER
@@ -1541,18 +1531,6 @@ def train_distributed(cfg: Config, metrics: Metrics | None = None,
                 # while the chunk executes on device — writers get the
                 # whole window)
                 m = fused_stream.next(cfg.train.total_steps - gstep + 1)
-            elif isinstance(replay, DeviceFrameReplay):
-                # sample AND dispatch under the lock: a concurrent actor
-                # flush donates the current ring buffer, so the step must be
-                # enqueued before the ring handle can be invalidated
-                # (dispatch is µs; device execution stays async)
-                with server.replay_lock:
-                    with timer.phase("sample"):
-                        batch = replay.sample(local_batch)
-                    sampled_at = batch.pop("_sampled_at")
-                    with timer.phase("dispatch"):
-                        m = solver.train_step_from_ring(
-                            replay.ring, batch, replay.frame_shape)
             else:
                 if stager is not None:
                     with timer.phase("sample"):  # wait on the pipeline
@@ -1726,20 +1704,24 @@ def _train_distributed_recurrent(cfg: Config, metrics: Metrics | None = None,
     solver = SequenceSolver(cfg, obs_dim=obs_dim)
     from distributed_deep_q_tpu.parallel.multihost import (
         all_processes_ready, local_rows)
+    # the fused sequence ring (per-host staging + lockstep flush) spans
+    # processes; the host-sampled one does not
+    fused_cfg = cfg.replay.prioritized and cfg.replay.device_per
     # config 5 full shape, recurrent edition: per-host server + actor
     # slice + sequence-replay shard
     cfg, local_batch, metrics, pc, pid = _split_fleet_across_processes(
-        cfg, pixel, metrics, "device sequence ring", fused_ok=True)
+        cfg, pixel, metrics,
+        "" if fused_cfg
+        else "host-sampled device sequence ring (replay.device_per=false)")
     seq_len = cfg.replay.sequence_length
     # transition-denominated config fields scale down to sequence units;
     # β anneal runs per sample() = per grad step in this topology
     seq_capacity = max(cfg.replay.capacity // seq_len, 64)
     # device residency: single-controller for the host-sampled per-step
-    # path; multi-controller ONLY through the fused ring (per-host
-    # staging + lockstep flush — the _split gate enforces prioritized +
-    # device_per for pc > 1)
+    # path; multi-controller ONLY through the fused ring (the _split gate
+    # enforces it for pc > 1)
     device_seq = pixel and cfg.replay.device_resident and (
-        pc == 1 or (cfg.replay.prioritized and cfg.replay.device_per))
+        pc == 1 or fused_cfg)
     if device_seq:
         # R2D2 pixel plane in HBM (replay/device_sequence.py): actors
         # stream stacked sequences over RPC unchanged; the server derives
@@ -1775,8 +1757,7 @@ def _train_distributed_recurrent(cfg: Config, metrics: Metrics | None = None,
     # priorities on device, chain grad steps per dispatch — the sequence
     # twin of the transition loop's fused_per branch above.
     # Prioritized-only (the device sampler draws from the priority row)
-    fused_seq = (device_seq and cfg.replay.device_per
-                 and cfg.replay.prioritized)
+    fused_seq = device_seq and fused_cfg
     # no fused-flops census on the sequence program (its scan carries
     # recurrent state — the transition-path census doesn't apply), so
     # live MFU is absent here; steps/s + ingest utilization still emit
@@ -1812,7 +1793,7 @@ def _train_distributed_recurrent(cfg: Config, metrics: Metrics | None = None,
                 # sample AND dispatch under the lock: a concurrent RPC
                 # flush donates the ring buffer, so the gather program
                 # must be enqueued before the handle can be invalidated
-                # (same discipline as the DeviceFrameReplay loop above)
+                # (dispatch is µs; device execution stays async)
                 with server.replay_lock:
                     with timer.phase("sample"):
                         batch = replay.sample(local_batch)

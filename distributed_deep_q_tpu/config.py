@@ -233,9 +233,13 @@ class ReplayConfig:
     # per-sample |TD| D2H fetch (async-copied at dispatch) never blocks the
     # step — see replay.prioritized.DelayedPriorityWriteback
     priority_writeback_delay: int = 8
-    # fully device-resident PER: priorities + metadata live in HBM and
-    # sampling/priority-update fuse into the train step (zero host round
-    # trips — replay/device_per.py); needs device_resident + prioritized
+    # the recurrent SEQUENCE loops' switch (train_recurrent,
+    # _train_distributed_recurrent): with device_resident + prioritized,
+    # true runs the fused sequence step (sampling and priority update on
+    # the device), false the host-sampled device sequence ring. The
+    # transition loops do not read it — a pixel device run builds the
+    # fused ring and nothing else (replay/device_per.pixel_device_ring);
+    # the field goes when the sequence side has one path too (ROADMAP D3a)
     device_per: bool = False
     # grad steps chained per fused-PER dispatch (lax.scan inside the two
     # XLA programs): dispatch + host bookkeeping amortize over the chunk;
@@ -699,7 +703,7 @@ def pong_config() -> Config:
     (exactly 1 once shard fills equalize — they correct for unequal
     per-shard sampleable mass, which plain weight=1 uniform ignores).
     Sampling/composition stay on device (no per-step host sum-tree/index
-    work) — measured ~2× the host-sampled uniform rate on v5e.
+    work).
     """
     c = Config()
     c.net = NetConfig(kind="nature_cnn", num_actions=6, compute_dtype="bfloat16")
@@ -756,8 +760,10 @@ def r2d2_config() -> Config:
     c.net = dataclasses.replace(c.net, kind="r2d2", lstm_size=512)
     c.replay = dataclasses.replace(
         c.replay, sequence_length=80, burn_in=40, batch_size=64,
-        # sequence replay prioritizes whole sequences on the host; the
-        # fused transition-level device-PER path does not apply here
+        # selects the HOST-sampled device sequence ring (per-slot sum-trees
+        # on the host, pixels gathered in HBM:
+        # ``SequenceLearner._build_ring_step``), not the fused sequence
+        # step ``train_recurrent`` also has (ROADMAP D3a)
         device_per=False)
     c.env = dataclasses.replace(c.env, games=(), full_action_space=False)
     return c

@@ -453,9 +453,6 @@ class Learner:
         self._replicated = NamedSharding(mesh, P())
         self._batch_sharding = NamedSharding(mesh, P(AXIS_DP))
         self._train_step = self._build_train_step()
-        # ring steps built lazily, keyed on the (static) frame shape the
-        # flat HBM ring's rows decode to
-        self._ring_steps: dict[tuple[int, int], Any] = {}
         # fused device-PER steps, keyed on the replay's static geometry
         self._device_per_steps: dict[tuple, Any] = {}
 
@@ -482,8 +479,9 @@ class Learner:
 
     def _step_core(self, state: TrainState, batch: dict[str, jax.Array]):
         """Loss + allreduce + optimizer + target refresh — shared by the
-        host-batch and device-ring paths. ``batch`` holds per-device local
-        arrays with ``obs``/``next_obs`` already composed."""
+        host-batch step and the fused step's tree body (``tree_train_fn``).
+        ``batch`` holds per-device local arrays with ``obs``/``next_obs``
+        already composed."""
         cfg, apply_fn, opt = self.cfg, self.apply_fn, self.opt
         # static at trace time: per-shard batch decides the auto gate
         use_stacked = (cfg.stack_forwards == "on"
@@ -556,45 +554,6 @@ class Learner:
             check_vma=False,
         )
         return jax.jit(sharded, donate_argnums=0)
-
-    def _build_ring_step(self, frame_shape: tuple[int, int]):
-        """Train step fed by the device-resident frame ring: pixels are
-        gathered/stacked per device from the local ring shard (indices are
-        shard-local), so only [B, stack] int32 + [B] scalars cross the
-        host boundary (SURVEY §7.3 item 1)."""
-        from distributed_deep_q_tpu.replay.device_ring import compose_stacks
-
-        def step_fn(state: TrainState, ring: jax.Array,
-                    batch: dict[str, jax.Array]):
-            composed = {
-                "obs": compose_stacks(ring, batch["oidx"], batch["valid"],
-                                      frame_shape),
-                "next_obs": compose_stacks(ring, batch["noidx"],
-                                           batch["nvalid"], frame_shape),
-                "action": batch["action"],
-                "reward": batch["reward"],
-                "discount": batch["discount"],
-                "weight": batch["weight"],
-            }
-            return self._step_core(state, composed)
-
-        sharded = shard_map(
-            step_fn,
-            mesh=self.mesh,
-            in_specs=(P(), P(AXIS_DP), P(AXIS_DP)),
-            out_specs=(P(), P(), P(AXIS_DP)),
-            check_vma=False,
-        )
-        return jax.jit(sharded, donate_argnums=0)
-
-    def train_step_from_ring(self, state: TrainState, ring: jax.Array,
-                             batch: dict[str, Any],
-                             frame_shape: tuple[int, int] = (84, 84)):
-        """One DP step sampling pixels from the HBM ring (device replay)."""
-        key = tuple(frame_shape)
-        if key not in self._ring_steps:
-            self._ring_steps[key] = self._build_ring_step(key)
-        return self._ring_steps[key](state, ring, batch)
 
     def _build_device_per_step(self, spec: tuple, chain: int,
                                donate: bool = True):

@@ -17,7 +17,11 @@ code). This module moves the WHOLE prioritized loop into HBM
   over the masked priorities (``cumsum`` + ``searchsorted`` — the sum-tree's
   job, done as one memory-bound pass at HBM bandwidth), compose frame
   stacks and n-step returns from the device rings, compute IS weights
-  (stratified-realized form, matching ``DeviceFrameReplay.sample``), run
+  for the REALIZED stratified distribution (each shard contributes
+  exactly ``B/D`` draws, proportional within the shard, so
+  ``P(i) = p_i / (D · mass_shard(i))`` over the shard's sampleable rows
+  and ``w_i = (N · P(i))^-β / max w`` — ``stratified_is_weights``; the
+  global mass would bias the weights wherever shard masses differ), run
   the DQN step, and scatter ``(|TD|+ε)^α`` straight back into the priority
   row — zero-step-stale, no D2H anywhere.
 
@@ -116,15 +120,6 @@ def draw_from_cdf(key: jax.Array, cdf: jax.Array, prio_masked: jax.Array,
     idx = jnp.clip(idx, 0, prio_masked.shape[0] - 1)
     p = prio_masked[idx] / jnp.maximum(mass, 1e-12)
     return idx, p
-
-
-def sample_from_cdf(key: jax.Array, prio_masked: jax.Array,
-                    num: int) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Build + draw in one call (single-step convenience). Returns
-    (indices [num], probabilities p_i/mass [num], mass [])."""
-    cdf, mass = build_cdf(prio_masked)
-    idx, p = draw_from_cdf(key, cdf, prio_masked, mass, num)
-    return idx, p, mass
 
 
 def _stack_window(boundary: jax.Array, local: jax.Array, sub: jax.Array,
@@ -244,21 +239,6 @@ def compose_meta(state_rows: dict[str, jax.Array], local: jax.Array,
     return meta, oflat, ovalid, nflat, nvalid
 
 
-def compose_from_state(state_rows: dict[str, jax.Array], local: jax.Array,
-                       sub: jax.Array, slot_cap: int, stack: int,
-                       n_step: int, gamma: float) -> dict[str, jax.Array]:
-    """Meta composition + the pixel gather in one call — the single-step
-    (unchained) convenience wrapper over ``compose_meta``/``gather_rows``.
-    """
-    meta, oflat, ovalid, nflat, nvalid = compose_meta(
-        state_rows, local, sub, slot_cap, stack, n_step, gamma)
-    return {
-        **meta,
-        "obs_rows": gather_rows(state_rows["frames"], oflat, ovalid),
-        "nobs_rows": gather_rows(state_rows["frames"], nflat, nvalid),
-    }
-
-
 @jax.named_scope("ddq.sample_prep")
 def fused_sample_prep(shard_rows: dict[str, jax.Array],
                       cursors: jax.Array, sizes: jax.Array,
@@ -278,26 +258,6 @@ def fused_sample_prep(shard_rows: dict[str, jax.Array],
     return pm, cdf, mass, n_glob
 
 
-def fused_sample_draw(key: jax.Array, shard_rows: dict[str, jax.Array],
-                      pm: jax.Array, cdf: jax.Array, mass: jax.Array,
-                      n_glob: jax.Array, per_shard: int, slot_cap: int,
-                      stack: int, n_step: int, gamma: float,
-                      beta: jax.Array, num_shards: int):
-    """One step's [B]-scale fused prioritized sample: CDF draw → meta
-    composition → IS weights; ``fused_sample_draw_many`` at chain=1.
-
-    REFERENCE implementation, not the production path: the learner runs
-    ``fused_sample_draw_packed`` (pack row-gathers + window DMA); this
-    gather-based twin is the executable spec the packed path is tested
-    against (tests/test_device_per.py equivalence test) and what the
-    zero-mass/uniformity unit tests drive directly."""
-    batch, oflat, ovalid, nflat, nvalid, idx = fused_sample_draw_many(
-        key[None], shard_rows, pm, cdf, mass, n_glob, per_shard, slot_cap,
-        stack, n_step, gamma, jnp.asarray(beta)[None], num_shards)
-    batch = {k: v[0] for k, v in batch.items()}
-    return (batch, oflat[0], ovalid[0], nflat[0], nvalid[0], idx[0])
-
-
 def fused_sample_draw_many(keys: jax.Array,
                            shard_rows: dict[str, jax.Array],
                            pm: jax.Array, cdf: jax.Array, mass: jax.Array,
@@ -309,19 +269,22 @@ def fused_sample_draw_many(keys: jax.Array,
     chunk-start priorities — and scanned bodies re-touch capacity-sized
     operands per iteration).
 
-    REFERENCE twin of the production ``fused_sample_draw_packed``: this
-    composes meta through ``compose_meta``'s window gathers (clear,
-    tile-amplified); the packed path composes the same values from
-    ``build_meta_pack`` row lanes. The equivalence test in
-    tests/test_device_per.py holds the two together.
+    THE reference of the production ``fused_sample_draw_packed``, not a
+    path the learner runs: this composes meta through ``compose_meta``'s
+    window gathers (clear, tile-amplified); the packed path composes the
+    same values from ``build_meta_pack`` row lanes.
+    ``test_packed_draw_matches_reference_draw`` (tests/test_device_per.py)
+    holds the two together, and the zero-mass unit test drives this one.
 
     Per-step key semantics: row i draws ``uniform(keys[i], (per_shard,))``
     — the vmap computes the same Threefry bits as ``chain`` separate
     calls, so a chain=k chunk byte-matches k single-step dispatches
     (``test_chained_fused_steps_match_sequential_alpha0``).
 
-    ``keys`` is [chain, 2] uint32, ``betas`` [chain]. Returns the same
-    tuple as ``fused_sample_draw`` with a leading [chain] axis everywhere.
+    ``keys`` is [chain, 2] uint32, ``betas`` [chain]. Returns (meta dict
+    incl. ``weight``, oflat, ovalid, nflat, nvalid, sampled shard-local
+    indices), a leading [chain] axis everywhere; the pixel gather is the
+    caller's (``gather_rows`` on the window indices).
     """
     from jax import lax
 
@@ -352,15 +315,14 @@ def stratified_is_weights(p: jax.Array, mass: jax.Array,
     sampler. ``p`` [chain, B] draw probabilities (p_i/mass),
     ``betas`` [chain]; runs inside shard_map (``lax.pmax`` over 'dp').
 
-    P(i) = p_i/(D·mass_s) — each shard contributes exactly B/D draws,
-    matching the host path's weight math; N = global sampleable count
-    (``n_glob``, psum'd once per chunk).
+    P(i) = p_i/(D·mass_s) — each shard contributes exactly B/D draws;
+    N = global sampleable count (``n_glob``, psum'd once per chunk).
 
     A shard whose masked priority mass is zero (e.g. its only sampleable
     slot sealed away post-warmup) would otherwise compose garbage rows
     with extreme weights: zero those weights (the caller points its
     priority scatter out of bounds), so the degenerate shard contributes
-    nothing — the host path raises instead; here the step stays total.
+    nothing and the step stays total.
     Masking must precede the pmax: a dead shard's floored p=1e-12 blows
     w up to ~1e4, and normalizing live shards by THAT w_max would crush
     the whole batch's learning signal."""
@@ -460,38 +422,6 @@ def fused_sample_draw_packed(keys: jax.Array, pack: jax.Array,
     ws = sub * slot_pad + (local - (stack - 1)) % slot_cap
     idx = jnp.where(mass > 0, idx, pm.shape[0])
     return meta, ws.astype(jnp.int32), idx.astype(jnp.int32)
-
-
-def fused_sample_indices(key: jax.Array, shard_rows: dict[str, jax.Array],
-                         cursors: jax.Array, sizes: jax.Array,
-                         per_shard: int, slot_cap: int, stack: int,
-                         n_step: int, gamma: float, beta: jax.Array,
-                         num_shards: int):
-    """prep + draw in one call (single-step / test convenience)."""
-    pm, cdf, mass, n_glob = fused_sample_prep(
-        shard_rows, cursors, sizes, slot_cap, stack, n_step)
-    return fused_sample_draw(key, shard_rows, pm, cdf, mass, n_glob,
-                             per_shard, slot_cap, stack, n_step, gamma,
-                             beta, num_shards)
-
-
-def fused_sample(key: jax.Array, shard_rows: dict[str, jax.Array],
-                 cursors: jax.Array, sizes: jax.Array, per_shard: int,
-                 slot_cap: int, stack: int, n_step: int, gamma: float,
-                 beta: jax.Array, num_shards: int,
-                 ) -> tuple[dict[str, jax.Array], jax.Array]:
-    """Single-step convenience: indices + the pixel gather in one call.
-    Returns (batch dict incl. ``weight``, with obs as flat ``*_rows``
-    stacks — see ``stack_rows_to_obs``; sampled shard-local indices).
-    The chained learner path hoists ``fused_sample_prep`` and the gather
-    out of its scan instead."""
-    batch, oflat, ovalid, nflat, nvalid, idx = fused_sample_indices(
-        key, shard_rows, cursors, sizes, per_shard, slot_cap, stack,
-        n_step, gamma, beta, num_shards)
-    batch = dict(batch)
-    batch["obs_rows"] = gather_rows(shard_rows["frames"], oflat, ovalid)
-    batch["nobs_rows"] = gather_rows(shard_rows["frames"], nflat, nvalid)
-    return batch, idx
 
 
 @jax.named_scope("ddq.priority_writeback")
@@ -612,18 +542,14 @@ class DevicePERFrameReplay(DeviceFrameReplay):
     def __init__(self, cfg, mesh, frame_shape=(84, 84), stack: int = 4,
                  gamma: float = 0.99, seed: int = 0, write_chunk: int = 64,
                  num_streams: int = 1):
-        import dataclasses
-
         from jax import shard_map
         from jax.sharding import NamedSharding
 
-        self.__cfg_full = cfg  # _alloc_ring (called by super) needs n_step
-        # host trees off: priorities live on device
-        super().__init__(dataclasses.replace(cfg, prioritized=False), mesh,
-                         frame_shape, stack, gamma, seed, write_chunk,
-                         num_streams)
+        super().__init__(cfg, mesh, frame_shape, stack, gamma, seed,
+                         write_chunk, num_streams)
+        # the priorities live on the device, whatever ``cfg.prioritized``
+        # says: a loop must not start a host write-back for this ring
         self.prioritized = True
-        self._cfg = cfg  # base stored the trees-off copy; β fields match
         self.n_step, self.gamma = cfg.n_step, gamma
         # frame column: the columnar path stages RAW rows — padding to
         # the DMA stride and the 4-per-int32 byte pack happen inside the
@@ -707,7 +633,7 @@ class DevicePERFrameReplay(DeviceFrameReplay):
         from distributed_deep_q_tpu.ops.ring_gather import padded_row_bytes
         from distributed_deep_q_tpu.parallel.mesh import pallas_interpret
 
-        cfg = self.__cfg_full
+        cfg = self._cfg
         self.window = self.stack + int(cfg.n_step)
         assert self.slot_cap >= self.window, (
             f"slot capacity {self.slot_cap} must hold one sample window "
@@ -831,16 +757,6 @@ class DevicePERFrameReplay(DeviceFrameReplay):
             NamedSharding(self.mesh, P()), np.ascontiguousarray(arr),
             global_shape=arr.shape)
 
-    def sample(self, batch_size: int):
-        raise TypeError(
-            "DevicePERFrameReplay has no host sample path — sampling is "
-            "fused into the learner step (Solver.train_step_device_per)")
-
-    def update_priorities(self, idx, td_abs, sampled_at=None):
-        raise TypeError(
-            "DevicePERFrameReplay has no host priority write-back — the "
-            "fused step scatters (|TD|+eps)^alpha on device itself")
-
     def reset_stream(self, stream: int) -> None:
         """Seal the stream's current slot on HOST AND DEVICE: the fused
         sampler reads the device boundary ring, so a host-only seal would
@@ -903,13 +819,14 @@ class DevicePERFrameReplay(DeviceFrameReplay):
                                            self.to_global(idx)))
 
     # -- learner-side inputs -------------------------------------------------
-    # (β comes from the inherited ``beta`` property; the fused path never
-    # calls host ``sample``, so the anneal advances via next_betas)
+    # (β comes from the inherited ``beta`` property; next_betas is what
+    # advances the anneal)
 
     def next_betas(self, k: int) -> np.ndarray:
         """β values for the next ``k`` fused steps, advancing the anneal
-        BEFORE each read — same ordering as the host path, whose
-        ``sample()`` increments ``_samples`` before computing weights."""
+        BEFORE each read — the ordering of the host tier's
+        ``PrioritizedReplay.sample``, which counts the draw before it
+        computes the weights."""
         out = np.empty(k, np.float32)
         for i in range(k):
             self._samples += 1
@@ -938,3 +855,27 @@ class DevicePERFrameReplay(DeviceFrameReplay):
                     sizes[li * subs + sub] = len(m)
             self._di_cache = (cursors, sizes)
         return self._di_cache
+
+
+def pixel_device_ring(cfg, mesh, frame_shape, stack: int, gamma: float,
+                      seed: int, num_streams: int = 1):
+    """The ring a pixel run with ``replay.device_resident=true`` trains
+    from — both train loops ask here, and the fused ring is the only
+    answer. ``cfg`` is the run's ``ReplayConfig``. Uniform replay is the
+    fused sampler at α = 0 (the ``pong`` preset's way), so a run that
+    asks for an unprioritized device ring is told what to set: nothing
+    rewrites its config for it.
+
+    ``DevicePERFrameReplay`` is looked up when this is called, and takes
+    ``write_chunk`` and ``num_streams`` by name: the benchmark's fleet
+    driver stands in for the class to hand the loop a ring it filled."""
+    if not cfg.prioritized:
+        raise ValueError(
+            "a pixel run with replay.device_resident=true samples inside "
+            "the fused step, which draws by priority: for uniform replay "
+            "set replay.prioritized=true replay.priority_alpha=0 (constant "
+            "priorities: uniform draws on the device), or set "
+            "replay.device_resident=false for the host replay tier")
+    return DevicePERFrameReplay(
+        cfg, mesh, frame_shape, stack, gamma, seed=seed,
+        write_chunk=cfg.write_chunk, num_streams=num_streams)

@@ -1,4 +1,4 @@
-"""Device-resident replay — frames live in HBM, metadata on host.
+"""The host side of a device-resident pixel ring — frames live in HBM.
 
 The TPU-first redesign of the replay data path (SURVEY.md §7.3 item 1: "this
 is where the 50× target is won or lost"). The reference streams full pixel
@@ -6,18 +6,19 @@ minibatches host→device every step (Caffe blob loads, SURVEY §3.1); a
 pmap-fed rebuild doing the same ships ~29 MB/step at batch 512 — measured
 at ~160 ms over this container's TPU link vs a 0.2 ms train step. Instead:
 
-- **Frames enter HBM once, at actor rate.** A uint8 ring ``[capacity, H·W]``
-  (frames flattened row-wise — TPU tiling-aware layout, see
-  ``compose_stacks``) lives on the learner mesh, sharded over the ``dp``
-  axis (each device owns a contiguous shard — Ape-X-style per-learner
-  replay shards). Writers append in fixed-size chunks through a donated
+- **Frames enter HBM once, at actor rate.** A ring of frame rows (frames
+  flattened row-wise: TPU tiles the two minor dims of an array, so a
+  ``[cap, 84, 84]`` uint8 ring would pad each frame to 96×128, 1.74× the
+  bytes) lives on the learner mesh, sharded over the ``dp`` axis (each
+  device owns a contiguous shard — Ape-X-style per-learner replay
+  shards). Writers append in fixed-size chunks through a donated
   ``shard_map`` scatter.
-- **The train step gathers on device.** The host samples *indices* (uniform
-  or PER sum-tree — pointer-chasing stays on host, SURVEY §7.3 item 2),
-  composes n-step returns/validity masks from metadata, and ships only
-  ``[B, stack]`` int32 indices + a few ``[B]`` scalars (~50 KB). Frame-stack
-  composition (gather + zero-masking + transpose) happens inside the jitted
-  step, reading HBM at memory bandwidth.
+- **Sampling is not here.** This module routes streams to slots, stages
+  rows and flushes them; the one sample path of a pixel device run is the
+  fused step over ``replay/device_per.py``'s ring, which inherits all of
+  this and draws ``batch/D`` rows a shard on the device, in mesh order
+  (``PartitionSpec('dp')`` row blocks: each device gathers only from its
+  local shard, no cross-device collective in the data path).
 
 Layout — shards and stream slots:
 
@@ -30,11 +31,7 @@ writer stream at a time. Stream i owns slots {g : g % num_streams == i} and
 cycles through them at episode boundaries; with fewer streams than shards a
 single stream still reaches every shard (episode round-robin), and with more
 streams than shards each shard hosts several sub-rings instead of
-interleaving writers. Sampling draws ``batch/D`` rows per shard (allocated
-across its slots by sampleable/priority mass) and concatenates in mesh
-order, matching ``PartitionSpec('dp')`` row-block layout — each device
-gathers only from its local shard, no cross-device collective in the data
-path.
+interleaving writers.
 """
 
 from __future__ import annotations
@@ -48,40 +45,16 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from distributed_deep_q_tpu import tracing
 from distributed_deep_q_tpu.config import ReplayConfig
 from distributed_deep_q_tpu.parallel.mesh import AXIS_DP
-from distributed_deep_q_tpu.replay.prioritized import (
-    SumTree, allocate_proportional, beta_at, filter_stale,
-    sample_valid_from_tree)
+from distributed_deep_q_tpu.replay.prioritized import beta_at
 from distributed_deep_q_tpu.replay.replay_memory import FrameStackReplay
 
 
-def compose_stacks(ring: jax.Array, oidx: jax.Array, valid: jax.Array,
-                   frame_shape: tuple[int, int] = (84, 84)) -> jax.Array:
-    """[capL, H·W] ring + [B, stack] indices/mask → [B, H, W, stack] uint8.
-
-    Pure jax; runs per-device inside the learner's shard_map (indices are
-    shard-local). Invalid frames (preceding episode start) zero out, matching
-    ``FrameStackReplay.gather`` / ``FrameStacker.reset`` semantics.
-
-    The ring stores frames FLATTENED to one [H·W] row per frame: TPU tiles
-    the two minor dims of an array ((32, 128) lanes for 8-bit types), so a
-    [cap, 84, 84] ring pads each frame to 96×128 — 1.74× HBM waste that
-    OOMs a 16 GB chip at the config-2 1M-frame capacity. Flattened, the
-    pad is 7056→7168 (1.6%) and the full 1M ring fits a single v5e with
-    room for the step. The gather is row-wise either way; only the final
-    reshape (free, layout-compatible) differs.
-    """
-    frames = ring[oidx]                                   # [B, S, H·W]
-    frames = frames * valid[..., None].astype(jnp.uint8)
-    frames = frames.reshape(frames.shape[:2] + tuple(frame_shape))
-    return jnp.moveaxis(frames, 1, -1)                    # [B, H, W, S]
-
-
 class DeviceFrameReplay:
-    """HBM frame ring + host metadata/priorities, one logical buffer.
-
-    Reference-parity surface (``add`` / ``sample`` / ``__len__`` [M]) plus
-    ``update_priorities``; ``sample`` returns an *index batch* whose pixels
-    are composed on device by the learner's ring train step.
+    """The host side of a device ring: geometry, stream→slot routing,
+    per-slot metadata, staging (columnar or legacy), prepared rounds, the
+    drain and the chunked flush into a uint8 HBM frame ring. It has no
+    sample path of its own: ``DevicePERFrameReplay`` (replay/device_per.py)
+    inherits this bookkeeping and is sampled inside the fused learner step.
     """
 
     prioritized: bool
@@ -128,18 +101,17 @@ class DeviceFrameReplay:
         self.write_chunk = int(write_chunk)
         self.prioritized = bool(cfg.prioritized)
         self._cfg = cfg
-        self._rng = np.random.default_rng(seed)
 
         # per-slot metadata rings (single writer each → adjacency holds)
         self.slots = [
             FrameStackReplay(self.slot_cap, frame_shape, stack, cfg.n_step,
                              gamma, seed=seed + i, store_frames=False)
             for i in range(g)]
-        # per-slot priority trees with SHARED max-priority/β bookkeeping
-        self.trees = ([SumTree(self.slot_cap, use_native=cfg.use_native)
-                       for _ in range(g)]
-                      if self.prioritized else None)
+        # keys of the ring's snapshot (replay/persistence.py) that nothing
+        # else reads: they go with the snapshot's next schema (ROADMAP D3b)
+        self._rng = np.random.default_rng(seed)
         self.max_priority = 1.0
+        # the β anneal's position: fused steps taken (``next_betas``)
         self._samples = 0
 
         # stream → its slot cycle over this process's LOCAL slots (stream
@@ -188,8 +160,8 @@ class DeviceFrameReplay:
         """Allocate the HBM frame plane + its scatter-writer. Overridden by
         ``DevicePERFrameReplay`` (flat padded ring + Pallas row-DMA).
 
-        Frames are flattened to [H·W] rows — see compose_stacks for why
-        (TPU (32,128) tiling of the minor dims). Allocated directly with
+        Frames are flattened to [H·W] rows (TPU (32,128) tiling of the
+        minor dims — module docstring). Allocated directly with
         the dp sharding (no host copy); the donated scatter lets each
         device write its chunk into its own ring shard, padding lanes
         carry idx == cap_local and are dropped."""
@@ -217,12 +189,6 @@ class DeviceFrameReplay:
     def _global_index(self, slot: int, local: np.ndarray) -> np.ndarray:
         shard, base = self._slot_base(slot)
         return shard * self.cap_local + base + local
-
-    def _slot_of_global(self, gidx: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-        """global ring row → (slot id, slot-local index)."""
-        shard, rem = gidx // self.cap_local, gidx % self.cap_local
-        sub, local = rem // self.slot_cap, rem % self.slot_cap
-        return sub * self.num_shards + shard, local
 
     # -- bookkeeping --------------------------------------------------------
 
@@ -256,8 +222,8 @@ class DeviceFrameReplay:
 
     def ready(self, learn_start: int) -> bool:
         """True when sampling can proceed: aggregate fill reached AND every
-        shard has at least one slot with sampleable transitions (sample
-        draws batch/D from *each* shard — SURVEY §7.3 item 6)."""
+        shard has at least one slot with sampleable transitions (the
+        fused draw takes batch/D from *each* shard — SURVEY §7.3 item 6)."""
         if len(self) < learn_start:
             return False
         # multi-host: a process can only see (and fill) its local shards;
@@ -297,12 +263,7 @@ class DeviceFrameReplay:
         self._pending_rows[shard] += len(idx)
 
     def _stage(self, slot: int, local: np.ndarray, frames: np.ndarray) -> None:
-        """Queue (slot-local rows, flat frames) for the HBM scatter and set
-        their fresh-row priorities."""
-        if self.prioritized:
-            self.trees[slot].set(
-                local, np.full(len(local),
-                               self.max_priority ** self._cfg.priority_alpha))
+        """Queue (slot-local rows, flat frames) for the HBM scatter."""
         shard, base = self._slot_base(slot)
         self._stage_rows(shard, (base + local).astype(np.int32), (frames,))
 
@@ -514,104 +475,8 @@ class DeviceFrameReplay:
         override this to feed their wider scatter program."""
         d, k = self.num_shards, self.write_chunk
         assert len(self.local_shards) == d, (
-            "DeviceFrameReplay's host-sample write path is "
-            "single-controller; multi-host pixel runs use the fused "
-            "DevicePERFrameReplay")
+            "DeviceFrameReplay's own uint8 ring is single-controller; "
+            "multi-host pixel runs use the fused DevicePERFrameReplay")
         self.ring = self._write(
             self.ring, idx.reshape(d * k),
             cols[0].reshape((d * k,) + self._stage_columns[0][0]))
-
-    # -- sample path --------------------------------------------------------
-
-    def _allocate(self, quota: int, masses: list[float]) -> list[int]:
-        """Split ``quota`` draws across slots ∝ mass (largest remainder)."""
-        return allocate_proportional(quota, masses)
-
-    def sample(self, batch_size: int) -> dict[str, np.ndarray]:
-        """Index batch (no pixels): per-shard draws concatenated in mesh
-        order so ``P('dp')`` row-blocks land on the owning devices."""
-        self.flush()
-        d = self.num_shards
-        per = batch_size // d
-        parts: list[dict[str, np.ndarray]] = []
-        self._samples += 1
-        for s in range(d):
-            shard_slots = [g for g in range(self.num_slots)
-                           if g % d == s]
-            if self.prioritized:
-                masses = [self.trees[g].total if self._sampleable(g) else 0.0
-                          for g in shard_slots]
-            else:
-                masses = [float(self._sampleable(g)) for g in shard_slots]
-            counts = self._allocate(per, masses)
-            assert sum(counts) == per, \
-                f"shard {s} has no sampleable slot (gate on ready())"
-            for g, c in zip(shard_slots, counts):
-                if c == 0:
-                    continue
-                meta = self.slots[g]
-                if self.prioritized:
-                    local = sample_valid_from_tree(
-                        self.trees[g], meta, c, self._rng)
-                    p = self.trees[g].get(local)
-                else:
-                    local = meta.sample_indices(c)
-                    p = np.ones(c)
-                m = meta.gather_meta(local)
-                _, base = self._slot_base(g)
-                for key in ("oidx", "noidx"):
-                    m[key] = (m[key] + base).astype(np.int32)
-                m["index"] = self._global_index(g, local).astype(np.int64)
-                m["_slot"] = np.full(c, g, np.int32)
-                m["_p"] = p
-                parts.append(m)
-        batch = {k: np.concatenate([p[k] for p in parts]) for k in parts[0]}
-
-        if self.prioritized:
-            # IS weights for the REALIZED stratified distribution: each
-            # shard contributes exactly batch/D draws (proportional within
-            # the shard), so P(i) = p_i / (D · mass_shard(i)) — using the
-            # global mass would bias weights whenever shard masses differ.
-            # Only SAMPLEABLE slots count: the allocation above zeroes
-            # unsampleable ones, so their mass is not part of the realized
-            # distribution either.
-            shard_mass = np.zeros(d)
-            for g in range(self.num_slots):
-                if self._sampleable(g):
-                    shard_mass[g % d] += self.trees[g].total
-            owner_shard = batch.pop("_slot") % d
-            n = len(self)
-            pr = np.maximum(
-                batch.pop("_p")
-                / np.maximum(d * shard_mass[owner_shard], 1e-12), 1e-12)
-            w = (n * pr) ** (-self.beta)
-            batch["weight"] = (w / w.max()).astype(np.float32)
-        else:
-            batch.pop("_p")
-            batch.pop("_slot")
-            batch["weight"] = np.ones(batch_size, np.float32)
-        batch["valid"] = batch["valid"].astype(np.uint8)
-        batch["nvalid"] = batch["nvalid"].astype(np.uint8)
-        batch["index"] = batch["index"].astype(np.int32)
-        batch["_sampled_at"] = tuple(m.steps_added for m in self.slots)
-        return batch
-
-    # -- learner feedback ---------------------------------------------------
-
-    def update_priorities(self, idx: np.ndarray, td_abs: np.ndarray,
-                          sampled_at=None) -> None:
-        if not self.prioritized:
-            return
-        gidx = np.asarray(idx, np.int64)
-        td = np.abs(np.asarray(td_abs, np.float64)) + self._cfg.priority_eps
-        slot_ids, local = self._slot_of_global(gidx)
-        for g in np.unique(slot_ids):
-            pick = slot_ids == g
-            li, lt = local[pick], td[pick]
-            if sampled_at is not None:
-                li, lt = filter_stale(li, lt, self.slots[g].steps_added,
-                                      sampled_at[g], self.slot_cap)
-                if li.size == 0:
-                    continue
-            self.trees[g].set(li, lt ** self._cfg.priority_alpha)
-            self.max_priority = max(self.max_priority, float(lt.max()))

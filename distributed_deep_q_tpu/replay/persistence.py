@@ -188,9 +188,6 @@ def replay_state(replay) -> dict:
                 d[f"dev_{k}"] = np.asarray(getattr(replay.dstate, k))
         else:
             d["dev_frames"] = np.asarray(replay.ring)
-            if replay.prioritized:
-                for i, t in enumerate(replay.trees):
-                    d[f"tree{i}"] = t.tree
     elif isinstance(base, FrameStackReplay):
         d.setdefault("meta_kind", "frame_stack")
         d["meta_capacity"] = base.capacity
@@ -330,10 +327,9 @@ def load_replay(replay, path: str) -> None:
                           "prio", "maxp")})
             replay._di_cache = None
         else:
+            # (an older file of this kind may carry per-slot ``tree{i}``
+            # keys: they load ignored)
             replay.ring = jax.device_put(z["dev_frames"], sharded)
-            if replay.prioritized:
-                for i, t in enumerate(replay.trees):
-                    t.set(np.arange(t.size), z[f"tree{i}"][t.size: 2 * t.size])
     elif isinstance(base, FrameStackReplay):
         assert int(z["meta_capacity"]) == base.capacity, "capacity mismatch"
         _frame_stack_restore(base, z, inner)

@@ -138,23 +138,6 @@ class Solver:
             out["index"] = batch["index"]
         return out
 
-    def train_step_from_ring(self, ring, batch: dict[str, Any],
-                             frame_shape: tuple[int, int] | None = None,
-                             ) -> dict[str, Any]:
-        """One gradient step sampling pixels from the device-resident replay
-        ring (``replay/device_ring.py``): ``batch`` carries only indices,
-        masks, and scalars — frames are gathered in HBM inside the step.
-        ``frame_shape`` decodes the ring's flat rows (pass the replay's own
-        ``frame_shape``; defaults to the net config's)."""
-        self.state, metrics, td_abs = self.learner.train_step_from_ring(
-            self.state, ring, _strip_host_keys(batch),
-            frame_shape=tuple(frame_shape or self.config.net.frame_shape))
-        out: dict[str, Any] = dict(metrics)
-        out["td_abs"] = td_abs
-        if "index" in batch:
-            out["index"] = batch["index"]
-        return out
-
     def train_step_device_per(self, replay) -> dict[str, Any]:
         """One FUSED prioritized step on a ``DevicePERFrameReplay``:
         sampling, composition, the gradient step, and the priority update

@@ -20,7 +20,6 @@ from distributed_deep_q_tpu.config import Config
 from distributed_deep_q_tpu.metrics import Metrics, MovingAverage
 from distributed_deep_q_tpu.profiling import (
     StepTimer, TraceWindow, start_profiler_server)
-from distributed_deep_q_tpu.replay.device_ring import DeviceFrameReplay
 from distributed_deep_q_tpu.replay.prioritized import maybe_prioritize
 from distributed_deep_q_tpu.replay.replay_memory import FrameStackReplay, ReplayMemory
 from distributed_deep_q_tpu.solver import Solver
@@ -134,23 +133,14 @@ def train_single_process(cfg: Config, metrics: Metrics | None = None,
                     "replay.device_resident=True is single-controller only "
                     "(the host writes frames into a mesh-sharded HBM ring); "
                     "multi-host pixel runs need replay.device_resident=false")
-            # TPU-first data path: frames live in HBM, the step gathers
-            # stacks on device; PER (when enabled) is handled per shard
-            # inside DeviceFrameReplay — or fully fused into the step
-            # (device_per: priorities/metadata in HBM, zero host round
-            # trips per step)
-            if cfg.replay.prioritized and cfg.replay.device_per:
-                from distributed_deep_q_tpu.replay.device_per import (
-                    DevicePERFrameReplay)
-                replay = DevicePERFrameReplay(
-                    cfg.replay, solver.mesh, env.obs_shape, cfg.env.stack,
-                    cfg.train.gamma, seed=seed,
-                    write_chunk=cfg.replay.write_chunk)
-            else:
-                replay = DeviceFrameReplay(
-                    cfg.replay, solver.mesh, env.obs_shape, cfg.env.stack,
-                    cfg.train.gamma, seed=seed,
-                    write_chunk=cfg.replay.write_chunk)
+            # TPU-first data path: frames, metadata and priorities live in
+            # HBM and the fused step samples there (zero host round trips
+            # a step)
+            from distributed_deep_q_tpu.replay.device_per import (
+                pixel_device_ring)
+            replay = pixel_device_ring(
+                cfg.replay, solver.mesh, env.obs_shape, cfg.env.stack,
+                cfg.train.gamma, seed=seed)
         else:
             replay = maybe_prioritize(FrameStackReplay(
                 cfg.replay.capacity, env.obs_shape, cfg.env.stack,
@@ -267,11 +257,7 @@ def train_single_process(cfg: Config, metrics: Metrics | None = None,
                         sampled_at = batch.pop("_sampled_at",
                                                replay.steps_added)
                         with timer.phase("dispatch"):
-                            if isinstance(replay, DeviceFrameReplay):
-                                m = solver.train_step_from_ring(
-                                    replay.ring, batch, replay.frame_shape)
-                            else:
-                                m = solver.train_step(batch)
+                            m = solver.train_step(batch)
                     gsteps += 1
                     timer.step_done()
                     trace.on_step(gsteps)
@@ -436,9 +422,10 @@ def train_recurrent(cfg: Config, metrics: Metrics | None = None,
 
     # fused chained sequence path: sampling/meta/pixels/priorities all on
     # device, chain grad steps per dispatch (sequence twin of the
-    # transition path's FusedStepStream loop). Prioritized-only, same
-    # gate as the transition path: the device sampler draws from the
-    # priority row, so a uniform config must keep the per-step path.
+    # transition path's FusedStepStream loop). Prioritized-only: the
+    # device sampler draws from the priority row, so a uniform config
+    # keeps the per-step path here (the transition loops refuse one:
+    # replay/device_per.pixel_device_ring).
     fused_seq = (device_seq and cfg.replay.device_per
                  and cfg.replay.prioritized)
     stream = None

@@ -16,7 +16,8 @@ from distributed_deep_q_tpu.config import (
     Config, EnvConfig, MeshConfig, NetConfig, ReplayConfig, TrainConfig)
 from distributed_deep_q_tpu.parallel.mesh import make_mesh
 from distributed_deep_q_tpu.replay.device_per import (
-    DevicePERFrameReplay, sample_from_cdf, stack_rows_to_obs, valid_mask)
+    DevicePERFrameReplay, build_cdf, draw_from_cdf, stack_rows_to_obs,
+    valid_mask)
 from distributed_deep_q_tpu.replay.replay_memory import FrameStackReplay
 
 
@@ -105,6 +106,76 @@ def test_compose_matches_host_gather():
     np.testing.assert_array_equal(
         np.asarray(stack_rows_to_obs(jnp.asarray(nobs), (8, 8))),
         ref["next_obs"])
+
+
+def test_fused_draw_is_shard_local_dp8():
+    """The REAL sharded path at dp=8: the learner's own sample program
+    (packed draw + window DMA under ``shard_map``), each device's rows
+    held against a host shadow of ITS OWN slot — metadata and n-step math
+    from that slot's rows, pixels from that shard's block of the ring,
+    wrapped sub-rings and ghost rows included. Catches shard mis-ordering
+    or layout drift that a dp=1 comparison cannot."""
+    from distributed_deep_q_tpu.solver import Solver
+
+    dp, per, stack, n_step, chain = 8, 4, 4, 2, 2
+    cfg = Config()
+    cfg.mesh.backend = "cpu"
+    cfg.mesh.dp = dp
+    cfg.net = NetConfig(kind="nature_cnn", num_actions=4,
+                        frame_shape=(36, 36))
+    cfg.replay = ReplayConfig(capacity=dp * 128, batch_size=dp * per,
+                              n_step=n_step, prioritized=True,
+                              write_chunk=16)
+    solver = Solver(cfg)
+    dev = DevicePERFrameReplay(cfg.replay, solver.mesh, (36, 36),
+                               stack=stack, gamma=0.99, seed=0,
+                               write_chunk=16)
+    assert dev.subs_per_shard == 1      # one stream: slot g IS shard g
+    shadows = [FrameStackReplay(dev.slot_cap, (36, 36), stack, n_step, 0.99,
+                                seed=0) for _ in range(dp)]
+    rng = np.random.default_rng(0)
+    for i in range(1300):       # episodes round-robin the shards; they wrap
+        frame = rng.integers(0, 255, (36, 36), dtype=np.uint8)
+        a, r, done = int(rng.integers(4)), float(rng.standard_normal()), \
+            i % 9 == 8
+        shard, local = divmod(dev.add(frame, a, r, done), dev.cap_local)
+        assert shadows[shard].add(frame, a, r, done) == local
+    dev.flush()
+    assert all(len(m) == dev.slot_cap for m in shadows)
+
+    sample, _ = solver.learner.device_per_programs(
+        solver.device_per_spec(dev), chain)
+    cursors, sizes = dev.device_inputs()
+    keys = np.random.default_rng(5).integers(0, 2**32, (dp, chain, 2),
+                                             np.uint32)
+    d = dev.dstate
+    metas, win, idx = sample(keys, d.frames, d.action, d.reward, d.done,
+                             d.boundary, d.prio, cursors, sizes,
+                             np.full(chain, 0.4, np.float32))
+    metas = {k: np.asarray(v) for k, v in metas.items()}
+    idx = np.asarray(idx)                           # [chain, B] shard-local
+    win = np.asarray(win).reshape(chain, dp * per, stack + n_step, dev.rowp)
+    win = win.view(np.uint8)[..., :36 * 36]         # packed words -> pixels
+    assert (idx < dev.cap_local).all()
+    for c in range(chain):
+        for s in range(dp):
+            rows = slice(s * per, (s + 1) * per)
+            ref = shadows[s].gather(idx[c, rows])
+            np.testing.assert_array_equal(metas["action"][c, rows],
+                                          ref["action"])
+            np.testing.assert_allclose(metas["reward"][c, rows],
+                                       ref["reward"], atol=1e-5)
+            np.testing.assert_allclose(metas["discount"][c, rows],
+                                       ref["discount"], rtol=1e-6)
+            w = win[c, rows]
+            obs = w[:, :stack] * metas["ovalid"][c, rows][..., None]
+            nobs = w[:, n_step:] * metas["nvalid"][c, rows][..., None]
+            np.testing.assert_array_equal(
+                np.asarray(stack_rows_to_obs(jnp.asarray(obs), (36, 36))),
+                ref["obs"])
+            np.testing.assert_array_equal(
+                np.asarray(stack_rows_to_obs(jnp.asarray(nobs), (36, 36))),
+                ref["next_obs"])
 
 
 def test_packed_draw_matches_reference_draw():
@@ -197,9 +268,10 @@ def test_packed_draw_matches_reference_draw():
     np.testing.assert_array_equal(np.asarray(ws)[live], want_ws[live])
 
 
-def test_sample_from_cdf_proportional():
+def test_draw_from_cdf_proportional():
     p = jnp.asarray([0.0, 1.0, 3.0, 0.0, 6.0], jnp.float32)
-    idx, prob, mass = sample_from_cdf(jax.random.PRNGKey(0), p, 20_000)
+    cdf, mass = build_cdf(p)
+    idx, prob = draw_from_cdf(jax.random.PRNGKey(0), cdf, p, mass, 20_000)
     counts = np.bincount(np.asarray(idx), minlength=5) / 20_000
     np.testing.assert_allclose(counts, [0, 0.1, 0.3, 0, 0.6], atol=0.02)
     assert float(mass) == 10.0
@@ -455,14 +527,14 @@ def test_fused_sample_zero_mass_shard_yields_zero_weights():
     """A shard with zero masked priority mass must contribute zero-weight
     rows and drop its priority scatter (OOB index) instead of composing
     garbage with extreme IS weights."""
-    from distributed_deep_q_tpu.replay.device_per import fused_sample
+    from distributed_deep_q_tpu.replay.device_per import (
+        fused_sample_draw_many, fused_sample_prep)
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = make_mesh(MeshConfig(backend="cpu", num_fake_devices=8, dp=2))
     cap_local, slot_cap = 64, 64
     rows = {
-        "frames": jnp.zeros((2 * cap_local, 16), jnp.uint8),
         "action": jnp.zeros(2 * cap_local, jnp.int32),
         "reward": jnp.zeros(2 * cap_local, jnp.float32),
         "done": jnp.zeros(2 * cap_local, jnp.uint8),
@@ -474,19 +546,22 @@ def test_fused_sample_zero_mass_shard_yields_zero_weights():
     cursors = jnp.asarray([30, 0], jnp.int32)
     sizes = jnp.asarray([60, 0], jnp.int32)
 
-    def fn(frames, action, reward, done, boundary, prio, cur, siz):
-        shard_rows = {"frames": frames, "action": action, "reward": reward,
-                      "done": done, "boundary": boundary, "prio": prio}
-        batch, idx = fused_sample(jnp.asarray([0, 1], jnp.uint32),
-                                  shard_rows, cur, siz, 8, slot_cap,
-                                  2, 1, 0.99, jnp.float32(0.4), 2)
-        return batch["weight"], idx
+    def fn(action, reward, done, boundary, prio, cur, siz):
+        shard_rows = {"action": action, "reward": reward, "done": done,
+                      "boundary": boundary, "prio": prio}
+        pm, cdf, mass, n_glob = fused_sample_prep(
+            shard_rows, cur, siz, slot_cap, 2, 1)
+        batch, *_, idx = fused_sample_draw_many(
+            jnp.asarray([[0, 1]], jnp.uint32), shard_rows, pm, cdf, mass,
+            n_glob, 8, slot_cap, 2, 1, 0.99, jnp.full(1, 0.4, jnp.float32),
+            2)
+        return batch["weight"][0], idx[0]
 
     S = P("dp")
     w, idx = shard_map(
-        fn, mesh=mesh, in_specs=(S,) * 8, out_specs=(S, S),
+        fn, mesh=mesh, in_specs=(S,) * 7, out_specs=(S, S),
         check_vma=False)(
-        rows["frames"], rows["action"], rows["reward"], rows["done"],
+        rows["action"], rows["reward"], rows["done"],
         rows["boundary"], rows["prio"], cursors, sizes)
     w, idx = np.asarray(w), np.asarray(idx)
     assert np.all(np.isfinite(w))

@@ -77,8 +77,9 @@ def test_signal_games_differ():
 @pytest.mark.slow
 def test_pixel_path_learns_through_device_ring():
     """THE gate for the pixel topology: Nature-CNN learner fed by the
-    device-resident HBM ring beats the random policy (≈8/episode) by ≥2×
-    on SignalAtari greedy eval."""
+    device-resident HBM ring, uniform draws (the fused sampler at alpha
+    0), beats the random policy (≈8/episode) by ≥2× on SignalAtari greedy
+    eval."""
     from distributed_deep_q_tpu.train import train_single_process
 
     cfg = Config()
@@ -89,7 +90,8 @@ def test_pixel_path_learns_through_device_ring():
                         compute_dtype="float32")
     cfg.replay = ReplayConfig(capacity=8192, batch_size=32,
                               learn_start=500, n_step=1,
-                              device_resident=True, write_chunk=64)
+                              prioritized=True, priority_alpha=0.0,
+                              write_chunk=64)
     cfg.train = TrainConfig(lr=1e-3, adam_eps=1e-8, gamma=0.99,
                             target_tau=0.01, double_dqn=True,
                             total_steps=4000, train_every=2,

@@ -283,35 +283,6 @@ def test_distributed_cartpole_end_to_end():
 
 
 @pytest.mark.slow
-def test_distributed_pixel_device_ring_end_to_end():
-    """Actors streaming FakeAtari frames over RPC into the device ring while
-    the learner trains from it — exercises stream sub-rings, the locked
-    sample+dispatch (ring donation race), and PER priority write-back."""
-    from distributed_deep_q_tpu.actors.supervisor import train_distributed
-    from distributed_deep_q_tpu.config import pong_config, ReplayConfig
-
-    cfg = pong_config()
-    cfg.mesh.backend = "cpu"
-    cfg.mesh.num_fake_devices = 2
-    cfg.env.id = "fake"
-    cfg.env.kind = "fake_atari"
-    cfg.env.frame_shape = (36, 36)
-    cfg.net.frame_shape = (36, 36)
-    cfg.net.compute_dtype = "float32"
-    cfg.replay = ReplayConfig(capacity=4096, batch_size=16, learn_start=300,
-                              n_step=2, prioritized=True, write_chunk=16)
-    cfg.train.total_steps = 60
-    cfg.train.target_update_period = 10
-    cfg.actors.num_actors = 3   # 3 streams > 2 shards → sub-rings in play
-    cfg.actors.send_batch = 20
-    cfg.actors.param_sync_period = 25
-    summary = train_distributed(cfg, log_every=20)
-    assert summary["solver"].step == 60
-    assert np.isfinite(summary["loss"])
-    assert summary["env_steps"] >= 300
-
-
-@pytest.mark.slow
 def test_supervisor_restarts_killed_actor():
     """Fault injection (SURVEY §5.3): kill an actor mid-run; the supervisor
     must detect the death and respawn it, and training must keep going."""

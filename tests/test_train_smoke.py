@@ -35,8 +35,9 @@ def test_host_batch_loop_on_the_sharded_mesh():
 
 
 def test_fake_atari_pixel_path():
-    """Device ring + CNN learner end to end on FakeAtari frames, sharded
-    over the 8-device mesh for its 16 grad steps."""
+    """Fused device ring (uniform draws: alpha 0) + CNN learner end to end
+    on FakeAtari frames, sharded over the 8-device mesh for its 16 grad
+    steps."""
     cfg = Config()
     cfg.net = NetConfig(kind="nature_cnn", num_actions=4,
                         frame_shape=(84, 84), stack=4)
@@ -46,6 +47,8 @@ def test_fake_atari_pixel_path():
     cfg.replay.capacity = 2_000
     cfg.replay.batch_size = 16
     cfg.replay.learn_start = 200
+    cfg.replay.prioritized = True
+    cfg.replay.priority_alpha = 0.0
     cfg.train.total_steps = 260
     cfg.train.train_every = 4
     out = train_single_process(cfg, log_every=5)

@@ -144,10 +144,11 @@ def _pixel_cfg(vel_id: str = "signal-vel", total_steps: int = 6000,
 @pytest.mark.slow
 def test_velocity_learns_through_device_ring():
     """Motion gate #1: the frame-stack CNN over the device-resident HBM
-    ring must read displacement ACROSS stack channels — ≥2× random."""
+    ring, uniform draws (the fused sampler at alpha 0), must read
+    displacement ACROSS stack channels — ≥2× random."""
     from distributed_deep_q_tpu.train import train_single_process
 
-    cfg = _pixel_cfg(device_resident=True)
+    cfg = _pixel_cfg(prioritized=True, priority_alpha=0.0)
     summary = train_single_process(cfg, log_every=500)
     assert summary["eval_return"] >= 16.0, (
         f"device-ring path failed to learn motion: "
