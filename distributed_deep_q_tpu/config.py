@@ -179,6 +179,20 @@ class TokenQConfig:
     # that every token takes, whole on every member of the group
     routed_scaling_factor: float = 1.0
     n_shared_experts: int = 0
+    # generation by diffusion over blocks (0: off, one token a position
+    # under the causal mask): the window is cut into blocks of
+    # ``block_length`` positions after position 0 (a prompt block of its
+    # own); the train step runs it TWICE in one pass — a clean copy and a
+    # copy in which each block keeps its first ``reveal`` tokens and holds
+    # the mask token at the others, the two sharing position ids — under
+    # the three-part block mask (``ops/attention.block_diffusion_attention``)
+    # and reads ONE decision a block, at its first masked row
+    # (``parallel/sequence_learner.py``). The mask token is the LAST row
+    # held (``models/tokenq.mask_token``: ``num_actions - 1``, one more
+    # than the env's tokens — ``train.token_rows``): a row of the embedding
+    # and of the head that is no action; the token ring never holds it and
+    # every argmax skips its column
+    block_length: int = 0
     # kernel blocks: attention q/kv block (the window is padded to a
     # multiple) and the kv columns of one inner step (a divisor of it),
     # tokens per block of the Q head + TD loss (and of the dense
@@ -977,6 +991,39 @@ def laguna_tokenq_config() -> Config:
     return c
 
 
+def sdar_tokenq_config() -> Config:
+    """SDAR-30B-A3B-Chat (JetLM, config.json, ``model_type`` sdar_moe) as
+    a token-window Q-network, one chip's share of a 16-chip expert-parallel
+    deployment: every width as published (hidden 2048, 32/4 heads of 128
+    with q/k norms, rope theta 1e6, SwiGLU experts of width 768, softmax
+    router 128 wide, top 8); 4 of the 48 layers (the period is one layer),
+    8 of the 128 experts and 18 992 of the 151 936 vocabulary rows held
+    here, the last of them ``[MASK]``. Generation by diffusion over blocks
+    of 4: a window of 16 384 steps (+1 token) goes through the backbone
+    twice in one pass (32 769 rows) under the three-part block mask and
+    gives 4 096 decisions; batch 1, chain 4, a ring of 8 192 windows."""
+    c = smallthinker_tokenq_config()
+    c.net = NetConfig(
+        kind="tokenq", num_actions=18_992, compute_dtype="bfloat16",
+        tokenq=TokenQConfig(
+            hidden_size=2048, num_hidden_layers=4, num_attention_heads=32,
+            num_key_value_heads=4, head_dim=128, rms_norm_eps=1e-6,
+            sliding_window_layout=(0,) * 4, rope_layout=(1,) * 4,
+            rope_theta=1_000_000.0, qk_norm=True, hidden_act="silu",
+            router_input="ffn_norm",
+            moe_ffn_hidden_size=768, moe_num_primary_experts=128,
+            moe_num_active_primary_experts=8, experts_held=8,
+            expert_offset=0, block_length=4,
+            attn_block=1024, attn_compute_block=512, attn_fused_bwd=False,
+            head_block=1024, moe_tile=256))
+    c.replay = dataclasses.replace(
+        c.replay, capacity=8_192 * 16_384, batch_size=1,
+        sequence_length=16_384, learn_start=64 * 16_384)
+    c.train = dataclasses.replace(c.train, train_every=16_384)
+    c.env = dataclasses.replace(c.env, token_vocab=18_991)
+    return c
+
+
 def env_for_actor(env: EnvConfig, actor_id: int) -> EnvConfig:
     """Per-actor game assignment (config 4 multi-game fleets): actor i
     plays ``games[i % len(games)]``; single-game configs pass through."""
@@ -998,6 +1045,7 @@ PRESETS = {
     "keye_tokenq": keye_tokenq_config,
     "moonlight_tokenq": moonlight_tokenq_config,
     "laguna_tokenq": laguna_tokenq_config,
+    "sdar_tokenq": sdar_tokenq_config,
 }
 
 

@@ -88,7 +88,7 @@ def main(argv: list[str] | None = None) -> int:
         import numpy as np
         from distributed_deep_q_tpu.actors.game import make_env
         env = make_env(cfg.env, seed=cfg.train.seed)
-        cfg.net.num_actions = env.num_actions
+        cfg.net.num_actions = _num_actions(cfg, env)
         solver = _build_solver(cfg, env)
         restored = _maybe_restore(solver, cfg)
         if cfg.net.kind == "r2d2":
@@ -108,7 +108,7 @@ def main(argv: list[str] | None = None) -> int:
         import numpy as np
         from distributed_deep_q_tpu.actors.game import FrameStacker, make_env
         env = make_env(cfg.env, seed=cfg.train.seed)
-        cfg.net.num_actions = env.num_actions
+        cfg.net.num_actions = _num_actions(cfg, env)
         solver = _build_solver(cfg, env)
         _maybe_restore(solver, cfg)
         rng = np.random.default_rng(cfg.train.seed)
@@ -123,9 +123,8 @@ def main(argv: list[str] | None = None) -> int:
         prefix = [int(obs[0])] if tokens else []
         while not over:
             if tokens:
-                a = solver.token_act(
-                    np.asarray(prefix[-(cfg.replay.sequence_length + 1):]),
-                    cfg.actors.eval_eps, rng)
+                a = solver.token_act(solver.acting_prefix(prefix),
+                                     cfg.actors.eval_eps, rng)
             elif recurrent:
                 a, carry = solver.act(np.asarray(obs), carry,
                                       cfg.actors.eval_eps, rng)
@@ -142,6 +141,15 @@ def main(argv: list[str] | None = None) -> int:
         return 0
 
     return 2
+
+
+def _num_actions(cfg, env) -> int:
+    """The env's actions — for a token-window net its rows, the mask
+    token's among them (``train.token_rows``)."""
+    if cfg.net.kind != "tokenq":
+        return env.num_actions
+    from distributed_deep_q_tpu.train import token_rows
+    return token_rows(cfg, env)
 
 
 def _build_solver(cfg, env):

@@ -65,6 +65,21 @@ mixer and a feed-forward in pre-norm residual form, input x ``[B, T, h]``
     ("pre_mixer"); its renormalised gates are multiplied by
     ``routed_scaling_factor``.
 
+With ``block_length`` > 0 (generation by diffusion over blocks) the
+window is cut into blocks after position 0 and attention is no longer
+causal: on the training path the window stands in the rows TWICE
+(``bd_pack``: a clean copy, then a copy in which each block shows its
+first ``reveal`` tokens and the mask token at the rest), both copies at
+the same position ids, under the three-part block mask
+(``ops/attention.block_diffusion_attention``: a clean row sees the clean
+rows of its own and earlier blocks, a noised row the clean rows of
+earlier blocks and the noised rows of its own); one row a block — its
+first masked one (``bd_decision_rows``) — is a decision, and the learner
+gathers those before the head. On the acting path ONE copy runs under the
+block-causal part of that mask, the prefix followed by the mask token
+(``q_at``). Every other product (norms, projections, router, experts)
+runs on the packed rows as on any window.
+
 Every branch here is on a mechanism a ``TokenQConfig`` field names, never
 on which model is being run.
 
@@ -80,7 +95,11 @@ router with scaled gates, and a shared expert beside them. Laguna's:
 window and full attention at a head count and a rotary embedding of
 their own (YaRN over half of each head on the full layers), a gate a head
 on every attention output, a leading dense layer, SwiGLU experts behind a
-sigmoid router with no bias and scaled gates, a shared expert.
+sigmoid router with no bias and scaled gates, a shared expert. SDAR's
+(``sdar_moe``): Keye's numbers without the indexer — plain attention with
+q/k norms and rope on every layer, SwiGLU experts behind a softmax router
+reading the second norm — in blocks of 4 under the block mask, the last
+vocabulary row held the mask token.
 
 Then the final RMSNorm; the untied head ``[h, V]`` is applied by the
 learner, blockwise over tokens, together with the TD loss
@@ -111,7 +130,8 @@ import numpy as np
 from distributed_deep_q_tpu.config import (
     NetConfig, RopeParameters, TokenQConfig)
 from distributed_deep_q_tpu.ops import moe, sparse_attention
-from distributed_deep_q_tpu.ops.attention import causal_attention
+from distributed_deep_q_tpu.ops.attention import (
+    bd_rows, block_diffusion_attention, causal_attention)
 from distributed_deep_q_tpu.ops.short_conv import short_conv_mix
 
 INIT_STD = 0.02
@@ -190,7 +210,21 @@ def layer_plan(tq: TokenQConfig) -> list[dict[str, Any]]:
     if tq.qk_norm and any(k["latent"] for k in plan):
         raise ValueError("a latent_attention layer takes no qk_norm (its "
                          "latent has a norm of its own: kv_norm)")
+    if tq.block_length and (
+            not all(plain) or tq.gating or per_layer or any(
+                k["windowed"] or k["rope_params"] for k in plan)):
+        raise ValueError(
+            "block_length > 0 (generation by diffusion over blocks) is the "
+            "plain full attention mixer's, with one head count and one "
+            "rope_theta: with a window, a conv, sparse or latent mixer, "
+            "gating or rotary parameters a kind it is held to no reference")
     return plan
+
+
+def mask_token(cfg: NetConfig) -> int:
+    """The mask token of generation by diffusion over blocks: the LAST row
+    held, which is no action (-1 with ``block_length`` 0: none)."""
+    return cfg.num_actions - 1 if cfg.tokenq.block_length else -1
 
 
 def _rope_params(tq: TokenQConfig, windowed: bool) -> RopeParameters | None:
@@ -331,15 +365,27 @@ def rmsnorm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
     return x * jax.lax.rsqrt(var + eps) * w
 
 
-def rotary_by_table(x: jax.Array, inv, factor: float) -> jax.Array:
-    """Rotary embedding over ``[B, H, T, D]`` at positions 0..T-1 from a
-    kind's table (``rotary_table``: ``inv`` [r / 2]), float32: only the
-    FIRST r columns of each head turn, rotate-half among themselves
-    (i with i + r/2), pair i by ``t · inv[i]``, cos and sin multiplied by
-    ``factor``; the other D - r pass through unturned and unscaled."""
+def _positions(t: int, positions) -> jax.Array:
+    """Row i's position id, float32 [T]: ``positions`` where the caller
+    states them (two rows of a packed window may share one), else the row
+    index."""
+    if positions is None:
+        return jnp.arange(t, dtype=jnp.float32)
+    return jnp.asarray(positions, jnp.float32)
+
+
+def rotary_by_table(x: jax.Array, inv, factor: float,
+                    positions=None) -> jax.Array:
+    """Rotary embedding over ``[B, H, T, D]`` from a kind's table
+    (``rotary_table``: ``inv`` [r / 2]), float32, row i at position
+    ``positions[i]`` (``None``: i, the row index — every window that holds
+    each position once): only the FIRST r columns of each head turn,
+    rotate-half among themselves (i with i + r/2), pair i by ``position ·
+    inv[i]``, cos and sin multiplied by ``factor``; the other D - r pass
+    through unturned and unscaled."""
     d, t = x.shape[-1], x.shape[-2]
     r = 2 * inv.shape[0]
-    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
+    ang = _positions(t, positions)[:, None] * inv[None, :]
     cos = jnp.concatenate([jnp.cos(ang)] * 2, -1) * factor
     sin = jnp.concatenate([jnp.sin(ang)] * 2, -1) * factor
     x1, x2 = x[..., :r // 2], x[..., r // 2:r]
@@ -347,16 +393,18 @@ def rotary_by_table(x: jax.Array, inv, factor: float) -> jax.Array:
     return jnp.concatenate([turned, x[..., r:]], -1) if r < d else turned
 
 
-def rotary(x: jax.Array, theta: float,
-           interleave: bool = False) -> jax.Array:
-    """Rotary embedding over ``[B, H, T, D]`` at positions 0..T-1,
-    float32, ONE base over all of ``D`` and no scaling (a kind of layer
-    with rotary parameters of its own turns by ``rotary_by_table``): pair
-    i turns by ``t · theta^(-2i/D)``. Rotate-half pairs element i with
-    i + D/2; ``interleave`` pairs 2i with 2i + 1."""
+def rotary(x: jax.Array, theta: float, interleave: bool = False,
+           positions=None) -> jax.Array:
+    """Rotary embedding over ``[B, H, T, D]``, float32, row i at position
+    ``positions[i]`` (``None``: the row index; the packed rows of a
+    block-diffusion window state theirs, which the clean and the noised
+    copy share), ONE base over all of ``D`` and no scaling (a kind of
+    layer with rotary parameters of its own turns by ``rotary_by_table``):
+    pair i turns by ``position · theta^(-2i/D)``. Rotate-half pairs element
+    i with i + D/2; ``interleave`` pairs 2i with 2i + 1."""
     d, t = x.shape[-1], x.shape[-2]
     inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
+    ang = _positions(t, positions)[:, None] * inv[None, :]
     if interleave:
         cos, sin = (jnp.repeat(f(ang), 2, -1) for f in (jnp.cos, jnp.sin))
         pairs = x.reshape(*x.shape[:-1], d // 2, 2)
@@ -468,7 +516,7 @@ def mixer(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
           windowed: bool, rope: bool, interpret: bool, *,
           conv: bool = False, dense: bool = False, sparse: bool = False,
           latent: bool = False, index_loss: bool = True, heads: int = 0,
-          rope_params: RopeParameters | None = None):
+          rope_params: RopeParameters | None = None, bd_steps: int = 0):
     """A block's first half, ``x' = x + m``; ``x`` [B, T, h] float32 →
     (x', the routing where the router reads the mixer's input — else
     ``None`` —, the mixer's counters: a sparse mixer's, under ``gating``
@@ -478,7 +526,10 @@ def mixer(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
     the layer's kind), with ``conv`` the gated short convolution, with
     ``sparse`` attention over the keys its indexer selects
     (``index_loss``: with the indexer's loss), with ``latent`` latent
-    attention."""
+    attention. ``bd_steps`` > 0: ``x`` holds the packed rows of windows of
+    that many steps in blocks of ``block_length`` (``ops/attention.
+    bd_rows``: one copy or two) — the rotary embedding turns each row at
+    its position there and attention runs under the block mask."""
     tq = cfg.tokenq
     router_first = tq.router_input == "pre_mixer"
     dtype = jnp.dtype(cfg.compute_dtype)
@@ -498,7 +549,10 @@ def mixer(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
         with jax.named_scope("ddq.attn_latent"):
             x = x + latent_attention(u, p, cfg, rope, interpret)
     else:
+        positions = bd_rows(bd_steps, tq.block_length,
+                            1 + (t > bd_steps + 1))[1] if bd_steps else None
         with jax.named_scope(
+                "ddq.attn_bd" if bd_steps else
                 "ddq.attn_sparse" if sparse else
                 "ddq.attn_window" if windowed else "ddq.attn_full"):
             # the sparse core takes whole query blocks: the window is
@@ -521,8 +575,18 @@ def mixer(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
                     q = rotary_by_table(q, *table)
                     k = rotary_by_table(k, *table)
             elif rope:
-                q, k = rotary(q, tq.rope_theta), rotary(k, tq.rope_theta)
-            if sparse:
+                q = rotary(q, tq.rope_theta, positions=positions)
+                k = rotary(k, tq.rope_theta, positions=positions)
+            if bd_steps:
+                # the kernel calls alone stand under ddq.attn_bd_core
+                a = block_diffusion_attention(
+                    q.astype(dtype), k.astype(dtype), v.astype(dtype),
+                    t=bd_steps, block_length=tq.block_length,
+                    block=tq.attn_block,
+                    compute_block=tq.attn_compute_block,
+                    fused_bwd=tq.attn_fused_bwd, interpret=interpret)
+                a = a.transpose(0, 2, 1, 3).reshape(b, t, hq * d)
+            elif sparse:
                 with jax.named_scope("ddq.indexer"):
                     indexer = _indexer_inputs(um, p, tq, rope)
                 a, counters = sparse_attention.sparse_attention(
@@ -604,7 +668,7 @@ def layer(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
           windowed: bool, rope: bool, interpret: bool, *,
           conv: bool = False, dense: bool = False, sparse: bool = False,
           latent: bool = False, index_loss: bool = True, heads: int = 0,
-          rope_params: RopeParameters | None = None):
+          rope_params: RopeParameters | None = None, bd_steps: int = 0):
     """One block, ``mixer`` then ``feed_forward``; ``x`` [B, T, h] float32
     → (x, the layer's counters: the expert layer's, under ``"dsa"`` a
     sparse mixer's, and a gated mixer's ``attn_gate_mean``; ``None`` where
@@ -612,7 +676,7 @@ def layer(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
     x, route, mixed = mixer(x, p, cfg, windowed, rope, interpret, conv=conv,
                             dense=dense, sparse=sparse, latent=latent,
                             index_loss=index_loss, heads=heads,
-                            rope_params=rope_params)
+                            rope_params=rope_params, bd_steps=bd_steps)
     x, counters = feed_forward(x, p, cfg, interpret, dense=dense,
                                route=route)
     if sparse:
@@ -627,8 +691,36 @@ KEEP_SELECTION = jax.checkpoint_policies.save_only_these_names(
     sparse_attention.SELECTION_NAME, sparse_attention.LOSS_NAME)
 
 
+def bd_pack(tokens: jax.Array, reveal: jax.Array,
+            cfg: NetConfig) -> jax.Array:
+    """The packed rows of block-diffusion windows: ``tokens`` [B, T+1]
+    int32 and ``reveal`` [B, G] int32 (tokens of each block already
+    revealed, 0..block_length-1, left to right) → [B, T + 1 + G·B] token
+    ids, the clean copy then the noised one (``ops/attention.bd_rows``):
+    noised position p holds ``tokens[p]`` where its offset in its block is
+    under the block's ``reveal``, else the mask token (and past ``T``,
+    where a last block is not filled, the mask token)."""
+    t, bl = tokens.shape[1] - 1, cfg.tokenq.block_length
+    _, pos, blk = bd_rows(t, bl)
+    pos, blk = pos[t + 1:], blk[t + 1:]                 # the noised rows
+    shown = ((pos - 1) % bl < reveal[:, blk]) & (pos <= t)
+    noised = jnp.where(shown, tokens[:, np.minimum(pos, t)],
+                       jnp.asarray(mask_token(cfg), tokens.dtype))
+    return jnp.concatenate([tokens, noised], axis=1)
+
+
+def bd_decision_rows(reveal: jax.Array, t: int, block_length: int):
+    """Block b's decision → (its packed row [B, G]: the first masked row
+    of the noised block, ``T + 1 + bB + reveal``; its step ``p_b = bB +
+    reveal`` [B, G]: the state is the prefix ``tok[0..p_b]``, the action
+    ``tok[p_b + 1]``)."""
+    step = jnp.arange(reveal.shape[1]) * block_length + reveal
+    return t + 1 + step, step
+
+
 def backbone(params: dict[str, Any], tokens: jax.Array, cfg: NetConfig,
-             interpret: bool = False, *, index_loss: bool = True):
+             interpret: bool = False, *, index_loss: bool = True,
+             reveal: jax.Array | None = None):
     """``tokens`` [B, T] int32 → (final-normed hidden [B, T, h] float32,
     counters: the expert layers' stacked over the EXPERT layers and,
     where there are sparse layers, theirs stacked over those as
@@ -638,16 +730,28 @@ def backbone(params: dict[str, Any], tokens: jax.Array, cfg: NetConfig,
     and the compiler with it); under ``gating`` ``attn_gate_mean``, the
     gate's mean over tokens and heads stacked over the layers. Only the
     attention kernels pad the window
-    (to their blocks); every other product runs on T."""
+    (to their blocks); every other product runs on T.
+
+    With ``block_length`` > 0 the window runs under the block mask. With
+    ``reveal`` [B, G] (the training path) it is packed first (``bd_pack``:
+    clean copy, then the noised copy with ``reveal[b]`` tokens of block b
+    shown) and the hidden states are those of the T + 1 + G·B packed
+    rows; without, ``tokens`` is ONE copy that holds the mask token where
+    the caller put it (the acting path: block-causal)."""
     tq = cfg.tokenq
-    x = params["embed"][tokens]
+    bd_steps = tokens.shape[1] - 1 if tq.block_length else 0
+    if bd_steps and reveal is not None:
+        with jax.named_scope("ddq.bd_pack"):
+            x = params["embed"][bd_pack(tokens, reveal, cfg)]
+    else:
+        x = params["embed"][tokens]
     counters, dsa, gates = [], [], []
     for i, kind in enumerate(layer_plan(tq)):
         p = params[layer_name(i)]
         kw = dict(conv=kind["conv"], dense=kind["dense"],
                   sparse=kind["sparse"], latent=kind["latent"],
                   index_loss=index_loss, heads=kind["heads"],
-                  rope_params=kind["rope_params"])
+                  rope_params=kind["rope_params"], bd_steps=bd_steps)
         if kind["sparse"] or kind["latent"]:
             # the two halves rematerialised apart: what the mixer's
             # backward needs (q, k, v, the indexer's inputs, o: 2.5 GB at
@@ -687,8 +791,22 @@ def backbone(params: dict[str, Any], tokens: jax.Array, cfg: NetConfig,
 
 def q_at(params: dict[str, Any], tokens: jax.Array, pos: jax.Array,
          cfg: NetConfig, interpret: bool = False) -> jax.Array:
-    """Q(prefix, ·) at position ``pos`` of each row: ``[B, V]`` — the
-    acting path (no cache: the whole window is run; what lies after
-    ``pos`` cannot reach it through causal attention)."""
+    """Q(prefix ``tokens[:, :pos + 1]``, ·) of each row: ``[B, V]`` — the
+    acting path (no cache: the whole window is run). Under the causal mask
+    it is read at position ``pos``, which nothing after it can reach.
+    With ``block_length`` > 0 attention is bidirectional inside a block,
+    so what follows the prefix is part of the state: every position after
+    ``pos`` is set to the mask token here (the prefix's block as the
+    sampler holds it after revealing it left to right; what lies past the
+    block's end is invisible under the block-causal mask), and Q is read
+    at the FIRST masked position, ``pos + 1`` — the row that predicts the
+    token that belongs there. The mask token's own column is no action:
+    callers skip it."""
+    tq = cfg.tokenq
+    if tq.block_length:
+        tokens = jnp.where(jnp.arange(tokens.shape[1]) > pos,
+                           jnp.asarray(mask_token(cfg), tokens.dtype),
+                           tokens)
+        pos = pos + 1
     hid, _ = backbone(params, tokens, cfg, interpret, index_loss=False)
     return _mm(hid[:, pos], params["head"], jnp.dtype(cfg.compute_dtype))

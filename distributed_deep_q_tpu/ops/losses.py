@@ -97,6 +97,36 @@ def sequence_bellman_targets(
     return reward + discount * q_sel
 
 
+def span_returns(
+    reward: jax.Array,      # [B, T] per step
+    discount: jax.Array,    # [B, T]: γ·(1-done) per step
+    mask: jax.Array,        # [B, T] 1.0 on valid steps
+    start: jax.Array,       # [B, G] int32: a span's first step
+    length: jax.Array,      # [B, G] int32: its steps, 1..max_len
+    max_len: int,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Uncorrected n-step returns over spans whose length is DATA (from
+    one decision of a block-diffusion window to the next): for span
+    ``[start, start + length)`` → (``R = Σ_k (Π_{i<k} discount[start + i])
+    reward[start + k]``, ``Γ = Π_i discount[start + i]``, valid = ``mask``
+    is 1 on every step of the span and the span ends inside the window),
+    each [B, G]. Exact: a loop over the ``max_len`` offsets a span can
+    have, so an episode end inside a span cuts the sum there (its
+    discount is 0) and the bootstrap with it."""
+    t = reward.shape[1]
+    ret = jnp.zeros(start.shape, jnp.float32)
+    gamma = jnp.ones(start.shape, jnp.float32)
+    valid = start + length <= t
+    for k in range(max_len):
+        inside = k < length
+        at = jnp.minimum(start + k, t - 1)
+        take = lambda x: jnp.take_along_axis(x, at, axis=1)  # noqa: E731
+        ret = ret + jnp.where(inside, gamma * take(reward), 0.0)
+        gamma = gamma * jnp.where(inside, take(discount), 1.0)
+        valid = valid & (~inside | (take(mask) > 0))
+    return ret, gamma, valid.astype(jnp.float32)
+
+
 def sequence_dqn_loss(
     q: jax.Array,         # [B, T, A] online Q over the training window
     actions: jax.Array,   # [B, T] int32

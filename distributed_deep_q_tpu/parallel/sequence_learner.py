@@ -29,6 +29,14 @@ learner, the loss and the optimizer step but has no carry and no burn-in:
 the thousands — and the Q head and the TD loss run blockwise over tokens
 (``_token_step_core``), because ``[tokens, vocabulary]`` Q-values cannot
 be materialised at that length. Its ring is ``replay/device_tokens.py``.
+Where the backbone generates by diffusion over blocks
+(``TokenQConfig.block_length`` > 0) a forward pass gives ONE decision a
+block, not one a position: the sample program also draws how much of each
+block is revealed, the SAME step body packs the window twice, gathers the
+decision rows before the head and takes its targets from one block's
+decision to the next's (``_block_decisions_loss``,
+``ops/losses.span_returns``); optimizer, counters and priorities are the
+one path's.
 """
 
 from __future__ import annotations
@@ -47,7 +55,7 @@ from distributed_deep_q_tpu.config import ReplayConfig, TrainConfig
 from distributed_deep_q_tpu.models.qnet import (
     r2d2_burn_carry, r2d2_param_split, r2d2_recur, stacked_r2d2_features)
 from distributed_deep_q_tpu.ops.losses import (
-    sequence_bellman_targets, sequence_dqn_loss)
+    sequence_bellman_targets, sequence_dqn_loss, span_returns)
 from distributed_deep_q_tpu.parallel.learner import (
     TrainState, clip_grads, fused_adam_target_step, make_optimizer,
     refresh_target)
@@ -60,7 +68,7 @@ from distributed_deep_q_tpu.parallel.multihost import (
 
 def token_q_select(hid_on: jax.Array, hid_tg: jax.Array, head_on: jax.Array,
                    head_tg: jax.Array, actions: jax.Array, *, block: int,
-                   dtype, double: bool):
+                   dtype, double: bool, skip: int = -1):
     """The Q head over token positions, BLOCKWISE: ``[positions,
     vocabulary]`` Q-values exist only one block of ``block`` positions at
     a time (at 32 768 positions x 18 992 rows they are 2.5 GB, three times
@@ -71,7 +79,9 @@ def token_q_select(hid_on: jax.Array, hid_tg: jax.Array, head_on: jax.Array,
     [h, V], ``actions`` [P] the token taken at each position. Per position
     p: ``q_sa`` = Q_θ(p, actions[p]), ``q_boot`` = Q_θ⁻(p, a*) with a* the
     argmax of Q_θ(p, ·) (Double-DQN) or of Q_θ⁻(p, ·), and ``q_row`` =
-    Σ_a Q_θ(p, a). Only ``q_sa`` carries a gradient."""
+    Σ_a Q_θ(p, a). Only ``q_sa`` carries a gradient. ``skip`` >= 0 is a
+    head column that is no action (a mask token's): no argmax picks it and
+    ``q_row`` leaves it out."""
     n, h = hid_on.shape
     nb = -(-n // block)
     pad = nb * block - n
@@ -88,11 +98,16 @@ def token_q_select(hid_on: jax.Array, hid_tg: jax.Array, head_on: jax.Array,
                        preferred_element_type=jnp.float32)
         q_tg = jnp.dot(ht.astype(dtype), w_tg,
                        preferred_element_type=jnp.float32)
-        a_star = jnp.argmax(lax.stop_gradient(q_on) if double else q_tg,
-                            axis=-1)
+        pick = lax.stop_gradient(q_on) if double else q_tg
+        if skip >= 0:
+            pick = pick.at[:, skip].set(-jnp.inf)
+        a_star = jnp.argmax(pick, axis=-1)
         q_sa = jnp.take_along_axis(q_on, a[:, None], axis=-1)[:, 0]
         q_boot = jnp.take_along_axis(q_tg, a_star[:, None], axis=-1)[:, 0]
-        return q_sa, q_boot, jnp.sum(lax.stop_gradient(q_on), axis=-1)
+        q_row = jnp.sum(lax.stop_gradient(q_on), axis=-1)
+        if skip >= 0:
+            q_row = q_row - lax.stop_gradient(q_on[:, skip])
+        return q_sa, q_boot, q_row
 
     _, (q_sa, q_boot, q_row) = lax.scan(
         lambda c, xs: (c, one(*xs)), None,
@@ -452,16 +467,26 @@ class SequenceLearner:
         dtype = jnp.dtype(net.compute_dtype)
         tokens = batch["tokens"]
         b, t1 = tokens.shape
+        # generation by diffusion over blocks: the sample program's draw
+        # of how much of each block is revealed (absent: one token a
+        # position under the causal mask)
+        reveal = batch.get("reveal")
         # the action at position p is the next token; the last position
         # only bootstraps
         actions = jnp.concatenate(
             [tokens[:, 1:], jnp.zeros((b, 1), tokens.dtype)], axis=1)
         hid_tg, _ = tokenq.backbone(state.target_params, tokens, net,
-                                    interpret, index_loss=False)
+                                    interpret, index_loss=False,
+                                    reveal=reveal)
 
         def loss_fn(params):
             hid_on, counters = tokenq.backbone(params, tokens, net,
-                                               interpret)
+                                               interpret, reveal=reveal)
+            if reveal is not None:
+                loss, priority, q_mean, bd = self._block_decisions_loss(
+                    hid_on, hid_tg, params["head"],
+                    state.target_params["head"], batch)
+                return loss, (loss, priority, q_mean, {**counters, **bd})
             with jax.named_scope("ddq.q_head_loss"):
                 q_sa, q_boot, q_row = token_q_select(
                     hid_on.reshape(b * t1, -1), hid_tg.reshape(b * t1, -1),
@@ -547,8 +572,80 @@ class SequenceLearner:
             # closes heads it falls)
             metrics["attn_gate_mean"] = lax.pmean(
                 jnp.mean(counters["attn_gate_mean"]), AXIS_DP)
+        if reveal is not None:
+            # block diffusion: decisions that carried a loss, tokens of a
+            # block already revealed (mean: (B - 1) / 2), env steps from
+            # one decision to the next (mean: B), and Q_θ(d, a) decision
+            # by decision [windows, G - 1] (a mean over thousands of
+            # decisions hides a few keys more or fewer in a row's mask;
+            # one decision's Q does not)
+            metrics.update({
+                "bd_decisions_valid": lax.psum(counters["bd_valid"],
+                                               AXIS_DP),
+                "bd_reveal_mean": lax.pmean(
+                    jnp.mean(reveal.astype(jnp.float32)), AXIS_DP),
+                "bd_span_mean": lax.pmean(counters["bd_span"], AXIS_DP),
+                "bd_q_sa": lax.all_gather(counters["bd_q_sa"], AXIS_DP,
+                                          tiled=True)})
         return (TrainState(params, target_params, opt_state, step), metrics,
                 priority)
+
+    def _block_decisions_loss(self, hid_on: jax.Array, hid_tg: jax.Array,
+                              head_on: jax.Array, head_tg: jax.Array,
+                              batch: dict[str, jax.Array]):
+        """The TD loss of a block-diffusion step: ONE decision a block.
+        ``hid_*`` [b, T + 1 + G·B, h] are θ's and θ⁻'s hidden states over
+        the packed rows. Block g's decision row is the first masked row of
+        its noised copy (``tokenq.bd_decision_rows``): its state the
+        prefix ``tok[0..p_g]``, ``p_g = gB + reveal[g]``, its action
+        ``tok[p_g + 1]``. The decision rows are gathered BEFORE the head
+        (G rows a window through it, not all the packed rows); the target
+        of decision g is the uncorrected n-step return over the ``p_{g+1}
+        - p_g`` env steps to the next block's decision
+        (``ops/losses.span_returns``), bootstrapped at that decision's row
+        of the same θ⁻ pass with the Double-DQN argmax of the same θ
+        pass; the last block only bootstraps. → (loss, priority [b], mean
+        Q over decisions and actions, counters: among them ``bd_q_sa``
+        [b, G - 1], Q_θ(d_g, a_g) decision by decision)."""
+        from distributed_deep_q_tpu.models import tokenq
+
+        cfg, tq = self.cfg, self.net_cfg.tokenq
+        tokens, reveal = batch["tokens"], batch["reveal"]
+        b, t1 = tokens.shape
+        g = reveal.shape[1]
+        with jax.named_scope("ddq.bd_gather"):
+            rows, step = tokenq.bd_decision_rows(reveal, t1 - 1,
+                                                 tq.block_length)
+            at = rows[..., None]
+            dec_on = jnp.take_along_axis(hid_on, at, axis=1)    # [b, G, h]
+            dec_tg = jnp.take_along_axis(hid_tg, at, axis=1)
+            actions = jnp.take_along_axis(
+                tokens, jnp.minimum(step + 1, t1 - 1), axis=1)
+        with jax.named_scope("ddq.q_head_loss"):
+            q_sa, q_boot, q_row = token_q_select(
+                dec_on.reshape(b * g, -1), dec_tg.reshape(b * g, -1),
+                head_on, head_tg, actions.reshape(-1), block=tq.head_block,
+                dtype=jnp.dtype(self.net_cfg.compute_dtype),
+                double=cfg.double_dqn, skip=tokenq.mask_token(self.net_cfg))
+            q_sa = q_sa.reshape(b, g)[:, :-1, None]
+            q_boot = q_boot.reshape(b, g)[:, 1:, None]
+            with jax.named_scope("ddq.block_return"):
+                span = step[:, 1:] - step[:, :-1]
+                ret, gamma, valid = span_returns(
+                    batch["reward"], batch["discount"], batch["mask"],
+                    step[:, :-1], span, 2 * tq.block_length - 1)
+            targets = sequence_bellman_targets(
+                ret, gamma, q_boot, q_boot, double=cfg.double_dqn,
+                rescale=cfg.value_rescale)
+            loss, priority = sequence_dqn_loss(
+                q_sa, jnp.zeros((b, g - 1), jnp.int32), targets, valid,
+                batch["weight"], cfg.huber_delta, eta=cfg.priority_eta)
+            q_mean = jnp.mean(q_row.reshape(b, g)[:, :-1]) / (
+                head_on.shape[1] - 1)
+        return loss, priority, q_mean, {
+            "bd_valid": jnp.sum(valid),
+            "bd_span": jnp.mean(span.astype(jnp.float32)),
+            "bd_q_sa": lax.stop_gradient(q_sa[..., 0])}
 
     def _build_token_fused_steps(self, spec: tuple, chain: int):
         """The fused two-program step on the token ring
@@ -570,6 +667,12 @@ class SequenceLearner:
         ring_spec = {"tokens": S, "reward": S, "flags": S}
         batch_spec = {"tokens": SK3, "reward": SK3, "discount": SK3,
                       "mask": SK3, "weight": SK}
+        # generation by diffusion over blocks: how many tokens of each
+        # block of each drawn window are already revealed, from the step's
+        # key (its own stream, beside the draw of the windows)
+        bd = self.net_cfg.tokenq.block_length if self.net_cfg else 0
+        if bd:
+            batch_spec["reveal"] = SK3
 
         def token_sample_fn(keys, ring, prio, sizes, betas):
             filled = (jnp.arange(caps_local) < sizes[0]).astype(
@@ -591,6 +694,10 @@ class SequenceLearner:
                      "discount": discount, "mask": mask,
                      "weight": stratified_is_weights(p, mass, n_glob,
                                                      betas, num_shards)}
+            if bd:
+                batch["reveal"] = jax.vmap(lambda k: jax.random.randint(
+                    jax.random.fold_in(k, 1),
+                    (per_shard, -(-seq_len // bd)), 0, bd))(keys[0])
             idx = jnp.where(mass > 0, idx, caps_local)
             return batch, idx.astype(jnp.int32)
 
@@ -836,24 +943,59 @@ class SequenceSolver:
 
     # -- token actor path ---------------------------------------------------
 
+    def acting_prefix(self, prefix) -> np.ndarray:
+        """The tail of an episode's token prefix that the acting path
+        runs: its last T + 1 tokens. With ``block_length`` > 0 the tail
+        starts a whole number of blocks into the episode (the ring's
+        windows do: they lie back to back, T a multiple of the block) and
+        holds at most T tokens, so that the first masked position lies in
+        the window."""
+        t = self.config.replay.sequence_length
+        bl = self.config.net.tokenq.block_length
+        if not bl:
+            return np.asarray(prefix[-(t + 1):])
+        drop = -(-max(len(prefix) - t, 0) // bl) * bl
+        return np.asarray(prefix[drop:])
+
     def token_q_values(self, prefix: np.ndarray) -> np.ndarray:
         """Q(prefix, ·) [V] for one token prefix of at most a window's
-        T+1 tokens. There is no cache: the prefix is padded to the window
-        (ONE compiled shape; causal attention keeps the padding out of
-        every real position) and the whole window is run."""
+        T+1 tokens (T with ``block_length`` > 0, where Q is read at the
+        position AFTER the prefix). There is no cache: the prefix is
+        padded to the window (ONE compiled shape) and the whole window is
+        run. Under the causal mask the padding reaches no real position;
+        under the block mask the rest of the prefix's block IS part of the
+        state, so the padding is the mask token (``tokenq.q_at``)."""
+        from distributed_deep_q_tpu.models import tokenq
+
         n = len(prefix)
-        window = np.zeros((1, self.config.replay.sequence_length + 1),
-                          np.int32)
+        blocks = self.config.net.tokenq.block_length
+        window = np.full((1, self.config.replay.sequence_length + 1),
+                         tokenq.mask_token(self.config.net) if blocks else 0,
+                         np.int32)
+        if blocks and n >= window.shape[1]:
+            raise ValueError(
+                f"a prefix of {n} tokens leaves no masked position in a "
+                f"window of {window.shape[1]} (acting_prefix cuts it)")
         window[0, :n] = prefix
         return np.asarray(self._fwd(self.state.params, window,
                                     np.int32(n - 1))[0])
 
     def token_act(self, prefix: np.ndarray, epsilon: float,
                   rng: np.random.Generator) -> int:
-        """ε-greedy next token."""
+        """ε-greedy next token; a mask token is no action (never drawn,
+        its column skipped by the argmax)."""
+        from distributed_deep_q_tpu.models import tokenq
+
+        mask_id = tokenq.mask_token(self.config.net)
         if rng.random() < epsilon:
-            return int(rng.integers(self.config.net.num_actions))
-        return int(np.argmax(self.token_q_values(prefix)))
+            if mask_id < 0:
+                return int(rng.integers(self.config.net.num_actions))
+            a = int(rng.integers(self.config.net.num_actions - 1))
+            return a + (a >= mask_id)
+        q = np.array(self.token_q_values(prefix))
+        if mask_id >= 0:
+            q[mask_id] = -np.inf
+        return int(np.argmax(q))
 
     # -- weight IO ----------------------------------------------------------
 

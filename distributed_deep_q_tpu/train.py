@@ -555,6 +555,14 @@ def train_recurrent(cfg: Config, metrics: Metrics | None = None,
 # -- token-window Q-network (net.kind = "tokenq") ---------------------------
 
 
+def token_rows(cfg: Config, env) -> int:
+    """Rows of the embedding and of the head for this env: its tokens, and
+    where the net generates by diffusion over blocks
+    (``net.tokenq.block_length`` > 0) one more — the mask token's, the
+    LAST row (``models/tokenq.mask_token``): the env never emits it."""
+    return env.num_actions + bool(cfg.net.tokenq.block_length)
+
+
 def make_token_replay(cfg: Config, mesh):
     """The token ring for this Config: ``replay.capacity`` counts steps
     (as for the other sequence rings), a window holds
@@ -607,12 +615,11 @@ def evaluate_tokenq(solver, cfg: Config, episodes: int | None = None,
     prefix (capped at the training window)."""
     env = make_env(cfg.env, seed=seed)
     rng = np.random.default_rng(seed)
-    cap = cfg.replay.sequence_length + 1
     returns = []
     for _ in range(episodes or cfg.train.eval_episodes):
         prefix, ep_ret, over = [int(env.reset()[0])], 0.0, False
         while not over:
-            a = solver.token_act(np.asarray(prefix[-cap:]),
+            a = solver.token_act(solver.acting_prefix(prefix),
                                  cfg.actors.eval_eps, rng)
             obs, r, _, over = env.step(a)
             prefix.append(int(obs[0]))
@@ -635,7 +642,7 @@ def train_tokenq(cfg: Config, metrics: Metrics | None = None,
 
     metrics = metrics or Metrics()
     env = make_env(cfg.env, seed=cfg.train.seed)
-    cfg.net.num_actions = env.num_actions
+    cfg.net.num_actions = token_rows(cfg, env)
     solver = SequenceSolver(cfg)
     replay = make_token_replay(cfg, solver.mesh)
     seq_len = cfg.replay.sequence_length
@@ -657,7 +664,7 @@ def train_tokenq(cfg: Config, metrics: Metrics | None = None,
     gsteps, summary = 0, {}
     for t in range(1, cfg.train.total_steps + 1):
         eps = epsilon_at(t, cfg.actors)
-        a = solver.token_act(np.asarray(prefix[-(seq_len + 1):]), eps, rng)
+        a = solver.token_act(solver.acting_prefix(prefix), eps, rng)
         obs, r, done, over = env.step(a)
         prefix.append(int(obs[0]))
         ep_ret += r
@@ -710,6 +717,10 @@ def train_tokenq(cfg: Config, metrics: Metrics | None = None,
                     if "attn_gate_mean" in m:       # gated attention only
                         summary["attn_gate_mean"] = float(
                             m["attn_gate_mean"])
+                    if "bd_decisions_valid" in m:   # block diffusion only
+                        summary.update({k: float(m[k]) for k in (
+                            "bd_decisions_valid", "bd_reveal_mean",
+                            "bd_span_mean")})
                     metrics.gauge("queue/replay_size", len(replay))
                     metrics.log(gsteps, **summary, **metrics.telemetry())
     trace.close()

@@ -729,6 +729,62 @@ def test_the_sibling_presets_state_none_of_lagunas_mechanisms():
                        if isinstance(v, dict)), preset
 
 
+# SDAR-30B-A3B-Chat (config.sdar_tokenq_config): generation by diffusion
+# over blocks of 4 — a window of 16 385 tokens packed to 32 769 rows (clean
+# copy, then the partly masked one) under the three-part block mask, 32
+# heads over 4 key/value heads of 128.
+
+def test_block_mask_attention_compiles_for_v5e(one_chip):
+    """The splash kernel under ``BlockDiffusionMask`` (a mask computed in
+    the kernel from one code a query row) at the cell's own shapes, blocks
+    and two-kernel backward; the block table leaves out the empty
+    quadrants."""
+    from distributed_deep_q_tpu.config import PRESETS
+    from distributed_deep_q_tpu.ops import attention
+
+    cfg = PRESETS["sdar_tokenq"]()
+    tq, t = cfg.net.tokenq, cfg.replay.sequence_length
+    n = len(attention.bd_rows(t, tq.block_length)[0])
+    assert (n, tq.block_length, tq.num_attention_heads,
+            tq.num_key_value_heads, tq.head_dim, tq.attn_fused_bwd) == (
+        32_769, 4, 32, 4, 128, False)
+    S = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.bfloat16,
+                          sharding=one_chip)
+    q = S((1, tq.num_attention_heads, n, tq.head_dim))
+    kv = S((1, tq.num_key_value_heads, n, tq.head_dim))
+    kw = dict(t=t, block_length=tq.block_length, block=tq.attn_block,
+              compute_block=tq.attn_compute_block,
+              fused_bwd=tq.attn_fused_bwd)
+
+    def run(q, k, v):
+        f = lambda *a: jnp.sum(attention.block_diffusion_attention(  # noqa: E731
+            *a, **kw).astype(jnp.float32))
+        return jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+
+    compiled = jax.jit(run).lower(q, kv, kv).compile()
+    for kernel in ("splash_mqa_fwd", "splash_mqa_dkv", "splash_mqa_dq"):
+        assert kernel in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2 ** 30
+    share = attention.bd_blocks_run_share(
+        n, t, tq.block_length,
+        tq.num_attention_heads // tq.num_key_value_heads,
+        **{k: v for k, v in kw.items() if k not in ("t", "block_length")})
+    pairs = attention.bd_allowed_per_row(t, tq.block_length).sum() / n ** 2
+    assert 0.2499 < pairs < 0.2502 and pairs < share < 0.4, (pairs, share)
+
+
+def test_the_sibling_presets_state_none_of_sdars_mechanisms():
+    """Blocks and the mask token are DATA whose defaults are what the
+    siblings ran: no block, no mask token, so no ``reveal`` in their
+    batch, the causal kernels, positions = the row index."""
+    from distributed_deep_q_tpu.config import PRESETS
+
+    for preset in ("tokenq", "smallthinker_tokenq", "lfm2_tokenq",
+                   "keye_tokenq", "moonlight_tokenq", "laguna_tokenq"):
+        tq = PRESETS[preset]().net.tokenq
+        assert tq.block_length == 0, preset
+
+
 # -- the token families' whole train programs, lowered for one chip ----------
 
 def _lowered_token_train_program(topo, preset: str):
@@ -765,6 +821,10 @@ def _lowered_token_train_program(topo, preset: str):
              for k in ("reward", "discount", "mask")}
     batch = {"tokens": S((chain, b, t + 1), jnp.int32, None, "dp", None),
              "weight": S((chain, b), jnp.float32, None, "dp"), **steps}
+    if cfg.net.tokenq.block_length:     # the sample program's draw
+        batch["reveal"] = S(
+            (chain, b, -(-t // cfg.net.tokenq.block_length)), jnp.int32,
+            None, "dp", None)
     return train.lower(state, batch, S((chain, b), jnp.int32, None, "dp"),
                        S((caps,), jnp.float32, "dp"), S((), jnp.float32))
 
@@ -774,10 +834,11 @@ def _lowered_token_train_program(topo, preset: str):
 # how often each operation stands in it, and every Mosaic kernel by name
 # with its operand and result types (a kernel's serialised body carries
 # the Python line numbers of whoever called it and is not read). Read on
-# the parent of PR 40 and on its change: equal. Written anew by
+# the parent of PR 40 and on its change: equal; PR 44 added the fifth
+# sibling (read on its parent) and left the four as they were. Written anew by
 # ``PYTHONPATH=. python tests/test_chip_compile.py``.
-SIBLING_PRESETS = ("keye_tokenq", "lfm2_tokenq", "moonlight_tokenq",
-                   "smallthinker_tokenq")
+SIBLING_PRESETS = ("keye_tokenq", "laguna_tokenq", "lfm2_tokenq",
+                   "moonlight_tokenq", "smallthinker_tokenq")
 SIBLING_PROGRAMS = os.path.join(os.path.dirname(__file__), "fixtures",
                                 "sibling_train_programs.json")
 
