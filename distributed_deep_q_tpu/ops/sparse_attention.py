@@ -12,8 +12,15 @@ rotary embedding:
 - ``S_t`` = the ``topk`` keys ``s <= t`` with the largest ``I[t, s]``
   (every key while ``t < topk``), ties to the smaller ``s``, EXACT: the
   ``topk``-th largest score of a row is found by a radix search over the
-  scores' bit patterns (``RADIX_BITS`` bits a pass, each pass one read of
-  the rows counting the keys at or above 15 candidates), not by a sort;
+  scores' bit patterns, not by a sort, in ONE Pallas kernel
+  (``threshold``) that takes a query block's keys where XLA holds them,
+  in VMEM: a tile of ``ROW_TILE`` rows of the chunks the block can see
+  (and of no other) is laid down once, flipped to signed order, and
+  every counting pass reads it there, ``RADIX_BITS`` = 1 bit a pass —
+  each of the 32 passes counts the keys at or above ONE candidate, 32
+  compare-and-counts a key, where four bits a pass cost 8 x 15: the
+  right trade only while every pass was a pass of its own over the
+  block's keys — then one more count, of the keys above the threshold;
   keys above it are kept, keys equal to it in order of ``s`` until
   ``topk`` are;
 - ``o[t, h] = Σ_{s in S_t} softmax_{s in S_t}(q[t, h] · k[s, g(h)] ·
@@ -38,8 +45,8 @@ Nothing ``[T, T]`` exists but the selection's bits, and the causal half
 is not multiplied in full: scores, selection and loss each run ONE loop
 over the query blocks, and inside it over the chunks of ``KEY_CHUNK_BLOCKS``
 blocks of keys the block can see — a loop whose length is data
-(``causal_scores``, ``threshold``, ``keep_chunk``, ``index_loss``), so the
-program holds one body whatever the window's length. The selection is
+(``causal_scores``, ``threshold``'s kernel, ``keep_chunk``, ``index_loss``),
+so the program holds one body whatever the window's length. The selection is
 ``[T / 32, T]`` int32 (``pack`` / ``unpack``: bit ``b`` of row ``r`` of
 query block ``i`` is query ``i · block + b · block / 32 + r``, so a kernel
 unpacks a block with 32 aligned row slabs): the caller keeps THAT across its
@@ -63,11 +70,20 @@ from jax.experimental.pallas import tpu as pltpu
 SELECTION_NAME = "dsa_selection"    # the residuals a remat policy keeps:
 LOSS_NAME = "dsa_index_loss"        # the bits; the loss and its gradients
 KEY_CHUNK_BLOCKS = 3    # query blocks' worth of keys scored at a time
-RADIX_BITS = 4          # bits of the threshold fixed by one counting pass
+# bits of the threshold fixed by one counting pass: b bits a pass cost
+# (32 / b)(2^b - 1) compare-and-counts a key — 32 at 1, 48 at 2, 120 at 4
+# — and with the rows resident in VMEM a pass costs nothing else (on the
+# chip, PR 45: the kernel 3.5 | 5.3 | 13.8 ms a sequence-layer of Keye's)
+RADIX_BITS = 1
+# rows searched at a time: their counts, their one candidate and a column
+# of their keys are 3 x 16 of the 64 vector registers
+ROW_TILE = 128
 WORD = 32               # queries a word of the selection holds
 LANES = 128
 MASKED = -1e30
 VMEM_LIMIT = 100 * 2 ** 20
+# the search's own: what a kernel may take, XLA cannot hold its operand in
+SEARCH_VMEM_LIMIT = 32 * 2 ** 20
 NT = (((1,), (1,)), ((), ()))       # a · bᵀ
 
 
@@ -107,31 +123,86 @@ def ordered_keys(scores: jax.Array, valid: jax.Array) -> jax.Array:
     return jnp.where(valid, _ordered_bits(scores), jnp.uint32(0))
 
 
-def threshold(u: jax.Array, topk: int, chunk: int, chunks):
+def _threshold_kernel(chunks_ref, u_ref, tau_ref, room_ref, keys_ref, *,
+                      topk: int):
+    """The search of one tile of rows: the tile's columns, the first
+    ``chunks`` chunks of them, come out of ``u_ref`` [R, T] into
+    ``keys_ref`` [T / chunk, tile, chunk] once; every counting pass reads
+    them there, the counts lane-wise in registers."""
+    _, tile, chunk = keys_ref.shape
+    rows = pl.ds(pl.multiple_of(pl.program_id(0) * tile, tile), tile)
+    lanes = LANES if chunk % LANES == 0 else chunk
+    chunks = chunks_ref[0]
+    top = jnp.int32(-2 ** 31)
+
+    def bring(c, _):
+        # Mosaic compares signed words: with the top bit flipped, once,
+        # the signed order of what lies here is the keys' unsigned order
+        keys_ref[c] = u_ref[rows, pl.ds(pl.multiple_of(c * chunk, chunk),
+                                        chunk)] ^ jnp.uint32(2 ** 31)
+
+    lax.fori_loop(0, chunks, bring, None)
+
+    def count(at_or_above):
+        """Keys of each row at or above each candidate [tile, 1] (bit
+        patterns of unsigned words) → as many [tile, 1] int32."""
+        cands = [jnp.broadcast_to(c ^ top, (tile, lanes))
+                 for c in at_or_above]
+
+        def one_chunk(c, counts):
+            for j in range(chunk // lanes):
+                x = lax.bitcast_convert_type(
+                    keys_ref[c, :, pl.ds(j * lanes, lanes)], jnp.int32)
+                counts = [n + (x >= cand).astype(jnp.int32)
+                          for n, cand in zip(counts, cands)]
+            return counts
+
+        counts = lax.fori_loop(0, chunks, one_chunk, [
+            jnp.zeros((tile, lanes), jnp.int32) for _ in cands])
+        return [jnp.sum(n, axis=1, keepdims=True) for n in counts]
+
+    def one_pass(i, tau):
+        # the largest prefix with at least ``topk`` keys at or above it
+        shift = 32 - RADIX_BITS * (i + 1)
+        enough = [(n >= topk).astype(jnp.int32) for n in count(
+            [tau | (jnp.int32(d) << shift)
+             for d in range(1, 2 ** RADIX_BITS)])]
+        return tau | (sum(enough) << shift)
+
+    tau = lax.fori_loop(0, 32 // RADIX_BITS, one_pass,
+                        jnp.zeros((tile, 1), jnp.int32))
+    tau_ref[...] = tau
+    # tau + 1 cannot wrap: no float's key is all ones
+    room_ref[...] = topk - count([tau + 1])[0]
+
+
+def threshold(u: jax.Array, topk: int, chunk: int, chunks, interpret: bool):
     """Rows of ``ordered_keys`` ``u`` [R, T], of which the first
     ``chunks`` (traced) chunks of ``chunk`` columns are read → (``tau``
     [R] uint32, the ``topk``-th largest key of each row — 0 where a row
     has fewer —, ``room`` [R] int32, how many keys EQUAL to it are kept
-    after every larger one). Exact."""
-    cands = jnp.arange(1, 2 ** RADIX_BITS, dtype=jnp.uint32)
-
-    def count(at_or_above):
-        """Keys of each row at or above each of its candidates [R, n]."""
-        return lax.fori_loop(0, chunks, lambda c, n: n + jnp.sum(
-            _at(u, c, chunk, 1)[:, None, :] >= at_or_above[:, :, None],
-            axis=-1, dtype=jnp.int32),
-            jnp.zeros(at_or_above.shape, jnp.int32))
-
-    def one_pass(i, tau):
-        # the largest prefix with at least ``topk`` keys at or above it
-        shift = (32 - RADIX_BITS * (i + 1)).astype(jnp.uint32)
-        enough = count(tau[:, None] | (cands[None, :] << shift)) >= topk
-        return tau | (jnp.sum(enough, axis=-1).astype(jnp.uint32) << shift)
-
-    tau = lax.fori_loop(0, 32 // RADIX_BITS, one_pass,
-                        jnp.zeros(u.shape[0], jnp.uint32))
-    # tau + 1 cannot wrap: no float's key is all ones
-    return tau, topk - count(tau[:, None] + jnp.uint32(1))[:, 0]
+    after every larger one). Exact. One kernel, a step a tile of
+    ``ROW_TILE`` rows (every row where they do not divide ``R``); ``u``
+    is a VMEM operand, so a caller whose ``u`` XLA already holds there (a
+    query block of ``select``) hands it over without a copy — and ``u``
+    with a tile's columns beside it has to FIT there: 4 R T bytes + a
+    quarter of that at the Keye widths (35 + 9 MB of 128); a window four
+    times as long wants a smaller query block."""
+    rows, t = u.shape
+    tile = ROW_TILE if rows % ROW_TILE == 0 else rows
+    out = pl.BlockSpec((tile, 1), lambda i, chunks: (i, 0))
+    tau, room = pl.pallas_call(
+        functools.partial(_threshold_kernel, topk=topk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(rows // tile,),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
+            out_specs=[out, out],
+            scratch_shapes=[pltpu.VMEM((t // chunk, tile, chunk), u.dtype)]),
+        out_shape=[jax.ShapeDtypeStruct((rows, 1), jnp.int32)] * 2,
+        compiler_params=_params("parallel", limit=SEARCH_VMEM_LIMIT),
+        interpret=interpret, name="topk_threshold")(
+            jnp.reshape(chunks, (1,)).astype(jnp.int32), u)
+    return lax.bitcast_convert_type(tau[:, 0], jnp.uint32), room[:, 0]
 
 
 def keep_chunk(uc: jax.Array, tau: jax.Array, room: jax.Array,
@@ -159,14 +230,15 @@ def kept_logsumexp(norm, uc: jax.Array, kept: jax.Array):
         jnp.where(kept, jnp.exp(s - m_next[:, None]), 0.0), axis=-1)
 
 
-def topk_mask(scores: jax.Array, valid: jax.Array, topk: int) -> jax.Array:
+def topk_mask(scores: jax.Array, valid: jax.Array, topk: int,
+              interpret: bool = True) -> jax.Array:
     """Rows of ``scores`` [R, T] float32, ``valid`` [R, T] bool → bool
     [R, T]: the ``topk`` valid entries with the largest score in each row
     (all of them where a row has no more), ties to the smaller column.
     Exact. (The whole width as one chunk: ``select`` runs the same two
     functions over the chunks a query block can see.)"""
     u = ordered_keys(scores, valid)
-    tau, room = threshold(u, topk, u.shape[1], 1)
+    tau, room = threshold(u, topk, u.shape[1], 1, interpret)
     return keep_chunk(u, tau, room, jnp.zeros(u.shape[0], jnp.int32))[0]
 
 
@@ -238,7 +310,8 @@ def causal_scores(qb: jax.Array, wb: jax.Array, k_i: jax.Array, chunks,
 
 
 def select(q_i: jax.Array, w_i: jax.Array, k_i: jax.Array, *, topk: int,
-           block: int, t_real: int, with_loss: bool = False):
+           block: int, t_real: int, with_loss: bool = False,
+           interpret: bool = True):
     """The selection of one sequence padded to ``T`` (a multiple of
     ``block``): ``q_i`` [T, Hi, Di], ``w_i`` [T, Hi], ``k_i`` [T, Di]
     float32 → (bits int32 [T/32, T], pairs selected by the first
@@ -263,7 +336,7 @@ def select(q_i: jax.Array, w_i: jax.Array, k_i: jax.Array, *, topk: int,
 
         u = causal_scores(qb, wb, k_i, chunks, chunk, keys)
         with jax.named_scope("ddq.topk"):
-            tau, room = threshold(u, topk, chunk, chunks)
+            tau, room = threshold(u, topk, chunk, chunks, interpret)
 
             def keep(c, carry):
                 bits, seen, kept, norm = carry
@@ -431,9 +504,9 @@ def _probs_kernel(i_ref, q_ref, k_ref, bits_ref, lse_ref, out_ref,
             out_ref[...] += jnp.exp(s - jnp.expand_dims(lse_ref[g], -1))
 
 
-def _params(*semantics: str):
+def _params(*semantics: str, limit: int = VMEM_LIMIT):
     return pltpu.CompilerParams(dimension_semantics=semantics,
-                                vmem_limit_bytes=VMEM_LIMIT)
+                                vmem_limit_bytes=limit)
 
 
 def _specs(hkv: int, group: int, block: int, d: int, q_major: bool):
@@ -646,7 +719,8 @@ def sparse_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     def selection(xs):
         bits, kept, lse_i = select(*xs, topk=topk, block=block,
-                                   t_real=t_real, with_loss=with_loss)
+                                   t_real=t_real, with_loss=with_loss,
+                                   interpret=interpret)
         return bits, kept.astype(jnp.float32), lse_i
 
     bits, kept, lse_i = lax.map(selection, (q_i, w_i, k_i))

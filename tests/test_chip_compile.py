@@ -377,12 +377,42 @@ def test_sparse_attention_compiles_for_v5e(one_chip, with_loss):
         S((1, hq, tm, d), jnp.bfloat16), S((1, hkv, tm, d), jnp.bfloat16),
         S((1, hkv, tm, d), jnp.bfloat16), S((1, tm, hi, di), jnp.float32),
         S((1, tm, hi), jnp.float32), S((1, tm, di), jnp.float32))
-    for kernel in ("sparse_core_fwd", "sparse_core_dq", "sparse_core_dkv"):
+    for kernel in ("topk_threshold", "sparse_core_fwd", "sparse_core_dq",
+                   "sparse_core_dkv"):
         assert kernel in text
     assert ("sparse_head_probs" in text) == with_loss
     products = re.findall(
         r"convolution\(.*operand_precision=\{highest,highest\}", text)
     assert len(products) == (1 + 3 if with_loss else 1)
+
+
+def test_the_selection_holds_its_keys_in_vmem_for_v5e(one_chip):
+    """``select`` at the Keye cell's own window (T 16 384 + 1 padded to
+    16 896, chunks of 1 536, top 2 048, query blocks of 512): the search's
+    kernel takes a block's keys ``u`` [512, 16 896] as a VMEM operand and
+    a tile of rows of ALL eleven chunks as scratch — what the 4 608-wide
+    window of the test above never asks of the chip's compiler — and XLA
+    holds ``u`` in VMEM (``S(1)``) from the loop that scores it to the
+    kernel and the keep loop: no copy of it reaches HBM. (A kernel that
+    asked for the core's 100 MiB would leave XLA no room for it.)"""
+    from distributed_deep_q_tpu.config import PRESETS
+    from distributed_deep_q_tpu.ops import sparse_attention as sa
+
+    tq = PRESETS["keye_tokenq"]().net.tokenq
+    block, t_real = tq.indexer_q_chunk, 16384 + 1
+    t = -(-t_real // block) * block
+    hi, di = tq.indexer_num_heads, tq.indexer_head_dim
+
+    def S(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    text = _compiled_text(
+        lambda *a: sa.select(*a, topk=tq.indexer_topk, block=block,
+                             t_real=t_real, interpret=False)[:2],
+        S(t, hi, di), S(t, hi), S(t, di))
+    assert "topk_threshold" in text
+    keys = re.findall(rf"u32\[{block},{t}\]\{{1,0:T[^}}]*\}}", text)
+    assert keys and all("S(1)" in layout for layout in keys), set(keys)
 
 
 # -- the fused CNN programs at the b512 cell's sizes ------------------------
@@ -835,7 +865,10 @@ def _lowered_token_train_program(topo, preset: str):
 # with its operand and result types (a kernel's serialised body carries
 # the Python line numbers of whoever called it and is not read). Read on
 # the parent of PR 40 and on its change: equal; PR 44 added the fifth
-# sibling (read on its parent) and left the four as they were. Written anew by
+# sibling (read on its parent) and left the four as they were; PR 45 wrote
+# the Keye entry anew (eight ``topk_threshold`` kernels where the search's
+# counting loops were: 96 -> 68 ``stablehlo.while``) and the four others
+# came out of the same writing as they were, to the byte. Written anew by
 # ``PYTHONPATH=. python tests/test_chip_compile.py``.
 SIBLING_PRESETS = ("keye_tokenq", "laguna_tokenq", "lfm2_tokenq",
                    "moonlight_tokenq", "smallthinker_tokenq")

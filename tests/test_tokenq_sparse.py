@@ -300,6 +300,93 @@ def test_topk_mask_is_exact_with_ties_to_the_smaller_key(rows, cols, topk):
     assert np.array_equal(got, want)
 
 
+def _search_in_jnp(u, topk, chunk, chunks):
+    """The search as it ran before the kernel (four bits a pass, 15
+    candidates a pass, a read of ``u`` a pass, plain ``jax.numpy``): the
+    oracle the kernel's ``(tau, room)`` are held to, bit for bit."""
+    cands = jnp.arange(1, 16, dtype=jnp.uint32)
+
+    def count(at_or_above):
+        return jax.lax.fori_loop(0, chunks, lambda c, n: n + jnp.sum(
+            sa._at(u, c, chunk, 1)[:, None, :] >= at_or_above[:, :, None],
+            axis=-1, dtype=jnp.int32),
+            jnp.zeros(at_or_above.shape, jnp.int32))
+
+    def one_pass(i, tau):
+        shift = (32 - 4 * (i + 1)).astype(jnp.uint32)
+        enough = count(tau[:, None] | (cands[None, :] << shift)) >= topk
+        return tau | (jnp.sum(enough, axis=-1).astype(jnp.uint32) << shift)
+
+    tau = jax.lax.fori_loop(0, 8, one_pass,
+                            jnp.zeros(u.shape[0], jnp.uint32))
+    return tau, topk - count(tau[:, None] + jnp.uint32(1))[:, 0]
+
+
+def _search_case(name):
+    """(scores [R, T] float32, valid [R, T] bool, topk, chunk, chunks
+    read) of one case of the search."""
+    rng = np.random.default_rng(len(name))
+    rows, chunk, chunks, read, topk = 2 * sa.ROW_TILE, 128, 3, 3, 64
+    if name == "a_block_the_row_tile_does_not_divide":
+        rows = sa.ROW_TILE + 8
+    if name == "a_chunk_that_is_not_whole_lanes":
+        rows, chunk = 40, 96
+    if name == "chunks_short_of_the_width_and_garbage_past_them":
+        chunks, read = 4, 2
+    t = chunk * chunks
+    scores = rng.integers(-3, 4, (rows, t)).astype(np.float32) * 0.5
+    valid = np.ones((rows, t), bool)
+    if name == "rows_with_fewer_valid_keys_than_topk":
+        valid = np.arange(t)[None, :] < np.arange(rows)[:, None]
+    if name == "zeros_of_both_signs_and_both_signs":
+        scores = rng.choice(np.float32(
+            [0.0, -0.0, 1e-30, -1e-30, 3e38, -3e38, 1.5, -1.5]), (rows, t))
+    if name == "a_row_of_one_repeated_value":
+        scores[:] = rng.standard_normal((rows, 1)).astype(np.float32)
+        scores[1], scores[2], valid[3] = 0.0, -0.0, False
+    if name in ("many_ties_and_the_cut_through_a_tie",
+                "a_block_the_row_tile_does_not_divide"):
+        valid = rng.random((rows, t)) < 0.9
+    return scores, valid, topk, chunk, read
+
+
+@pytest.mark.parametrize("name", [
+    "many_ties_and_the_cut_through_a_tie",
+    "rows_with_fewer_valid_keys_than_topk",
+    "zeros_of_both_signs_and_both_signs",
+    "a_row_of_one_repeated_value",
+    "chunks_short_of_the_width_and_garbage_past_them",
+    "a_block_the_row_tile_does_not_divide",
+    "a_chunk_that_is_not_whole_lanes"])
+def test_the_search_kernel_is_the_search_in_jnp_bit_for_bit(name):
+    """``threshold`` — one Pallas kernel, the rows resident across its
+    passes, here interpreted — returns what the search in ``jax.numpy``
+    returns and what a sort says: ``tau`` the ``topk``-th largest key of
+    the columns READ (0 where a row has fewer), ``room`` the keys equal
+    to it that are kept; the number of chunks read is traced."""
+    scores, valid, topk, chunk, read = _search_case(name)
+    u = np.array(sa.ordered_keys(jnp.asarray(scores), jnp.asarray(valid)))
+    u[:, read * chunk:] = np.random.default_rng(1).integers(
+        0, 2 ** 32, u[:, read * chunk:].shape, dtype=np.uint32)
+    tau, room = jax.jit(lambda u, n: sa.threshold(
+        u, topk, chunk, n, True))(jnp.asarray(u), read)
+    want_tau, want_room = jax.jit(lambda u, n: _search_in_jnp(
+        u, topk, chunk, n))(jnp.asarray(u), read)
+    assert tau.dtype == jnp.uint32 and room.dtype == jnp.int32
+    assert np.array_equal(np.asarray(tau), np.asarray(want_tau))
+    assert np.array_equal(np.asarray(room), np.asarray(want_room))
+
+    seen = -np.sort(-u[:, :read * chunk].astype(np.int64), axis=-1)
+    nth = seen[:, topk - 1]     # 0, an invalid entry's key, where fewer
+    assert np.array_equal(np.asarray(tau), nth)
+    assert np.array_equal(
+        np.asarray(room), topk - (seen > nth[:, None]).sum(-1))
+    if "ties" in name:      # the cut does go through a tie
+        assert ((seen == nth[:, None]).sum(-1) > np.asarray(room)).any()
+    if "fewer" in name:
+        assert not np.asarray(tau)[:topk].any()
+
+
 def test_pack_and_unpack_are_inverse_and_the_host_reads_the_same():
     rng = np.random.default_rng(0)
     keep = rng.random((2 * BLOCK, 50)) < 0.3
