@@ -150,6 +150,9 @@ REGISTRY = frozenset({
     # static gauge of the fused step (Solver.fused_gauges): 1 = its train
     # program unpacks the pixel windows by byte planes, 0 = by a bitcast
     "train/unpack_planes",
+    # static gauge of the fused token step (SequenceSolver.fused_gauges):
+    # 1 = every rotate-half layer turns q and k by the fused pass
+    "train/rotary_fused",
     # learning-dynamics plane (ISSUE 16): learn/* gauges the on-device
     # metrics plane accumulates inside the fused-chain / Anakin scan
     # bodies (learning.LearnAccumulator.gauges) + the TD-|error|

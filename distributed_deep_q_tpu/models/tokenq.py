@@ -19,6 +19,9 @@ mixer and a feed-forward in pre-norm residual form, input x ``[B, T, h]``
     layer's KIND (full / sliding), the first ``partial_rotary_factor ·
     head_dim`` columns at that kind's base, blended and scaled under YaRN
     (``rotary_table``, ``rotary_by_table``), the other columns unturned;
+    the rotation and the cast to the compute dtype are ONE pass over q
+    and over k each way where a head fills the 128 lanes
+    (``rotary_cast`` → ``ops/rotary.turn``), the plain form otherwise;
     with ``qk_norm`` an RMSNorm of each head of q and k before the
     rotation; ``num_attention_heads_per_layer[l]`` query heads where the
     configuration counts them a layer; ``ops/attention.causal_attention``
@@ -130,6 +133,7 @@ import numpy as np
 from distributed_deep_q_tpu.config import (
     NetConfig, RopeParameters, TokenQConfig)
 from distributed_deep_q_tpu.ops import moe, sparse_attention
+from distributed_deep_q_tpu.ops import rotary as rotary_pass
 from distributed_deep_q_tpu.ops.attention import (
     bd_rows, block_diffusion_attention, causal_attention)
 from distributed_deep_q_tpu.ops.short_conv import short_conv_mix
@@ -393,6 +397,37 @@ def rotary_by_table(x: jax.Array, inv, factor: float,
     return jnp.concatenate([turned, x[..., r:]], -1) if r < d else turned
 
 
+def rope_inv(theta: float, d: int) -> jax.Array:
+    """Pair i's inverse frequency at ONE base over all of a head,
+    ``theta^(-2i/d)`` [d / 2] float32."""
+    return theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+
+
+def rotary_cast(q: jax.Array, k: jax.Array, inv, factor: float, positions,
+                dtype, interpret: bool):
+    """``rotary_by_table(x, inv, factor, positions).astype(dtype)`` of
+    ``q`` and of ``k`` [B, H, T, D] float32, from ONE pair of tables and in
+    one pass over each, each way (``ops/rotary.turn``), where a head fills
+    the lanes; the plain form where it does not (decided from D alone)."""
+    d, t = q.shape[-1], q.shape[-2]
+    if not rotary_pass.fills_lanes(d):
+        return tuple(rotary_by_table(x, inv, factor, positions).astype(dtype)
+                     for x in (q, k))
+    table = rotary_pass.tables(inv, factor, _positions(t, positions), d)
+    return tuple(rotary_pass.turn(x, *table, 2 * inv.shape[0], dtype,
+                                  interpret) for x in (q, k))
+
+
+def rotary_fused(tq: TokenQConfig) -> int | None:
+    """1 where every rotate-half layer of the plan turns q and k by the
+    fused pass, 0 where they keep the plain form (``rotary_cast``'s own
+    rule); ``None`` where no layer turns rotate-half (conv and latent
+    mixers do not)."""
+    turning = any(k["rope"] and not (k["conv"] or k["latent"])
+                  for k in layer_plan(tq))
+    return int(rotary_pass.fills_lanes(tq.head_dim)) if turning else None
+
+
 def rotary(x: jax.Array, theta: float, interleave: bool = False,
            positions=None) -> jax.Array:
     """Rotary embedding over ``[B, H, T, D]``, float32, row i at position
@@ -403,17 +438,14 @@ def rotary(x: jax.Array, theta: float, interleave: bool = False,
     pair i turns by ``position · theta^(-2i/D)``. Rotate-half pairs element
     i with i + D/2; ``interleave`` pairs 2i with 2i + 1."""
     d, t = x.shape[-1], x.shape[-2]
-    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    inv = rope_inv(theta, d)
+    if not interleave:
+        return rotary_by_table(x, inv, 1.0, positions)
     ang = _positions(t, positions)[:, None] * inv[None, :]
-    if interleave:
-        cos, sin = (jnp.repeat(f(ang), 2, -1) for f in (jnp.cos, jnp.sin))
-        pairs = x.reshape(*x.shape[:-1], d // 2, 2)
-        rot = jnp.stack([-pairs[..., 1], pairs[..., 0]], -1).reshape(x.shape)
-        return x * cos + rot * sin
-    cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)
-    sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)
-    x1, x2 = x[..., :d // 2], x[..., d // 2:]
-    return x * cos + jnp.concatenate([-x2, x1], -1) * sin
+    cos, sin = (jnp.repeat(f(ang), 2, -1) for f in (jnp.cos, jnp.sin))
+    pairs = x.reshape(*x.shape[:-1], d // 2, 2)
+    rot = jnp.stack([-pairs[..., 1], pairs[..., 0]], -1).reshape(x.shape)
+    return x * cos + rot * sin
 
 
 def _mm(a: jax.Array, w: jax.Array, dtype) -> jax.Array:
@@ -571,12 +603,11 @@ def mixer(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
                 k = rmsnorm(k, p["k_norm"], tq.rms_norm_eps)
             if rope and rope_params is not None:
                 with jax.named_scope("ddq.rotary"):
-                    table = rotary_table(rope_params, d)
-                    q = rotary_by_table(q, *table)
-                    k = rotary_by_table(k, *table)
+                    q, k = rotary_cast(q, k, *rotary_table(rope_params, d),
+                                       None, dtype, interpret)
             elif rope:
-                q = rotary(q, tq.rope_theta, positions=positions)
-                k = rotary(k, tq.rope_theta, positions=positions)
+                q, k = rotary_cast(q, k, rope_inv(tq.rope_theta, d), 1.0,
+                                   positions, dtype, interpret)
             if bd_steps:
                 # the kernel calls alone stand under ddq.attn_bd_core
                 a = block_diffusion_attention(

@@ -135,6 +135,9 @@ class SequenceLearner:
         self._ring_steps: dict[tuple, Any] = {}
         # fused chained sequence steps, keyed on (spec, chain)
         self._fused_steps: dict[tuple, Any] = {}
+        # static gauge ``train/rotary_fused`` of the last token train
+        # program built (``models/tokenq.rotary_fused``); None before
+        self.rotary_fused: int | None = None
 
     def init_state(self, params: Any) -> TrainState:
         state = TrainState(
@@ -673,6 +676,9 @@ class SequenceLearner:
         bd = self.net_cfg.tokenq.block_length if self.net_cfg else 0
         if bd:
             batch_spec["reveal"] = SK3
+        if self.net_cfg:
+            from distributed_deep_q_tpu.models.tokenq import rotary_fused
+            self.rotary_fused = rotary_fused(self.net_cfg.tokenq)
 
         def token_sample_fn(keys, ring, prio, sizes, betas):
             filled = (jnp.arange(caps_local) < sizes[0]).astype(
@@ -896,6 +902,13 @@ class SequenceSolver:
             replay.dmeta["prio"] = prio
             replay.dmaxp = maxp
         return dict(metrics)
+
+    def fused_gauges(self) -> dict[str, int]:
+        """Static gauges of the fused token step for the train loop's log
+        rows: ``train/rotary_fused`` (``SequenceLearner.rotary_fused``);
+        nothing before a token train program was built."""
+        fused = self.learner.rotary_fused
+        return {} if fused is None else {"train/rotary_fused": fused}
 
     def fused_executables(self, replay, chain: int) -> dict[str, Any]:
         """``Solver.fused_executables`` for the token ring's fused step:

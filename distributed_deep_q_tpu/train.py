@@ -722,13 +722,15 @@ def train_tokenq(cfg: Config, metrics: Metrics | None = None,
                             "bd_decisions_valid", "bd_reveal_mean",
                             "bd_span_mean")})
                     metrics.gauge("queue/replay_size", len(replay))
-                    metrics.log(gsteps, **summary, **metrics.telemetry())
+                    metrics.log(gsteps, **summary, **solver.fused_gauges(),
+                                **metrics.telemetry())
     trace.close()
     if ckpt:
         ckpt.save(solver.state, extra={"env_steps": cfg.train.total_steps},
                   wait=True)
     summary["final_return_avg100"] = ep_returns.value
     summary["grad_steps"] = gsteps
+    summary["train_rotary_fused"] = solver.learner.rotary_fused
     summary["eval_return"] = evaluate_tokenq(solver, cfg)
     summary["solver"] = solver
     summary["replay"] = replay

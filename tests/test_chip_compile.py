@@ -736,6 +736,117 @@ def test_laguna_attention_kinds_compile_for_v5e(one_chip, windowed):
     assert two.memory_analysis().temp_size_in_bytes < one / 3
 
 
+# The fused rotary pass (``ops/rotary.turn``) at the geometries that run
+# it: Laguna's two kinds on T 16 385 (not a multiple of 8: the last row
+# block holds one row) — 48 heads with r 64 of 128 (the lane select, YaRN's
+# factor), 64 heads with r 128 — over 8 key/value heads, and SDAR's packed
+# window of 32 769 rows at their own position ids.
+
+ROTARY_GEOMETRIES = {
+    "laguna_full": ("laguna_tokenq", False, (48, 8, 16385, 64)),
+    "laguna_sliding": ("laguna_tokenq", True, (64, 8, 16385, 128)),
+    "sdar_packed": ("sdar_tokenq", False, (32, 4, 32769, 128)),
+}
+
+
+def _rotary_geometry(geometry):
+    """(the preset's NetConfig, the layer's index and plan entry, the
+    rows of a window as the mixer sees them, bd_steps, (inv, factor),
+    the rows' position ids)."""
+    from distributed_deep_q_tpu.config import PRESETS
+    from distributed_deep_q_tpu.models import tokenq
+    from distributed_deep_q_tpu.ops.attention import bd_rows
+
+    preset, windowed, want = ROTARY_GEOMETRIES[geometry]
+    cfg = PRESETS[preset]()
+    tq, steps = cfg.net.tokenq, cfg.replay.sequence_length
+    i, kind = next((i, k) for i, k in enumerate(tokenq.layer_plan(tq))
+                   if k["windowed"] == windowed and k["rope"])
+    positions = bd_rows(steps, tq.block_length)[1] if tq.block_length \
+        else None
+    rows = steps + 1 if positions is None else len(positions)
+    table = tokenq.rotary_table(kind["rope_params"], tq.head_dim) \
+        if kind["rope_params"] else (tokenq.rope_inv(tq.rope_theta,
+                                                     tq.head_dim), 1.0)
+    assert (kind["heads"], tq.num_key_value_heads, rows,
+            2 * table[0].shape[0]) == want
+    assert cfg.replay.batch_size == 1 and tq.head_dim == 128
+    return cfg.net, i, kind, rows, steps * bool(tq.block_length), table, \
+        positions
+
+
+@pytest.mark.parametrize("geometry", ROTARY_GEOMETRIES)
+def test_rotary_pass_compiles_for_v5e(one_chip, geometry):
+    """Forward and backward, q and k from one pair of tables: four calls
+    of the ONE kernel, and Mosaic takes each."""
+    from distributed_deep_q_tpu.models import tokenq
+
+    net, _, kind, rows, _, table, positions = _rotary_geometry(geometry)
+    S = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32,
+                          sharding=one_chip)
+    q = S((1, kind["heads"], rows, 128))
+    k = S((1, net.tokenq.num_key_value_heads, rows, 128))
+
+    def fwd_bwd(q, k, wq, wk):
+        def loss(q, k):
+            tq, tk = tokenq.rotary_cast(q, k, *table, positions,
+                                        jnp.bfloat16, False)
+            return jnp.sum(tq.astype(jnp.float32) * wq) + jnp.sum(
+                tk.astype(jnp.float32) * wk)
+        return jax.value_and_grad(loss, argnums=(0, 1))(q, k)
+
+    text = _compiled_text(fwd_bwd, q, k, q, k)
+    calls = [(shape, op) for name, shape, op in _instructions(text)
+             if "rotary_turn" in name]
+    assert len(calls) == 4 and {op for _, op in calls} == {"custom-call"}
+    assert sorted(shape.split("[")[0] for shape, _ in calls) == [
+        "bf16", "bf16", "f32", "f32"]
+
+
+@pytest.mark.parametrize("windowed", [False, True], ids=["full", "sliding"])
+def test_laguna_mixer_turns_q_and_k_on_the_lanes_for_v5e(one_chip,
+                                                         windowed):
+    """ONE Laguna layer's mixer as the train program runs it (rematerialised
+    under ``jax.grad``), compiled: what stands under ``ddq.rotary`` is the
+    six kernel calls (q and k; forward, recomputed forward, backward) and
+    the tables — no half of a head laid out on padded lanes, no
+    ``concatenate`` or ``pad`` that rebuilds a ``[.., T, 128]`` float32
+    array from slices. The plain form held 256 (full) / 194 (sliding)
+    instructions under the scope, float32 ``[1, 48, 16385, 32]`` and
+    ``[1, 64, 16385, 64]`` pairs among them (ISSUE 46)."""
+    from distributed_deep_q_tpu.models import tokenq
+
+    net, i, kind, rows, _, _, _ = _rotary_geometry(
+        "laguna_sliding" if windowed else "laguna_full")
+    S = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32,
+                          sharding=one_chip)
+    p = jax.tree.map(S, tokenq.param_shapes(net)[tokenq.layer_name(i)],
+                     is_leaf=lambda x: isinstance(x, tuple))
+
+    def fwd_bwd(x, p):
+        def loss(x, p):
+            y, _, _ = jax.checkpoint(lambda x, p: tokenq.mixer(
+                x, p, net, windowed, True, False, heads=kind["heads"],
+                rope_params=kind["rope_params"]))(x, p)
+            return jnp.sum(jnp.square(y))
+        return jax.grad(loss, argnums=(0, 1))(x, p)
+
+    text = _compiled_text(fwd_bwd, S((1, rows, net.tokenq.hidden_size)), p)
+    scopes = scope_table(text)["scopes"]
+    under = [(name, shape, op) for name, shape, op in _instructions(text)
+             if "ddq.rotary" in scopes.get(name.lstrip("%"), ())]
+    kernels = [shape for name, shape, op in under
+               if op == "custom-call" and "rotary_turn" in name]
+    assert len(kernels) == 6 and len(under) < 128
+    big = rows * 8 * 32         # a quarter of ONE key head's columns
+    assert not _off_the_lanes(
+        "\n".join(f"{n} = {s} {o}(" for n, s, o in under), big)
+    rebuilt = [(name, shape, op) for name, shape, op in under
+               if op in ("concatenate", "pad")
+               and re.match(rf"f32\[(\d+,)*{rows},128\]", shape)]
+    assert not rebuilt, rebuilt
+
+
 def test_the_sibling_presets_state_none_of_lagunas_mechanisms():
     """What this family added is DATA whose defaults are what the four
     presets ran before: the head count of the configuration on every
@@ -868,7 +979,14 @@ def _lowered_token_train_program(topo, preset: str):
 # sibling (read on its parent) and left the four as they were; PR 45 wrote
 # the Keye entry anew (eight ``topk_threshold`` kernels where the search's
 # counting loops were: 96 -> 68 ``stablehlo.while``) and the four others
-# came out of the same writing as they were, to the byte. Written anew by
+# came out of the same writing as they were, to the byte; PR 46 wrote
+# Keye's, Laguna's and SmallThinker's anew (``rotary_turn`` kernels where
+# the rotate-half slices, negations and concatenates were: Laguna 18 -> 58
+# custom calls, 163 -> 61 ``stablehlo.concatenate``, 30 -> 15 cosines — q
+# and k share a table) and LFM2's (a head of 64 keeps the plain form; q
+# and k now share the inverse frequencies: 9 -> 6 ``stablehlo.power``,
+# and twelve products by the factor 1.0 the compiler drops); Moonlight's
+# came out as it was. Written anew by
 # ``PYTHONPATH=. python tests/test_chip_compile.py``.
 SIBLING_PRESETS = ("keye_tokenq", "laguna_tokenq", "lfm2_tokenq",
                    "moonlight_tokenq", "smallthinker_tokenq")
