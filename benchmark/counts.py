@@ -1,10 +1,10 @@
 """Operations and bytes of the fused learner's pieces, from shapes alone
 (the benchmark's own count; the program's XLA census is only printed).
 
-``train_flops_per_step``: copy of ``bench.analytic_flops_per_step`` with
-the head width and the number of forwards taken from the configuration
-instead of assumed. Multiply-adds count 2. The backward pass of the online
-net on s costs twice its forward; recomputation does not count.
+``train_flops_per_step``: the head width and the number of forwards are
+taken from the configuration, not assumed. Multiply-adds count 2. The
+backward pass of the online net on s costs twice its forward;
+recomputation does not count.
 """
 
 from __future__ import annotations

@@ -10,7 +10,7 @@ the causal triangle on a full layer, the band of ``sliding_window`` keys
 on a sliding one — at the heads of THAT kind of layer: the counts do not
 move with the kernel's blocks or with padding. Only the experts HELD are
 counted for the expert layers; the shared expert is counted whole. The
-dense layer, the held experts (even routing; ``laguna_expert_ffn_roofline``
+dense layer, the held experts (even routing; ``expert_ffn_roofline``
 scales it by the share the layers' counter read), router and head are
 counted under the keys the ``lfm2`` family counts them by, so by import.
 """
@@ -18,8 +18,8 @@ counted under the keys the ``lfm2`` family counts them by, so by import.
 from __future__ import annotations
 
 from benchmark.families.lfm2.counts import (  # noqa: F401 — same keys
-    dense_ffn_flops, expected_held_slots, expert_ffn_flops, expert_layers,
-    head_flops, router_flops, tokens)
+    dense_ffn_flops, expected_held_slots, expected_slots_held_share,
+    expert_ffn_flops, expert_layers, head_flops, router_flops, tokens)
 from benchmark.families.tokenq.counts import (
     FORWARDS, causal_pairs, tokens_per_window)
 

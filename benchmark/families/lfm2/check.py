@@ -87,8 +87,9 @@ def assert_hparams(conf: dict, cfg) -> None:
     }
     bad = {k: (hp.get(k), v) for k, v in have.items() if hp.get(k) != v}
     # what the reference computes as facts of the architecture; the last
-    # three are constants of the program too (its conv layers have 3 taps,
-    # ``route`` always renormalises and never scales)
+    # three are held as constants here (the program's conv layers have 3
+    # taps and ``route`` always renormalises; it takes a ``scale`` since
+    # PR 38, 1.0 for this family)
     attn = [i for i in range(n) if tq.layer_types[i] != "conv"]
     facts = {
         "moe_primary_router_apply_softmax": (

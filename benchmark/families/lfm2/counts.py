@@ -77,10 +77,17 @@ def expected_held_slots(hp: dict) -> float:
             / hp["router_experts"])
 
 
+def expected_slots_held_share(hp: dict) -> float:
+    """Per cent of an expert layer's token-slots that come to the experts
+    held here under even routing (100 x held / router width): what
+    ``expert_ffn_roofline`` divides the measured share by."""
+    return 100.0 * hp["experts_held"] / hp["router_experts"]
+
+
 def expert_ffn_flops(hp: dict) -> float:
     """The grouped products of the experts held, one grad step, all
     expert layers: gate, up and down of ``moe_intermediate_size`` a slot
-    (even routing; ``lfm2_expert_ffn_roofline`` scales it by the share
+    (even routing; ``expert_ffn_roofline`` scales it by the share
     the layer's counter read)."""
     per_slot = 6.0 * hp["hidden_size"] * hp["moe_intermediate_size"]
     return FORWARDS * expert_layers(hp) * per_slot * expected_held_slots(hp)

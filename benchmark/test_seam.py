@@ -17,7 +17,11 @@
     (``family.judge``), which no comparison module can leave out;
 (d) ``metrics_for`` asks none of the frame-ring family's four device
     metrics of a cell outside their lists, and every name in every
-    ``workloads`` list of ``BENCHMARK.json`` is a cell.
+    ``workloads`` list of ``BENCHMARK.json`` is a cell;
+(e) one per-layer metric a mechanism, not one a family (PR 47): every cell
+    reads what it read at PR 46 (``fixtures/per_layer_readings_at_pr46
+    .json``), under one name a reading; no two entries read the same thing
+    unless the later one is a family's copy waiting for its fold.
 """
 
 from __future__ import annotations
@@ -37,6 +41,7 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from benchmark import counts, family, run  # noqa: E402
+from benchmark.common import load_json  # noqa: E402
 
 CELLS = ("ddqn_per_b512.learner_only", "ddqn_per_b512.fleet4",
          "dqn_b32.learner_only")
@@ -93,12 +98,158 @@ def test_every_listed_workload_is_a_cell():
             assert m.get("workloads", True), f"{m['name']}: empty list"
 
 
-@pytest.mark.parametrize("cell,count", zip(CELLS, (10, 18, 10)))
+@pytest.mark.parametrize("cell,count", zip(CELLS, (21, 29, 22)))
 def test_the_accepted_cells_keep_their_per_layer_metrics(cell, count):
     names = [m["name"] for m in run.metrics_for(bench_json(), "per_layer",
                                                 cell)]
     assert len(names) == count
     assert set(FRAME_RING_ONLY) <= set(names)
+
+
+# --- (e) one metric a mechanism ----------------------------------------------
+
+TOKEN_CELLS = ("smallthinker_21b_tokenq_ep8.seq_learner_only",
+               "lfm2_24b_tokenq_ep8.seq_learner_only",
+               "keye_vl2_30b_tokenq_ep16.seq_learner_only",
+               "moonlight_16b_tokenq_ep8.seq_learner_only",
+               "laguna_xs2_tokenq_ep16.seq_learner_only",
+               "sdar_30b_tokenq_ep16.seq_learner_only")
+ALL_CELLS = (*CELLS, *TOKEN_CELLS)
+# what was accepted for the three CNN cells at PR 35 comes first in their
+# lists (PR 36 appended 11 / 11 / 12)
+ACCEPTED_AT_PR35 = dict(zip(CELLS, (10, 18, 10)))
+# per cent of the token-slots the experts held take under even routing
+EXPECTED_HELD_SHARE = dict(zip(TOKEN_CELLS,
+                               (12.5, 12.5, 6.25, 12.5, 6.25, 6.25)))
+
+
+def metric_spec(name: str) -> dict:
+    return load_json("layer_metrics", f"{name}.json")
+
+
+def cell_names(cell: str) -> list[str]:
+    return [m["name"] for m in run.metrics_for(bench_json(), "per_layer",
+                                               cell)]
+
+
+@pytest.mark.parametrize("cell,count", zip(
+    ALL_CELLS, (21, 29, 22, 16, 17, 20, 19, 22, 21)))
+def test_every_cell_keeps_its_count_with_what_was_accepted_first(cell, count):
+    names = cell_names(cell)
+    assert len(names) == count and len(set(names)) == count
+    if cell in CELLS:
+        assert set(FRAME_RING_ONLY) <= set(names[:ACCEPTED_AT_PR35[cell]])
+        return
+    # the readings every token cell shares (the first token cell's list)
+    # stand before what only some families have
+    first = set(cell_names(TOKEN_CELLS[0]))
+    shared = [n in first for n in names]
+    assert shared == sorted(shared, reverse=True), names
+    # the whole step's share of the peak, and a roofline a kernel
+    assert "tokenq_train_mfu" in names and "expert_ffn_roofline" in names
+
+
+@pytest.mark.parametrize("cell", ALL_CELLS)
+def test_a_cell_reads_what_it_read_at_pr46(cell):
+    """(reader, arguments) of every metric the cell lists, ``scale.expected``
+    resolved through the cell's own counts module to its number: the
+    fixture was made from PR 46's ``BENCHMARK.json`` and metric files."""
+    want = load_json("fixtures", "per_layer_readings_at_pr46.json")[cell]
+    conf = run.load_cell(cell)[2]["conf"]
+    got = []
+    for name in cell_names(cell):
+        spec = metric_spec(name)
+        scale = spec["args"].get("scale", {})
+        if isinstance(scale.get("expected"), str):
+            scale["expected"] = getattr(
+                family.load_counts(conf), scale["expected"])(conf["hparams"])
+        got.append([spec["reader"], spec["args"]])
+
+    def canon(rows):
+        return sorted(json.dumps(r, sort_keys=True) for r in rows)
+    assert canon(got) == canon(want)
+
+
+@pytest.mark.parametrize("cell", TOKEN_CELLS)
+def test_the_expected_held_share_comes_from_the_configuration(cell):
+    conf = run.load_cell(cell)[2]["conf"]
+    hp = conf["hparams"]
+    held = hp.get("experts_held", hp.get("moe_experts_held"))
+    width = hp.get("router_experts", hp.get("moe_router_experts"))
+    share = family.load_counts(conf).expected_slots_held_share(hp)
+    assert share == 100.0 * held / width == EXPECTED_HELD_SHARE[cell]
+
+
+@pytest.mark.parametrize("cell", [TOKEN_CELLS[1], TOKEN_CELLS[4]],
+                         ids=["expected_12.5", "expected_6.25"])
+def test_the_roofline_scales_by_the_name_as_it_did_by_the_number(cell):
+    """``hlo_scope_roofline`` with ``scale.expected`` as the NAME of the
+    family's function reads what it read with PR 46's number in the
+    file, on a small synthetic trace: one execution of the train program
+    (chain from the configuration), one grouped matmul of 2 ms under
+    ``ddq.experts``, log rows inside and outside the traced steps."""
+    from benchmark.readers import hlo_scope_roofline
+
+    conf = run.load_cell(cell)[2]["conf"]
+    hp = conf["hparams"]
+    gmm = "%gmm.1 = bf16[8,8] custom-call(%p), custom_call_target=\"tpu\""
+    ctx = types.SimpleNamespace(
+        conf=conf, hp=hp, peaks=load_json("peaks.json")["TPU v5 lite"],
+        trace={"/device:TPU:0": {
+            "XLA Modules": [("jit_token_train_fn(1)", 0, 5_000_000)],
+            "XLA Ops": [(gmm, 1_000_000, 2_000_000)]}},
+        result={"hlo_scopes": {"jit_token_train_fn": {"gmm.1":
+                                                      "ddq.experts"}},
+                "traced_steps": (4, 8),
+                "rows": [{"step": 4, "moe_slots_held_share": 50.0},
+                         {"step": 8, "moe_slots_held_share": 5.0}]})
+    args = metric_spec("expert_ffn_roofline")["args"]
+    assert args["scale"]["expected"] == "expected_slots_held_share"
+    by_name = hlo_scope_roofline.read(ctx, **args)
+    number = EXPECTED_HELD_SHARE[cell]
+    by_number = hlo_scope_roofline.read(
+        ctx, **{**args, "scale": {**args["scale"], "expected": number}})
+    work = family.load_counts(conf).expert_ffn_flops(hp) * 5.0 / number
+    assert by_name == by_number == pytest.approx(
+        100.0 * (work / 197e12) / (2e-3 / hp["fused_chain"]), rel=1e-12)
+
+
+def test_no_two_per_layer_entries_read_the_same_thing(capsys):
+    """Equal (reader, arguments) is allowed only to a family's copy that
+    waits for its fold: a ``model_config`` PR may only add, so it names
+    its copy ``<family>_<accepted name>`` and lists its own cells. The
+    count is printed, never refused: filling the room is what it is for."""
+    per_layer = bench_json()["per_layer"]
+    cells = {w["name"] for w in bench_json()["workloads"]}
+    seen: dict[str, dict] = {}
+    waiting, refused = [], []
+    for m in per_layer:
+        spec = metric_spec(m["name"])
+        key = json.dumps([spec["reader"], spec["args"]], sort_keys=True)
+        first = seen.setdefault(key, m)
+        if first is m:
+            continue
+        disjoint = not (set(first.get("workloads", cells))
+                        & set(m.get("workloads", cells)))
+        fam = m["name"].removesuffix("_" + first["name"])
+        if fam not in ("", m["name"]) and disjoint:
+            waiting.append((m["name"], first["name"]))
+        else:
+            refused.append((m["name"], first["name"]))
+    with capsys.disabled():
+        print(f"\nper_layer: {len(per_layer)} of 128 entries; "
+              f"{len(waiting)} copies a fold would free: {waiting}")
+    assert not refused, (
+        f"{refused}: append the cell to the earlier entry's workloads (a "
+        "benchmark PR), or name the copy <family>_<accepted name> with "
+        "cells of its own (benchmark/README.md, Adding a model family)")
+
+
+def test_every_metric_file_is_listed_and_every_entry_has_its_file():
+    files = {f[:-5] for f in os.listdir(
+        os.path.join(ROOT, "benchmark", "layer_metrics"))
+        if f.endswith(".json")}
+    assert files == {m["name"] for m in bench_json()["per_layer"]}
 
 
 def test_a_cell_of_another_family_is_asked_none_of_the_frame_ring_metrics():
