@@ -68,11 +68,14 @@ class TokenQConfig:
     heads or with ``num_attention_heads_per_layer[l]`` of them, a rotary
     embedding whose parameters may belong to the KIND of layer, and with
     ``gating`` a sigmoid gate a head on its output; a gated short
-    convolution; attention over the keys a learned indexer selects; or
-    latent attention, keys and values expanded from one low-rank latent)
+    convolution; attention over the keys a learned indexer selects;
+    latent attention, keys and values expanded from one low-rank latent;
+    or a Mamba-2 state-space mixer, a scan with a carried state)
     and a
     feed-forward (dense, or the experts held here, with
-    ``n_shared_experts`` beside a shared expert every token takes), read
+    ``n_shared_experts`` beside a shared expert every token takes; three
+    matrices with a gate, or two without) — or, under
+    ``hybrid_override_pattern``, ONE of the two alone —, read
     off these keys by
     ``models/tokenq.layer_plan``. ``experts_held`` / ``expert_offset``
     and ``net.num_actions`` (the vocabulary rows held) say which SHARE of
@@ -153,8 +156,13 @@ class TokenQConfig:
     num_dense_layers: int = 0
     intermediate_size: int = 0
     # the gate of every feed-forward, dense or expert: "relu" (ReGLU,
-    # SmallThinker) | "silu" (SwiGLU, LFM2)
+    # SmallThinker) | "silu" (SwiGLU, LFM2) | "relu2" (relu(x)², the
+    # ``nemotron_h`` family's ``mlp_hidden_act``)
     hidden_act: str = "relu"
+    # false: every feed-forward (dense, expert, shared) is TWO matrices,
+    # ``act(x W_up) W_down`` — no gate matrix and no leaf for one
+    # (``nemotron_h``'s MLP)
+    ffn_gated: bool = True
     # experts of width moe_ffn_hidden_size. Router over ALL
     # moe_num_primary_experts, top moe_num_active_primary_experts kept and
     # renormalised to sum 1: a softmax
@@ -179,6 +187,30 @@ class TokenQConfig:
     # that every token takes, whole on every member of the group
     routed_scaling_factor: float = 1.0
     n_shared_experts: int = 0
+    # the shared expert's width where the source states it on its own
+    # (0: ``n_shared_experts * moe_ffn_hidden_size``)
+    moe_shared_expert_intermediate_size: int = 0
+    # the ``nemotron_h`` family's layer pattern, one letter a layer, each
+    # layer ONE part alone under one norm: "M" a Mamba-2 state-space mixer
+    # (the keys below), "*" attention (as the two layouts say), "E" the
+    # expert layer (the source's "-", a dense feed-forward alone, is in no
+    # published pattern here and is refused). Empty: every layer is a
+    # mixer AND a feed-forward (``layer_types``, ``num_dense_layers``). A
+    # longer published pattern is cut to the depth
+    hybrid_override_pattern: str = ""
+    # a Mamba-2 mixer (``ops/ssd.py``): ``mamba_num_heads`` heads of
+    # ``mamba_head_dim`` channels, each carrying a state of
+    # ``ssm_state_size`` a channel; B and C shared by the heads of one of
+    # ``n_groups`` groups; a causal depthwise convolution of
+    # ``conv_kernel`` taps with a bias over x, B and C; the scan in chunks
+    # of ``chunk_size`` positions; the gated RMSNorm over each group's
+    # channels
+    mamba_num_heads: int = 4
+    mamba_head_dim: int = 16
+    ssm_state_size: int = 16
+    n_groups: int = 2
+    conv_kernel: int = 4
+    chunk_size: int = 8
     # generation by diffusion over blocks (0: off, one token a position
     # under the causal mask): the window is cut into blocks of
     # ``block_length`` positions after position 0 (a prompt block of its
@@ -210,6 +242,12 @@ class TokenQConfig:
     attn_fused_bwd: bool = True
     head_block: int = 128
     moe_tile: int = 128
+    # rows of a state-space layer run at a time, the state carried from
+    # one segment to the next (0: the whole window; a multiple of
+    # ``chunk_size``): what bounds the layer's intermediates — a tile field
+    # like the three above (at the Nemotron cell's sizes 2 048 rows read a
+    # 10 % shorter step than 4 096 on the chip: PERF.md section 6, PR 48)
+    ssm_segment: int = 0
 
 
 @dataclass
@@ -991,6 +1029,46 @@ def laguna_tokenq_config() -> Config:
     return c
 
 
+def nemotron_tokenq_config() -> Config:
+    """NVIDIA-Nemotron-3-Nano-30B-A3B (nvidia, config.json, ``model_type``
+    nemotron_h) as a token-window Q-network, one chip's share of a 16-chip
+    expert-parallel deployment: every width as published (hidden 2688;
+    Mamba-2 mixers of 64 heads of 64, state 128, 8 groups, a convolution
+    of 4 taps, chunks of 128; attention of 32 / 2 heads of 128 with no
+    positional embedding; two-matrix relu² experts of width 1 856 behind a
+    sigmoid router 128 wide with a selection bias, top 6, gates x 2.5, and
+    a shared expert 3 712 wide); 7 layers = the published layers 0-6, the
+    unit ``MEMEM*E`` the pattern repeats (each layer ONE part alone under
+    one norm), 8 of the 128 experts and 16 384 of the 131 072 vocabulary
+    rows held here. Windows of 8 191 steps (+1 token = 64 whole chunks),
+    chain 4, batch 2."""
+    c = smallthinker_tokenq_config()
+    c.net = NetConfig(
+        kind="tokenq", num_actions=16_384, compute_dtype="bfloat16",
+        tokenq=TokenQConfig(
+            hidden_size=2688, num_hidden_layers=7,
+            hybrid_override_pattern="MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*E"
+                                    "MEMEMEM*EMEMEMEME",
+            num_attention_heads=32, num_key_value_heads=2, head_dim=128,
+            rms_norm_eps=1e-5, sliding_window_layout=(0,) * 7,
+            rope_layout=(0,) * 7, mamba_num_heads=64, mamba_head_dim=64,
+            ssm_state_size=128, n_groups=8, conv_kernel=4, chunk_size=128,
+            hidden_act="relu2", ffn_gated=False,
+            moe_primary_router_apply_softmax=False, use_expert_bias=True,
+            router_input="ffn_norm", moe_ffn_hidden_size=1856,
+            moe_num_primary_experts=128, moe_num_active_primary_experts=6,
+            experts_held=8, expert_offset=0, routed_scaling_factor=2.5,
+            n_shared_experts=1, moe_shared_expert_intermediate_size=3712,
+            attn_block=1024, attn_compute_block=512, head_block=1024,
+            moe_tile=256, ssm_segment=2048))
+    c.replay = dataclasses.replace(
+        c.replay, capacity=16_384 * 8_191, batch_size=2,
+        sequence_length=8_191, learn_start=64 * 8_191)
+    c.train = dataclasses.replace(c.train, train_every=8_191)
+    c.env = dataclasses.replace(c.env, token_vocab=16_384)
+    return c
+
+
 def sdar_tokenq_config() -> Config:
     """SDAR-30B-A3B-Chat (JetLM, config.json, ``model_type`` sdar_moe) as
     a token-window Q-network, one chip's share of a 16-chip expert-parallel
@@ -1046,6 +1124,7 @@ PRESETS = {
     "moonlight_tokenq": moonlight_tokenq_config,
     "laguna_tokenq": laguna_tokenq_config,
     "sdar_tokenq": sdar_tokenq_config,
+    "nemotron_tokenq": nemotron_tokenq_config,
 }
 
 

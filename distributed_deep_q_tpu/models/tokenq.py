@@ -8,8 +8,10 @@ position. Every size comes from ``config.TokenQConfig`` (the published
 (the vocabulary rows held); nothing is hard-coded here.
 
 ONE backbone whose layers are data (``layer_plan``): each is a token
-mixer and a feed-forward in pre-norm residual form, input x ``[B, T, h]``
-(float32 residual stream), ``u = rmsnorm_1(x)``:
+mixer and a feed-forward in pre-norm residual form — or, where
+``hybrid_override_pattern`` says so, ONE of the two alone under its one
+norm (``norm_1`` a mixer's, ``norm_2`` a feed-forward's) —, input x
+``[B, T, h]`` (float32 residual stream), ``u = rmsnorm_1(x)``:
 
 - mixer, ``x' = x + m``:
   - attention (grouped-query, causal; ``sliding_window_layout[l]`` = 1
@@ -53,13 +55,24 @@ mixer and a feed-forward in pre-norm residual form, input x ``[B, T, h]``
     ``[q_n | q_r] · [k_n | k_r]`` at their width to the -1/2, values
     ``v_head_dim`` wide: ``m = attn · W_o``. Keys and values are expanded
     a head (the form that is not absorbed);
+  - a pattern's "M" (``hybrid_override_pattern`` alone names it: a layer
+    with this mixer has no feed-forward): the Mamba-2
+    state-space mixer (``mamba_mixer``): ``[z | xBC | dt] = u W_in``, a
+    causal depthwise convolution of ``conv_kernel`` taps with a bias and
+    SiLU over x, B and C, the selective scan with a state ``[head_dim,
+    ssm_state_size]`` a head in chunks of ``chunk_size``
+    (``ops/ssd.py``), a gated RMSNorm a group, ``W_out``; the window a
+    segment of ``ssm_segment`` rows at a time, state and convolution tail
+    carried, each segment rematerialised on its own;
 - feed-forward over ``w = rmsnorm_2(x')``, ``y = x' + f``:
   - ``l < num_dense_layers``: ``f = (act(w W_gate) * (w W_up)) W_down`` of
     width ``intermediate_size``, blockwise over tokens;
   - else ``f = Σ_{e in top k, held here} p_e · (act(w W_gate,e) * (w
     W_up,e)) W_down,e`` (``ops/moe.held_experts_ffn``: this process's
     share of an expert-parallel layer), ``act`` = ``hidden_act``: relu
-    (ReGLU) or silu (SwiGLU); with ``n_shared_experts`` > 0 plus ``S(w)``,
+    (ReGLU) or silu (SwiGLU) — with ``ffn_gated`` false every
+    feed-forward is TWO matrices, ``act(w W_up) W_down`` (``relu2``:
+    relu(x)²); with ``n_shared_experts`` > 0 plus ``S(w)``,
     ONE ungated feed-forward of width ``n_shared_experts ·
     moe_ffn_hidden_size`` that every token takes, whole on every member
     of the group (``dense_ffn``, blockwise). The ROUTER
@@ -98,7 +111,11 @@ router with scaled gates, and a shared expert beside them. Laguna's:
 window and full attention at a head count and a rotary embedding of
 their own (YaRN over half of each head on the full layers), a gate a head
 on every attention output, a leading dense layer, SwiGLU experts behind a
-sigmoid router with no bias and scaled gates, a shared expert. SDAR's
+sigmoid router with no bias and scaled gates, a shared expert.
+Nemotron-3-Nano's (``nemotron_h``): the pattern ``MEMEM*E`` — Mamba-2
+mixers, expert layers and attention with no positional embedding, each
+alone under one norm; two-matrix relu² experts behind LFM2's router with
+scaled gates, a shared expert. SDAR's
 (``sdar_moe``): Keye's numbers without the indexer — plain attention with
 q/k norms and rope on every layer, SwiGLU experts behind a softmax router
 reading the second norm — in blocks of 4 under the block mask, the last
@@ -109,7 +126,8 @@ learner, blockwise over tokens, together with the TD loss
 (``parallel/sequence_learner.py``). Matmuls run in ``net.compute_dtype``
 with float32 accumulation; norms, router, gates and convolution, rotary
 and the residual stream are float32. Each layer is rematerialised in the
-backward pass; a sparse layer's two halves apart (``mixer``,
+backward pass (a state-space layer a segment at a time, inside its mixer);
+a sparse layer's two halves apart (``mixer``,
 ``feed_forward``: what the mixer's backward needs does not stand beside
 the expert layer's buffers), and its mixer keeps its selection (bits, no
 gradient) and its indexer's loss with that loss's gradients across it, so
@@ -132,7 +150,7 @@ import numpy as np
 
 from distributed_deep_q_tpu.config import (
     NetConfig, RopeParameters, TokenQConfig)
-from distributed_deep_q_tpu.ops import moe, sparse_attention
+from distributed_deep_q_tpu.ops import moe, sparse_attention, ssd
 from distributed_deep_q_tpu.ops import rotary as rotary_pass
 from distributed_deep_q_tpu.ops.attention import (
     bd_rows, block_diffusion_attention, causal_attention)
@@ -141,10 +159,18 @@ from distributed_deep_q_tpu.ops.short_conv import short_conv_mix
 INIT_STD = 0.02
 BIAS_STD = 0.01     # the expert bias: seeded, and no gradient reaches it
 CONV_TAPS = 3       # a conv layer's taps (LFM2's ``conv_L_cache``)
-ACTS = {"relu": jax.nn.relu, "silu": jax.nn.silu}       # ``hidden_act``
+ACTS = {"relu": jax.nn.relu, "silu": jax.nn.silu,       # ``hidden_act``
+        "relu2": lambda x: jnp.square(jax.nn.relu(x))}
 ROUTER_INPUTS = ("pre_mixer", "ffn_norm")
 MIXERS = ("conv", "full_attention", "sparse_attention",     # ``layer_types``
           "latent_attention")
+# ``hybrid_override_pattern``: a letter a layer -> (its mixer, its
+# feed-forward), ONE of the two and "none" for the other
+PATTERN = {"M": ("mamba", "none"), "*": ("full_attention", "none"),
+           "E": ("none", "experts")}
+# a Mamba-2 mixer's seeded Δ: log-uniform between the ``nemotron_h``
+# family's ``time_step_min`` and ``time_step_max`` (keys of the init alone)
+DT_RANGE = (1e-3, 1e-1)
 ROPE_TYPES = ("default", "yarn")
 
 
@@ -153,13 +179,27 @@ def layer_name(i: int) -> str:
 
 
 def layer_plan(tq: TokenQConfig) -> list[dict[str, Any]]:
-    """Per layer ``{windowed, rope, conv, sparse, latent, dense}`` (bools):
-    the mixer from ``layer_types`` (absent: attention) and the two
-    layouts, the feed-forward from ``num_dense_layers``; ``heads``, the
-    layer's query heads (``num_attention_heads_per_layer``; absent:
-    ``num_attention_heads``); ``rope_params``, the ``RopeParameters`` of
-    the layer's kind (``None``: ``rope_theta`` over all of ``head_dim``)."""
+    """Per layer ``{windowed, rope, conv, sparse, latent, mamba, dense}``
+    (bools): the mixer from ``layer_types`` (absent: attention) and the
+    two layouts, the feed-forward from ``num_dense_layers``; ``mixer`` /
+    ``ffn``: whether the layer has that half at all (both, unless
+    ``hybrid_override_pattern`` gives every layer ONE part alone: then the
+    pattern's letters say the mixer and the feed-forward, ``PATTERN``);
+    ``heads``, the layer's query heads (``num_attention_heads_per_layer``;
+    absent: ``num_attention_heads``); ``rope_params``, the
+    ``RopeParameters`` of the layer's kind (``None``: ``rope_theta`` over
+    all of ``head_dim``)."""
     n = tq.num_hidden_layers
+    pattern = tq.hybrid_override_pattern[:n]
+    if pattern:
+        if len(pattern) < n or set(pattern) - set(PATTERN):
+            raise ValueError(
+                f"hybrid_override_pattern must name {n} layers by "
+                f"{' | '.join(PATTERN)}: {tq.hybrid_override_pattern!r}")
+        if tq.layer_types or tq.num_dense_layers:
+            raise ValueError("hybrid_override_pattern states every layer's "
+                             "mixer and feed-forward: layer_types and "
+                             "num_dense_layers must be empty / 0")
     if len(tq.sliding_window_layout) < n or len(tq.rope_layout) < n:
         raise ValueError(
             f"sliding_window_layout/rope_layout must cover "
@@ -174,7 +214,10 @@ def layer_plan(tq: TokenQConfig) -> list[dict[str, Any]]:
     if tq.router_input not in ROUTER_INPUTS:
         raise ValueError(f"router_input must be one of {ROUTER_INPUTS}: "
                          f"{tq.router_input!r}")
-    kinds = tq.layer_types[:n] or ("full_attention",) * n
+    kinds = (tuple(PATTERN[c][0] for c in pattern) or tq.layer_types[:n]
+             or ("full_attention",) * n)
+    ffns = tuple(PATTERN[c][1] for c in pattern) or tuple(
+        "dense" if i < tq.num_dense_layers else "experts" for i in range(n))
     per_layer = tuple(int(x) for x in tq.num_attention_heads_per_layer)
     if per_layer and len(per_layer) < n:
         raise ValueError(f"num_attention_heads_per_layer must cover {n} "
@@ -184,13 +227,21 @@ def layer_plan(tq: TokenQConfig) -> list[dict[str, Any]]:
              "conv": kinds[i] == "conv",
              "sparse": kinds[i] == "sparse_attention",
              "latent": kinds[i] == "latent_attention",
-             "dense": i < tq.num_dense_layers,
+             "mamba": kinds[i] == "mamba",
+             "mixer": kinds[i] != "none", "ffn": ffns[i] != "none",
+             "dense": ffns[i] == "dense",
              "heads": per_layer[i] if per_layer
              else tq.num_attention_heads,
              "rope_params": _rope_params(
                  tq, bool(tq.sliding_window_layout[i]))}
             for i in range(n)]
-    plain = [not (k["conv"] or k["sparse"] or k["latent"]) for k in plan]
+    plain = [kinds[i] == "full_attention" for i in range(n)]
+    if "mamba" in kinds and tq.mamba_num_heads % tq.n_groups:
+        raise ValueError(f"{tq.n_groups} groups do not divide "
+                         f"{tq.mamba_num_heads} state-space heads")
+    if pattern and tq.router_input != "ffn_norm":
+        raise ValueError("an expert layer alone has one norm: "
+                         "router_input must be 'ffn_norm'")
     if any(k["heads"] % tq.num_key_value_heads
            for k, p in zip(plan, plain) if p):
         raise ValueError(
@@ -304,6 +355,12 @@ def param_shapes(cfg: NetConfig) -> dict[str, Any]:
               "w_o": (hq * dv, h)}
     conv = {"w_in": (h, 3 * h), "w_conv": (h, CONV_TAPS),
             "w_out": (h, h)}
+    nh = tq.mamba_num_heads
+    di = nh * tq.mamba_head_dim                     # the mixer's channels
+    xbc = di + 2 * tq.n_groups * tq.ssm_state_size  # x, B, C: convolved
+    mamba = {"w_in": (h, di + xbc + nh), "ssm_conv_w": (xbc, tq.conv_kernel),
+             "ssm_conv_b": (xbc,), "a_log": (nh,), "d_skip": (nh,),
+             "dt_bias": (nh,), "gate_norm": (di,), "w_out": (di, h)}
     hi, di = tq.indexer_num_heads, tq.indexer_head_dim
     indexer = {"w_iq": (h, hi * di), "w_ik": (h, di), "w_iw": (h, hi),
                "ik_norm": (di,)}
@@ -312,35 +369,63 @@ def param_shapes(cfg: NetConfig) -> dict[str, Any]:
     if tq.use_expert_bias:
         experts["expert_bias"] = (tq.moe_num_primary_experts,)
     if tq.n_shared_experts:
-        fs = tq.n_shared_experts * f
+        fs = shared_width(tq)
         experts.update({"shared_gate": (h, fs), "shared_up": (h, fs),
                         "shared_down": (fs, h)})
     fi = tq.intermediate_size
     dense = {"w_gate": (h, fi), "w_up": (h, fi), "w_down": (fi, h)}
+    if not tq.ffn_gated:        # two matrices: no leaf for a gate
+        del experts["w_gate"], dense["w_gate"]
+        experts.pop("shared_gate", None)
     shapes: dict[str, Any] = {"embed": (v, h), "final_norm": (h,),
                               "head": (h, v)}
     for i, kind in enumerate(layer_plan(tq)):
-        shapes[layer_name(i)] = {
-            "norm_1": (h,), "norm_2": (h,),
-            **(conv if kind["conv"] else latent if kind["latent"]
-               else attention(kind["heads"])),
-            **(indexer if kind["sparse"] else {}),
-            **(dense if kind["dense"] else experts)}
+        mixer = {"norm_1": (h,),
+                 **(conv if kind["conv"] else latent if kind["latent"]
+                    else mamba if kind["mamba"]
+                    else attention(kind["heads"])),
+                 **(indexer if kind["sparse"] else {})}
+        ffn = {"norm_2": (h,), **(dense if kind["dense"] else experts)}
+        # a layer that is one part alone has that part's norm alone
+        shapes[layer_name(i)] = {**(mixer if kind["mixer"] else {}),
+                                 **(ffn if kind["ffn"] else {})}
     return shapes
+
+
+def shared_width(tq: TokenQConfig) -> int:
+    """The shared expert's width: stated, or ``n_shared_experts`` experts'
+    widths side by side."""
+    return (tq.moe_shared_expert_intermediate_size
+            or tq.n_shared_experts * tq.moe_ffn_hidden_size)
 
 
 def init_params(cfg: NetConfig, seed: int) -> dict[str, Any]:
     """Normal(0, 0.02) matrices, unit norms, a Normal(0, 0.01) expert
-    bias (it stays as seeded: no gradient reaches it), float32."""
+    bias (it stays as seeded: no gradient reaches it), float32. A Mamba-2
+    mixer's own: taps and their bias uniform within ``±taps^-½`` (the
+    source framework's default for a depthwise convolution), ``A``
+    uniform in [1, 16) (``a_log`` its log), ``dt_bias`` the inverse
+    softplus of a Δ log-uniform in ``DT_RANGE``, ``d_skip`` 1."""
     shapes = param_shapes(cfg)
     leaves, treedef = jax.tree_util.tree_flatten_with_path(
         shapes, is_leaf=lambda x: isinstance(x, tuple))
     keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
 
     def leaf(key, path, shape):
+        name = path[-1].key
+        if name in ("ssm_conv_w", "ssm_conv_b"):
+            bound = cfg.tokenq.conv_kernel ** -0.5
+            return jax.random.uniform(key, shape, jnp.float32, -bound, bound)
+        if name == "a_log":
+            return jnp.log(jax.random.uniform(key, shape, jnp.float32,
+                                              1.0, 16.0))
+        if name == "dt_bias":
+            lo, hi = np.log(DT_RANGE)
+            dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32, lo, hi))
+            return dt + jnp.log(-jnp.expm1(-dt))
         if len(shape) > 1:
             return INIT_STD * jax.random.normal(key, shape, jnp.float32)
-        if path[-1].key == "expert_bias":
+        if name == "expert_bias":
             return BIAS_STD * jax.random.normal(key, shape, jnp.float32)
         return jnp.ones(shape, jnp.float32)
 
@@ -422,8 +507,9 @@ def rotary_fused(tq: TokenQConfig) -> int | None:
     """1 where every rotate-half layer of the plan turns q and k by the
     fused pass, 0 where they keep the plain form (``rotary_cast``'s own
     rule); ``None`` where no layer turns rotate-half (conv and latent
-    mixers do not)."""
-    turning = any(k["rope"] and not (k["conv"] or k["latent"])
+    mixers do not, nor does a state-space mixer or a layer without one)."""
+    turning = any(k["rope"] and k["mixer"]
+                  and not (k["conv"] or k["latent"] or k["mamba"])
                   for k in layer_plan(tq))
     return int(rotary_pass.fills_lanes(tq.head_dim)) if turning else None
 
@@ -462,24 +548,28 @@ def _route(w: jax.Array, p: dict[str, jax.Array], tq: TokenQConfig):
             bias=p.get("expert_bias"), scale=tq.routed_scaling_factor)
 
 
-def dense_ffn(w: jax.Array, w_gate: jax.Array, w_up: jax.Array,
+def dense_ffn(w: jax.Array, w_gate: jax.Array | None, w_up: jax.Array,
               w_down: jax.Array, *, act, block: int, dtype) -> jax.Array:
-    """``(act(w W_gate) * (w W_up)) W_down`` over ``w`` [N, h], a block of
+    """``(act(w W_gate) * (w W_up)) W_down`` — with ``w_gate`` ``None``
+    the two-matrix ``act(w W_up) W_down`` — over ``w`` [N, h], a block of
     ``block`` tokens at a time, each recomputed in the backward pass: the
     gate and up activations of a leading dense layer (LFM2: 5.75 x the
     hidden size) never exist for the whole batch."""
     n, h = w.shape
     block = min(block, n)
     nb = -(-n // block)
-    w_gu = jnp.concatenate([w_gate, w_up], axis=-1).astype(dtype)
+    gated = w_gate is not None
+    w_gu = (jnp.concatenate([w_gate, w_up], axis=-1) if gated
+            else w_up).astype(dtype)
     w_down = w_down.astype(dtype)
-    f = w_gate.shape[1]
+    f = w_up.shape[1]
 
     @jax.checkpoint
     def one(wb):
         gu = jnp.dot(wb.astype(dtype), w_gu,
                      preferred_element_type=jnp.float32)
-        return _mm(act(gu[:, :f]) * gu[:, f:], w_down, dtype)
+        return _mm(act(gu[:, :f]) * gu[:, f:] if gated else act(gu),
+                   w_down, dtype)
 
     blocks = jnp.pad(w, ((0, nb * block - n), (0, 0))).reshape(nb, block, h)
     return jax.lax.map(one, blocks).reshape(nb * block, h)[:n]
@@ -544,21 +634,89 @@ def latent_attention(u: jax.Array, p: dict[str, jax.Array],
                dtype)
 
 
+def mamba_mixer(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig):
+    """A Mamba-2 layer whole, ``x`` [B, T, h] float32 → (``x + g · W_out``,
+    the mean Δ). ``u = rmsnorm_1(x)``; ``[z | xBC | dt] = u W_in``; ``xBC
+    ← silu(conv(xBC) + b)`` (causal, depthwise); x a head, B and C a
+    group; ``Δ = softplus(dt + dt_bias)``, ``A = -exp(a_log)``; the scan
+    (``ops/ssd.ssd_scan``: the state zero before the window); ``g = y ·
+    silu(z)`` under an RMSNorm over each GROUP's channels, times
+    ``gate_norm``. The two projections and the scan's four products in
+    ``compute_dtype`` with float32 accumulation; convolution, Δ, decays,
+    state, skip and norm float32.
+
+    The window runs a SEGMENT of ``ssm_segment`` rows at a time (0: whole),
+    the convolution's last rows and the state carried from one to the
+    next, each segment rematerialised on its own in the backward pass:
+    this is the layer's ONE ``jax.checkpoint`` (the caller adds none), and
+    a segment's intermediates — ``[z | xBC | dt]`` alone is 10 304 float32
+    a token at the published widths — never stand for the whole window. A
+    window no segment divides is padded with rows after its last, which
+    nothing before them reads."""
+    tq = cfg.tokenq
+    dtype = jnp.dtype(cfg.compute_dtype)
+    b, t, h = x.shape
+    nh, hd, g, n = (tq.mamba_num_heads, tq.mamba_head_dim, tq.n_groups,
+                    tq.ssm_state_size)
+    di, gn = nh * hd, g * n
+    seg = min(tq.ssm_segment or t, t)
+    ns = -(-t // seg)
+
+    @jax.checkpoint
+    def segment(carry, rows, real, p):
+        tail, state = carry
+        u = rmsnorm(rows, p["norm_1"], tq.rms_norm_eps)
+        zxbcdt = _mm(u, p["w_in"], dtype)
+        z, xbc, dt = (zxbcdt[..., :di], zxbcdt[..., di:2 * di + 2 * gn],
+                      zxbcdt[..., 2 * di + 2 * gn:])
+        with jax.named_scope("ddq.ssm_conv"):
+            xbc, tail = ssd.causal_conv(xbc, p["ssm_conv_w"],
+                                        p["ssm_conv_b"], tail)
+        with jax.named_scope("ddq.ssm_scan"):
+            delta = jax.nn.softplus(dt + p["dt_bias"])
+            y, state = ssd.ssd_scan(
+                xbc[..., :di].reshape(b, seg, nh, hd), delta,
+                -jnp.exp(p["a_log"]),
+                xbc[..., di:di + gn].reshape(b, seg, g, n),
+                xbc[..., di + gn:].reshape(b, seg, g, n), p["d_skip"],
+                state, chunk=tq.chunk_size, dtype=dtype)
+        gated = (y.reshape(b, seg, di) * jax.nn.silu(z)).reshape(
+            b, seg, g, di // g)
+        var = jnp.mean(jnp.square(gated), axis=-1, keepdims=True)
+        normed = (gated * jax.lax.rsqrt(var + tq.rms_norm_eps)).reshape(
+            b, seg, di) * p["gate_norm"]
+        return (tail, state), (rows + _mm(normed, p["w_out"], dtype),
+                               jnp.sum(delta * real[:, None]))
+
+    xs = jnp.pad(x, ((0, 0), (0, ns * seg - t), (0, 0))).reshape(
+        b, ns, seg, h).transpose(1, 0, 2, 3)
+    real = (jnp.arange(ns * seg) < t).astype(jnp.float32).reshape(ns, seg)
+    init = (jnp.zeros((b, tq.conv_kernel - 1, di + 2 * gn), jnp.float32),
+            jnp.zeros((b, g, nh // g, hd, n), jnp.float32))
+    _, (ys, dts) = jax.lax.scan(
+        lambda carry, xr: segment(carry, *xr, p), init, (xs, real))
+    return (ys.transpose(1, 0, 2, 3).reshape(b, ns * seg, h)[:, :t],
+            jnp.sum(dts) / (b * t * nh))
+
+
 def mixer(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
           windowed: bool, rope: bool, interpret: bool, *,
           conv: bool = False, dense: bool = False, sparse: bool = False,
-          latent: bool = False, index_loss: bool = True, heads: int = 0,
+          latent: bool = False, mamba: bool = False,
+          index_loss: bool = True, heads: int = 0,
           rope_params: RopeParameters | None = None, bd_steps: int = 0):
     """A block's first half, ``x' = x + m``; ``x`` [B, T, h] float32 →
     (x', the routing where the router reads the mixer's input — else
     ``None`` —, the mixer's counters: a sparse mixer's, under ``gating``
-    the gate's mean over tokens and heads — else ``None``). The mixer is
+    the gate's mean over tokens and heads, a state-space mixer's
+    ``ssm_dt_mean`` — else ``None``). The mixer is
     attention (``windowed``, ``rope``; ``heads`` query heads, 0:
     ``num_attention_heads``; ``rope_params``: the rotary parameters of
     the layer's kind), with ``conv`` the gated short convolution, with
     ``sparse`` attention over the keys its indexer selects
     (``index_loss``: with the indexer's loss), with ``latent`` latent
-    attention. ``bd_steps`` > 0: ``x`` holds the packed rows of windows of
+    attention, with ``mamba`` the Mamba-2 state-space mixer
+    (``mamba_mixer``). ``bd_steps`` > 0: ``x`` holds the packed rows of windows of
     that many steps in blocks of ``block_length`` (``ops/attention.
     bd_rows``: one copy or two) — the rotary embedding turns each row at
     its position there and attention runs under the block mask."""
@@ -568,6 +726,10 @@ def mixer(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
     b, t, _ = x.shape
     hq, hkv, d = (heads or tq.num_attention_heads, tq.num_key_value_heads,
                   tq.head_dim)
+    if mamba:   # its norm, its residual and its checkpoint are inside
+        with jax.named_scope("ddq.ssm"):
+            x, dt_mean = mamba_mixer(x, p, cfg)
+        return x, None, {"ssm_dt_mean": dt_mean}
     u = rmsnorm(x, p["norm_1"], tq.rms_norm_eps)
     route = _route(u, p, tq) if router_first and not dense else None
     counters = None
@@ -663,7 +825,7 @@ def feed_forward(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
     if dense:
         with jax.named_scope("ddq.dense_ffn"):
             v2 = rmsnorm(x, p["norm_2"], tq.rms_norm_eps)
-            y = dense_ffn(v2.reshape(b * t, h), p["w_gate"], p["w_up"],
+            y = dense_ffn(v2.reshape(b * t, h), p.get("w_gate"), p["w_up"],
                           p["w_down"], act=act,
                           block=tq.head_block, dtype=dtype)
         return x + y.reshape(b, t, h), None
@@ -678,7 +840,7 @@ def feed_forward(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
         # overflow; the layer walks it in blocks and runs those that hold
         # a slot, so no buffer of that size ever stands
         y, counters = moe.held_experts_ffn(
-            v2, idx, prob, p["w_gate"], p["w_up"], p["w_down"],
+            v2, idx, prob, p.get("w_gate"), p["w_up"], p["w_down"],
             offset=tq.expert_offset,
             rows=moe.buffer_rows(b * t, k, tq.experts_held, tq.moe_tile),
             tile=tq.moe_tile, compute_dtype=dtype, interpret=interpret,
@@ -689,8 +851,9 @@ def feed_forward(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
             # for Moonlight) never stand for the whole batch
             with jax.named_scope("ddq.shared_expert"):
                 y = y + dense_ffn(
-                    v2.reshape(b * t, h), p["shared_gate"], p["shared_up"],
-                    p["shared_down"], act=act, block=tq.head_block,
+                    v2.reshape(b * t, h), p.get("shared_gate"),
+                    p["shared_up"], p["shared_down"], act=act,
+                    block=tq.head_block,
                     dtype=dtype).reshape(b, t, h)
     return x + y, counters
 
@@ -698,18 +861,25 @@ def feed_forward(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
 def layer(x: jax.Array, p: dict[str, jax.Array], cfg: NetConfig,
           windowed: bool, rope: bool, interpret: bool, *,
           conv: bool = False, dense: bool = False, sparse: bool = False,
-          latent: bool = False, index_loss: bool = True, heads: int = 0,
+          latent: bool = False, mamba: bool = False,
+          has_mixer: bool = True, has_ffn: bool = True,
+          index_loss: bool = True, heads: int = 0,
           rope_params: RopeParameters | None = None, bd_steps: int = 0):
-    """One block, ``mixer`` then ``feed_forward``; ``x`` [B, T, h] float32
+    """One block, ``mixer`` then ``feed_forward`` — or the ONE of the two
+    the layer has (``has_mixer``, ``has_ffn``); ``x`` [B, T, h] float32
     → (x, the layer's counters: the expert layer's, under ``"dsa"`` a
-    sparse mixer's, and a gated mixer's ``attn_gate_mean``; ``None`` where
-    it has none)."""
-    x, route, mixed = mixer(x, p, cfg, windowed, rope, interpret, conv=conv,
-                            dense=dense, sparse=sparse, latent=latent,
-                            index_loss=index_loss, heads=heads,
-                            rope_params=rope_params, bd_steps=bd_steps)
-    x, counters = feed_forward(x, p, cfg, interpret, dense=dense,
-                               route=route)
+    sparse mixer's, a gated mixer's ``attn_gate_mean`` and a state-space
+    mixer's ``ssm_dt_mean``; ``None`` where it has none)."""
+    route = mixed = counters = None
+    if has_mixer:
+        x, route, mixed = mixer(
+            x, p, cfg, windowed, rope, interpret, conv=conv, dense=dense,
+            sparse=sparse, latent=latent, mamba=mamba,
+            index_loss=index_loss, heads=heads, rope_params=rope_params,
+            bd_steps=bd_steps)
+    if has_ffn:
+        x, counters = feed_forward(x, p, cfg, interpret, dense=dense,
+                                   route=route)
     if sparse:
         counters = {**(counters or {}), "dsa": mixed}
     elif mixed is not None:
@@ -759,8 +929,9 @@ def backbone(params: dict[str, Any], tokens: jax.Array, cfg: NetConfig,
     without ``index_loss``: θ⁻ and the acting path) and ``dsa_bits`` (the
     selected pairs as bits: a caller that does not read them drops them,
     and the compiler with it); under ``gating`` ``attn_gate_mean``, the
-    gate's mean over tokens and heads stacked over the layers. Only the
-    attention kernels pad the window
+    gate's mean over tokens and heads stacked over the layers; with
+    state-space layers ``ssm_dt_mean``, their mean Δ stacked over them.
+    Only the attention kernels pad the window
     (to their blocks); every other product runs on T.
 
     With ``block_length`` > 0 the window runs under the block mask. With
@@ -776,12 +947,13 @@ def backbone(params: dict[str, Any], tokens: jax.Array, cfg: NetConfig,
             x = params["embed"][bd_pack(tokens, reveal, cfg)]
     else:
         x = params["embed"][tokens]
-    counters, dsa, gates = [], [], []
+    counters, dsa, gates, dts = [], [], [], []
     for i, kind in enumerate(layer_plan(tq)):
         p = params[layer_name(i)]
         kw = dict(conv=kind["conv"], dense=kind["dense"],
                   sparse=kind["sparse"], latent=kind["latent"],
-                  index_loss=index_loss, heads=kind["heads"],
+                  mamba=kind["mamba"], index_loss=index_loss,
+                  heads=kind["heads"],
                   rope_params=kind["rope_params"], bd_steps=bd_steps)
         if kind["sparse"] or kind["latent"]:
             # the two halves rematerialised apart: what the mixer's
@@ -800,20 +972,28 @@ def backbone(params: dict[str, Any], tokens: jax.Array, cfg: NetConfig,
                     route=route))(x, p, route)
             if kind["sparse"]:
                 dsa.append(dsa_i)
+        elif kind["mamba"]:
+            # rematerialised inside, a segment of the window at a time
+            x, _, c = mixer(x, p, cfg, False, False, interpret, **kw)
         else:
             x, c = jax.checkpoint(
                 lambda x, p, kind=kind, kw=kw: layer(
                     x, p, cfg, kind["windowed"], kind["rope"], interpret,
+                    has_mixer=kind["mixer"], has_ffn=kind["ffn"],
                     **kw))(x, p)
         if c is not None and "attn_gate_mean" in c:
             # a dense layer has a gate too: stacked over the GATED layers
             gates.append(c.pop("attn_gate_mean"))
+        if c is not None and "ssm_dt_mean" in c:
+            dts.append(c.pop("ssm_dt_mean"))
         if c:
             counters.append(c)
     x = rmsnorm(x, params["final_norm"], tq.rms_norm_eps)
     out = jax.tree.map(lambda *a: jnp.stack(a), *counters)
     if gates:
         out["attn_gate_mean"] = jnp.stack(gates)
+    if dts:
+        out["ssm_dt_mean"] = jnp.stack(dts)
     if dsa:
         out.update({f"dsa_{k}": jnp.stack([d[k] for d in dsa])
                     for k in dsa[0]})

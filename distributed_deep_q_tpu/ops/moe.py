@@ -72,20 +72,28 @@ def buffer_rows(tokens: int, top_k: int, held: int, tile: int) -> int:
 
 
 def _fit(dim: int, want: int = 512) -> int:
-    """Largest multiple of 128 <= ``want`` that divides ``dim`` (the
-    grouped matmul's k/n tile), else ``dim`` whole."""
-    for t in range(min(want, dim) // 128 * 128, 0, -128):
-        if dim % t == 0:
-            return t
-    return dim
+    """The grouped matmul's k/n tile for a dimension ``dim``: the largest
+    multiple of 128 <= ``want`` that divides it; where none does, the one
+    that rounds it up least (the largest of those: 1 856 = 14.5 x 128
+    runs in 5 tiles of 384 = 1 920 — the kernel masks the last tile's
+    overhang at the call, 3.4 % of its columns, counted nowhere as work,
+    and the held weights keep their published shape); under 128 (a toy's
+    width) ``dim`` whole."""
+    tiles = range(min(want, dim) // 128 * 128, 0, -128)
+    if not tiles:
+        return dim
+    return min(tiles, key=lambda t: (-(-dim // t) * t, -t))
 
 
-def _gmm(lhs, rhs, sizes, tile, interpret):
+def _gmm(lhs, rhs, sizes, tile, interpret, transposed=False):
+    """``lhs`` [rows, k] against ``rhs`` [groups, k, n] (``transposed``:
+    [groups, n, k]), the rows of group g by group g's matrix."""
     from jax.experimental.pallas.ops.tpu import megablox
 
-    tiling = (tile, _fit(rhs.shape[1]), _fit(rhs.shape[2]))
-    return megablox.gmm(lhs, rhs, sizes, jnp.float32, tiling, None, None,
-                        False, interpret)
+    k, n = rhs.shape[1:][::-1] if transposed else rhs.shape[1:]
+    return megablox.gmm(lhs, rhs, sizes, jnp.float32,
+                        (tile, _fit(k), _fit(n)), None, None, transposed,
+                        interpret)
 
 
 def block_rows(rows: int, tile: int) -> int:
@@ -103,7 +111,9 @@ def held_experts_ffn(x: jax.Array, idx: jax.Array, p: jax.Array,
                      act=jax.nn.relu):
     """Σ over the chosen experts HELD here of ``p_e · f_e(x)``, ``f_e(x) =
     (act(x W_gate,e) * (x W_up,e)) W_down,e``: ReGLU with ``act`` relu,
-    SwiGLU with silu.
+    SwiGLU with silu; with ``w_gate`` ``None`` the two-matrix form
+    ``act(x W_up,e) W_down,e`` (one product less a block; the sort, the
+    walk and the counters are the same).
 
     ``x`` [..., T, h] float32 over N tokens in all (``[B, T, h]`` as the
     model holds it; inside, its rows lie ``T`` rounded up to the float32
@@ -118,8 +128,14 @@ def held_experts_ffn(x: jax.Array, idx: jax.Array, p: jax.Array,
     n, k = idx.shape
     *outer, t, h = x.shape
     stride = -(-t // 8) * 8         # [..., stride, h] -> [-1, h]: no copy
-    held = w_gate.shape[0]
-    f = w_gate.shape[2]
+    held, _, f = w_up.shape
+    gated = w_gate is not None
+    # a width the 128 lanes do not divide: the chip keeps ``[held, h, f]``
+    # with ``h`` innermost (no lane is padding), so the up product reads
+    # its weights as ``[held, f, h]`` — as they lie — and nothing is laid
+    # out again on the way into and out of the chained steps (at 1 856
+    # that was 12 copies of 160 MB each way, 1.9 GB held)
+    up_t = f > 128 and f % 128 != 0
     block = block_rows(rows, tile)
     padded = -(-rows // block) * block
     local = idx - offset
@@ -147,14 +163,17 @@ def held_experts_ffn(x: jax.Array, idx: jax.Array, p: jax.Array,
 
     def one_block(xs, ps, w_gu, w_d, sizes, valid):
         xs = jnp.where(valid, xs, 0.0).astype(compute_dtype)
-        gu = _gmm(xs, w_gu, sizes, tile, interpret)            # [block, 2f]
-        hid = (act(gu[:, :f]) * gu[:, f:]).astype(compute_dtype)
+        gu = _gmm(xs, w_gu, sizes, tile, interpret, up_t)  # [block, 2f | f]
+        hid = (act(gu[:, :f]) * gu[:, f:] if gated else act(gu)).astype(
+            compute_dtype)
         out = _gmm(hid, w_d, sizes, tile, interpret)
         # rows past the last group were never visited by the kernels
         return jnp.where(valid, out, 0.0) * jnp.where(valid, ps[:, None], 0.0)
 
     def forward(x, ps, w_gate, w_up, w_down, order, ends):
-        w_gu = jnp.concatenate([w_gate, w_up], axis=-1).astype(compute_dtype)
+        w_gu = (jnp.concatenate([w_gate, w_up], axis=-1) if gated
+                else w_up).astype(compute_dtype)
+        w_gu = w_gu.swapaxes(1, 2) if up_t else w_gu
         w_d = w_down.astype(compute_dtype)
 
         def body(i, y):
@@ -183,9 +202,11 @@ def held_experts_ffn(x: jax.Array, idx: jax.Array, p: jax.Array,
             jnp.zeros_like(x), jnp.zeros_like(ps),
             jnp.zeros(w_gu.shape, jnp.float32),
             jnp.zeros(w_d.shape, jnp.float32)))
-        return (dx, dps, dw_gu[..., :f].astype(w_gate.dtype),
-                dw_gu[..., f:].astype(w_up.dtype), dw_d.astype(w_down.dtype),
-                None, None)
+        dw_gu = dw_gu.swapaxes(1, 2) if up_t else dw_gu
+        return (dx, dps,
+                dw_gu[..., :f].astype(w_gate.dtype) if gated else None,
+                dw_gu[..., -f:].astype(w_up.dtype),
+                dw_d.astype(w_down.dtype), None, None)
 
     walk = jax.custom_vjp(lambda *a: forward(*a)[0])
     walk.defvjp(forward, backward)

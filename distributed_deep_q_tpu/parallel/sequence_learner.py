@@ -575,6 +575,12 @@ class SequenceLearner:
             # closes heads it falls)
             metrics["attn_gate_mean"] = lax.pmean(
                 jnp.mean(counters["attn_gate_mean"]), AXIS_DP)
+        if "ssm_dt_mean" in counters:
+            # the state-space mixers' step Δ: its mean over tokens, heads
+            # and the Mamba layers (a scan that forgets everything reads
+            # large, one that forgets nothing near 0)
+            metrics["ssm_dt_mean"] = lax.pmean(
+                jnp.mean(counters["ssm_dt_mean"]), AXIS_DP)
         if reveal is not None:
             # block diffusion: decisions that carried a loss, tokens of a
             # block already revealed (mean: (B - 1) / 2), env steps from

@@ -717,6 +717,8 @@ def train_tokenq(cfg: Config, metrics: Metrics | None = None,
                     if "attn_gate_mean" in m:       # gated attention only
                         summary["attn_gate_mean"] = float(
                             m["attn_gate_mean"])
+                    if "ssm_dt_mean" in m:          # state-space layers only
+                        summary["ssm_dt_mean"] = float(m["ssm_dt_mean"])
                     if "bd_decisions_valid" in m:   # block diffusion only
                         summary.update({k: float(m[k]) for k in (
                             "bd_decisions_valid", "bd_reveal_mean",

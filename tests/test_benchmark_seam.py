@@ -87,7 +87,8 @@ def test_every_configuration_resolves_its_family_modules(config):
 TOKEN_CELLS = ["smallthinker_21b_tokenq_ep8.seq_learner_only",
                "lfm2_24b_tokenq_ep8.seq_learner_only",
                "keye_vl2_30b_tokenq_ep16.seq_learner_only",
-               "moonlight_16b_tokenq_ep8.seq_learner_only"]
+               "moonlight_16b_tokenq_ep8.seq_learner_only",
+               "nemotron3_nano_30b_tokenq_ep16.seq_learner_only"]
 
 
 @pytest.mark.parametrize("cell", TOKEN_CELLS)
@@ -114,14 +115,16 @@ def test_the_token_family_walks_its_cell_on_the_cpu(cell):
 # the written priority and do not judge it: their ``check.PRINTED_ONLY``)
 SEPARATING = ("loss_first_rel", "grad_norm_first_rel",
               "moment_first_worst_leaf")
-CONTROL_FLOOR = dict(zip(TOKEN_CELLS, (1e-3, 1e-4, 1e-4, 1e-4)))
+CONTROL_FLOOR = dict(zip(TOKEN_CELLS, (1e-3, 1e-4, 1e-4, 1e-4, 1e-4)))
 CONTROL_READS = dict(zip(TOKEN_CELLS, (
     (*SEPARATING, "priority_first_max_rel"), SEPARATING,
-    (*SEPARATING, "priority_first_max_rel"), SEPARATING)))
+    (*SEPARATING, "priority_first_max_rel"), SEPARATING,
+    # nemotron prints the first loss's gap and judges the chunk's largest
+    ("loss_max_rel", *SEPARATING[1:]))))
 
 
 @pytest.mark.parametrize("cell", TOKEN_CELLS)
-def test_the_token_familys_control_is_not_correct(cell):
+def test_the_token_familys_control_is_not_correct(cell, capsys):
     """``control.py``'s readings at the toy sizes: the program (float32
     there) agrees with the reference to rounding, and the reference one
     precision down — fp8 operands where the configuration states bfloat16
@@ -143,6 +146,9 @@ def test_the_token_familys_control_is_not_correct(cell):
     for k in ("windows_illegal", "token_window_mismatch",
               "validity_mismatch", "expert_buffer_overflow"):
         assert n[k]["sound_max"] == 0
+    # the nemotron family names the leaf each worst-leaf number sits on
+    assert ('"worst_leaves": {"moment_first_worst_leaf": "' in
+            capsys.readouterr().out) == ("nemotron" in cell)
 
 
 def test_the_lfm2_familys_planted_faults_move_what_they_are_read_for():
@@ -162,6 +168,23 @@ def test_the_lfm2_familys_planted_faults_move_what_they_are_read_for():
     eta = table["priority_eta_1"]["smallest"]
     assert max(eta[k] for k in ("loss_max_rel", "grad_norm_max_rel",
                                 "held_share_max_abs")) < 1e-5
+
+
+def test_the_nemotron_familys_planted_faults_move_what_they_are_read_for():
+    """``families/nemotron/faults.py`` at the toy sizes: each planted fault
+    of the reference (the gated norm over one group, a head reading the
+    wrong group, the convolution's bias left out, relu for relu², gates not
+    scaled) moves the number it is read for far past what the sound
+    program reads there (under 1e-5)."""
+    from benchmark import rehearse
+    from benchmark.families.nemotron import faults
+
+    rs = faults.readings(TOKEN_CELLS[4], [2 ** 31 + 5], backend="cpu",
+                         conf_patch=rehearse.toy, prefill=256)
+    table = faults.summarize(rs)
+    assert set(table) == set(faults.FAULTS) and len(table) == 5
+    for name, row in table.items():
+        assert row["smallest"][row["planted_for"]] > 1e-3, name
 
 
 def test_the_keye_familys_planted_faults_move_the_selection():

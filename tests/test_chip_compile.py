@@ -248,6 +248,108 @@ def test_held_experts_grouped_matmul_compiles_for_v5e(one_chip, preset,
     assert f"f32[4096,{h}]" in text
 
 
+# NVIDIA-Nemotron-3-Nano-30B-A3B (config.nemotron_tokenq_config): Mamba-2
+# mixers of 64 heads of 64 with a state of 128 (8 groups, chunks of 128,
+# segments of 2 048 rows), ONE attention layer of 32 query heads over 2
+# key/value heads (a group of 16), two-matrix relu² experts of width 1 856
+# = 14.5 x 128 over hidden 2 688, on windows of 8 192 tokens.
+
+def test_attention_in_groups_of_16_compiles_for_v5e(one_chip):
+    from distributed_deep_q_tpu.config import PRESETS
+    from distributed_deep_q_tpu.ops.attention import causal_attention
+
+    cfg = PRESETS["nemotron_tokenq"]()
+    tq, t = cfg.net.tokenq, cfg.replay.sequence_length + 1
+    assert (t, tq.num_attention_heads, tq.num_key_value_heads, tq.head_dim,
+            t % tq.attn_block, any(tq.rope_layout[:7])) == (
+        8192, 32, 2, 128, 0, False)
+    S = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.bfloat16,
+                          sharding=one_chip)
+    q = S((1, tq.num_attention_heads, t, tq.head_dim))
+    kv = S((1, tq.num_key_value_heads, t, tq.head_dim))
+
+    def fwd_bwd(q, k, v):
+        f = lambda *a: jnp.sum(causal_attention(  # noqa: E731
+            *a, block=tq.attn_block,
+            compute_block=tq.attn_compute_block).astype(jnp.float32))
+        return jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+
+    text = _compiled_text(fwd_bwd, q, kv, kv)
+    for kernel in ("splash_mqa_fwd", "splash_mqa_dkv"):    # dq is fused in
+        assert kernel in text
+
+
+def test_two_matrix_experts_off_the_lanes_compile_for_v5e(one_chip):
+    """The expert layer without a gate matrix at a width no multiple of
+    128 divides: the grouped matmul takes tiles of 384 (the last one
+    overhangs and the kernel masks it), the up product reads its weights
+    as ``[held, f, h]`` — the layout the chip keeps ``[held, h, f]`` in
+    when ``f`` is off the lanes —, nothing is padded to 1 920 and no
+    tile is 1 856 wide."""
+    from distributed_deep_q_tpu.config import PRESETS
+    from distributed_deep_q_tpu.models.tokenq import ACTS
+    from distributed_deep_q_tpu.ops import moe
+
+    cfg = PRESETS["nemotron_tokenq"]()
+    tq = cfg.net.tokenq
+    b, t = cfg.replay.batch_size, cfg.replay.sequence_length + 1
+    n, k, held = b * t, tq.moe_num_active_primary_experts, tq.experts_held
+    h, f = tq.hidden_size, tq.moe_ffn_hidden_size
+    rows = moe.buffer_rows(n, k, held, tq.moe_tile)
+    assert (h, f, rows, tq.ffn_gated, tq.hidden_act) == (
+        2688, 1856, 98304, False, "relu2")
+    assert (moe._fit(f), moe._fit(h)) == (384, 384)
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+
+    def fwd_bwd(x, idx, p, wu, wd):
+        f_ = lambda x, wu, wd: jnp.sum(jnp.sin(  # noqa: E731
+            moe.held_experts_ffn(
+                x, idx, p, None, wu, wd, offset=0, rows=rows,
+                tile=tq.moe_tile, compute_dtype=jnp.bfloat16,
+                act=ACTS[tq.hidden_act])[0]))
+        return jax.grad(f_, argnums=(0, 1, 2))(x, wu, wd)
+
+    text = _compiled_text(
+        fwd_bwd, S((b, t, h), jnp.float32), S((n, k), jnp.int32),
+        S((n, k), jnp.float32), S((held, h, f), jnp.float32),
+        S((held, f, h), jnp.float32))
+    assert text.count('custom_call_target="tpu_custom_call"') >= 5
+    assert "1920" not in re.sub(r"metadata=\{[^}]*\}", "", text)
+    assert f"bf16[{held},{f},{h}]" in text      # the up matrices, as held
+    assert not re.findall(rf"\b(?:f32|bf16)\[(?:{rows}|{n * k}),\d+\]",
+                          text)
+
+
+def test_a_state_space_layer_compiles_for_v5e(one_chip):
+    """A Mamba-2 layer whole (``models/tokenq.mamba_mixer``: norm, the two
+    projections, convolution, the chunked scan, the gated group norm) at
+    the preset's sizes, forward and backward: a segment of 2 048 rows at a
+    time, so what stands beside the layer's input, output and gradients is
+    a segment's intermediates, under 2.5 GB where the whole window's were
+    4.9 GB."""
+    from distributed_deep_q_tpu.config import PRESETS
+    from distributed_deep_q_tpu.models import tokenq
+
+    cfg = PRESETS["nemotron_tokenq"]()
+    tq = cfg.net.tokenq
+    b, t = cfg.replay.batch_size, cfg.replay.sequence_length + 1
+    assert (tq.mamba_num_heads, tq.mamba_head_dim, tq.ssm_state_size,
+            tq.n_groups, tq.conv_kernel, tq.chunk_size, tq.ssm_segment,
+            t % tq.ssm_segment) == (64, 64, 128, 8, 4, 128, 2048, 0)
+    S = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32,
+                          sharding=one_chip)
+    p = jax.tree.map(S, tokenq.param_shapes(cfg.net)["layer_00"],
+                     is_leaf=lambda x: isinstance(x, tuple))
+
+    def fwd_bwd(x, p):
+        return jax.grad(lambda x, p: jnp.sum(jnp.square(
+            tokenq.mamba_mixer(x, p, cfg.net)[0])), argnums=(0, 1))(x, p)
+
+    compiled = jax.jit(fwd_bwd).lower(S((b, t, tq.hidden_size)), p).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5 * 2 ** 30
+    assert "tpu_custom_call" not in compiled.as_text()  # plain XLA, no kernel
+
+
 def _instructions(text: str) -> str:
     """The optimised module's computations alone: no module header, no
     ``metadata={...}``, none of the source tables after the computations
@@ -986,10 +1088,13 @@ def _lowered_token_train_program(topo, preset: str):
 # and k share a table) and LFM2's (a head of 64 keeps the plain form; q
 # and k now share the inverse frequencies: 9 -> 6 ``stablehlo.power``,
 # and twelve products by the factor 1.0 the compiler drops); Moonlight's
-# came out as it was. Written anew by
+# came out as it was; PR 48 added the sixth sibling (Nemotron: a state-space
+# mixer, layers of one part alone, two-matrix experts) and the five others
+# came out of the same writing as they were, to the byte. Written anew by
 # ``PYTHONPATH=. python tests/test_chip_compile.py``.
 SIBLING_PRESETS = ("keye_tokenq", "laguna_tokenq", "lfm2_tokenq",
-                   "moonlight_tokenq", "smallthinker_tokenq")
+                   "moonlight_tokenq", "nemotron_tokenq",
+                   "smallthinker_tokenq")
 SIBLING_PROGRAMS = os.path.join(os.path.dirname(__file__), "fixtures",
                                 "sibling_train_programs.json")
 

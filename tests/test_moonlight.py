@@ -648,7 +648,9 @@ def test_the_other_presets_keep_their_leaves_and_their_plan(
     assert not any(k["latent"] for k in kinds)
     assert all(k["sparse"] == ("sparse" in plan) for k in kinds)
     assert set(kinds[0]) == {"windowed", "rope", "conv", "sparse", "latent",
-                             "dense", "heads", "rope_params"}
+                             "mamba", "mixer", "ffn", "dense", "heads",
+                             "rope_params"}
+    assert all(k["mixer"] and k["ffn"] and not k["mamba"] for k in kinds)
     assert all(k["heads"] == tq.num_attention_heads
                and k["rope_params"] is None for k in kinds)
     if preset == "lfm2_tokenq":
